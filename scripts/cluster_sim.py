@@ -165,7 +165,12 @@ p.add_argument("--mesh", default=None, metavar="TPxSPxEP",
                help="run each colocated replica as a ShardedServingEngine "
                     "on this TP/SP/EP mesh serving the tiny MoE model "
                     "(--engine colocated only; implied 1x1x1 by "
-                    "--overlap)")
+                    "--overlap). Needs tp*sp*ep devices: real ones, or "
+                    "--sim")
+p.add_argument("--sim", action="store_true",
+               help="provision the virtual CPU mesh --mesh needs (the "
+                    "simulator is only ever used when asked for; too few "
+                    "live devices is otherwise an error naming the count)")
 p.add_argument("--overlap", choices=("off", "ep", "ep+sp"), default="off",
                help="fine-grained compute/comm overlap inside each "
                     "sharded replica (ISSUE 16; --engine colocated only). "
@@ -295,6 +300,11 @@ else:
     # of (params, prompt)) makes per-request traces placement-invariant.
     import jax  # noqa: E402
 
+    from triton_dist_tpu.utils.env import (configure_compile_cache,  # noqa: E402
+                                           force_virtual_cpu_devices,
+                                           require_devices)
+    configure_compile_cache()
+
     if args.mesh is not None:
         # sharded replicas (ISSUE 16): each replica is the MoE
         # ShardedServingEngine on its own TP/SP/EP mesh, overlap as
@@ -302,8 +312,10 @@ else:
         # engine pinned to overlap=off, so every verified trace is an
         # overlap-on-vs-off bit-identity witness
         tp, sp, ep = (int(d) for d in args.mesh.lower().split("x"))
-        from triton_dist_tpu.utils.env import force_virtual_cpu_devices  # noqa: E402
-        force_virtual_cpu_devices(tp * sp * ep)
+        if args.sim:
+            force_virtual_cpu_devices(tp * sp * ep)
+        else:
+            require_devices(tp * sp * ep, f"--mesh {args.mesh}")
         from triton_dist_tpu.models.moe import (MoEConfig,  # noqa: E402
                                                 init_moe_params)
         from triton_dist_tpu.serving import (ShardedServingEngine,  # noqa: E402

@@ -15,14 +15,11 @@
 #   JAX_NUM_PROCESSES=4 JAX_PROCESS_ID=<this host's index> \
 #   scripts/launch.sh python -m tutorials.t05_ag_gemm --case perf
 #
-#   # GCE/GKE TPU pods: the TPU metadata supplies everything —
-#   # jax.distributed.initialize() auto-discovers; just run:
-#   scripts/launch.sh python train_script.py
-#
 # ShmemContext.initialize_distributed() calls jax.distributed.initialize()
 # when any of JAX_COORDINATOR_ADDRESS / COORDINATOR_ADDRESS /
-# MEGASCALE_COORDINATOR_ADDRESS / TPU_WORKER_ID is set (shmem/context.py),
-# so no per-op launcher changes are needed.
+# MEGASCALE_COORDINATOR_ADDRESS is set (shmem/context.py), so no per-op
+# launcher changes are needed. TPU_WORKER_ID alone does NOT trigger it:
+# single-host TPU machines export it too.
 
 set -euo pipefail
 

@@ -11,6 +11,14 @@ matching knobs (--slots/--page-size/--layers mirror bench_serving's).
     python scripts/serve_sim.py --sim 20 --disagg --mesh 1x2x1  # composed
     python scripts/serve_sim.py --sim 30 --crash-at 25 --recover  # ISSUE 9
     python scripts/serve_sim.py --sim 40 --queue-cap 6 --ttl 50  # overload
+    python scripts/serve_sim.py --preset mistral_7b --layers 28 \
+        --workload 'n=12,plen=128:1024,mnt=32:64' ...   # on the chip
+
+``--sim N`` replays N uniform requests ON THE SIMULATOR: with ``--mesh`` /
+``--disagg`` it provisions the virtual CPU mesh they need. Without it
+(``--workload``) the live devices are driven, and too few of them is an
+error naming the count — never a silent CPU mesh (chip_smoke.py runs the
+full-width presets this way).
 
 A deliberately small --pages forces preemption-by-eviction; the replay is
 bit-deterministic (same seed => same tokens, same metrics counters), which
@@ -29,12 +37,19 @@ sys.path.insert(0, REPO)
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from triton_dist_tpu.models.llama import LlamaConfig, init_params  # noqa: E402
+from triton_dist_tpu.models import (init_moe_params, init_params,  # noqa: E402
+                                    preset_config)
 from triton_dist_tpu.serving import ServingEngine  # noqa: E402
+from triton_dist_tpu.utils.env import (configure_compile_cache,  # noqa: E402
+                                       force_virtual_cpu_devices,
+                                       require_devices)
 
 p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-p.add_argument("--sim", type=int, default=50,
-               help="number of synthetic requests to replay")
+p.add_argument("--sim", type=int, default=None,
+               help="replay this many uniform synthetic requests (default "
+                    "50) on the SIMULATOR: --mesh/--disagg then get the "
+                    "virtual CPU mesh they need. Omitted (--workload), the "
+                    "live devices are used and too few is an error")
 p.add_argument("--slots", type=int, default=4,
                help="continuous-batching slots (engine batch rows)")
 p.add_argument("--page-size", type=int, default=8,
@@ -43,7 +58,14 @@ p.add_argument("--pages", type=int, default=24,
                help="usable KV pool pages (small => forced preemption)")
 p.add_argument("--pages-per-seq", type=int, default=8,
                help="block-table width (max pages one request may own)")
-p.add_argument("--layers", type=int, default=2, help="model layers")
+p.add_argument("--preset", default="tiny",
+               help="model preset: a LlamaConfig/MoEConfig classmethod "
+                    "name (tiny, mistral_7b, mixtral_8x7b, deepseek_infer, "
+                    "...) at its published widths; an MoE-only name "
+                    "implies --model moe")
+p.add_argument("--layers", type=int, default=None,
+               help="depth override — the one cut that fits a full-width "
+                    "preset beside a KV pool (default: the preset's own)")
 p.add_argument("--max-new", type=int, default=12,
                help="max decode budget per request (uniform 2..max-new)")
 p.add_argument("--arrive-every", type=int, default=2,
@@ -76,13 +98,14 @@ p.add_argument("--disagg", action="store_true",
                     "(KV handed off by page migration; needs >= 2 devices; "
                     "--prefill-chunk defaults to 2*page_size here — chunks "
                     "ARE the migration unit)")
-p.add_argument("--model", choices=("llama", "moe"), default="llama",
-               help="'moe' serves MoEConfig.tiny through the sharded "
-                    "engine (EP MoE FFN; defaults --mesh to 1x1x1)")
+p.add_argument("--model", choices=("llama", "moe"), default=None,
+               help="'moe' serves the MoE preset through the sharded "
+                    "engine (EP MoE FFN; defaults --mesh to 1x1x1); "
+                    "default: the --preset's family, dense first")
 p.add_argument("--mesh", default=None, metavar="TPxSPxEP",
                help="serve under shard_map on this TP/SP/EP mesh, e.g. "
-                    "2x2x2 (implies --model moe; spins up tp*sp*ep "
-                    "virtual CPU devices when hardware has fewer; "
+                    "2x2x2 (implies --model moe; needs tp*sp*ep devices "
+                    "— real ones, or simulated under --sim; "
                     "--prefill-chunk defaults to 8 — the sharded engine "
                     "REQUIRES the chunked path). Combine with --disagg "
                     "for the COMPOSED engine: disaggregated prefill "
@@ -195,9 +218,16 @@ if args.recover and args.crash_at is None:
     p.error("--recover needs --crash-at")
 if args.chaos is not None:
     args.disagg = True
+simulate = args.sim is not None
+if args.sim is None:
+    args.sim = 50
 if args.mesh is not None:
     args.model = "moe"
-elif args.model == "moe":
+try:
+    args.model, cfg = preset_config(args.preset, args.model, args.layers)
+except ValueError as e:
+    p.error(str(e))
+if args.model == "moe" and args.mesh is None:
     args.mesh = "1x1x1"
 if args.overlap != "off" and (args.mesh is None or args.disagg):
     p.error("--overlap rides the sharded engine: needs --mesh (or "
@@ -235,27 +265,38 @@ elif args.prefill_buckets == "exact":
 else:
     buckets = tuple(int(b) for b in args.prefill_buckets.split(","))
 
-if args.mesh is not None:
-    # with --disagg on top, the composed engine runs BOTH fleets on this
-    # one mesh (ISSUE 12) — the device count is still tp*sp*ep
-    tp, sp, ep = (int(d) for d in args.mesh.lower().split("x"))
-    from triton_dist_tpu.utils.env import force_virtual_cpu_devices  # noqa: E402
-    force_virtual_cpu_devices(tp * sp * ep)
-elif args.disagg:
-    # the role mesh needs 2 ranks; on fewer (e.g. plain-CPU jax) fall
-    # back to the 2-device virtual CPU simulator — real chips are kept
-    from triton_dist_tpu.utils.env import force_virtual_cpu_devices  # noqa: E402
-    force_virtual_cpu_devices(2)
+configure_compile_cache()
 
-if args.model == "moe":
-    from triton_dist_tpu.models.moe import MoEConfig, init_moe_params  # noqa: E402
-    cfg = MoEConfig.tiny(n_layers=args.layers)
-    params = init_moe_params(jax.random.PRNGKey(args.seed), cfg)
-    vocab = cfg.base.vocab_size
+# device gate: with --disagg on top of --mesh the composed engine runs
+# BOTH fleets on the one mesh (ISSUE 12) — the count is still tp*sp*ep;
+# plain --disagg needs the 2-rank role mesh
+mesh_ctx = None
+if args.mesh is not None:
+    tp, sp, ep = (int(d) for d in args.mesh.lower().split("x"))
+    n_devices, what = tp * sp * ep, f"--mesh {args.mesh}"
 else:
-    cfg = LlamaConfig.tiny(n_layers=args.layers)
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
-    vocab = cfg.vocab_size
+    n_devices, what = (2 if args.disagg else 1), "--disagg"
+if n_devices > 1:
+    if simulate:
+        force_virtual_cpu_devices(n_devices)
+    else:
+        require_devices(n_devices, what)
+if args.mesh is not None:
+    # the mesh comes FIRST: weights are then born sharded on it
+    from triton_dist_tpu.serving import (serving_mesh,  # noqa: E402
+                                         serving_param_shardings)
+    mesh_ctx = serving_mesh(tp, sp, ep)
+
+# init under jit: each weight is generated straight into its final dtype
+# and placement — no f32 [L, D, F] transient, and on a mesh no device
+# ever holds more than its own shard
+init_fn = init_moe_params if args.model == "moe" else init_params
+params = jax.jit(
+    init_fn, static_argnums=1,
+    out_shardings=None if mesh_ctx is None
+    else serving_param_shardings(mesh_ctx))(
+    jax.random.PRNGKey(args.seed), cfg)
+vocab = (cfg.base if args.model == "moe" else cfg).vocab_size
 
 # multi-tenant SLO policy (ISSUE 14): both specs fail loudly NAMING the
 # bad field (argparse-style) instead of replaying a default-shaped trace
@@ -340,11 +381,10 @@ def mk_engine(fresh=False):
         # unified pool contract made the old mutual exclusion obsolete)
         import jax.numpy as jnp  # noqa: E402
 
-        from triton_dist_tpu.serving import (DisaggShardedEngine,  # noqa: E402
-                                             serving_mesh)
+        from triton_dist_tpu.serving import DisaggShardedEngine  # noqa: E402
         wire = {"auto": "auto", "fp8": jnp.float8_e4m3fn,
                 "none": None}[args.wire]
-        eng = DisaggShardedEngine(params, cfg, serving_mesh(tp, sp, ep),
+        eng = DisaggShardedEngine(params, cfg, mesh_ctx,
                                   prefill_chunk=args.prefill_chunk or 8,
                                   wire_dtype=wire, **common)
         if not fresh:
@@ -356,11 +396,10 @@ def mk_engine(fresh=False):
     elif args.mesh is not None:
         import jax.numpy as jnp  # noqa: E402
 
-        from triton_dist_tpu.serving import (ShardedServingEngine,  # noqa: E402
-                                             serving_mesh)
+        from triton_dist_tpu.serving import ShardedServingEngine  # noqa: E402
         wire = {"auto": "auto", "fp8": jnp.float8_e4m3fn,
                 "none": None}[args.wire]
-        eng = ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep),
+        eng = ShardedServingEngine(params, cfg, mesh_ctx,
                                    prefill_chunk=args.prefill_chunk or 8,
                                    wire_dtype=wire, overlap=args.overlap,
                                    long_context=args.long_context,

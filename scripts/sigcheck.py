@@ -24,8 +24,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from triton_dist_tpu.utils.env import force_virtual_cpu_devices  # noqa: E402
 
-# the migrate_pages determinism lint traces through shard_map on a 2-device
-# mesh; everything else is device-count independent
+# trace-time only, by design on the CPU: the migrate_pages determinism lint
+# traces through shard_map on a 2-device mesh; everything else is
+# device-count independent
 force_virtual_cpu_devices(2)
 
 

@@ -36,21 +36,6 @@ def main() -> None:
     me = jax.process_index()
     sharding = NamedSharding(ctx.mesh, P("x"))
 
-    # Backend capability probe FIRST: on the jax 0.4.x line the jaxlib CPU
-    # client refuses ANY computation spanning processes ("Multiprocess
-    # computations aren't implemented on the CPU backend") — the bootstrap
-    # above succeeds, the first spanning jit raises. Probe it with a tiny
-    # array so that version's pinned outcome is one explicit token the
-    # test keys on, not a traceback halfway through the real work.
-    try:
-        jax.block_until_ready(
-            jax.jit(lambda: jnp.zeros((4, 1), jnp.float32),
-                    out_shardings=sharding)())
-    except Exception as e:  # noqa: BLE001 — the token carries the type
-        print(f"MP_BACKEND_NO_MULTIPROC {type(e).__name__}: "
-              f"{str(e)[:160]}", flush=True)
-        os._exit(0)
-
     # pure-XLA collective across both processes' devices, traced into a
     # merged per-host-track profile when the harness asks for one
     from triton_dist_tpu.utils.perf import group_profile

@@ -164,9 +164,9 @@ def test_migrate_pages_exact_copy(role_ctx):
         assert (hk[1, :, d] == 100 + s).all()
         assert (hv[1, :, d] == 200 + s).all()
     assert not hk[1, :, 7].any(), "padding entry must not migrate"
-    # producer shard untouched outside its scratch page (id 0 is scratch
-    # by the migrate_pages contract — the interpret path mirror-writes it)
-    for p in range(1, Pg):
+    # producer shard untouched, scratch page (id 0) included: the
+    # transport is one-sided
+    for p in range(Pg):
         assert (hk[0, :, p] == 100 + p).all()
         assert (hv[0, :, p] == 200 + p).all()
 

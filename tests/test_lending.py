@@ -158,9 +158,9 @@ def test_lend_pages_kernel_exact_copy(role_ctx):
         assert (hk[1, :, d] == 100 + s).all()
         assert (hv[1, :, d] == 200 + s).all()
     assert not hk[1, :, 7].any(), "padding entry must not be lent"
-    # the lender keeps its pages: shard 0 is untouched outside the
-    # scratch page (id 0 — the interpret path mirror-writes it)
-    for p in range(1, Pg):
+    # the lender keeps its pages: shard 0 is untouched, scratch page
+    # (id 0) included — the transport is one-sided
+    for p in range(Pg):
         assert (hk[0, :, p] == 100 + p).all()
         assert (hv[0, :, p] == 200 + p).all()
 

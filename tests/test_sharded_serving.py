@@ -289,11 +289,6 @@ def test_tp_column_linear_xla_bitwise_ag_gemm_allclose():
     out_xla = jax.jit(lambda h, w: tp_column_linear(
         ctx, h, w, axis="tp", impl="xla"))(h, w)
     assert jnp.array_equal(out_xla, ref)
-    from triton_dist_tpu.ops.all_to_all import _interp_supports_remote_dma
-    if not _interp_supports_remote_dma():
-        pytest.skip("Pallas interpreter on this jax has no remote-DMA "
-                    "model — the ag_gemm impl cannot execute here "
-                    "(same gate the wire collectives use)")
     out_ag = jax.jit(lambda h, w: tp_column_linear(
         ctx, h, w, axis="tp", impl="ag_gemm",
         cfg=GemmConfig(block_m=8, block_n=128)))(h, w)

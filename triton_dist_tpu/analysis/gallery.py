@@ -222,8 +222,8 @@ def _dma_call(ctx: FakeContext, kernel, name: str):
         return pl.pallas_call(
             functools.partial(kernel, axis, mesh_axes),
             out_shape=jax.ShapeDtypeStruct((n * _M, 128), f32),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.DMA((n,)),
                             pltpu.SemaphoreType.DMA((n,))],
             compiler_params=pltpu.CompilerParams(
@@ -248,8 +248,8 @@ def _flag_call(ctx: FakeContext, kernel, name: str):
         return pl.pallas_call(
             functools.partial(kernel, axis, mesh_axes),
             out_shape=jax.ShapeDtypeStruct((_M, 128), f32),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.REGULAR],
             compiler_params=pltpu.CompilerParams(
                 has_side_effects=True,

@@ -319,16 +319,23 @@ def build_artifact(spec: ArtifactSpec, out_dir: str,
     if params is None:
         params = spec.init_params()
     os.makedirs(os.path.join(out_dir, _PROGRAMS), exist_ok=True)
-    cache_dir = os.path.join(out_dir, _XLA_CACHE)
-    os.makedirs(cache_dir, exist_ok=True)
-
-    # the artifact's cache must hold EVERY load-path executable — drop the
-    # min-compile-time floor for the build's duration
+    # The artifact bundles an XLA cache so a cold process finds its
+    # executables ready: the build re-points jax at that directory and
+    # rehearses the load path into it. Where the environment PLACED the
+    # cache (JAX_COMPILATION_CACHE_DIR), the directory is left alone: no
+    # re-pointing, no rehearsal, no bundled cache — a cold load then
+    # compiles the StableHLO once, into the environment's cache.
+    bundle_cache = not os.environ.get("JAX_COMPILATION_CACHE_DIR")
     old_cache = jax.config.jax_compilation_cache_dir
     old_floor = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _reset_xla_cache()
+    if bundle_cache:
+        cache_dir = os.path.join(out_dir, _XLA_CACHE)
+        os.makedirs(cache_dir, exist_ok=True)
+        # the artifact's cache must hold EVERY load-path executable — drop
+        # the min-compile-time floor for the build's duration
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        _reset_xla_cache()
 
     from jax import export as jax_export
     programs: Dict[str, Dict[str, dict]] = {}
@@ -350,11 +357,12 @@ def build_artifact(spec: ArtifactSpec, out_dir: str,
                 with open(os.path.join(out_dir, _PROGRAMS, fname),
                           "wb") as f:
                     f.write(data)
-                # rehearse the LOAD path so its XLA compile lands in the
-                # artifact cache: deserialize + jit(call) + lower/compile
-                # is byte-for-byte what a cold process will do
-                g = jax_export.deserialize(data)
-                jax.jit(g.call).lower(*rec.avals).compile()
+                if bundle_cache:
+                    # rehearse the LOAD path so its XLA compile lands in
+                    # the artifact cache: deserialize + jit(call) + lower/
+                    # compile is byte-for-byte what a cold process will do
+                    g = jax_export.deserialize(data)
+                    jax.jit(g.call).lower(*rec.avals).compile()
                 programs[ekey][name] = {
                     "file": f"{_PROGRAMS}/{fname}",
                     "digest": f"{_fnv1a_bytes(data):08x}",
@@ -363,10 +371,11 @@ def build_artifact(spec: ArtifactSpec, out_dir: str,
                 log(f"[aot]   {name}: {len(data)} bytes, "
                     f"{exp.nr_devices} device(s)")
     finally:
-        jax.config.update("jax_compilation_cache_dir", old_cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          old_floor)
-        _reset_xla_cache()
+        if bundle_cache:
+            jax.config.update("jax_compilation_cache_dir", old_cache)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                              old_floor)
+            _reset_xla_cache()
 
     if registry is not None:
         registry.save(os.path.join(out_dir, _REGISTRY))
@@ -406,8 +415,10 @@ def _reset_xla_cache() -> None:
 def _install_xla_cache(artifact_cache: str) -> None:
     """Make the artifact's persisted executables visible to this process:
     copy entries into the active compilation-cache dir when one is
-    configured (tests run under a per-suite temp cache), else point the
-    process at the artifact's own cache directory."""
+    configured (by ``JAX_COMPILATION_CACHE_DIR``, an entry point's
+    ``configure_compile_cache``, or the tests' per-suite temp cache) —
+    that directory is never replaced — else point the process at the
+    artifact's own cache directory."""
     if not os.path.isdir(artifact_cache):
         return
     active = jax.config.jax_compilation_cache_dir

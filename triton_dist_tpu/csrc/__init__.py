@@ -2,7 +2,10 @@
 csrc's pybind module ``libtriton_distributed`` → ``distributed.*`` ops,
 op_pybind.cc:34-48 — here a C ABI + ctypes, no pybind11 in the image).
 
-The library builds lazily on first import (g++ is in the base image); set
+The library builds lazily on first use (g++ is in the base image) from the
+TRACKED sources in ``csrc/`` into ``_build/``, under a name keyed by their
+content: a stale ``.so`` left in a working tree (or copied to another
+machine with fresh mtimes) can never be what gets loaded. Set
 ``TDT_NO_NATIVE=1`` to skip the native path entirely (pure-jnp fallbacks in
 ops.group_gemm keep everything functional).
 """
@@ -10,6 +13,7 @@ ops.group_gemm keep everything functional).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -17,19 +21,28 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_HERE))
-_SO = os.path.join(_HERE, "_build", "libtdt_host.so")
-_SRC = os.path.join(_REPO, "csrc")
+_SRCS = [os.path.join(_REPO, "csrc", f)
+         for f in ("moe_align.cc", "a2a_route.cc")]
 
 _lib = None
 
 
-def _build() -> None:
-    os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    srcs = [os.path.join(_SRC, "moe_align.cc"),
-            os.path.join(_SRC, "a2a_route.cc")]
+def _so_path() -> str:
+    digest = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    return os.path.join(_HERE, "_build",
+                        f"libtdt_host-{digest.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> None:
+    os.makedirs(os.path.dirname(so), exist_ok=True)
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-Wall",
-           *srcs, "-o", _SO]
+           *_SRCS, "-o", tmp]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
+    os.replace(tmp, so)       # atomic: a concurrent loader never sees half
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -41,12 +54,10 @@ def get_lib() -> ctypes.CDLL | None:
     if os.environ.get("TDT_NO_NATIVE") == "1":
         return None
     try:
-        if not os.path.exists(_SO) or any(
-                os.path.getmtime(s) > os.path.getmtime(_SO)
-                for s in [os.path.join(_SRC, "moe_align.cc"),
-                          os.path.join(_SRC, "a2a_route.cc")]):
-            _build()
-        lib = ctypes.CDLL(_SO)
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        lib = ctypes.CDLL(so)
     except (OSError, subprocess.CalledProcessError):
         return None
     lib.tdt_moe_align_padded_rows.restype = ctypes.c_int64

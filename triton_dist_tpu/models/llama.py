@@ -21,6 +21,7 @@ Design is TPU-first and functional:
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 import math
 from typing import Any
@@ -137,6 +138,28 @@ def param_specs(cfg: LlamaConfig, tp: str | None = "tp",
         "final_norm": P(None),
         "lm_head": P(None, tp),
     }
+
+
+class LayerParams(collections.abc.Mapping):
+    """Layer ``layer`` of a stacked-per-layer ``blocks`` tree, for the
+    Python-unrolled layer loops. ``p[name]`` is ``blocks[name][layer]``,
+    sliced when asked for. A consumer that can index the stacked array in
+    place takes ``p.blocks[name]`` and ``p.layer`` instead: XLA cannot
+    fuse a slice into a Pallas operand, so ``p["we_gate"]`` handed to the
+    grouped-GEMM kernels costs a layer-sized HBM copy per layer per call
+    (at Mixtral widths: the expert tables held twice)."""
+
+    def __init__(self, blocks: dict, layer: int):
+        self.blocks, self.layer = blocks, layer
+
+    def __getitem__(self, name):
+        return self.blocks[name][self.layer]
+
+    def __iter__(self):
+        return iter(self.blocks)
+
+    def __len__(self):
+        return len(self.blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -390,7 +413,7 @@ def decode_step(params: dict, token: jax.Array, pos: jax.Array,
     else:
         ks_l, vs_l = [], []
         for i in range(cfg.n_layers):
-            p = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
+            p = LayerParams(params["blocks"], i)
             x, (ck, cv) = body(x, (p, cache["k"][i], cache["v"][i]))
             ks_l.append(ck)
             vs_l.append(cv)
@@ -494,7 +517,7 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     else:
         ks_l, vs_l = [], []
         for i in range(cfg.n_layers):
-            p = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
+            p = LayerParams(params["blocks"], i)
             x, (kp, vp) = body(x, (p, pages["k"][i], pages["v"][i]))
             ks_l.append(kp)
             vs_l.append(vp)
@@ -601,7 +624,7 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     else:
         ks_l, vs_l = [], []
         for i in range(cfg.n_layers):
-            p = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
+            p = LayerParams(params["blocks"], i)
             x, (kp, vp) = body(x, (p, pages["k"][i], pages["v"][i]))
             ks_l.append(kp)
             vs_l.append(vp)
@@ -805,7 +828,7 @@ def decode_step_sp(ctx, params: dict, token: jax.Array, pos: jax.Array,
     # partitioner on every backend, and decode-step jaxprs are small
     ks_out, vs_out = [], []
     for i in range(cfg.n_layers):
-        p = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
+        p = LayerParams(params["blocks"], i)
         ck, cv = cache["k"][i], cache["v"][i]
         h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
         q = rope((h @ p["wq"]).reshape(B, 1, Hq, Dh), positions,
@@ -930,7 +953,8 @@ def forward_tp_overlap(ctx: ShmemContext, params: dict, tokens: jax.Array,
     return (x @ params["lm_head"]).astype(jnp.float32)
 
 
-__all__ = ["LlamaConfig", "init_params", "param_specs", "forward",
+__all__ = ["LlamaConfig", "LayerParams", "init_params", "param_specs",
+           "forward",
            "forward_tp_overlap", "mlp_tp_overlap", "rmsnorm", "rope",
            "block_apply", "init_kv_cache", "init_page_pool", "prefill",
            "decode_step", "decode_step_paged", "decode_multistep_paged",

@@ -208,7 +208,8 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
                        block_n: int = 128, block_k: int | None = None,
                        down_block_n: int | None = None,
                        we_gate_up_packed: jax.Array | None = None,
-                       microbatches: int = 1
+                       microbatches: int = 1,
+                       layer: int | None = None
                        ) -> jax.Array:
     """The reference's EP MoE inference block (test_ep_moe_inference.py /
     tutorial 04) on the Pallas kernel stack: router → low-latency A2A
@@ -216,8 +217,16 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
     with top-k weights.
 
     x2d [T, D] globally P(axis)-sharded token rows; router_w [D, E];
-    we_* [E, D, F]/[E, F, D] — each rank uses its local expert slice
+    we_* [E, D, F]/[E, F, D] — consumed P(axis)-sharded on the expert dim,
+    so each rank holds and uses only its local expert slice
     we_*[me*Elocal:(me+1)*Elocal].
+
+    ``layer=i``: ``we_*`` are the STACKED per-layer tables [L, E, ., .]
+    (``init_moe_params``' layout) and layer ``i`` is indexed IN PLACE —
+    the kernels stream expert ``i*Elocal + e`` of the flattened
+    [L*Elocal, ., .] view, so no layer-sized slice is ever materialized
+    for the Pallas operands (a slice XLA cannot fuse into a custom call:
+    one HBM copy of the layer's experts per layer per call otherwise).
 
     With a 2-tier layer (``EPAll2AllLayer.create(axis=(major, minor))``)
     the dispatch/combine run the hierarchical path and ``axis`` is taken
@@ -239,9 +248,9 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
     """
     from triton_dist_tpu.ops.all_to_all import QuantTokens
     from triton_dist_tpu.ops.group_gemm import (PackedGatedWeights,
-                                                apply_grouped, grouped_gemm,
+                                                apply_grouped, fit_block_k,
+                                                grouped_gemm,
                                                 grouped_gemm_gated)
-    from triton_dist_tpu.shmem import device as shd
 
     a2a = a2a_layer.a2a
     is_2d = getattr(a2a_layer, "is_2d", False)
@@ -327,9 +336,10 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
     n = ctx.axis_size(group)
 
     packed = we_gate_up_packed is not None
+    assert layer is None or not packed, (
+        "the packed gate|up layout is per-layer: pass layer=None")
 
     def expert_ffn(tok, ids, wg, wu, wd, *sc):
-        me = shd.my_pe(group)
         H = tok.shape[-1]
         rows = 1
         for d in tok.shape[:-1]:
@@ -337,14 +347,19 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
         tflat = tok.reshape(rows, H)
         iflat = ids.reshape(rows)
         sflat = sc[0].reshape(rows) if sc else None
+        # wg/wu/wd arrive as this rank's expert slice (w_spec below).
         # packed serving layout: wg carries the pre-interleaved [E, H, 2F]
         # gate‖up weights (pack_gated_weights — one double-width tile
         # stream, measured 538.9→381.5 µs for the gate+up kernel at the
         # deployed full-K (128,128) config; wu unused)
-        wg_l = lax.dynamic_slice_in_dim(wg, me * e_local, e_local)
-        wu_l = (None if packed
-                else lax.dynamic_slice_in_dim(wu, me * e_local, e_local))
-        wd_l = lax.dynamic_slice_in_dim(wd, me * e_local, e_local)
+        wg_l, wu_l, wd_l = wg, (None if packed else wu), wd
+        e_off = 0
+        if layer is not None:
+            # [L, Elocal, ., .] -> [L*Elocal, ., .] is a free view; layer
+            # i's experts start at row i*Elocal of it
+            flat = lambda w: w.reshape((-1,) + w.shape[2:])  # noqa: E731
+            wg_l, wu_l, wd_l = flat(wg), flat(wu), flat(wd)
+            e_off = layer * e_local
         if packed:
             # re-carry the pack width on the per-rank slice so the kernel
             # re-validates it (the layer-level check above ran on the full
@@ -361,8 +376,15 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
         # masked=False: apply_grouped's scatter drops invalid rows by
         # index, so the zeroing pass over each output is skipped.
         def ffn(xs, be, nb, *ss):
+            be = be + e_off
+            # K-splits adapt to the widths: full-K strips where they fit
+            # scoped VMEM (the measured-best DeepSeek config), a fitted
+            # split where they cannot (Mixtral's F=14336 down projection)
+            wsize = jnp.dtype(wd_l.dtype).itemsize
             kw = dict(block_m=block_m, block_n=block_n, n_blocks_used=nb,
-                      masked=False, block_k=block_k, packed=packed)
+                      masked=False, packed=packed,
+                      block_k=block_k or fit_block_k(
+                          H, block_m, block_n, wsize, n_weights=2))
             if ss:
                 kw["row_scale"] = ss[0]
                 kw["out_dtype"] = a2a.dtype
@@ -371,9 +393,11 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
             # serving shape (432.7 µs at bn=128 -> 199.8 at bn=512 — the
             # (F, 128) weight tiles were DMA-overhead-bound; 1024/1792
             # overshoot: 336/357 µs; scripts/moe_probe.py round 5)
-            return grouped_gemm(hh, wd_l, be, block_m=block_m,
-                                block_n=down_block_n or 512,
-                                n_blocks_used=nb, masked=False)
+            dbn = down_block_n or 512
+            return grouped_gemm(hh, wd_l, be, block_m=block_m, block_n=dbn,
+                                n_blocks_used=nb, masked=False,
+                                block_k=fit_block_k(wd_l.shape[1], block_m,
+                                                    dbn, wsize))
 
         # fp8 wire rows are cast to the compute dtype inside the gather
         # pass (Mosaic rejects fp8 x-strips in the grouped pipelines on
@@ -407,7 +431,12 @@ def moe_mlp_ep_overlap(ctx: ShmemContext, a2a_layer, x2d: jax.Array,
             return out.reshape(tok.shape[:-1] + (-1,))
         return out.reshape(n, tok.shape[-2], -1)
 
-    w_spec = P(None, None, None)
+    # expert weights enter sharded on their expert dim: each rank holds
+    # only its e_local experts. Weights committed that way (the sharded
+    # serving engine's layout) never move; replicated ones are sliced in
+    # place — no rank ever needs the whole table
+    w_spec = (P(group, None, None) if layer is None
+              else P(None, group, None, None))
     sm = ctx.shard_map(expert_ffn,
                        in_specs=(shard_spec,) * 2 + (w_spec,) * 3
                        + (shard_spec,) * (1 if quant else 0),

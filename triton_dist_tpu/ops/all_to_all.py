@@ -39,32 +39,16 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.ops.common import collective_id_for
 from triton_dist_tpu.shmem import device as shd
 from triton_dist_tpu.shmem.context import ShmemContext
-from triton_dist_tpu.utils import default_interpret, on_cpu
+from triton_dist_tpu.utils import default_interpret
 
 
 def _xla_wire(ctx: ShmemContext, axis: str) -> bool:
     """True when this axis' wire exchange must run as plain XLA collectives
     instead of the Pallas remote-DMA kernel: the host-driven DCN tier
-    (remote DMA cannot cross a slice boundary), or the CPU simulator on jax
-    builds whose interpreter has no cross-device semaphore/DMA model (the
-    0.4.x line — ``get_barrier_semaphore`` and remote copies only lower on
-    Mosaic there). ``TDT_FORCE_COMPILED=1`` still traces the kernel path
-    for the AOT topology gate."""
-    import os
-    if ctx.is_dcn_axis(axis):
-        return True
-    if os.environ.get("TDT_FORCE_COMPILED") == "1":
-        return False
-    return on_cpu() and not _interp_supports_remote_dma()
-
-
-def _interp_supports_remote_dma() -> bool:
-    """Whether Pallas interpret mode on this jax can execute the remote-DMA
-    collective kernel (TPU interpret mode with shared-memory simulation).
-    The 0.4.x generic interpreter cannot — it has no lowering for
-    ``get_barrier_semaphore`` / cross-device ``make_async_remote_copy``."""
-    return (getattr(pltpu, "InterpretParams", None) is not None
-            or getattr(pltpu, "TPUInterpretParams", None) is not None)
+    (remote DMA cannot cross a slice boundary). Every ICI axis — and the
+    CPU simulator, whose TPU interpreter models remote DMA and semaphores —
+    takes the kernel."""
+    return ctx.is_dcn_axis(axis)
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +194,10 @@ def _a2a_kernel(axis, mesh_axes, n_arrays, dequant, quant, refs):
         dequant_slot(me)
     for p in range(1, n):
         src = lax.rem(me + p, n)
-        for a in range(n_arrays):
+        # every WIRE array, i.e. including the scale wire ``quant``
+        # appends past the caller's arrays: an unwaited delivery is read
+        # before it lands and leaves its semaphore signalled at exit
+        for a in range(n_wire):
             shd.wait_recv(outs[a].at[src], recv_sems.at[a, src])
         if dequant is not None:
             dequant_slot(src)
@@ -266,7 +253,7 @@ def all_to_all_push(ctx: ShmemContext, *arrays: jax.Array,
         cap_q, H_q = arrays[0].shape[-2:]
         q_aligned = cap_q % 128 == 0 and H_q % 128 == 0
         if _xla_wire(ctx, axis) or not (fuse_quant and q_aligned):
-            # send-edge fallback (host-driven DCN tier / CPU simulator,
+            # send-edge fallback (host-driven DCN tier,
             # sub-128 caps that can't take the in-kernel (128, H) row
             # tiles, or an explicit fuse_quant=False): one XLA quantize
             # pass, then the plain quantized-wire push below
@@ -286,7 +273,7 @@ def all_to_all_push(ctx: ShmemContext, *arrays: jax.Array,
                                    fuse_dequant=fuse_dequant)
         quant = (wire_q, cap_q, H_q)
     if _xla_wire(ctx, axis):
-        # DCN tier (or CPU simulator without a remote-DMA interpreter):
+        # DCN tier:
         # remote DMA cannot cross a slice boundary — run this axis'
         # exchange as an XLA ``lax.all_to_all`` (host-driven DCN
         # transfers, XLA-scheduled). Identical slot semantics: local slot
@@ -506,7 +493,7 @@ def all_to_all_push_seg(ctx: ShmemContext, *arrays: jax.Array,
     the UNFUSED quant/dequant edges (one XLA pass outside the collective),
     whose rows are bit-identical to the fused in-kernel pipelines by
     construction (same f32 amax/divide chain — see ``_quant_slot_pipeline``).
-    DCN tiers and the CPU simulator fall back to ``all_to_all_push``'s XLA
+    DCN tiers fall back to ``all_to_all_push``'s XLA
     exchange — identical slot semantics, identical bytes."""
     del fuse_dequant, fuse_quant
     axis = axis or ctx.axis_names[0]

@@ -573,7 +573,7 @@ def pool_ag_start_local(ctx: ShmemContext, k_pages: jax.Array,
     [P, Hkv, page_size, D] sharded P(axis) on the page dim in; FULL pools
     (replicated) out, assembled in canonical page order — bitwise identical
     to the tiled ``lax.all_gather`` concatenation the non-overlapped SP
-    path uses (the DCN/CPU fallback IS that all_gather). One kernel moves
+    path uses (the DCN fallback IS that all_gather). One kernel moves
     both pools so K and V ride the wire together."""
     from triton_dist_tpu.ops.all_to_all import _xla_wire
     n = ctx.axis_size(axis)
@@ -653,10 +653,23 @@ def sp_paged_attend_write(ctx: ShmemContext, q: jax.Array,
     """
     n = ctx.axis_size(axis)
     if n == 1:
-        kp, vp = paged_kv_write(k_pages, v_pages, k_new, v_new,
-                                block_table, pos, active=active)
-        out, _ = gqa_decode_paged(q, kp, vp, block_table, kv_len)
-        return out, kp, vp
+        def single(q, kn, vn, kp, vp, bt, pos, kv_len, *act):
+            kp, vp = paged_kv_write(kp, vp, kn, vn, bt, pos,
+                                    active=act[0] if act else None)
+            out, _ = gqa_decode_paged(q, kp, vp, bt, kv_len)
+            return out, kp, vp
+
+        args = (q, k_new, v_new, k_pages, v_pages, block_table, pos, kv_len)
+        if active is not None:
+            args += (active,)
+        if ctx.num_ranks == 1:
+            return single(*args)
+        # |axis| = 1 on a wider mesh (1x1x2): the kernel still runs under
+        # shard_map, every rank on its replica — a pallas_call left to the
+        # SPMD partitioner is a replicated side-effecting op, which it
+        # refuses for interpret-mode kernels
+        return ctx.shard_map(single, in_specs=(P(),) * len(args),
+                             out_specs=(P(), P(), P()))(*args)
 
     assert k_pages.shape[0] % n == 0, (
         f"pool pages {k_pages.shape[0]} not divisible by |{axis}|={n} — "

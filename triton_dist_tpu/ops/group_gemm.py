@@ -146,6 +146,31 @@ def used_block_count(ids: jax.Array, num_experts: int, block_m: int):
     return jnp.maximum(1, jnp.sum((counts + bm - 1) // bm)).astype(jnp.int32)
 
 
+# Mosaic's scoped-VMEM stack is 16 MB; the pipelines' double-buffered
+# operand tiles get 12 of it (the budget GemmConfig is calibrated to).
+_VMEM_TILE_BUDGET = 12 * 1024 * 1024
+
+
+def fit_block_k(K: int, block_m: int, block_n: int, itemsize: int,
+                n_weights: int = 1) -> int | None:
+    """The contraction split a grouped GEMM needs at this shape: None when
+    the full-K strips fit scoped VMEM (x strip + ``n_weights`` weight
+    tiles, double-buffered), else the largest lane-multiple divisor of
+    ``K`` whose tiles plus the f32 accumulators do. Full-K is the measured
+    best where it fits (K=7168 at (128, 128)); a wide FFN's down
+    projection (K=14336 at (128, 512): 37 MB of tiles) does not."""
+    per_k = 2 * (block_m + n_weights * block_n) * itemsize
+    if K * per_k <= _VMEM_TILE_BUDGET:
+        return None
+    acc = n_weights * block_m * block_n * 4
+    for bk in range(K // 128 * 128, 0, -128):
+        if K % bk == 0 and bk * per_k + acc <= _VMEM_TILE_BUDGET:
+            return bk
+    raise ValueError(
+        f"no lane-multiple K-split of K={K} fits scoped VMEM at "
+        f"block_m={block_m}, block_n={block_n}")
+
+
 def _gemm_block(t_blk, w_blk, sc_row, out_dtype):
     """THE grouped-GEMM accumulator body, shared by the bounded and
     unbounded paths: f32 MXU accumulate, optional per-row dequant scale
@@ -872,5 +897,6 @@ def moe_ffn_local(tokens: jax.Array, ids: jax.Array, w_up: jax.Array,
 
 
 __all__ = ["align_tokens_by_expert", "used_block_count", "emit_grouped_gemm",
+           "fit_block_k",
            "grouped_gemm", "grouped_gemm_gated", "pack_gated_weights",
            "PackedGatedWeights", "apply_grouped", "moe_ffn_local"]

@@ -27,7 +27,7 @@ The page ids ride in SMEM as runtime scalars, so ONE compiled program
 serves every chunk of every request (the serving compile-guard relies on
 this); the static shape is only (pages-per-chunk max, layers, page).
 
-Entry barrier (compiled path): like ``_ag_push_kernel``, the DMA and
+Entry barrier: like ``_ag_push_kernel``, the DMA and
 chunk semaphores are physical registers reused across calls — the barrier
 keeps a fast producer's call k+1 signals out of a consumer still draining
 call k. Chunk-to-chunk overlap therefore happens at the SERVING level
@@ -35,19 +35,8 @@ call k. Chunk-to-chunk overlap therefore happens at the SERVING level
 async hardware); within a call, all (layer, page) puts are in flight at
 once and are quieted in a second pass.
 
-Interpret-mode path (the CPU cluster simulator): jax 0.4.x's generic
-Pallas interpreter emulates a remote DMA with an ``all_gather`` inside
-the discharge rule — which means every device must execute every
-``dma_start`` (SPMD-uniform, single named axis), and REGULAR-semaphore
-remote signals are unimplemented (``barrier_all`` included; the
-collective kernels' CPU failures in the seed tier-1 set are exactly
-this). So under interpret the kernel takes a symmetric variant of the
-same protocol: the consumer mirrors each put with a same-shape put into
-the PRODUCER's scratch page (keeping the emulation uniform; scratch is
-write-only garbage by contract), the chunk announcement is elided, and
-delivery ordering rides the per-page DMA semaphores alone — which is the
-TPU-native signal anyway; the landed report stays ordered after every
-delivery wait, so the host-visible contract is identical on both paths.
+The CPU simulator (Pallas TPU interpret mode) runs this same protocol —
+remote signals, the entry barrier and divergent role branches included.
 """
 
 from __future__ import annotations
@@ -65,7 +54,6 @@ from triton_dist_tpu.utils import default_interpret
 
 
 def _transport_kernel(axis, mesh_axes, producer, consumer, n_layers,
-                      interpreting,
                       n_ref, src_ref, dst_ref, tag_ref, kpool, vpool,
                       kpool_out, vpool_out, landed_ref,
                       send_k, recv_k, send_v, recv_v, chunk_sem):
@@ -81,10 +69,8 @@ def _transport_kernel(axis, mesh_axes, producer, consumer, n_layers,
     echo is grounded here, in the same report that is ordered after the
     delivery waits, not in host bookkeeping.
 
-    All pool traffic goes through the OUTPUT refs: on hardware the alias
-    makes them the same buffer, and the generic interpreter only carries
-    writes made through the output ref (aliased-input writes are dropped
-    — jax b/370563936)."""
+    All pool traffic goes through the OUTPUT refs (the alias makes them
+    the same buffer as the inputs)."""
     del kpool, vpool                  # aliased: use the output refs only
     kpool, vpool = kpool_out, vpool_out
     me = shd.my_pe(axis)
@@ -94,48 +80,7 @@ def _transport_kernel(axis, mesh_axes, producer, consumer, n_layers,
     landed_ref[0, 0] = 0
     landed_ref[0, 1] = tag_ref[0]
 
-    if interpreting:
-        # -- symmetric interpret path (module docstring) ------------------
-        is_prod = me == producer
-        peer = shd.pe_at(mesh_axes, axis,
-                         jnp.where(is_prod, consumer, producer))
-        for i in range(pmax):
-            @pl.when(i < n)
-            def _(i=i):
-                # producer sends real pages; the consumer mirrors into the
-                # peer's scratch page (id 0 — reserved, write-only)
-                s = jnp.where(is_prod, src_ref[i], 0)
-                d = jnp.where(is_prod, dst_ref[i], 0)
-                for l in range(n_layers):
-                    shd.putmem_nbi(kpool.at[l * pages + d],
-                                   kpool.at[l * pages + s],
-                                   send_k.at[l, i], recv_k.at[l, i], peer)
-                    shd.putmem_nbi(vpool.at[l * pages + d],
-                                   vpool.at[l * pages + s],
-                                   send_v.at[l, i], recv_v.at[l, i], peer)
-        for i in range(pmax):
-            @pl.when(i < n)
-            def _(i=i):
-                my_out = jnp.where(is_prod, src_ref[i], 0)   # what I sent
-                my_in = jnp.where(is_prod, 0, dst_ref[i])    # what I got
-                for l in range(n_layers):
-                    if not shd._serial():   # serialized puts already sent
-                        pltpu.make_async_copy(kpool.at[l * pages + my_out],
-                                              kpool.at[l * pages + my_out],
-                                              send_k.at[l, i]).wait()
-                        pltpu.make_async_copy(vpool.at[l * pages + my_out],
-                                              vpool.at[l * pages + my_out],
-                                              send_v.at[l, i]).wait()
-                    shd.wait_recv(kpool.at[l * pages + my_in],
-                                  recv_k.at[l, i])
-                    shd.wait_recv(vpool.at[l * pages + my_in],
-                                  recv_v.at[l, i])
-        # ordered after every delivery wait — the consumer-side read of
-        # this count is the admission gate's ground truth
-        landed_ref[0, 0] = n
-        return
-
-    # -- compiled path: the full one-sided protocol -----------------------
+    # -- the full one-sided protocol --------------------------------------
     # entry barrier: the semaphores are physical registers reused across
     # calls (see module docstring / _ag_push_kernel)
     shd.barrier_all((axis,), mesh_axes=mesh_axes)
@@ -207,8 +152,7 @@ def paged_transport(ctx: ShmemContext, pool_k: jax.Array, pool_v: jax.Array,
     global ``[n_roles, L, P, Hkv, page_size, D]`` sharded ``P(axis)``
     (each role owns an identically-shaped local pool; remote refs are
     (buffer, device) pairs, symmetric by construction). Page id 0 of each
-    local pool must be a reserved scratch page (never a live sequence's —
-    the interpret path mirror-writes the producer's).
+    local pool must be a reserved scratch page (never a live sequence's).
     ``src_ids``/``dst_ids``: ``[pmax]`` int32, replicated — producer-local
     source page ids and consumer-side destination ids, valid up to
     ``n_pages`` (``[1]`` int32). Entries past ``n_pages`` are never
@@ -225,7 +169,6 @@ def paged_transport(ctx: ShmemContext, pool_k: jax.Array, pool_v: jax.Array,
     entry barrier."""
     axis = axis or ctx.axis_names[0]
     mesh_axes = ctx.axis_names
-    interp = default_interpret()
 
     def f(n, src, dst, tg, kp, vp):
         L = kp.shape[1]
@@ -233,8 +176,7 @@ def paged_transport(ctx: ShmemContext, pool_k: jax.Array, pool_v: jax.Array,
         kpl, vpl = flat(kp), flat(vp)
         pmax = src.shape[0]
         kernel = lambda *refs: _transport_kernel(
-            axis, mesh_axes, producer, consumer, L,
-            interp is not False, *refs)
+            axis, mesh_axes, producer, consumer, L, *refs)
         ko, vo, landed = pl.pallas_call(
             kernel,
             out_shape=(jax.ShapeDtypeStruct(kpl.shape, kpl.dtype),
@@ -254,7 +196,7 @@ def paged_transport(ctx: ShmemContext, pool_k: jax.Array, pool_v: jax.Array,
             compiler_params=pltpu.CompilerParams(
                 has_side_effects=True,
                 collective_id=collective_id_for(f"{name}_{axis}")),
-            interpret=interp,
+            interpret=default_interpret(),
         )(n, src, dst, tg, kpl, vpl)
         return ko.reshape(kp.shape), vo.reshape(vp.shape), landed
 

@@ -86,7 +86,8 @@ from triton_dist_tpu.serving.scheduler import (AdmissionRejected, ClassSpec,
 from triton_dist_tpu.serving.sharded import (MESH_AXES,
                                              ReplicatedDecisionError,
                                              ShardedServingEngine,
-                                             serving_mesh)
+                                             serving_mesh,
+                                             serving_param_shardings)
 from triton_dist_tpu.serving.speculate import (ngram_draft, resolve_spec_k,
                                                spec_accept)
 from triton_dist_tpu.serving.workload import (WorkloadSpec,
@@ -99,6 +100,7 @@ __all__ = [
     "ShardedServingEngine",
     "ReplicatedDecisionError",
     "serving_mesh",
+    "serving_param_shardings",
     "MESH_AXES",
     "DisaggServingEngine",
     "DisaggShardedEngine",

@@ -172,6 +172,10 @@ class DisaggShardedEngine:
             prefix_cache=prefix_cache,
             artifact=artifact, artifact_key=self._aot_key)
         self.decode._preempt_hook = self._on_decode_preempt
+        # ONE committed weight copy: the prefill fleet dispatches the
+        # decode engine's chunk executable, so it must present the same
+        # (mesh-committed) arrays or pjit would compile a second signature
+        self.params = self.decode.params
         self.mesh_desc = self.decode.mesh_desc
         self.wire_dtype = self.decode.wire_dtype
         n_sp = ctx.axis_size("sp")

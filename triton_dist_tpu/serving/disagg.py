@@ -47,7 +47,7 @@ enter; the off-role shard runs the same program on PARKED inputs
 (prompt_len 0 / limit 0 rows write only to its own reserved scratch
 page). This is the interpret-mesh/TDT_SERIAL form of the two-process
 deployment (see docs/serving.md for the launch recipe and the
-``MP_BACKEND_NO_MULTIPROC`` caveat).
+``MP_AG_UNSUPPORTED`` CPU caveat).
 """
 
 from __future__ import annotations

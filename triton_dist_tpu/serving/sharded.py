@@ -80,6 +80,27 @@ def serving_mesh(tp: int = 1, sp: int = 1, ep: int = 1) -> ShmemContext:
                                   mesh_shape=(tp, sp, ep))
 
 
+def serving_param_shardings(ctx: ShmemContext) -> dict:
+    """Where each ``init_moe_params`` leaf lives on the serving mesh — the
+    layout the engine's hooks consume without moving a byte: dense
+    projections column-sharded over ``tp`` (``tp_column_linear``; ``wo``
+    too — the serving path has no row-parallel reduction), expert tables
+    sharded over ``ep`` on their expert dim (``moe_mlp_ep_overlap``),
+    everything else replicated. Not ``models.moe.moe_param_specs``: that
+    is the training layout (row-parallel ``wo``, experts split over ``tp``),
+    which these hooks would reshard on every dispatch."""
+    ns = lambda *spec: jax.sharding.NamedSharding(ctx.mesh, P(*spec))  # noqa: E731
+    col, expert = ns(None, None, "tp"), ns(None, "ep")
+    return {
+        "embed": ns(),
+        "blocks": {"attn_norm": ns(), "wq": col, "wk": col, "wv": col,
+                   "wo": col, "mlp_norm": ns(), "w_router": ns(),
+                   "we_gate": expert, "we_up": expert, "we_down": expert},
+        "final_norm": ns(),
+        "lm_head": ns(None, "tp"),
+    }
+
+
 def fd_attn_split_us(n_sp: int, n_layers: int, slots: int, steps: int,
                      page_kv_bytes: int, slab_row_bytes: int
                      ) -> tuple[float, float]:
@@ -277,10 +298,13 @@ class ShardedServingEngine(ServingEngine):
 
         def moe_ffn(a2a):
             def ffn(h, p):
+                # p is the unrolled loop's LayerParams view: the expert
+                # tables go in STACKED and are indexed in place
+                b = p.blocks
                 return moe_mlp_ep_overlap(ctx, a2a, h, p["w_router"],
-                                          p["we_gate"], p["we_up"],
-                                          p["we_down"], block_m=moe_block_m,
-                                          microbatches=mb)
+                                          b["we_gate"], b["we_up"],
+                                          b["we_down"], block_m=moe_block_m,
+                                          microbatches=mb, layer=p.layer)
             return ffn
 
         sp_overlap = overlap == "ep+sp"
@@ -368,6 +392,12 @@ class ShardedServingEngine(ServingEngine):
                          artifact_key=artifact_key,
                          speculate=(spec_k or None), spec_hist=spec_hist,
                          spec_bucket=spec_bucket)
+
+        # commit the weights to the mesh ONCE (a no-op for params already
+        # initialized sharded): uncommitted params would land whole on
+        # device 0 and be resharded by every dispatch
+        self.params = jax.device_put(self.params,
+                                     serving_param_shardings(ctx))
 
         # shard the pool arrays over SP on the page dim, padding the page
         # count up to a multiple of |sp|. The ALLOCATOR never learns about
@@ -529,4 +559,5 @@ class ShardedServingEngine(ServingEngine):
 
 
 __all__ = ["ShardedServingEngine", "ReplicatedDecisionError",
-           "serving_mesh", "fd_attn_split_us", "MESH_AXES"]
+           "serving_mesh", "serving_param_shardings", "fd_attn_split_us",
+           "MESH_AXES"]

@@ -32,21 +32,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _DEFAULT_CONTEXT: "ShmemContext | None" = None
 
 
-def _distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a fallback for jax
-    versions that predate the public accessor (e.g. 0.4.37 exposes only
-    ``initialize``/``shutdown``): the coordination-service client on the
-    private global state is None exactly until ``initialize`` succeeds."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:
-        return False
-
-
 def initialize_distributed(axis_names: Sequence[str] = ("x",),
                            mesh_shape: Sequence[int] | None = None,
                            seed: int = 42) -> "ShmemContext":
@@ -61,13 +46,16 @@ def initialize_distributed(axis_names: Sequence[str] = ("x",),
     global _DEFAULT_CONTEXT
     # Multi-host bootstrap. Must happen BEFORE any backend use (so no
     # jax.process_count()/jax.devices() in this guard). Opt-in via the
-    # standard coordinator env vars or TPU-pod env; failures are surfaced,
-    # not swallowed, so a pod never silently degrades to single-host.
+    # coordinator env vars ONLY; failures are surfaced, not swallowed, so a
+    # pod never silently degrades to single-host. TPU_WORKER_ID is not a
+    # trigger: single-host TPU machines export it too (the v5e host this
+    # repo runs on sets TPU_WORKER_ID=0), and initializing a cluster there
+    # waits on a coordinator that does not exist.
     multihost_env = any(os.environ.get(k) for k in (
         "JAX_COORDINATOR_ADDRESS", "COORDINATOR_ADDRESS",
-        "MEGASCALE_COORDINATOR_ADDRESS", "TPU_WORKER_ID",
+        "MEGASCALE_COORDINATOR_ADDRESS",
     ))
-    if multihost_env and not _distributed_initialized():
+    if multihost_env and not jax.distributed.is_initialized():
         # jax auto-detects only managed clusters (Slurm/MPI/GKE-TPU);
         # the explicit JAX_NUM_PROCESSES/JAX_PROCESS_ID spelling that
         # scripts/launch.sh documents for ad-hoc pods must be forwarded by
@@ -93,7 +81,7 @@ def initialize_distributed(axis_names: Sequence[str] = ("x",),
                          f"only {devices.size} available")
     if (n_mesh == devices.size and n_mesh > 1
             and devices[0].platform == "cpu"
-            and not _distributed_initialized()
+            and not jax.distributed.is_initialized()
             and os.environ.get("TDT_NO_CPU_SPARES") != "1"):
         # (n_mesh > 1: a single-device mesh has no cross-device waits to
         # deadlock — don't churn the backend for it.)
@@ -127,8 +115,7 @@ def initialize_distributed(axis_names: Sequence[str] = ("x",),
             "invalidated (set TDT_NO_CPU_SPARES=1 to opt out).",
             stacklevel=2)
         from triton_dist_tpu.utils.env import force_virtual_cpu_devices
-        force_virtual_cpu_devices(n_mesh + max(4, n_mesh),
-                                  skip_if_satisfied=False)
+        force_virtual_cpu_devices(n_mesh + max(4, n_mesh))
         devices = np.array(jax.devices())
     dev_grid = None
     if n_mesh == devices.size and devices[0].platform == "tpu":
@@ -138,11 +125,19 @@ def initialize_distributed(axis_names: Sequence[str] = ("x",),
         # topology detection feeding its AG method pick
         # (utils.py:504-607, allgather.py:54-69) — here jax's device-coords
         # mesh builder does the detection.
+        from jax.experimental import mesh_utils
         try:
-            from jax.experimental import mesh_utils
             dev_grid = mesh_utils.create_device_mesh(tuple(mesh_shape))
-        except Exception:
-            dev_grid = None   # odd topologies/subsets: fall back to order
+        except (ValueError, NotImplementedError, AssertionError) as e:
+            # a mesh shape jax cannot lay on this topology: enumeration
+            # order still works (LOGICAL ids follow the mesh, not the
+            # torus), but neighbors may no longer be ICI-adjacent — say so
+            import warnings
+            warnings.warn(
+                f"create_device_mesh{tuple(mesh_shape)} failed "
+                f"({type(e).__name__}: {e}); falling back to device "
+                "enumeration order — ring neighbors may not be "
+                "ICI-adjacent", stacklevel=2)
     if dev_grid is None:
         # Prefix subset (e.g. a 4-device test mesh on an 8-device host) or
         # non-TPU backend: plain enumeration order.
@@ -247,13 +242,6 @@ class ShmemContext:
         """SPMD-launch ``f`` over the mesh — the analog of "one process per
         GPU running this kernel" in the reference's torchrun model. Pallas
         kernels with manual DMA/semaphores do not carry varying-manual-axes
-        info, hence ``check_vma=False`` (spelled ``check_rep`` on jax
-        versions that predate the public ``jax.shard_map``, e.g. 0.4.x —
-        same knob, renamed when the API was promoted)."""
-        sm = getattr(jax, "shard_map", None)
-        if sm is not None:
-            return sm(f, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        from jax.experimental.shard_map import shard_map as sm_exp
-        return sm_exp(f, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
+        info, hence ``check_vma=False``."""
+        return jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)

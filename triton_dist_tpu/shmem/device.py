@@ -39,9 +39,8 @@ import itertools
 import os
 from typing import Sequence
 
-import jax
 from jax import lax
-from jax.experimental import pallas as pl  # noqa: F401  (re-exported for kernels)
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import faults
@@ -282,20 +281,11 @@ def wait_recv(dst_ref, recv_sem):
 
 
 def signal_read(sem_ref):
-    """Non-destructive read of the semaphore count (debug/poll).
-
-    ``semaphore_read`` moved from ``pltpu`` to ``pl`` across jax releases;
-    resolve whichever this jax exposes."""
+    """Non-destructive read of the semaphore count (debug/poll)."""
     tracer = trace.active_tracer()
     if tracer is not None:
         return tracer.signal_read(sem_ref)
-    read = getattr(pl, "semaphore_read", None) or getattr(
-        pltpu, "semaphore_read", None)
-    if read is None:
-        raise NotImplementedError(
-            "neither pl.semaphore_read nor pltpu.semaphore_read exists on "
-            f"jax {jax.__version__}")
-    return read(sem_ref)
+    return pl.semaphore_read(sem_ref)
 
 
 # -- ordering ---------------------------------------------------------------

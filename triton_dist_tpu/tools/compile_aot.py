@@ -50,9 +50,11 @@ def main(argv=None) -> int:
                          "(0 = leave the backend alone)")
     args = ap.parse_args(argv)
 
+    from triton_dist_tpu.utils.env import (configure_compile_cache,
+                                           force_virtual_cpu_devices)
+    configure_compile_cache()
     if args.devices:
-        from triton_dist_tpu.utils.env import force_virtual_cpu_devices
-        force_virtual_cpu_devices(args.devices, skip_if_satisfied=True)
+        force_virtual_cpu_devices(args.devices)
 
     if args.spec:
         with open(args.spec, encoding="utf-8") as f:

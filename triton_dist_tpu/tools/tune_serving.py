@@ -52,11 +52,9 @@ def sweep(ctx, shapes: dict, ops, d_ff: int,
           log=lambda s: None) -> list:
     """Run each requested autotuned wrapper once at the traffic-derived
     shapes; the installed default registry records each winner (or the
-    ``registry_hit`` marker when a prior run already persisted one). An op
-    whose kernel cannot execute on this backend (the 0.4.x generic
-    interpreter has no cross-device semaphore model — ops/all_to_all.py
-    ``_interp_supports_remote_dma``) is logged and skipped, never fatal.
-    Returns the list of ops that completed."""
+    ``registry_hit`` marker when a prior run already persisted one). A
+    sweep that raises is a failed tuning run, not a skipped op: the error
+    propagates. Returns the list of ops swept."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -72,12 +70,9 @@ def sweep(ctx, shapes: dict, ops, d_ff: int,
     def attempt(op, thunk, desc):
         if op not in ops:
             return
-        try:
-            thunk()
-            done.append(op)
-            log(f"{op} swept at {desc}")
-        except Exception as e:
-            log(f"{op} SKIPPED ({desc}): {type(e).__name__}: {e}")
+        thunk()
+        done.append(op)
+        log(f"{op} swept at {desc}")
 
     attempt("ag_gemm", lambda: at.ag_gemm_autotuned(
         ctx,
@@ -123,8 +118,11 @@ def main(argv=None) -> int:
                                       "trace)")
     ap.add_argument("--out", required=True, help="registry JSON to write")
     ap.add_argument("--world", type=int, default=4,
-                    help="ranks on the tuning mesh (virtual CPU devices "
-                         "are forced to match)")
+                    help="ranks on the tuning mesh (live devices; too few "
+                         "is an error naming the count)")
+    ap.add_argument("--sim", action="store_true",
+                    help="tune on a simulated --world-device CPU mesh "
+                         "instead of live devices")
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--d-ff", type=int, default=512)
     ap.add_argument("--ops",
@@ -136,8 +134,14 @@ def main(argv=None) -> int:
                          "registries)")
     args = ap.parse_args(argv)
 
-    from triton_dist_tpu.utils.env import force_virtual_cpu_devices
-    force_virtual_cpu_devices(args.world, skip_if_satisfied=True)
+    from triton_dist_tpu.utils.env import (configure_compile_cache,
+                                           force_virtual_cpu_devices,
+                                           require_devices)
+    configure_compile_cache()
+    if args.sim:
+        force_virtual_cpu_devices(args.world)
+    else:
+        require_devices(args.world, f"--world {args.world}")
 
     if args.journal:
         from triton_dist_tpu.serving.journal import ControlJournal
