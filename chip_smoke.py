@@ -26,7 +26,13 @@ process at a time, so every leg is a sequential child process under a
 timeout, all sharing one compile cache (``JAX_COMPILATION_CACHE_DIR`` when
 set, else ``<checkout>/.jax_cache``). Per-leg logs land in
 ``chiprun_out/chip_smoke/``. It measures nothing: the seconds it prints say
-that it ran, and ``"claim": null`` is part of the summary.
+that it ran, and the summary line ends with ``"claim": null``.
+
+Standard output is one JSON object per line: the probe (device, versions),
+one line per leg, the summary, and LAST exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+A platform other than ``tpu`` (without the rehearsal flag), or a probe that
+cannot start, prints nothing there and exits != 0.
 """
 
 from __future__ import annotations
@@ -187,7 +193,23 @@ def parent(rehearsal: bool) -> int:
               "rehearsal is --cpu-rehearsal)", file=sys.stderr)
         return 2
     print(json.dumps(probe), flush=True)       # FIRST line: the device
+    try:
+        summary = _run_legs(device, rehearsal)
+    except RuntimeError as e:
+        print(f"chip_smoke: FAILED — {e}", file=sys.stderr)
+        summary = None
+    if summary is not None:
+        print(json.dumps(summary), flush=True)
+    # LAST line, these keys and no others: the verdict and the device as jax
+    # reports it. Everything else is on the lines above.
+    print(json.dumps({"ok": summary is not None, "device": device}),
+          flush=True)
+    return 0 if summary is not None else 1
 
+
+def _run_legs(device: dict, rehearsal: bool) -> dict:
+    """Every leg in turn, one JSON line each; returns the run's summary.
+    Raises ``RuntimeError`` from the first leg that fails."""
     legs = {}
     one = _run_leg("one_chip", [], rehearsal, 850)
     print(json.dumps(one), flush=True)
@@ -222,9 +244,8 @@ def parent(rehearsal: bool) -> int:
         print(json.dumps(four), flush=True)
     legs["four_chip"] = four
 
-    print(json.dumps({"ok": True, "device": device, "legs": legs,
-                      "rehearsal": rehearsal, "claim": None}), flush=True)
-    return 0
+    return {"leg": "summary", "legs": legs, "rehearsal": rehearsal,
+            "claim": None}
 
 
 # ---------------------------------------------------------------------------

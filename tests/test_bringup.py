@@ -38,7 +38,7 @@ def test_chip_smoke_cpu_rehearsal():
     r = _run("chip_smoke.py", "--cpu-rehearsal")
     assert r.returncode == 0, r.stderr[-3000:]
     lines = [json.loads(ln) for ln in r.stdout.splitlines() if ln.strip()]
-    probe, last = lines[0], lines[-1]
+    probe, summary, last = lines[0], lines[-2], lines[-1]
     assert probe["device"]["platform"] == "cpu"
     assert {"jax", "jaxlib", "libtpu", "native_host_ops",
             "compile_cache"} <= set(probe)
@@ -50,9 +50,11 @@ def test_chip_smoke_cpu_rehearsal():
     assert one["reduced"] == {"n_layers": 2}
     four = next(ln for ln in lines if ln.get("leg") == "four_chip")
     assert four["skipped"] == "1 device"       # loud, never silent
-    assert last["ok"] is True and last["rehearsal"] is True
-    assert last["device"] == probe["device"]
-    assert list(last)[-1] == "claim" and last["claim"] is None
+    assert summary["leg"] == "summary" and summary["rehearsal"] is True
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
+    # the driver's contract: LAST line, exactly these keys
+    assert last == {"ok": True, "device": probe["device"]}
+    assert set(last["device"]) == {"platform", "kind", "count"}
 
 
 def test_chip_smoke_refuses_non_tpu():
