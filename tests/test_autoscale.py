@@ -23,20 +23,18 @@ THE contract, four rungs:
 Plus the closed-form rendezvous churn bound (a scale event at fleet size
 N moves <= c/N of a fixed key population) and the units underneath
 (``AttainmentWindow``, ``parse_budgets``).
-
-Every test runs under the per-test SIGALRM watchdog (test_cluster.py
-pattern).
 """
 
 import json
 import os
-import signal
 import subprocess
 import sys
 from collections import deque
 
 import numpy as np
 import pytest
+
+from conftest import WATCHDOG_S
 
 from triton_dist_tpu.serving import (Autoscaler, Cluster, ReplicaState,
                                      SimEngine, expected_tokens,
@@ -48,24 +46,7 @@ from triton_dist_tpu.shmem import FaultPlan
 
 pytestmark = [pytest.mark.autoscale, pytest.mark.serving]
 
-WATCHDOG_S = 240
 PS = 8                        # page size everywhere below
-
-
-@pytest.fixture(autouse=True)
-def autoscale_watchdog():
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"autoscale watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "an engine (or the controller loop) is hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
 
 
 def _mk_cluster(replicas=2, tmp_path=None, slots=4, **kw):
@@ -647,7 +628,6 @@ def test_cluster_sim_autoscale_100k():
     """The ISSUE 18 acceptance run at full scale: 100k requests through
     scale-ups, drains and a forced crash-mid-drain, every trace verified
     bitwise by the script's own golden gate."""
-    signal.alarm(1800)            # beyond the quick-tier watchdog
     panel, summary = _run_cluster_sim(100_000, timeout=1740)
     assert summary["verified_bit_identical"] == 100_000
     assert panel["replica_steps_saved_pct"] > 0

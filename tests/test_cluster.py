@@ -3,9 +3,10 @@ replica wrapper, and the deterministic router.
 
 THE contract, composed tier: ``DisaggShardedEngine`` — a disaggregated
 prefill fleet feeding a ``ShardedServingEngine`` decode fleet on ONE
-TP/SP/EP mesh over the unified pool contract — replays a preemption-
-heavy trace BIT-IDENTICALLY to the plain sharded engine's 1x1x1 golden
-at n∈{2,4}, with the compile guard pinned at one executable per program
+TP/SP/EP mesh over the unified pool contract — replays the trace whose
+1x1x1 golden preempts BIT-IDENTICALLY to that golden at n=2 (the whole
+trace) and n=4 (`slow`; its first four requests, as do the fault-ladder
+replays: those four do not preempt), with the compile guard pinned at one executable per program
 (the prefill fleet REUSES the decode engine's chunk executable) and the
 decode panel's ``step_prefill_tokens`` identically 0 (fault-free).
 
@@ -15,21 +16,15 @@ journals are path-namespaced so N replicas in one directory never
 cross-replay (the no-bleed test kills and restores BOTH); and a routed,
 preempted, killed-and-restored SimEngine workload matches the closed-
 form ``expected_tokens`` golden bitwise.
-
-Every test runs under the per-test SIGALRM watchdog (test_chaos.py /
-test_sharded_serving.py pattern).
 """
 
 import json
-import signal
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from triton_dist_tpu.models.llama import LlamaConfig
-from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
+from conftest import (N4_REQUESTS, N_REQUESTS, SHARDED_KW as ENGINE_KW,
+                      seeded_trace)
 from triton_dist_tpu.serving import (Cluster, ControlJournal,
                                      DisaggShardedEngine, EngineReplica,
                                      ShardedServingEngine, SimEngine,
@@ -38,52 +33,7 @@ from triton_dist_tpu.shmem.faults import FaultPlan, InjectedCrash
 
 pytestmark = [pytest.mark.cluster, pytest.mark.serving]
 
-WATCHDOG_S = 240
-N_REQUESTS = 16
 MAX_STEPS = 100_000
-WIRE = jnp.float8_e4m3fn  # pinned — "auto" resolves per rank count
-
-
-@pytest.fixture(autouse=True)
-def cluster_watchdog():
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"cluster watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "an engine (or a mesh collective) is hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
-
-
-@pytest.fixture(scope="module")
-def moe_model():
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-def _trace(n=N_REQUESTS):
-    rng = np.random.RandomState(77)
-    out = []
-    for i in range(n):
-        plen = int(rng.randint(3, 17))
-        mnt = int(rng.randint(2, 6))
-        prompt = rng.randint(1, 128, size=plen).tolist()
-        out.append((i // 2, prompt, mnt))
-    return out
-
-
-ENGINE_KW = dict(num_slots=4, page_size=8, num_pages=9, pages_per_seq=4,
-                 prefill_chunk=8, wire_dtype=WIRE)
 
 
 def _composed(moe_model, tp, sp, ep, **kw):
@@ -100,7 +50,9 @@ def golden(moe_model):
     cfg, params = moe_model
     eng = ShardedServingEngine(params, cfg, serving_mesh(1, 1, 1),
                                **ENGINE_KW)
-    return eng.run(max_steps=MAX_STEPS, arrivals=_trace())
+    out = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N_REQUESTS))
+    assert eng.metrics.counters["preemptions"] >= 1, "trace lost its bite"
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -108,18 +60,20 @@ def golden(moe_model):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.mesh
-@pytest.mark.parametrize("mesh", [(1, 2, 1), (1, 2, 2)],
-                         ids=["1x2x1", "1x2x2"])
+@pytest.mark.parametrize("mesh", [
+    (1, 2, 1),       # 5-10 s an interpreter step at n=4: over a minute
+    pytest.param((1, 2, 2), marks=pytest.mark.slow)], ids=["1x2x1", "1x2x2"])
 def test_composed_bit_identical_to_sharded_golden(moe_model, golden, mesh):
     """ISSUE 12 acceptance: the disagg demo with its decode role under
     shard_map on a TP/SP(/EP) mesh, per-request trace bit-identical to
     the n=1 golden at n∈{2,4} — plus the compile guard (ONE chunk
     executable SHARED by both fleets, one decode, one migration copy)
     and the decode-panel prefill-isolation invariant."""
+    n = N4_REQUESTS if mesh == (1, 2, 2) else N_REQUESTS
     eng = _composed(moe_model, *mesh)
-    out = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
-    assert set(out) == set(golden)
-    for rid in golden:
+    out = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(n))
+    assert set(out) == set(range(n))
+    for rid in out:
         assert out[rid] == golden[rid], (
             f"rid {rid} diverged on composed mesh {eng.mesh_desc}: "
             f"{out[rid]} != {golden[rid]}")
@@ -128,11 +82,32 @@ def test_composed_bit_identical_to_sharded_golden(moe_model, golden, mesh):
                                  "migrate_compiles": 1}
     # every request went through the full remote pipeline...
     c, d = eng.metrics.counters, eng.metrics_decode.counters
-    assert c["handoffs"] == N_REQUESTS and d["handoffs"] == N_REQUESTS
+    assert c["handoffs"] == n and d["handoffs"] == n
     assert c["pages_migrated"] > 0
     # ...and the decode fleet never prefilled a token (fault-free run)
     assert eng.metrics_decode.hist["step_prefill_tokens"].max in (0, None)
     assert d["degradations"] == 0 and d["failed_requests"] == 0
+
+
+@pytest.mark.mesh
+def test_composed_engine_at_1x2x2_is_one_pool_contract(moe_model):
+    """Tier 1's stand-in for the 1x2x2 replay (`slow`), nothing dispatched:
+    both fleets' pools share ONE sp-aware shape on the 4-device mesh, no
+    program exists before the first dispatch, and neither pool will ship
+    a scratch or an SP padding page."""
+    from triton_dist_tpu.serving.kv_pool import PageLedgerError
+    eng = _composed(moe_model, 1, 2, 2)
+    assert eng.mesh_desc == "1x2x2" and eng.decode.n_ranks == 4
+    assert eng.compile_stats == {"prefill_chunk_compiles": 0,
+                                 "decode_compiles": 0, "migrate_compiles": 0}
+    p, d = eng.alloc_p, eng.decode.alloc
+    assert p.sp_ranks == d.sp_ranks == 2
+    assert p.device_pages == d.device_pages and p.device_pages % 2 == 0
+    pages = p.alloc("r", 2)
+    p.check_migratable("r", pages)
+    for bad in (0, p.num_pages):
+        with pytest.raises(PageLedgerError):
+            p.check_migratable("r", [bad])
 
 
 @pytest.mark.mesh
@@ -142,11 +117,11 @@ def test_composed_retry_rung_recovers_bit_identical(moe_model, golden):
     eng = _composed(moe_model, 1, 2, 1,
                     fault_plan=FaultPlan(seed=11, p_drop=0.25),
                     signal_deadline_steps=2, max_retries=4)
-    out = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
+    out = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N4_REQUESTS))
     d = eng.metrics_decode.counters
     assert d["retries"] > 0, "drop plan should have forced retries"
     assert d["failed_requests"] == 0
-    assert out == {rid: golden[rid] for rid in out} and len(out) == len(golden)
+    assert out == {rid: golden[rid] for rid in range(N4_REQUESTS)}
 
 
 @pytest.mark.mesh
@@ -160,13 +135,11 @@ def test_composed_degrade_rung_local_reprefill_bit_identical(moe_model,
     eng = _composed(moe_model, 1, 2, 1,
                     fault_plan=FaultPlan(seed=19, p_drop=1.0, rids=(1, 3)),
                     signal_deadline_steps=2, max_retries=1)
-    out = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
+    out = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N4_REQUESTS))
     d = eng.metrics_decode.counters
     assert d["degradations"] >= 1
     assert d["failed_requests"] == 0
-    assert set(out) == set(golden)
-    for rid in golden:
-        assert out[rid] == golden[rid]
+    assert out == {rid: golden[rid] for rid in range(N4_REQUESTS)}
     # degraded requests DID re-prefill on the decode fleet
     assert eng.metrics_decode.counters["prefill_chunks"] > 0
 
@@ -181,9 +154,9 @@ def test_composed_crash_recover_bit_identical(moe_model, golden, tmp_path):
     jpath = str(tmp_path / "composed.jsonl")
     journal = ControlJournal(path=jpath)
     eng = _composed(moe_model, 1, 2, 1, journal=journal,
-                    checkpoint_every=8,
-                    fault_plan=FaultPlan(seed=0, crash_at=(12,)))
-    arrivals = _trace()
+                    checkpoint_every=2,
+                    fault_plan=FaultPlan(seed=0, crash_at=(4,)))
+    arrivals = seeded_trace(N4_REQUESTS)
     with pytest.raises(InjectedCrash):
         eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
     done = sum(1 for e in journal.entries
@@ -191,13 +164,11 @@ def test_composed_crash_recover_bit_identical(moe_model, golden, tmp_path):
     assert 0 < done
     j2 = ControlJournal.load(jpath)
     eng2 = _composed(moe_model, 1, 2, 1, journal=j2,
-                     fault_plan=FaultPlan(seed=0, crash_at=(12,)))
+                     fault_plan=FaultPlan(seed=0, crash_at=(4,)))
     out = eng2.run(max_steps=MAX_STEPS, arrivals=arrivals[done:],
                    recover=True)
     assert eng2.metrics.counters["restores"] == 1
-    assert set(out) == set(golden)
-    for rid in golden:
-        assert out[rid] == golden[rid]
+    assert out == {rid: golden[rid] for rid in range(N4_REQUESTS)}
 
 
 # ---------------------------------------------------------------------------

@@ -21,12 +21,8 @@ Plus the kernel in isolation (``ops.lend_pages`` — the transport copy
 where the LENDER KEEPS its pages, unlike migration) and the ledger /
 index units underneath (``check_lendable`` sole-ownership gating,
 ``ReplicaPrefixIndex.prune``/``reassign``).
-
-Every test runs under the per-test SIGALRM watchdog (test_cluster.py
-pattern).
 """
 
-import signal
 
 import jax
 import jax.numpy as jnp
@@ -43,25 +39,8 @@ from triton_dist_tpu.shmem.context import initialize_distributed
 
 pytestmark = [pytest.mark.lending, pytest.mark.serving]
 
-WATCHDOG_S = 240
 PS = 8                        # page size everywhere below
 BORROWER_ROLE = 1             # 2-rank lend mesh: lender=0, borrower=1
-
-
-@pytest.fixture(autouse=True)
-def lending_watchdog():
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"lending watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "an engine (or a lend ladder) is hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
 
 
 @pytest.fixture(scope="module")

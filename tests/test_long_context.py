@@ -5,9 +5,11 @@ engine.
 THE contract: ``long_context=True`` flips the SP attention leg from the
 pool-allgather walk to ``flash_decode_dist`` — one request's KV pages
 round-robined across the SP shards (``KVPagePool(layout="interleaved")``),
-per-rank attention compute ∝ kv_len/n — and a 50-request forced-preemption
-trace served on an n>1 interpret mesh is still BIT-IDENTICAL per request
-to the n=1 golden. Two goldens, in fact:
+per-rank attention compute ∝ kv_len/n — and a forced-preemption
+trace served on an n>1 interpret mesh (sp=2 in tier 1, sp=4 `slow`: the
+trace's first four requests on the ``N4_PAGES`` pool, a preemption on the
+interleaved pool asserted in both) is still BIT-IDENTICAL per request to
+the n=1 golden. Two goldens, in fact:
 
 - the long-context engine at mesh 1x1x1 (same code path, n=1 fold), and
 - the PLAIN (``long_context=False``) engine at 1x1x1 — layout and op
@@ -19,26 +21,18 @@ the ``long``/``lplen`` workload population and its RNG-stream-preserving
 ``long=0`` form, ``parse_slo``'s 3-class long tier, the modeled
 ``fd_attn_split_us`` sublinearity, and the per-class ``chunk_budget``
 drip (runtime scalar — one compiled chunk program).
-
-Every test runs under the per-test SIGALRM watchdog (test_chaos.py
-pattern): a mesh-collective hang must kill the test loudly, not stall
-the suite.
 """
 
 import dataclasses
-import signal
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
-from triton_dist_tpu.models.llama import LlamaConfig, init_params
-from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
+from conftest import (N4_PAGES, N4_REQUESTS, N_REQUESTS,
+                      assert_replay_identical, seeded_trace, sharded_engine)
 from triton_dist_tpu.ops import flash_decode_dist
-from triton_dist_tpu.serving import (ServingEngine, ShardedServingEngine,
-                                     serving_mesh)
+from triton_dist_tpu.serving import ServingEngine, serving_mesh
 from triton_dist_tpu.serving.kv_pool import KVPagePool, PageLedgerError
 from triton_dist_tpu.serving.scheduler import ClassSpec, SLOPolicy
 from triton_dist_tpu.serving.sharded import fd_attn_split_us
@@ -47,84 +41,14 @@ from triton_dist_tpu.serving.workload import (WorkloadSpec, generate_arrivals,
 
 pytestmark = [pytest.mark.longctx, pytest.mark.serving]
 
-WATCHDOG_S = 240          # per-test wall cap — generous, CPU CI is slow
-N_REQUESTS = 50
 MAX_STEPS = 100_000       # engine's own stall watchdog trips far earlier
-WIRE = jnp.float8_e4m3fn  # pinned (NOT "auto") — see test_sharded_serving
-
-
-@pytest.fixture(autouse=True)
-def longctx_watchdog():
-    """Hard per-test wall-clock watchdog (test_chaos.py pattern): SIGALRM,
-    not a thread, so even a wedged collective inside jax is interrupted."""
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"longctx watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "a mesh collective (or the engine) is hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
 
 
 # --------------------------------------------------------- engine fixtures
-@pytest.fixture(scope="module")
-def moe_model():
-    """Micro MoE (test_sharded_serving.py shape): the smallest config that
-    exercises every sharded path."""
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-@pytest.fixture(scope="module")
-def tiny_model():
-    cfg = dataclasses.replace(
-        LlamaConfig(vocab_size=128, d_model=32, n_layers=1, n_heads=2,
-                    n_kv_heads=1, d_ff=64, max_seq_len=64),
-        dtype=jnp.float32)
-    params = init_params(jax.random.key(1), cfg)
-    return cfg, params
-
-
-def _trace():
-    """50 requests, bursty arrivals (two per step) against a 9-page pool —
-    growth-driven preemption is forced, not incidental. Deterministic,
-    and deliberately the SAME trace test_sharded_serving.py replays: the
-    long-context engine must serve the ordinary workload too."""
-    rng = np.random.RandomState(77)
-    out = []
-    for i in range(N_REQUESTS):
-        plen = int(rng.randint(3, 17))
-        mnt = int(rng.randint(2, 6))
-        prompt = rng.randint(1, 128, size=plen).tolist()
-        out.append((i // 2, prompt, mnt))
-    return out
-
-
-def _engine(moe_model, tp, sp, ep, **kw):
-    cfg, params = moe_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 9)          # tight: forces preemption
-    kw.setdefault("pages_per_seq", 4)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("wire_dtype", WIRE)
+def _serve(moe_model, tp, sp, ep, n=N_REQUESTS, **kw):
     kw.setdefault("long_context", True)
-    return ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep), **kw)
-
-
-def _serve(moe_model, tp, sp, ep, **kw):
-    eng = _engine(moe_model, tp, sp, ep, **kw)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
+    eng = sharded_engine(moe_model, tp, sp, ep, **kw)
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(n))
     m = eng.metrics
     return {"tokens": tokens, "compiles": eng.compile_stats,
             "counters": dict(m.counters),
@@ -142,23 +66,39 @@ def golden(moe_model):
 
 @pytest.fixture(scope="module")
 def n2_run(moe_model):
-    return _serve(moe_model, 1, 2, 1)
+    """sp=2: the trace's first four on the pool where they still preempt."""
+    return _serve(moe_model, 1, 2, 1, n=N4_REQUESTS, num_pages=N4_PAGES)
 
 
 @pytest.fixture(scope="module")
 def n4_run(moe_model):
     """sp=4 with the OTHER decode horizon: K=4 multi-token dispatches —
-    the trace must still replay the K=1 n=1 golden exactly."""
-    return _serve(moe_model, 1, 4, 1, decode_horizon=4)
+    the trace's first four must still replay the K=1 n=1 golden exactly."""
+    return _serve(moe_model, 1, 4, 1, n=N4_REQUESTS, num_pages=N4_PAGES,
+                  decode_horizon=4)
 
 
 # --------------------------------------------- engine cross-mesh bitwise
+def test_longctx_trace_forces_preemption(golden):
+    """The contract is vacuous unless preemption actually fires — and
+    every request must still finish."""
+    assert golden["counters"]["preemptions"] >= 1
+    assert len(golden["tokens"]) == N_REQUESTS
+
+
+def _assert_preempted_and_identical(run, golden):
+    assert_replay_identical(run["tokens"], golden["tokens"], N4_REQUESTS)
+    assert run["counters"]["preemptions"] >= 1, \
+        "the interleaved pool never preempted across chips"
+
+
 def test_longctx_n2_bitwise(golden, n2_run):
-    assert n2_run["tokens"] == golden["tokens"]
+    _assert_preempted_and_identical(n2_run, golden)
 
 
+@pytest.mark.slow          # 5-10 s an interpreter step at n=4: over a minute
 def test_longctx_n4_bitwise(golden, n4_run):
-    assert n4_run["tokens"] == golden["tokens"]
+    _assert_preempted_and_identical(n4_run, golden)
 
 
 def test_longctx_n1_equals_replicated(moe_model, golden):
@@ -169,30 +109,35 @@ def test_longctx_n1_equals_replicated(moe_model, golden):
     assert plain["layout"] == "blocked"
 
 
-def test_longctx_trace_forces_preemption(golden):
-    """The contract is vacuous unless preemption actually fires — and
-    every request must still finish."""
-    assert golden["counters"]["preemptions"] >= 1
-    assert len(golden["tokens"]) == N_REQUESTS
-
-
-def test_longctx_one_program_per_path(n4_run):
+def test_longctx_one_program_per_path(n2_run):
     """ONE decode program, ONE chunk program at n>1 — the interleaved
     layout and the fold are runtime data, never a shape."""
-    assert n4_run["compiles"]["decode_compiles"] == 1
-    assert n4_run["compiles"]["prefill_chunk_compiles"] == 1
+    assert n2_run["compiles"]["decode_compiles"] == 1
+    assert n2_run["compiles"]["prefill_chunk_compiles"] == 1
 
 
-def test_longctx_layout_and_attn_metrics(golden, n4_run):
+def test_longctx_layout_and_attn_metrics(golden, n2_run):
     """long_context flips the pool to interleaved, and the modeled
     attention split lands in the histograms: the fold-wait half is zero
-    at n=1 (nothing to fold) and strictly positive at n=4."""
+    at n=1 (nothing to fold) and strictly positive at n=2."""
     assert golden["layout"] == "interleaved"
-    assert n4_run["layout"] == "interleaved"
-    assert n4_run["attn_count"] > 0
-    assert (n4_run["attn_local_mean"] or 0.0) > 0.0
-    assert (n4_run["attn_fold_mean"] or 0.0) > 0.0
+    assert n2_run["layout"] == "interleaved"
+    assert n2_run["attn_count"] > 0
+    assert (n2_run["attn_local_mean"] or 0.0) > 0.0
+    assert (n2_run["attn_fold_mean"] or 0.0) > 0.0
     assert (golden["attn_fold_mean"] or 0.0) == 0.0
+
+
+def test_longctx_engine_at_sp4_interleaves_a_padded_pool(moe_model):
+    """Tier 1's stand-in for the sp=4 replay (`slow`), nothing dispatched:
+    the engine a 1x4x1 mesh builds owns an interleaved pool padded to the
+    SP axis, with every request's pages round-robined over the four ranks."""
+    eng = sharded_engine(moe_model, 1, 4, 1, long_context=True)
+    pool = eng.alloc
+    assert eng.mesh_desc == "1x4x1" and pool.layout == "interleaved"
+    assert pool.device_pages == 12 and pool.device_pages % 4 == 0
+    assert sorted(pool.page_shard(p) for p in range(1, 9)) == \
+        [0, 0, 1, 1, 2, 2, 3, 3]
 
 
 # ------------------------------------------------- op-level bit-identity
@@ -361,8 +306,8 @@ def test_fd_attn_split_model_is_sublinear():
 
 
 # --------------------------------------------- per-class chunk budget
-def _colocated(tiny_model, **kw):
-    cfg, params = tiny_model
+def _colocated(micro_model, **kw):
+    cfg, params = micro_model
     kw.setdefault("num_slots", 4)
     kw.setdefault("page_size", 8)
     kw.setdefault("num_pages", 16)
@@ -372,7 +317,7 @@ def _colocated(tiny_model, **kw):
     return ServingEngine(params, cfg, **kw)
 
 
-def test_long_chunk_budget_drips_without_recompiling(tiny_model):
+def test_long_chunk_budget_drips_without_recompiling(micro_model):
     """A ``chunk_budget=2`` long class drips a 24-token prompt through
     the ONE compiled chunk program two real tokens at a time — the
     shrink is a runtime scalar (compile count stays 1, ``chunk_shrinks``
@@ -382,11 +327,11 @@ def test_long_chunk_budget_drips_without_recompiling(tiny_model):
     arrivals = [(0, rng.randint(1, 128, size=24).tolist(), 2,
                  "l0", "long")]
     slo = SLOPolicy.chat_batch(long_weight=1, long_chunk_budget=2)
-    eng = _colocated(tiny_model, slo=slo)
+    eng = _colocated(micro_model, slo=slo)
     tokens = eng.run(max_steps=MAX_STEPS, arrivals=list(arrivals))
     assert len(tokens) == 1
     assert eng.metrics.counters["chunk_shrinks"] >= 10   # ~12 clamped
     assert eng.compile_stats["prefill_chunk_compiles"] == 1
-    base = _colocated(tiny_model)
+    base = _colocated(micro_model)
     assert base.run(max_steps=MAX_STEPS, arrivals=list(arrivals)) == tokens
     assert base.metrics.counters.get("chunk_shrinks", 0) == 0

@@ -67,7 +67,7 @@ def _run_cluster(via_launch_sh):
     outs = []
     try:
         for p in procs:
-            # generous: the worker ends with a 45 s overlap-kernel
+            # generous: the worker ends with a 20 s overlap-kernel
             # watchdog, and a fully loaded CI box stretches everything
             out, _ = p.communicate(timeout=420)
             outs.append(out)
@@ -133,7 +133,7 @@ def test_two_process_merged_profile(tmp_path):
     outs = []
     try:
         for p in procs:
-            # generous: the worker ends with a 45 s overlap-kernel
+            # generous: the worker ends with a 20 s overlap-kernel
             # watchdog, and a fully loaded CI box stretches everything
             out, _ = p.communicate(timeout=420)
             outs.append(out)

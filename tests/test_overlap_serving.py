@@ -6,11 +6,13 @@ THE claim under test: ``overlap="ep"`` (microbatched EP dispatch riding
 the segmented counted-signal a2a, expert FFN overlapping the next
 microbatch's wire) and ``overlap="ep+sp"`` (plus start-local SP pool
 assembly under the allgather) move the SCHEDULE only — every combine is
-still a concat or fixed-order fold — so the 50-request forced-preemption
-trace is BIT-IDENTICAL to the overlap=off n=1 golden at every mesh size,
-decode horizon and chunk size. The fast tier covers n∈{1,2,4}, K∈{1,4}
-and chunk∈{4,8} across its runs; the slow tier fills in the full cross
-product.
+still a concat or fixed-order fold — so the trace is BIT-IDENTICAL to
+the overlap=off n=1 golden at every mesh size, decode horizon and chunk
+size. At n=1 that is the forced-preemption trace; the runs across chips
+replay its first four requests on the ``N4_PAGES`` pool, where they preempt
+too (asserted in every run of the matrix and in the chaos replay).
+The fast tier covers n∈{1,2} at K=1, chunk 8; the slow tier fills in the
+n∈{1,2,4} × K∈{1,4} × chunk∈{4,8} cross product.
 
 Also covered: the one-decode + one-chunk compile-count guard stays
 pinned with overlap on; a PR 7-style chaos schedule (seeded digest skew
@@ -25,14 +27,12 @@ suite: auto resolves per rank count, a pinned wire makes every run
 quantize identically).
 """
 
-import signal
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
+from conftest import (N4_PAGES, N4_REQUESTS, N_REQUESTS, SHARDED_KW,
+                      assert_replay_identical, seeded_trace, sharded_engine)
 from triton_dist_tpu.models.llama import LlamaConfig
 from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
 from triton_dist_tpu.serving import ShardedServingEngine, serving_mesh
@@ -41,10 +41,8 @@ from triton_dist_tpu.shmem import FaultPlan
 
 pytestmark = [pytest.mark.mesh, pytest.mark.serving]
 
-WATCHDOG_S = 240
-N_REQUESTS = 50
+WIRE = SHARDED_KW["wire_dtype"]
 MAX_STEPS = 100_000
-WIRE = jnp.float8_e4m3fn  # pinned (NOT "auto") — see module docstring
 
 # exactly one compiled program per path, regardless of overlap mode —
 # overlap must not fork the program cache
@@ -52,60 +50,13 @@ ONE_OF_EACH = {"decode_compiles": 1, "prefill_compiles": 0,
                "prefill_programs": 0, "prefill_chunk_compiles": 1}
 
 
-@pytest.fixture(autouse=True)
-def mesh_watchdog():
-    """Per-test SIGALRM wall cap (test_sharded_serving.py pattern)."""
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"mesh watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "a mesh collective (or the engine) is hanging")
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
-
-
-@pytest.fixture(scope="module")
-def moe_model():
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-def _trace(n=N_REQUESTS):
-    """The sharded suite's 50-request bursty trace against a 9-page pool:
-    growth-driven preemption is forced, not incidental."""
-    rng = np.random.RandomState(77)
-    out = []
-    for i in range(n):
-        plen = int(rng.randint(3, 17))
-        mnt = int(rng.randint(2, 6))
-        out.append((i // 2, rng.randint(1, 128, size=plen).tolist(), mnt))
-    return out
-
-
-def _engine(moe_model, tp, sp, ep, **kw):
-    cfg, params = moe_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 9)          # tight: forces preemption
-    kw.setdefault("pages_per_seq", 4)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("wire_dtype", WIRE)
-    return ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep), **kw)
-
-
 def _serve(moe_model, tp, sp, ep, **kw):
-    eng = _engine(moe_model, tp, sp, ep, **kw)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
-    return {"tokens": tokens, "compiles": eng.compile_stats,
+    n = N_REQUESTS
+    if tp * sp * ep > 1:
+        n, kw = N4_REQUESTS, {"num_pages": N4_PAGES, **kw}
+    eng = sharded_engine(moe_model, tp, sp, ep, **kw)
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(n))
+    return {"tokens": tokens, "n": n, "compiles": eng.compile_stats,
             "engine": eng, "snap": eng.metrics.snapshot()}
 
 
@@ -126,17 +77,12 @@ def golden(moe_model):
     return get
 
 
-def _assert_identical(tokens, gold):
-    assert tokens.keys() == gold.keys()
-    bad = [r for r in gold if tokens[r] != gold[r]]
-    assert not bad, f"token streams diverged from n=1 golden: rids {bad}"
-
-
 # -- the bit-identity matrix -------------------------------------------------
 # fast tier: the two cheapest corners (n=1 degenerate + the canonical n=2
 # ep+sp case) keep the quick suite inside the tier-1 time budget; the slow
 # tier completes the n∈{1,2,4} × K∈{1,4} × chunk∈{4,8} × mode cross
-# product (every combo runs the full 50-request forced-preemption trace).
+# product (n=1 runs the forced-preemption trace, n>1 its first four on the
+# N4_PAGES pool).
 
 _FAST = [
     (1, 1, 1, 1, 8, "ep+sp"),
@@ -158,11 +104,12 @@ _SLOW = [
 def _run_matrix_case(moe_model, golden, tp, sp, ep, horizon, chunk, mode):
     run = _serve(moe_model, tp, sp, ep, decode_horizon=horizon,
                  prefill_chunk=chunk, overlap=mode)
-    _assert_identical(run["tokens"], golden(horizon, chunk))
+    assert_replay_identical(run["tokens"], golden(horizon, chunk), run["n"])
     # compile guard: overlap still compiles exactly ONE decode + ONE
     # chunk program at this mesh size
     assert run["compiles"] == ONE_OF_EACH, run["compiles"]
     assert run["engine"].overlap == mode
+    assert run["engine"].metrics.counters["preemptions"] >= 1
     assert run["engine"].overlap_microbatches == 2   # the tuned default
 
 
@@ -181,25 +128,23 @@ def test_overlap_bit_identical_full(moe_model, golden, tp, sp, ep, horizon,
 
 # -- chaos replay with overlap on --------------------------------------------
 
-def test_chaos_digest_skew_replay_with_overlap(moe_model):
+def test_chaos_digest_skew_replay_with_overlap(moe_model, golden):
     """A seeded fault schedule (transient digest skew through the PR 9
-    restore rung) replayed with overlap ON: the divergence is absorbed
-    exactly once and the tokens still match the overlap=off run of the
-    SAME schedule — overlap changes nothing the control plane can see."""
-    arrivals = _trace(20)
-
-    def run(overlap):
-        eng = _engine(moe_model, 1, 1, 2, journal=ControlJournal(),
-                      checkpoint_every=4, digest_every=1, overlap=overlap,
-                      fault_plan=FaultPlan(seed=5, digest_skew_at=(9,)))
-        toks = eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
-        return toks, eng.metrics.counters
-
-    toks_off, _ = run("off")
-    toks_on, c = run("ep+sp")
+    restore rung) replayed with overlap ON across two chips (the trace's
+    first four requests, preempting on the N4_PAGES pool): the
+    divergence is absorbed exactly once and the tokens still match the
+    fault-free overlap=off n=1 golden — neither the fault nor overlap
+    changes anything a request can see."""
+    eng = sharded_engine(moe_model, 1, 1, 2, journal=ControlJournal(),
+                         checkpoint_every=2, digest_every=1, overlap="ep+sp",
+                         num_pages=N4_PAGES,
+                         fault_plan=FaultPlan(seed=5, digest_skew_at=(5,)))
+    toks = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N4_REQUESTS))
+    c = eng.metrics.counters
     assert c["digest_recoveries"] == 1
     assert c["faults_injected"] >= 1
-    assert toks_on == toks_off
+    assert c["preemptions"] >= 1
+    assert_replay_identical(toks, golden(1, 8), N4_REQUESTS)
 
 
 # -- tuned-key gate ----------------------------------------------------------
@@ -248,18 +193,20 @@ def test_overlap_mb_tuned_key_gated_and_consumed():
 
 
 def test_overlap_mb_explicit_overrides_registry(moe_model):
-    eng = _engine(moe_model, 1, 1, 1, overlap="ep", overlap_microbatches=1)
+    eng = sharded_engine(moe_model, 1, 1, 1, overlap="ep",
+                         overlap_microbatches=1)
     assert eng.overlap_microbatches == 1
 
 
 def test_overlap_rejects_indivisible_microbatch(moe_model):
     with pytest.raises(AssertionError, match="microbatch"):
-        _engine(moe_model, 1, 1, 1, overlap="ep", overlap_microbatches=3)
+        sharded_engine(moe_model, 1, 1, 1, overlap="ep",
+                       overlap_microbatches=3)
 
 
 def test_overlap_rejects_unknown_mode(moe_model):
     with pytest.raises(AssertionError, match="overlap"):
-        _engine(moe_model, 1, 1, 1, overlap="sp")
+        sharded_engine(moe_model, 1, 1, 1, overlap="sp")
 
 
 # -- exposed/overlapped comm split -------------------------------------------
@@ -269,8 +216,8 @@ def test_comm_split_metrics(moe_model):
     overlap=off exposes everything, overlap=on hides a strictly positive
     share at n>1, and n=1 (no wire) observes zeros on both."""
     def split(tp, sp, ep, overlap):
-        eng = _engine(moe_model, tp, sp, ep, overlap=overlap)
-        eng.run(max_steps=MAX_STEPS, arrivals=_trace(6))
+        eng = sharded_engine(moe_model, tp, sp, ep, overlap=overlap)
+        eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(1))
         s = eng.metrics.snapshot()
         return (s["exposed_comm_us"]["mean"],
                 s["overlapped_comm_us"]["mean"])

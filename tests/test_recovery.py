@@ -1,5 +1,7 @@
 """Crash-consistent serving (ISSUE 9): journal/checkpoint/restore held to
-the bit-identity contract on all three engines.
+the bit-identity contract on all three engines (the colocated engine here;
+the sharded mesh, the digest rung and the disaggregated pair in
+test_recovery_mesh.py; the crash/recover harness both share is conftest's).
 
 The trace-determinism contract (greedy argmax decode + LIFO page
 allocation + strict-FIFO scheduling) makes every request's tokens a pure
@@ -10,7 +12,7 @@ cursor 0, and regenerates bit-identical tokens through the
 already-compiled programs. The tests pin exactly that:
 
 - **crash sweep**: inject ``InjectedCrash`` at strided steps of the
-  50-request forced-preemption trace (every step under ``-m slow``),
+  forced-preemption trace (every step under ``-m slow``),
   recover into a fresh engine, and assert the union of pre-crash and
   post-recovery finishes is BIT-IDENTICAL to the fault-free golden — on
   colocated, sharded (n ∈ {1, 2, 4}), and disaggregated (including a
@@ -27,26 +29,15 @@ already-compiled programs. The tests pin exactly that:
 - **overload terminals**: a bounded admission queue + TTL shed excess
   load with typed REJECTED terminals while every admitted request still
   finishes bit-identically.
+"""
 
-Every test runs under the per-test SIGALRM watchdog (test_chaos.py
-pattern)."""
-
-import dataclasses
-import signal
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
-from triton_dist_tpu.models.llama import LlamaConfig, init_params
-from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
+from conftest import (N_REQUESTS, RECOVERY_MAX_STEPS as MAX_STEPS,
+                      crash_then_recover, journaled_steps, seeded_trace)
 from triton_dist_tpu.serving import (AdmissionRejected, ControlJournal,
-                                     DisaggServingEngine,
-                                     ReplicatedDecisionError, ServingEngine,
-                                     ShardedServingEngine, TtlExpired,
-                                     serving_mesh)
+                                     ServingEngine, TtlExpired)
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
 from triton_dist_tpu.serving.checkpoint import (CheckpointIntegrityError,
                                                 rebuild_request,
@@ -54,144 +45,25 @@ from triton_dist_tpu.serving.checkpoint import (CheckpointIntegrityError,
 from triton_dist_tpu.serving.kv_pool import KVPagePool
 from triton_dist_tpu.serving.scheduler import Request, RequestState
 from triton_dist_tpu.shmem import FaultPlan
-from triton_dist_tpu.shmem.context import initialize_distributed
 from triton_dist_tpu.shmem.faults import InjectedCrash
 
 pytestmark = [pytest.mark.recovery, pytest.mark.serving]
 
-WATCHDOG_S = 240          # per-test wall cap — generous, CPU CI is slow
-N_REQUESTS = 50
-MAX_STEPS = 6000          # far above any legitimate run length
-WIRE = jnp.float8_e4m3fn  # pinned wire dtype (test_sharded_serving caveat)
 
-
-@pytest.fixture(autouse=True)
-def recovery_watchdog():
-    """Hard per-test wall-clock watchdog: a hang anywhere in the
-    crash/recover cycle must kill the test loudly, not stall the suite."""
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"recovery watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "the engine (or its recovery harness) is hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
-
-
-# ---------------------------------------------------------------- fixtures
-@pytest.fixture(scope="module")
-def tiny_model():
-    """Chaos-scale 1-layer model — the sweep reruns the trace many times,
-    so per-step cost dominates the budget."""
-    cfg = dataclasses.replace(
-        LlamaConfig(vocab_size=128, d_model=32, n_layers=1, n_heads=2,
-                    n_kv_heads=1, d_ff=64, max_seq_len=64),
-        dtype=jnp.float32)
-    params = init_params(jax.random.key(1), cfg)
-    return cfg, params
-
-
-@pytest.fixture(scope="module")
-def moe_model():
-    """The micro MoE test_sharded_serving.py uses (d_model=128 is the A2A
-    wire-lane floor)."""
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-@pytest.fixture(scope="module")
-def role_ctx():
-    return initialize_distributed(axis_names=("role",), mesh_shape=(2,))
-
-
-def _trace(n=N_REQUESTS):
-    """The 50-request forced-preemption trace (test_chaos idiom):
-    staggered arrivals, prompts spanning 1..2 pages, mixed budgets."""
-    rng = np.random.RandomState(77)
-    out = []
-    for i in range(n):
-        plen = int(rng.randint(3, 17))
-        mnt = int(rng.randint(2, 6))
-        out.append((2 * i, list(rng.randint(1, 128, size=plen)), mnt))
-    return out
+def _trace(n):
+    return seeded_trace(n, staggered=True)
 
 
 # ------------------------------------------------------- engine factories
-def _colocated(tiny_model, **kw):
-    cfg, params = tiny_model
+def _colocated(micro_model, **kw):
+    cfg, params = micro_model
     kw.setdefault("num_slots", 4)
     kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 12)        # tight: forces preemption
-    kw.setdefault("pages_per_seq", 6)
+    kw.setdefault("num_pages", 5)         # tight: forces preemption
+    kw.setdefault("pages_per_seq", 4)      # the chunk grid is rows x pages
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("prefill_buckets", None)
     return ServingEngine(params, cfg, **kw)
-
-
-def _sharded(moe_model, tp, sp, ep, **kw):
-    cfg, params = moe_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 9)         # tight: forces preemption
-    kw.setdefault("pages_per_seq", 4)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("wire_dtype", WIRE)
-    return ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep), **kw)
-
-
-def _disagg(tiny_model, ctx, **kw):
-    cfg, params = tiny_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("num_prefill_slots", 2)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 64)
-    kw.setdefault("pages_per_seq", 6)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("signal_deadline_steps", 3)
-    return DisaggServingEngine(params, cfg, ctx=ctx, **kw)
-
-
-# ----------------------------------------------------- crash/recover harness
-def _crash_then_recover(mk_engine, arrivals, crash_step, checkpoint_every=8):
-    """The whole crash-consistency cycle at one crash point: journaled run
-    crashes at ``crash_step`` (returns None if the trace finished first —
-    nothing to recover), then a FRESH engine of the same configuration
-    restores from the journal and serves the not-yet-journaled remainder.
-    Returns the recovered {rid: tokens} union."""
-    journal = ControlJournal()
-    eng = mk_engine(journal=journal, checkpoint_every=checkpoint_every,
-                    fault_plan=FaultPlan(seed=3, crash_at=(crash_step,)))
-    try:
-        eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
-        return None                      # ran to completion — no crash
-    except InjectedCrash:
-        pass
-    # the journal is the durable artifact; everything else is rebuilt
-    done = sum(1 for e in journal.entries if e["kind"] == "submit")
-    eng2 = mk_engine(journal=journal, checkpoint_every=checkpoint_every)
-    res = eng2.run(max_steps=MAX_STEPS, arrivals=arrivals[done:],
-                   recover=True)
-    assert eng2.metrics.counters["restores"] == 1
-    return res
-
-
-def _journaled_steps(mk_engine, arrivals):
-    """Total step count of the fault-free journaled run (the sweep's
-    crash-point domain) plus its result (the golden)."""
-    journal = ControlJournal()
-    eng = mk_engine(journal=journal, checkpoint_every=8)
-    res = eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
-    return eng._steps, res, journal
 
 
 # ------------------------------------------------------------ journal units
@@ -283,23 +155,32 @@ def test_fault_plan_engine_tier():
 
 
 # --------------------------------------------------- colocated crash sweep
-def test_colocated_crash_sweep_quick(tiny_model):
-    """Strided crash points over the full 50-request trace (every step is
+@pytest.fixture(scope="module")
+def journaled_golden(micro_model):
+    """(steps, tokens, journal) of the fault-free journaled run of the
+    forced-preemption trace: the golden every sweep below is held to."""
+    return journaled_steps(lambda **kw: _colocated(micro_model, **kw),
+                           _trace(N_REQUESTS))
+
+
+def test_colocated_crash_sweep_quick(micro_model, journaled_golden):
+    """Three crash points over the forced-preemption trace (every step is
     the slow-tier sweep): each crash+recover must reproduce the golden
     bit-for-bit."""
-    arrivals = _trace()
-    mk = lambda **kw: _colocated(tiny_model, **kw)          # noqa: E731
-    total, golden, _ = _journaled_steps(mk, arrivals)
+    arrivals = _trace(N_REQUESTS)
+    mk = lambda **kw: _colocated(micro_model, **kw)          # noqa: E731
+    total, golden, journal = journaled_golden
     assert len(golden) == N_REQUESTS
-    stride = max(1, total // 8)
-    points = list(range(1, total, stride))
-    for s in points:
-        res = _crash_then_recover(mk, arrivals, s)
+    assert journal.counts().get("preempt", 0) >= 1, "trace lost its bite"
+    # three points that differ in kind: before the first checkpoint, mid-run
+    # after one, and the last step (most requests already journaled finished)
+    for s in (1, total // 2, total - 1):
+        res = crash_then_recover(mk, arrivals, s)
         assert res is not None, f"crash at step {s} never fired"
         assert res == golden, f"crash at step {s}: not bit-identical"
 
 
-def test_colocated_crash_sweep_prefix_cache(tiny_model):
+def test_colocated_crash_sweep_prefix_cache(micro_model):
     """Strided crash sweep with the prefix cache ON over a template-
     sharing trace (so adoption/COW state is live at most crash points).
     The restore contract — fresh pool, EMPTY cache, KV re-earned via
@@ -309,43 +190,43 @@ def test_colocated_crash_sweep_prefix_cache(tiny_model):
     rng = np.random.RandomState(13)
     tpls = [rng.randint(1, 128, size=16).tolist() for _ in range(3)]
     arrivals = []
-    for i in range(24):
+    for i in range(10):
         t = int(rng.randint(0, 3))
         tail = rng.randint(1, 128, size=int(rng.randint(1, 5))).tolist()
         arrivals.append((2 * i, tpls[t] + tail, int(rng.randint(2, 6))))
-    mk = lambda **kw: _colocated(tiny_model, prefix_cache=True,  # noqa: E731
-                                 **kw)
-    total, golden, _ = _journaled_steps(mk, arrivals)
-    _, golden_off, _ = _journaled_steps(
-        lambda **kw: _colocated(tiny_model, **kw), arrivals)
+    mk = lambda **kw: _colocated(micro_model, prefix_cache=True,  # noqa: E731
+                                 num_pages=12, **kw)
+    total, golden, _ = journaled_steps(mk, arrivals)
+    _, golden_off, _ = journaled_steps(
+        lambda **kw: _colocated(micro_model, num_pages=12, **kw), arrivals)
     assert golden == golden_off, "prefix cache changed tokens"
-    stride = max(1, total // 6)
+    stride = max(1, total // 2)
     for s in range(1, total, stride):
-        res = _crash_then_recover(mk, arrivals, s)
+        res = crash_then_recover(mk, arrivals, s)
         assert res is not None, f"crash at step {s} never fired"
         assert res == golden, f"crash at step {s}: not bit-identical"
 
 
 @pytest.mark.slow
-def test_colocated_crash_sweep_dense(tiny_model):
-    arrivals = _trace()
-    mk = lambda **kw: _colocated(tiny_model, **kw)          # noqa: E731
-    total, golden, _ = _journaled_steps(mk, arrivals)
+def test_colocated_crash_sweep_dense(micro_model, journaled_golden):
+    arrivals = _trace(N_REQUESTS)
+    mk = lambda **kw: _colocated(micro_model, **kw)          # noqa: E731
+    total, golden, _ = journaled_golden
     for s in range(1, total):
-        res = _crash_then_recover(mk, arrivals, s)
+        res = crash_then_recover(mk, arrivals, s)
         assert res is not None and res == golden, f"crash at step {s}"
 
 
-def test_colocated_checkpoint_cadence_sweep(tiny_model):
+def test_colocated_checkpoint_cadence_sweep(micro_model, journaled_golden):
     """Recovery is cadence-independent: sparse checkpoints only lengthen
     the replay suffix, never change the outcome. cadence=None = no
     checkpoints at all — the whole journal is the suffix."""
-    arrivals = _trace(24)
-    mk = lambda **kw: _colocated(tiny_model, **kw)          # noqa: E731
-    total, golden, _ = _journaled_steps(mk, arrivals)
+    arrivals = _trace(N_REQUESTS)
+    mk = lambda **kw: _colocated(micro_model, **kw)          # noqa: E731
+    total, golden, _ = journaled_golden
     crash = total // 2
-    for every in (2, 16, 64, None):
-        res = _crash_then_recover(mk, arrivals, crash, checkpoint_every=every)
+    for every in (2, 16, None):
+        res = crash_then_recover(mk, arrivals, crash, checkpoint_every=every)
         assert res == golden, f"checkpoint_every={every}"
     # dense cadence actually produced checkpoints
     j = ControlJournal()
@@ -356,15 +237,15 @@ def test_colocated_checkpoint_cadence_sweep(tiny_model):
         "checkpoints"]
 
 
-def test_restore_compiles_nothing(tiny_model):
+def test_restore_compiles_nothing(micro_model):
     """The compile guard (ISSUE 9 acceptance): restore is host-only —
     the jit trace caches are untouched by restore itself, and the whole
     recovered run still ends at exactly one decode + one chunk program."""
-    arrivals = _trace(24)
-    mk = lambda **kw: _colocated(tiny_model, **kw)          # noqa: E731
+    arrivals = _trace(N_REQUESTS)
+    mk = lambda **kw: _colocated(micro_model, **kw)          # noqa: E731
     journal = ControlJournal()
     eng = mk(journal=journal, checkpoint_every=8,
-             fault_plan=FaultPlan(seed=3, crash_at=(21,)))
+             fault_plan=FaultPlan(seed=3, crash_at=(11,)))
     with pytest.raises(InjectedCrash):
         eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
     done = sum(1 for e in journal.entries if e["kind"] == "submit")
@@ -377,7 +258,7 @@ def test_restore_compiles_nothing(tiny_model):
     assert eng2._chunk_step._cache_size() == 0
     assert info["replayed"] > 0
     res = eng2.run(max_steps=MAX_STEPS, arrivals=arrivals[done:])
-    golden = _colocated(tiny_model).run(max_steps=MAX_STEPS,
+    golden = _colocated(micro_model).run(max_steps=MAX_STEPS,
                                         arrivals=arrivals)
     assert res == golden
     stats = eng2.compile_stats
@@ -385,12 +266,13 @@ def test_restore_compiles_nothing(tiny_model):
     assert stats["prefill_chunk_compiles"] == 1
 
 
-def test_recover_without_checkpoint_replays_whole_journal(tiny_model):
+def test_recover_without_checkpoint_replays_whole_journal(micro_model,
+                                                         journaled_golden):
     """A crash before the first checkpoint cadence still recovers: the
     journal alone (checkpoint=None path) is a complete WAL."""
-    arrivals = _trace(16)
-    mk = lambda **kw: _colocated(tiny_model, **kw)          # noqa: E731
-    _, golden, _ = _journaled_steps(mk, arrivals)
+    arrivals = _trace(N_REQUESTS)
+    mk = lambda **kw: _colocated(micro_model, **kw)          # noqa: E731
+    _, golden, _ = journaled_golden
     journal = ControlJournal()
     eng = mk(journal=journal, checkpoint_every=1000,  # never reached
              fault_plan=FaultPlan(seed=3, crash_at=(7,)))
@@ -404,152 +286,8 @@ def test_recover_without_checkpoint_replays_whole_journal(tiny_model):
     assert res == golden
 
 
-# ----------------------------------------------------- sharded crash sweep
-@pytest.mark.mesh
-@pytest.mark.parametrize("tp,sp,ep,points", [
-    (1, 1, 1, 2),
-    (1, 2, 1, 2),
-    (2, 2, 1, 1),
-])
-def test_sharded_crash_recovery(moe_model, tp, sp, ep, points):
-    """Crash+recover on the mesh (n ∈ {1, 2, 4}): the restored engine
-    reproduces the n-rank golden bit-for-bit — recovery composes with the
-    cross-mesh bitwise contract instead of breaking it."""
-    arrivals = _trace(20)
-    mk = lambda **kw: _sharded(moe_model, tp, sp, ep, **kw)  # noqa: E731
-    total, golden, _ = _journaled_steps(mk, arrivals)
-    stride = max(1, total // (points + 1))
-    for s in range(stride, total, stride)[:points] or [1]:
-        res = _crash_then_recover(mk, arrivals, s)
-        assert res is not None and res == golden, \
-            f"mesh {tp}x{sp}x{ep}, crash at step {s}"
-
-
-@pytest.mark.slow
-@pytest.mark.mesh
-@pytest.mark.parametrize("tp,sp,ep,stride", [
-    (1, 1, 1, 1),
-    (1, 2, 1, 3),
-    (2, 2, 1, 6),
-])
-def test_sharded_crash_sweep_dense(moe_model, tp, sp, ep, stride):
-    arrivals = _trace()
-    mk = lambda **kw: _sharded(moe_model, tp, sp, ep, **kw)  # noqa: E731
-    total, golden, _ = _journaled_steps(mk, arrivals)
-    for s in range(1, total, stride):
-        res = _crash_then_recover(mk, arrivals, s)
-        assert res is not None and res == golden, f"crash at step {s}"
-
-
-# ----------------------------------------------- digest-divergence rung
-@pytest.mark.mesh
-def test_digest_skew_absorbed_by_restore(moe_model):
-    """A transient seeded digest divergence is QUARANTINED and absorbed:
-    exactly one digest_recovery, tokens still golden, nothing raised."""
-    arrivals = _trace(20)
-    golden = _sharded(moe_model, 1, 2, 1).run(max_steps=MAX_STEPS,
-                                              arrivals=arrivals)
-    journal = ControlJournal()
-    eng = _sharded(moe_model, 1, 2, 1, journal=journal, checkpoint_every=4,
-                   digest_every=1,
-                   fault_plan=FaultPlan(seed=5, digest_skew_at=(9,)))
-    res = eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
-    c = eng.metrics.counters
-    assert c["digest_recoveries"] == 1
-    assert c["restores"] == 1
-    assert c["faults_injected"] >= 1
-    assert res == golden
-    assert journal.counts().get("digest_divergence") == 1
-    assert eng.metrics.hist["digest_recovery_s"].count == 1
-
-
-@pytest.mark.mesh
-def test_persistent_digest_skew_escalates(moe_model):
-    """Skew that re-diverges with no agreed step since the restore is
-    PERSISTENT: the rung escalates (raises) instead of looping, and the
-    report embeds the counters + journal tail post-mortem."""
-    journal = ControlJournal()
-    eng = _sharded(moe_model, 1, 2, 1, journal=journal, checkpoint_every=4,
-                   digest_every=1)
-    eng._digest_skew[1] = 1               # persistent per-rank corruption
-    with pytest.raises(ReplicatedDecisionError, match="persistent skew"):
-        eng.run(max_steps=MAX_STEPS, arrivals=_trace(8))
-    assert eng.metrics.counters["digest_recoveries"] == 1  # tried once
-    try:
-        eng2 = _sharded(moe_model, 1, 2, 1, journal=ControlJournal(),
-                        checkpoint_every=4, digest_every=1)
-        eng2._digest_skew[1] = 1
-        eng2.run(max_steps=MAX_STEPS, arrivals=_trace(8))
-    except ReplicatedDecisionError as e:
-        assert "counters" in str(e) and "journal tail" in str(e)
-
-
-@pytest.mark.mesh
-def test_digest_skew_without_journal_still_raises(moe_model):
-    """No journal = no restore rung: the pre-ISSUE-9 hard raise stands
-    (fail loud beats silently serving forked block tables)."""
-    eng = _sharded(moe_model, 1, 2, 1, digest_every=1)
-    eng._digest_skew[1] = 1
-    with pytest.raises(ReplicatedDecisionError, match="digest diverged"):
-        eng.run(max_steps=MAX_STEPS, arrivals=_trace(8))
-    assert eng.metrics.counters["digest_recoveries"] == 0
-
-
-# ------------------------------------------------------ disagg crash sweep
-@pytest.mark.disagg
-def test_disagg_crash_recovery(tiny_model, role_ctx):
-    """Crash+recover on the disaggregated engine, including a crash with
-    a migration IN FLIGHT: the restarted engine re-admits the migrated
-    request through the rebuilt ledger (re-prefill + re-migrate), never
-    fails it for having been half-handed-off."""
-    arrivals = _trace(24)
-    mk = lambda **kw: _disagg(tiny_model, role_ctx, **kw)   # noqa: E731
-    total, golden, ref = _journaled_steps(mk, arrivals)
-    # a crash point with a handoff in flight: a rid went MIGRATING at
-    # step s (journal "handoff") and only finished at some step > s + 1
-    finish_step = {e["rid"]: e["step"] for e in ref.entries
-                   if e["kind"] == "finish"}
-    midflight = [e["step"] for e in ref.entries if e["kind"] == "handoff"
-                 and finish_step.get(e["rid"], 10**9) > e["step"] + 1]
-    points = sorted({max(1, total // 3), midflight[0] if midflight
-                     else total // 2, total - 1})
-    for s in points:
-        res = _crash_then_recover(mk, arrivals, s)
-        assert res is not None and res == golden, f"crash at step {s}"
-
-
-@pytest.mark.slow
-@pytest.mark.disagg
-def test_disagg_crash_sweep_dense(tiny_model, role_ctx):
-    arrivals = _trace()
-    mk = lambda **kw: _disagg(tiny_model, role_ctx, **kw)   # noqa: E731
-    total, golden, _ = _journaled_steps(mk, arrivals)
-    for s in range(1, total):
-        res = _crash_then_recover(mk, arrivals, s)
-        assert res is not None and res == golden, f"crash at step {s}"
-
-
-@pytest.mark.disagg
-def test_disagg_journal_records_migration(tiny_model, role_ctx):
-    """The disagg journal carries the migration story: migrate attempts
-    (with chunk + page counts), handoffs, and the per-event digest over
-    BOTH workers' control planes."""
-    journal = ControlJournal()
-    eng = _disagg(tiny_model, role_ctx, journal=journal, checkpoint_every=8)
-    eng.run(max_steps=MAX_STEPS, arrivals=_trace(8))
-    counts = journal.counts()
-    assert counts["migrate"] >= counts["handoff"] >= 1
-    assert counts["finish"] == 8
-    m = next(e for e in journal.entries if e["kind"] == "migrate")
-    assert m["pages"] >= 1 and "chunk" in m and "attempt" in m
-    # pool audit: nothing leaked through the journaled run
-    assert eng.alloc_p.used_pages == 0 and eng.alloc_d.used_pages == 0
-    eng.alloc_p.check(eng.channel.ledger)
-    eng.alloc_d.check(eng.channel.ledger)
-
-
 # ------------------------------------------------------- overload terminals
-def test_queue_cap_rejects_typed(tiny_model):
+def test_queue_cap_rejects_typed(micro_model):
     """2x oversubscription against a bounded queue: the excess is shed
     with typed AdmissionRejected terminals, every admitted request
     finishes bit-identical to the uncapped golden, and the engine never
@@ -557,7 +295,7 @@ def test_queue_cap_rejects_typed(tiny_model):
     rng = np.random.RandomState(7)
     arrivals = [(0, list(rng.randint(1, 128, size=int(rng.randint(3, 17)))),
                  int(rng.randint(2, 6))) for _ in range(20)]
-    mk = lambda **kw: _colocated(tiny_model, num_slots=2, num_pages=8,
+    mk = lambda **kw: _colocated(micro_model, num_slots=2, num_pages=8,
                                  **kw)                       # noqa: E731
     golden = mk().run(max_steps=MAX_STEPS, arrivals=arrivals)
     journal = ControlJournal()
@@ -577,14 +315,14 @@ def test_queue_cap_rejects_typed(tiny_model):
     assert journal.counts()["reject"] == c["rejections"]
 
 
-def test_ttl_expires_typed(tiny_model):
+def test_ttl_expires_typed(micro_model):
     """A slow-draining queue expires never-admitted requests past their
     TTL with typed TtlExpired terminals; admitted requests are immune
     (preemption requeues never expire) and finish bit-identically."""
     rng = np.random.RandomState(7)
     arrivals = [(0, list(rng.randint(1, 128, size=12)), 5)
                 for _ in range(8)]
-    mk = lambda **kw: _colocated(tiny_model, num_slots=1, num_pages=8,
+    mk = lambda **kw: _colocated(micro_model, num_slots=1, num_pages=8,
                                  **kw)                       # noqa: E731
     golden = mk().run(max_steps=MAX_STEPS, arrivals=arrivals)
     journal = ControlJournal()
@@ -601,14 +339,14 @@ def test_ttl_expires_typed(tiny_model):
     assert journal.counts()["expire"] == c["expirations"]
 
 
-def test_overload_survives_crash_recovery(tiny_model):
+def test_overload_survives_crash_recovery(micro_model):
     """Overload terminals are journaled state: a crash after rejections
     restores them — the recovered engine reports the same terminal set
     and still finishes every admitted request bit-identically."""
     rng = np.random.RandomState(7)
     arrivals = [(0, list(rng.randint(1, 128, size=int(rng.randint(3, 17)))),
                  int(rng.randint(2, 6))) for _ in range(20)]
-    mk = lambda **kw: _colocated(tiny_model, num_slots=2, num_pages=8,
+    mk = lambda **kw: _colocated(micro_model, num_slots=2, num_pages=8,
                                  queue_cap=4, **kw)          # noqa: E731
     golden_eng = mk()
     golden = golden_eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
@@ -630,15 +368,15 @@ def test_overload_survives_crash_recovery(tiny_model):
 
 
 # -------------------------------------------------------------- post-mortem
-def test_postmortem_embeds_journal_tail(tiny_model):
+def test_postmortem_embeds_journal_tail(micro_model):
     """Engine error reports carry the forensic record: non-zero counters
     plus the last journal entries (bulky checkpoint payloads elided)."""
     journal = ControlJournal()
-    eng = _colocated(tiny_model, journal=journal, checkpoint_every=4)
+    eng = _colocated(micro_model, journal=journal, checkpoint_every=4)
     eng.run(max_steps=MAX_STEPS, arrivals=_trace(6))
     pm = eng._postmortem()
     assert "counters" in pm and "journal tail" in pm
     assert "finish" in pm and "tokens_generated" in pm
     assert "<elided>" in pm or "checkpoint" not in journal.counts()
     # without a journal the report says so instead of crashing
-    assert "<no journal attached>" in _colocated(tiny_model)._postmortem()
+    assert "<no journal attached>" in _colocated(micro_model)._postmortem()

@@ -357,13 +357,13 @@ def test_engine_smoke(tiny_model):
 
 @pytest.fixture(scope="module")
 def golden_trace(tiny_model):
-    """Golden for the acceptance trace: 50 requests through ONE
+    """Golden for the acceptance trace: 8 requests through ONE
     single-slot engine with an ample pool — requests run strictly one at
     a time (per-request single-batch decoding, horizon 1)."""
     cfg, params = tiny_model
-    reqs = _mk_requests(cfg, 50, seed=2, mnt_lo=6, mnt_hi=14)
+    reqs = _mk_requests(cfg, 8, seed=2, mnt_lo=6, mnt_hi=14)
     gold_eng = ServingEngine(params, cfg, num_slots=1, page_size=8,
-                             num_pages=8, pages_per_seq=8)
+                             num_pages=8, pages_per_seq=4)
     gold_rids = [gold_eng.submit(p, m) for p, m in reqs]
     gold = gold_eng.run(max_steps=5000)
     assert gold_eng.metrics.counters["preemptions"] == 0
@@ -371,10 +371,10 @@ def golden_trace(tiny_model):
 
 
 @pytest.mark.parametrize("horizon", [1, 4])
-@pytest.mark.parametrize("chunk", [None, 64, 256])
+@pytest.mark.parametrize("chunk", [None, 8, 16])  # many chunks a prompt; few
 def test_trace_bit_identical_under_preemption(tiny_model, golden_trace,
                                               chunk, horizon):
-    """The acceptance trace: 50 requests through a 4-slot engine with a
+    """The acceptance trace: 8 requests through a 4-slot engine with a
     pool small enough to force preemptions. Every request's tokens must be
     bit-identical to the same request decoded in a single-batch engine
     with an uncontended pool — including every preempted request, at
@@ -387,8 +387,8 @@ def test_trace_bit_identical_under_preemption(tiny_model, golden_trace,
     # contended: 4 slots, pool deliberately too small for 4 long tails —
     # growth must preempt. Arrivals staggered so admission interleaves
     # with decode of earlier requests.
-    eng = ServingEngine(params, cfg, num_slots=4, page_size=8, num_pages=9,
-                        pages_per_seq=8, decode_horizon=horizon,
+    eng = ServingEngine(params, cfg, num_slots=4, page_size=8, num_pages=7,
+                        pages_per_seq=4, decode_horizon=horizon,
                         prefill_chunk=chunk)
     arrivals = [(i // 2, p, m) for i, (p, m) in enumerate(reqs)]
     res = eng.run(max_steps=5000, arrivals=arrivals)
@@ -463,10 +463,14 @@ def test_bucketed_prefill_token_identical_to_exact(tiny_model):
     assert run("pow2") == run(None)
 
 
-def test_compile_count_guard(tiny_model, monkeypatch):
-    """A trace with 20 DISTINCT prompt lengths must compile the decode
-    step exactly once and at most one prefill program per bucket — the
-    whole point of bucketing + shape-stable multi-step decode."""
+@pytest.mark.quick
+@pytest.mark.parametrize("chunk", [None, 8], ids=["bucketed", "chunked"])
+def test_compile_count_guard(tiny_model, monkeypatch, chunk):
+    """A trace with 10 DISTINCT prompt lengths compiles the decode step
+    exactly once, and beside it: bucketed, at most one prefill program a
+    bucket (the whole point of bucketing + shape-stable multi-step
+    decode); chunked, ONE chunk program and no bucketed one (start offset
+    and prompt length are runtime scalars of the chunk program)."""
     cfg, params = tiny_model
     real_jit = jax.jit
     made = []
@@ -477,24 +481,29 @@ def test_compile_count_guard(tiny_model, monkeypatch):
 
     monkeypatch.setattr(jax, "jit", counting_jit)
     eng = ServingEngine(params, cfg, num_slots=4, page_size=8, num_pages=32,
-                        pages_per_seq=8, decode_horizon=2,
-                        prefill_buckets=(8, 16, 32))
+                        pages_per_seq=4, decode_horizon=2,
+                        prefill_buckets=(8, 16, 32), prefill_chunk=chunk)
     rng = np.random.RandomState(3)
     arrivals = []
-    for i, plen in enumerate(range(3, 23)):    # 20 distinct prompt lengths
+    for i, plen in enumerate(range(3, 23, 2)):  # 10 distinct prompt lengths
         prompt = [int(t) for t in rng.randint(1, cfg.vocab_size, size=plen)]
         arrivals.append((i, prompt, int(rng.randint(2, 8))))
     res = eng.run(max_steps=5000, arrivals=arrivals)
-    assert len(res) == 20
+    assert len(res) == 10
     stats = eng.compile_stats
     assert stats["decode_compiles"] == 1
-    assert stats["prefill_programs"] <= 3      # one per bucket, max
-    assert stats["prefill_compiles"] <= 3
-    # the jit-entry hook agrees: one decode program + one per prefill bucket
-    # (pallas interpret mode jits its own internal wrappers — not ours)
+    if chunk is None:
+        assert 1 <= stats["prefill_programs"] <= 3     # one per bucket, max
+        assert stats["prefill_compiles"] <= 3
+        assert stats["prefill_chunk_compiles"] == 0
+    else:
+        assert stats["prefill_chunk_compiles"] == 1
+        assert stats["prefill_programs"] == stats["prefill_compiles"] == 0
+    # the jit-entry hook agrees (pallas interpret mode jits its own internal
+    # wrappers — not ours)
     ours = [f for f in made
             if "ServingEngine" in getattr(f, "__qualname__", "")]
-    assert len(ours) == 1 + stats["prefill_programs"]
+    assert len(ours) == 1 + (stats["prefill_programs"] if chunk is None else 1)
 
 
 def test_eos_truncation_multistep(tiny_model):
@@ -545,41 +554,6 @@ def test_dispatch_count_bound(tiny_model, horizon):
 # ---------------------------------------------------------------------------
 # chunked paged prefill (ISSUE 5)
 # ---------------------------------------------------------------------------
-
-@pytest.mark.quick
-def test_compile_count_guard_chunked(tiny_model, monkeypatch):
-    """With prefill_chunk set, 20 DISTINCT prompt lengths compile exactly
-    TWO ServingEngine programs total: one decode step and one chunk
-    program. The bucketed prefill programs never compile — start offset
-    and prompt length are runtime scalars of the chunk program."""
-    cfg, params = tiny_model
-    real_jit = jax.jit
-    made = []
-
-    def counting_jit(fun, *a, **k):
-        made.append(fun)
-        return real_jit(fun, *a, **k)
-
-    monkeypatch.setattr(jax, "jit", counting_jit)
-    eng = ServingEngine(params, cfg, num_slots=4, page_size=8, num_pages=32,
-                        pages_per_seq=8, decode_horizon=2,
-                        prefill_buckets=(8, 16, 32), prefill_chunk=8)
-    rng = np.random.RandomState(3)
-    arrivals = []
-    for i, plen in enumerate(range(3, 23)):    # 20 distinct prompt lengths
-        prompt = [int(t) for t in rng.randint(1, cfg.vocab_size, size=plen)]
-        arrivals.append((i, prompt, int(rng.randint(2, 8))))
-    res = eng.run(max_steps=5000, arrivals=arrivals)
-    assert len(res) == 20
-    stats = eng.compile_stats
-    assert stats["decode_compiles"] == 1
-    assert stats["prefill_chunk_compiles"] == 1
-    assert stats["prefill_programs"] == 0
-    assert stats["prefill_compiles"] == 0
-    ours = [f for f in made
-            if "ServingEngine" in getattr(f, "__qualname__", "")]
-    assert len(ours) == 2                      # decode + chunk, nothing else
-
 
 @pytest.mark.quick
 def test_mid_prefill_preemption_resumes_at_cursor(tiny_model):

@@ -1,10 +1,13 @@
 """Sharded serving (ISSUE 8): the engine's two compiled programs under
 shard_map on a TP/SP/EP mesh, held to the bitwise cross-mesh contract.
 
-THE contract (sharded.py module docstring): a 50-request forced-preemption
+THE contract (sharded.py module docstring): a forced-preemption
 trace served on an n>1 interpret mesh is BIT-IDENTICAL per request to the
 n=1 golden — same tokens, same preemption-survival, across decode horizons
-K∈{1,4} and prefill-chunk sizes. The golden is the SAME
+K∈{1,4} and prefill-chunk sizes. Tier 1 replays the whole trace once across
+chips (``n2_run``); every further run across chips replays its first four
+requests on the ``N4_PAGES`` pool, where they preempt too: EVERY run held to
+the golden asserts a preemption. The golden is the SAME
 ``ShardedServingEngine`` at mesh 1x1x1: hooks set, loops unrolled, fp8
 wire round-tripped — so n>1 changes ONLY the rank count, never the code
 path.
@@ -19,22 +22,16 @@ Also covered: the one-program-per-path compile-count guard at n>1, the
 replicated-decision digest guard (sensitivity + divergence injection),
 constructor precondition refusals, and the ag_gemm TP impl's
 allclose-only status.
-
-Every test runs under the per-test SIGALRM watchdog (same pattern as
-tests/test_chaos.py): a mesh-collective hang must kill the test loudly,
-not stall the suite.
 """
 
-import signal
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
-from triton_dist_tpu.models.llama import LlamaConfig
-from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
+from conftest import (N4_PAGES, N4_REQUESTS, N_REQUESTS, SHARDED_KW,
+                      assert_replay_identical, seeded_trace, sharded_engine)
 from triton_dist_tpu.ops.allgather_gemm import GemmConfig, tp_column_linear
 from triton_dist_tpu.serving import (ReplicatedDecisionError,
                                      ShardedServingEngine, serving_mesh)
@@ -43,72 +40,14 @@ from triton_dist_tpu.serving.scheduler import ContinuousBatchingScheduler
 
 pytestmark = [pytest.mark.mesh, pytest.mark.serving]
 
-WATCHDOG_S = 240          # per-test wall cap — generous, CPU CI is slow
-N_REQUESTS = 50
+WIRE = SHARDED_KW["wire_dtype"]
 MAX_STEPS = 100_000       # engine's own stall watchdog trips far earlier
-WIRE = jnp.float8_e4m3fn  # pinned (NOT "auto") — see module docstring
 
 
-@pytest.fixture(autouse=True)
-def mesh_watchdog():
-    """Hard per-test wall-clock watchdog (test_chaos.py pattern): SIGALRM,
-    not a thread, so even a wedged collective inside jax is interrupted."""
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"mesh watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "a mesh collective (or the engine) is hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
-
-
-@pytest.fixture(scope="module")
-def moe_model():
-    """Micro MoE: smallest shape that exercises every sharded path
-    (d_model=128 is the A2A wire-lane floor; 2 KV heads so GQA grouping
-    is real; 4 experts / topk 2 so EP dispatch actually routes)."""
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-def _trace():
-    """50 requests, bursty arrivals (two per step) against a 9-page pool —
-    growth-driven preemption is forced, not incidental. Deterministic."""
-    rng = np.random.RandomState(77)
-    out = []
-    for i in range(N_REQUESTS):
-        plen = int(rng.randint(3, 17))
-        mnt = int(rng.randint(2, 6))
-        prompt = rng.randint(1, 128, size=plen).tolist()
-        out.append((i // 2, prompt, mnt))
-    return out
-
-
-def _engine(moe_model, tp, sp, ep, **kw):
-    cfg, params = moe_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 9)          # tight: forces preemption
-    kw.setdefault("pages_per_seq", 4)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("wire_dtype", WIRE)
-    return ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep), **kw)
-
-
-def _serve(moe_model, tp, sp, ep, **kw):
-    eng = _engine(moe_model, tp, sp, ep, **kw)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
-    return {"tokens": tokens, "compiles": eng.compile_stats,
+def _serve(moe_model, tp, sp, ep, n=N_REQUESTS, **kw):
+    eng = sharded_engine(moe_model, tp, sp, ep, **kw)
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(n))
+    return {"tokens": tokens, "n": n, "compiles": eng.compile_stats,
             "counters": dict(eng.metrics.counters)}
 
 
@@ -126,15 +65,18 @@ def n2_run(moe_model):
 @pytest.fixture(scope="module")
 def n4_run(moe_model):
     """n=4 with the OTHER decode horizon: SP×EP mesh, K=4 multi-token
-    dispatches — trace must still replay the K=1 n=1 golden exactly."""
-    return _serve(moe_model, 1, 2, 2, decode_horizon=4)
+    dispatches — the trace's first four must still replay the K=1 n=1
+    golden exactly."""
+    return _serve(moe_model, 1, 2, 2, n=N4_REQUESTS, num_pages=N4_PAGES,
+                  decode_horizon=4)
 
 
 def _assert_identical(run, golden):
-    assert run["tokens"].keys() == golden["tokens"].keys()
-    bad = [r for r in golden["tokens"]
-           if run["tokens"][r] != golden["tokens"][r]]
-    assert not bad, f"token streams diverged from n=1 golden: rids {bad}"
+    """Every request the run was given finished with the n=1 golden's
+    tokens (the runs on the trace's first four are held to rids 0-3), and
+    the run preempted on the way."""
+    assert_replay_identical(run["tokens"], golden["tokens"], run["n"])
+    assert run["counters"]["preemptions"] >= 1, "the run never preempted"
 
 
 def test_golden_trace_shape(golden):
@@ -157,15 +99,18 @@ def test_trace_bit_identical_n2(n2_run, golden):
     assert n2_run["counters"]["digest_checks"] > 0
 
 
+@pytest.mark.slow          # 5-10 s an interpreter step at n=4: over a minute
 def test_trace_bit_identical_n4_horizon4(n4_run, golden):
     _assert_identical(n4_run, golden)
+    assert n4_run["compiles"] == golden["compiles"]
 
 
 def test_trace_bit_identical_chunk_variant(moe_model, golden):
     """Chunk-size invariance composes with mesh invariance: n=2 with a
     DIFFERENT prefill_chunk (4, the other row-count-specialized A2A
     layer) still replays the chunk=8 golden per request."""
-    run = _serve(moe_model, 1, 1, 2, prefill_chunk=4)
+    run = _serve(moe_model, 1, 1, 2, n=N4_REQUESTS, num_pages=N4_PAGES,
+                 prefill_chunk=4)
     _assert_identical(run, golden)
 
 
@@ -178,11 +123,11 @@ def test_trace_bit_identical_full_sweep(moe_model, golden):
         _assert_identical(run, golden)
 
 
-def test_one_program_per_path(golden, n2_run, n4_run):
+def test_one_program_per_path(golden, n2_run):
     """Compile-count guard at n>1 (the GSPMD output-sharding flip this
     pins is real — see the out_shardings comment in engine.py): exactly
     ONE decode program and ONE chunk program per run, same as n=1."""
-    for run in (golden, n2_run, n4_run):
+    for run in (golden, n2_run):
         assert run["compiles"]["decode_compiles"] == 1, run["compiles"]
         assert run["compiles"]["prefill_chunk_compiles"] == 1, \
             run["compiles"]
@@ -220,7 +165,7 @@ def test_digest_divergence_raises(moe_model):
     """Inject a per-rank digest skew (the test hook — a single-controller
     process cannot organically fork a replicated digest) and the guard
     must trip on the next productive step."""
-    eng = _engine(moe_model, 1, 1, 2)
+    eng = sharded_engine(moe_model, 1, 1, 2)
     eng.submit([1, 2, 3, 4, 5], 4)
     assert eng.step()                      # healthy step passes the check
     eng._digest_skew[1] = 1                # rank 1 now disagrees
@@ -231,8 +176,21 @@ def test_digest_divergence_raises(moe_model):
     eng.check_replicated_decisions()       # healthy again
 
 
+def test_digest_guard_names_the_rank_at_n4(moe_model):
+    """The guard over the full 1x2x2 mesh, no step dispatched (tier 1's
+    stand-in for the n=4 replay, which is `slow`): four ranks agree, then
+    ONE skewed rank is named with the mesh it sits on."""
+    eng = sharded_engine(moe_model, 1, 2, 2)
+    assert eng.n_ranks == 4 and eng.mesh_desc == "1x2x2"
+    eng.check_replicated_decisions()
+    eng._digest_skew[3] = 1
+    with pytest.raises(ReplicatedDecisionError, match=r"ranks \[3\].*1x2x2"):
+        eng.check_replicated_decisions()
+    assert eng.metrics.counters["digest_checks"] == 2
+
+
 def test_digest_every_disables(moe_model):
-    eng = _engine(moe_model, 1, 1, 2, digest_every=0)
+    eng = sharded_engine(moe_model, 1, 1, 2, digest_every=0)
     eng._digest_skew[1] = 1                # would trip if checks ran
     eng.submit([1, 2, 3], 2)
     eng.run(max_steps=MAX_STEPS)

@@ -20,7 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from conftest import TEST_WORLD  # noqa: F401
+from conftest import TEST_WORLD, xfail_on_cpu
 from triton_dist_tpu.ops.common import collective_id_for
 from triton_dist_tpu.shmem import device as shd
 from triton_dist_tpu.shmem.context import initialize_distributed
@@ -156,6 +156,8 @@ def test_subaxis_barrier_then_signal(barrier_axes):
     np.testing.assert_array_equal(got, np.ones(6, np.int32))
 
 
+@xfail_on_cpu("pl.semaphore_read has no MLIR lowering for platform cpu "
+              "(the interpreter implements the wait, not the read)")
 def test_signal_read_after_partial_consume():
     """signal_read is NON-destructive and sees the residue of a partially
     consumed count: accumulate 3, wait 2 (TPU waits consume), read -> 1,

@@ -27,26 +27,18 @@ Layers pinned, cheapest first:
   typed per-class shed) colocated and sharded, deadline-aware chunk
   shrink with flat compile_stats, chaos schedules and the crash sweep
   under WFQ.
-
-Every test runs under the per-test SIGALRM watchdog (test_chaos.py
-pattern)."""
+"""
 
 import dataclasses
-import signal
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
+from conftest import sharded_engine
 from test_chaos import SCHEDULES
-from triton_dist_tpu.models.llama import LlamaConfig, init_params
-from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
 from triton_dist_tpu.serving import (AdmissionRejected, ControlJournal,
                                      DisaggServingEngine, ServingEngine,
-                                     ShardedServingEngine, TtlExpired,
-                                     serving_mesh)
+                                     TtlExpired)
 from triton_dist_tpu.serving.deadline import Deadline
 from triton_dist_tpu.serving.journal import SCHEMA_VERSION
 from triton_dist_tpu.serving.scheduler import (ClassSpec,
@@ -60,27 +52,7 @@ from triton_dist_tpu.shmem.faults import InjectedCrash
 
 pytestmark = [pytest.mark.slo, pytest.mark.serving, pytest.mark.quick]
 
-WATCHDOG_S = 240
 MAX_STEPS = 6000
-WIRE = jnp.float8_e4m3fn
-
-
-@pytest.fixture(autouse=True)
-def slo_watchdog():
-    """Hard per-test wall-clock watchdog: a scheduling bug that starves a
-    class must kill the test loudly, not stall the suite."""
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"slo watchdog: test exceeded {WATCHDOG_S}s wall — the "
-            "engine (or the policy scheduler) is starving/hanging")
-
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
 
 
 # ------------------------------------------------------ scheduler helpers
@@ -425,33 +397,12 @@ def test_journal_v2_header_on_fresh_files(tmp_path):
 
 # ------------------------------------------------------ engine fixtures
 @pytest.fixture(scope="module")
-def tiny_model():
-    cfg = dataclasses.replace(
-        LlamaConfig(vocab_size=128, d_model=32, n_layers=1, n_heads=2,
-                    n_kv_heads=1, d_ff=64, max_seq_len=64),
-        dtype=jnp.float32)
-    params = init_params(jax.random.key(1), cfg)
-    return cfg, params
-
-
-@pytest.fixture(scope="module")
-def moe_model():
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-@pytest.fixture(scope="module")
 def role_ctx():
     return initialize_distributed(axis_names=("role",), mesh_shape=(2,))
 
 
-def _colocated(tiny_model, **kw):
-    cfg, params = tiny_model
+def _colocated(micro_model, **kw):
+    cfg, params = micro_model
     kw.setdefault("num_slots", 4)
     kw.setdefault("page_size", 8)
     kw.setdefault("num_pages", 16)
@@ -461,24 +412,13 @@ def _colocated(tiny_model, **kw):
     return ServingEngine(params, cfg, **kw)
 
 
-def _sharded(moe_model, tp, sp, ep, **kw):
-    cfg, params = moe_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 12)
-    kw.setdefault("pages_per_seq", 4)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("wire_dtype", WIRE)
-    return ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep), **kw)
-
-
-def _disagg(tiny_model, ctx, **kw):
-    cfg, params = tiny_model
+def _disagg(micro_model, ctx, **kw):
+    cfg, params = micro_model
     kw.setdefault("num_slots", 4)
     kw.setdefault("num_prefill_slots", 2)
     kw.setdefault("page_size", 8)
     kw.setdefault("num_pages", 64)
-    kw.setdefault("pages_per_seq", 6)
+    kw.setdefault("pages_per_seq", 3)
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("signal_deadline_steps", 3)
     kw.setdefault("max_retries", 3)
@@ -529,20 +469,20 @@ def _chat_ttft(eng):
 
 
 # ---------------------------------------------------- flood isolation
-def test_flood_isolation_colocated(tiny_model):
+def test_flood_isolation_colocated(micro_model):
     """The headline: a 2x batch flood on the colocated engine sheds ONLY
     batch (typed, class-named), admits and finishes every chat request
     with tokens bit-identical to the unflooded golden, and holds chat
     TTFT within a fixed bound of the unflooded p99."""
-    chat = _chat_trace()
+    chat = _chat_trace(n=8)
     slo = SLOPolicy.chat_batch(**FLOOD_POLICY)
-    golden = _colocated(tiny_model, slo=slo)
+    golden = _colocated(micro_model, slo=slo)
     golden.run(max_steps=MAX_STEPS, arrivals=chat)
     gold_map, gold_ttft = _chat_map(golden), _chat_ttft(golden)
     assert len(gold_map) == len(chat)
 
-    flooded = _colocated(tiny_model, slo=slo)
-    arrivals = sorted(chat + _batch_flood(), key=lambda a: a[0])
+    flooded = _colocated(micro_model, slo=slo)
+    arrivals = sorted(chat + _batch_flood(n=16), key=lambda a: a[0])
     flooded.run(max_steps=MAX_STEPS, arrivals=arrivals)
 
     # every chat request finished, bit-identical to the unflooded golden
@@ -568,31 +508,68 @@ def test_flood_isolation_colocated(tiny_model):
         f"{budget}-step bound (unflooded p99 {gold_ttft[-1]})")
 
 
-@pytest.mark.mesh
-@pytest.mark.parametrize("tp,sp,ep", [(1, 1, 1), (1, 2, 1), (2, 2, 1)])
-def test_flood_isolation_sharded(moe_model, tp, sp, ep):
-    """Same isolation contract on the mesh (n ∈ {1, 2, 4}): admitted
-    chat tokens bit-identical to the n=1 unflooded golden — the policy
-    books are replicated host state, so WFQ must not fork the digest."""
-    chat = _chat_trace(n=8)
-    slo = SLOPolicy.chat_batch(**FLOOD_POLICY)
-    golden = _sharded(moe_model, 1, 1, 1, slo=slo)
+# a third of the colocated flood, so a third of its queue cap
+SHARDED_FLOOD_POLICY = {**FLOOD_POLICY, "batch_queue_cap": 2}
+
+
+@pytest.fixture(scope="module")
+def unflooded_n1_chat(moe_model):
+    """{rid: tokens} of the chat trace alone at mesh 1x1x1: the golden of
+    every mesh's flooded run."""
+    chat = _chat_trace(n=4)
+    golden = sharded_engine(moe_model, 1, 1, 1, num_pages=12,
+                            slo=SLOPolicy.chat_batch(**SHARDED_FLOOD_POLICY))
     golden.run(max_steps=MAX_STEPS, arrivals=chat)
     gold_map = _chat_map(golden)
     assert len(gold_map) == len(chat)
+    return gold_map
 
-    flooded = _sharded(moe_model, tp, sp, ep, slo=slo)
-    arrivals = sorted(chat + _batch_flood(n=12, max_plen=24),
+
+@pytest.mark.mesh
+@pytest.mark.parametrize("tp,sp,ep", [
+    (1, 1, 1), (1, 1, 2),  # 5-10 s an interpreter step at n=4: over a minute
+    pytest.param(2, 2, 1, marks=pytest.mark.slow)])
+def test_flood_isolation_sharded(moe_model, unflooded_n1_chat, tp, sp, ep):
+    """Same isolation contract on the mesh (n ∈ {1, 2, 4}): admitted
+    chat tokens bit-identical to the n=1 unflooded golden — the policy
+    books are replicated host state, so WFQ must not fork the digest."""
+    chat = _chat_trace(n=4)
+    slo = SLOPolicy.chat_batch(**SHARDED_FLOOD_POLICY)
+    gold_map = unflooded_n1_chat
+
+    flooded = sharded_engine(moe_model, tp, sp, ep, num_pages=12, slo=slo)
+    arrivals = sorted(chat + _batch_flood(n=6, max_plen=24),
                       key=lambda a: a[0])
     flooded.run(max_steps=MAX_STEPS, arrivals=arrivals)
     assert _chat_map(flooded) == gold_map, (
         f"mesh {tp}x{sp}x{ep}: flood changed admitted chat tokens")
+    assert flooded._rejected, "flood never shed: overload lost its teeth"
     for r in flooded._rejected:
         assert r.cls == "batch", f"chat shed on mesh {tp}x{sp}x{ep}"
 
 
+@pytest.mark.mesh
+def test_flood_sheds_only_batch_at_submit_on_the_n4_mesh(moe_model):
+    """Tier 1's stand-in for the 2x2x1 flood (`slow`), nothing dispatched:
+    admission control is host state replicated over the 4 ranks — the
+    batch flood is shed at submit past its queue cap, typed and class-
+    named, chat is untouched, and every rank still agrees on the digest."""
+    eng = sharded_engine(moe_model, 2, 2, 1, num_pages=12,
+                         slo=SLOPolicy.chat_batch(**FLOOD_POLICY))
+    flood = _batch_flood(n=12, max_plen=24)
+    for _, prompt, mnt, tenant, cls in _chat_trace(n=4) + flood:
+        eng.submit(prompt, mnt, tenant=tenant, cls=cls)
+    shed = eng._rejected
+    assert len(shed) == len(flood) - FLOOD_POLICY["batch_queue_cap"]
+    for r in shed:
+        assert r.cls == "batch" and isinstance(r.failure, AdmissionRejected)
+        assert "'batch'" in str(r.failure)
+    assert eng.n_ranks == 4
+    eng.check_replicated_decisions()
+
+
 # ------------------------------------------- deadline-aware chunk sizing
-def test_chunk_shrink_fires_with_flat_compile_stats(tiny_model):
+def test_chunk_shrink_fires_with_flat_compile_stats(micro_model):
     """chat_stall_budget shrinks co-scheduled batch prefill chunks while
     a chat request decodes — through the SAME chunk program (runtime
     prompt_len scalar), so compile_stats stays at one decode + one chunk
@@ -605,7 +582,7 @@ def test_chunk_shrink_fires_with_flat_compile_stats(tiny_model):
 
     res_by_budget = {}
     for budget in (None, 4):
-        eng = _colocated(tiny_model, slo=SLOPolicy.chat_batch(
+        eng = _colocated(micro_model, slo=SLOPolicy.chat_batch(
             chat_stall_budget=budget))
         res = eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
         res_by_budget[budget] = res
@@ -622,10 +599,10 @@ def test_chunk_shrink_fires_with_flat_compile_stats(tiny_model):
         "chunk shrink changed tokens")
 
 
-def test_unpoliced_engine_has_no_class_metrics(tiny_model):
+def test_unpoliced_engine_has_no_class_metrics(micro_model):
     """Pay-for-play: without a policy the metrics panel is exactly the
     pre-ISSUE-14 shape — no {class=...} keys, no quota counters moving."""
-    eng = _colocated(tiny_model)
+    eng = _colocated(micro_model)
     eng.run(max_steps=MAX_STEPS,
             arrivals=[(0, [3, 5, 7], 3), (1, [2, 4, 6, 8], 2)])
     assert len(eng._finished) == 2
@@ -635,7 +612,7 @@ def test_unpoliced_engine_has_no_class_metrics(tiny_model):
 
 
 # ------------------------------------- chaos + crash recovery under WFQ
-def _two_class_trace(n=24, seed=77, vocab=128):
+def _two_class_trace(n=3, seed=77, vocab=128):
     """The chaos/crash trace with class stamps: same shape as the ISSUE
     7/9 suites' _trace, alternating tenants, no caps/quotas in the
     policy — shedding must stay OFF so every request reaches a terminal
@@ -652,25 +629,25 @@ def _two_class_trace(n=24, seed=77, vocab=128):
 
 
 @pytest.fixture(scope="module")
-def chaos_wfq_golden(tiny_model, role_ctx):
+def chaos_wfq_golden(micro_model, role_ctx):
     slo = SLOPolicy.chat_batch()
-    eng = _disagg(tiny_model, role_ctx, slo=slo)
+    eng = _disagg(micro_model, role_ctx, slo=slo)
     gold = eng.run(max_steps=MAX_STEPS, arrivals=_two_class_trace())
-    assert len(gold) == 24 and not eng.failed
+    assert len(gold) == 3 and not eng.failed
     return gold
 
 
 @pytest.mark.chaos
 @pytest.mark.parametrize("name,plan", SCHEDULES,
                          ids=[n for n, _ in SCHEDULES])
-def test_chaos_schedules_bit_identical_under_wfq(tiny_model, role_ctx,
+def test_chaos_schedules_bit_identical_under_wfq(micro_model, role_ctx,
                                                  chaos_wfq_golden, name,
                                                  plan):
     """The ISSUE 7 fault matrix re-run with two-class WFQ live: every
     survivable schedule still finishes all requests bit-identical to the
     policied fault-free golden — the policy composes with the recovery
     ladder instead of racing it."""
-    eng = _disagg(tiny_model, role_ctx, slo=SLOPolicy.chat_batch(),
+    eng = _disagg(micro_model, role_ctx, slo=SLOPolicy.chat_batch(),
                   fault_plan=plan)
     res = eng.run(max_steps=MAX_STEPS, arrivals=_two_class_trace())
     assert eng.failed == [], (
@@ -678,29 +655,32 @@ def test_chaos_schedules_bit_identical_under_wfq(tiny_model, role_ctx,
         f"failures: {[(r.rid, r.failure) for r in eng.failed]}")
     assert res == chaos_wfq_golden, (
         f"{name}: tokens diverged from the policied golden")
+    injected = eng.metrics.counters["faults_injected"] > 0
+    assert injected == (name != "clean"), (
+        f"{name}: schedule injected nothing under WFQ")
 
 
 @pytest.mark.recovery
-def test_crash_sweep_bit_identical_under_wfq(tiny_model):
+def test_crash_sweep_bit_identical_under_wfq(micro_model):
     """The ISSUE 9 strided crash sweep with WFQ + a quota bucket in
     deficit at most crash points: checkpoint/restore must carry the
     policy books (service counters, vfloor, bucket levels) or replay
     forks — the union of pre-crash and post-recovery finishes must stay
     bit-identical to the fault-free policied golden."""
-    arrivals = _two_class_trace(n=20)
+    arrivals = _two_class_trace(n=8)
     slo = dict(chat_weight=4, batch_weight=1, quotas={"b0": (1, 2)})
     mk = lambda **kw: _colocated(                           # noqa: E731
-        tiny_model, slo=SLOPolicy.chat_batch(**slo), **kw)
+        micro_model, slo=SLOPolicy.chat_batch(**slo), **kw)
 
     journal = ControlJournal()
     eng = mk(journal=journal, checkpoint_every=8)
     golden = eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
     total = eng._steps
-    assert len(golden) == 20
+    assert len(golden) == 8
     assert eng.metrics.counters["quota_throttled"] > 0, (
         "quota never bit — the sweep is not exercising bucket restore")
 
-    stride = max(1, total // 6)
+    stride = max(1, total // 2)
     for s in range(1, total, stride):
         j = ControlJournal()
         e1 = mk(journal=j, checkpoint_every=8,

@@ -5,10 +5,14 @@ contract as every other serving lever.
 THE claim under test: the bigram prompt-lookup drafter + the exact-match
 greedy accept rule change ONLY the dispatch count — a committed token is
 committed because a verify row fed the identical committed prefix
-produced it, so the 50-request forced-preemption trace is BIT-IDENTICAL
+produced it, so the forced-preemption trace is BIT-IDENTICAL
 to ``speculate=off`` on the colocated engine and across mesh sizes
 n∈{1,2,4} at K∈{1,4}. The fast tier covers the colocated K sweep plus
 the two cheapest mesh corners; the slow tier fills in the cross product.
+The n=1 runs replay the whole trace; the runs across chips replay its
+first four requests on the ``N4_PAGES`` pool, where they preempt too
+(asserted in every sharded run, as ``test_spec_preempts_mid_verify_slot``
+does on the colocated engine).
 
 Also covered: the one-decode-program compile guard stays pinned across K
 and spec on/off; the EOS/limit accept edges ride plain int arrays
@@ -25,28 +29,24 @@ suite: auto resolves per rank count, a pinned wire makes every run
 quantize identically).
 """
 
-import signal
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
+from conftest import (N4_PAGES, N4_REQUESTS, N_REQUESTS, SHARDED_KW,
+                      assert_replay_identical, seeded_trace, sharded_engine)
 from triton_dist_tpu.models.llama import LlamaConfig, init_params
-from triton_dist_tpu.models.moe import MoEConfig, init_moe_params
-from triton_dist_tpu.serving import (ServingEngine, ShardedServingEngine,
-                                     ngram_draft, serving_mesh, spec_accept)
+from triton_dist_tpu.serving import ServingEngine, ngram_draft, spec_accept
 from triton_dist_tpu.serving.journal import ControlJournal
 from triton_dist_tpu.serving.speculate import SPEC_K_DEFAULT, resolve_spec_k
 from triton_dist_tpu.shmem import FaultPlan
 
 pytestmark = [pytest.mark.serving, pytest.mark.spec]
 
-WATCHDOG_S = 240
-N_REQUESTS = 50
 MAX_STEPS = 100_000
-WIRE = jnp.float8_e4m3fn  # pinned (NOT "auto") — see module docstring
+WIRE = SHARDED_KW["wire_dtype"]
 EOS = 5
 
 # exactly one compiled program per path, regardless of K or spec on/off —
@@ -54,22 +54,6 @@ EOS = 5
 # decode program; the drafter traces into it)
 ONE_OF_EACH = {"decode_compiles": 1, "prefill_compiles": 0,
                "prefill_programs": 0, "prefill_chunk_compiles": 1}
-
-
-@pytest.fixture(autouse=True)
-def spec_watchdog():
-    """Per-test SIGALRM wall cap (test_sharded_serving.py pattern)."""
-    def boom(signum, frame):
-        raise TimeoutError(
-            f"spec watchdog: test exceeded {WATCHDOG_S}s wall — "
-            "a mesh collective (or the engine) is hanging")
-    old = signal.signal(signal.SIGALRM, boom)
-    signal.alarm(WATCHDOG_S)
-    try:
-        yield
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
 
 
 @pytest.fixture(scope="module")
@@ -83,30 +67,6 @@ def llama_model():
     return cfg, params
 
 
-@pytest.fixture(scope="module")
-def moe_model():
-    cfg = MoEConfig(base=LlamaConfig(vocab_size=128, d_model=128,
-                                     n_layers=1, n_heads=4, n_kv_heads=2,
-                                     d_ff=128, max_seq_len=128,
-                                     dtype=jnp.float32),
-                    num_experts=4, topk=2, moe_d_ff=64)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    return cfg, params
-
-
-def _trace(n=N_REQUESTS):
-    """The sharded suite's 50-request bursty trace against a 9-page pool:
-    growth-driven preemption is forced, not incidental — slots holding
-    speculative KV get evicted mid-flight."""
-    rng = np.random.RandomState(77)
-    out = []
-    for i in range(n):
-        plen = int(rng.randint(3, 17))
-        mnt = int(rng.randint(2, 6))
-        out.append((i // 2, rng.randint(1, 128, size=plen).tolist(), mnt))
-    return out
-
-
 def _coloc(llama_model, **kw):
     cfg, params = llama_model
     kw.setdefault("num_slots", 4)
@@ -116,23 +76,6 @@ def _coloc(llama_model, **kw):
     kw.setdefault("prefill_chunk", 8)
     kw.setdefault("eos_id", EOS)
     return ServingEngine(params, cfg, **kw)
-
-
-def _sharded(moe_model, tp, sp, ep, **kw):
-    cfg, params = moe_model
-    kw.setdefault("num_slots", 4)
-    kw.setdefault("page_size", 8)
-    kw.setdefault("num_pages", 9)
-    kw.setdefault("pages_per_seq", 4)
-    kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("wire_dtype", WIRE)
-    return ShardedServingEngine(params, cfg, serving_mesh(tp, sp, ep), **kw)
-
-
-def _assert_identical(tokens, gold):
-    assert tokens.keys() == gold.keys()
-    bad = [r for r in gold if tokens[r] != gold[r]]
-    assert not bad, f"token streams diverged from spec-off golden: rids {bad}"
 
 
 # -- the accept rule on plain int arrays (the EOS/limit edges) ---------------
@@ -244,7 +187,7 @@ def test_resolve_spec_k_ladder():
 @pytest.fixture(scope="module")
 def coloc_golden(llama_model):
     eng = _coloc(llama_model)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N_REQUESTS))
     return tokens, eng.compile_stats
 
 
@@ -252,8 +195,8 @@ def coloc_golden(llama_model):
 def test_spec_bit_identical_colocated(llama_model, coloc_golden, k):
     gold, gold_compiles = coloc_golden
     eng = _coloc(llama_model, speculate=k)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
-    _assert_identical(tokens, gold)
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N_REQUESTS))
+    assert_replay_identical(tokens, gold, N_REQUESTS)
     # the compile guard: ONE decode program, flat across K and on/off
     assert eng.compile_stats == ONE_OF_EACH == gold_compiles
     c = eng.metrics.counters
@@ -268,8 +211,8 @@ def test_spec_preempts_mid_verify_slot(llama_model, coloc_golden):
     — and the trace STILL matches the spec-off golden bitwise."""
     gold, _ = coloc_golden
     eng = _coloc(llama_model, speculate=4)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
-    _assert_identical(tokens, gold)
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N_REQUESTS))
+    assert_replay_identical(tokens, gold, N_REQUESTS)
     c = eng.metrics.counters
     assert c["preemptions"] > 0, "pool never preempted — the test lost its bite"
     assert c["spec_rewinds"] > 0, "no draft was ever rejected at K=4"
@@ -284,7 +227,7 @@ def test_spec_accept_rate_on_repetitive_trace(llama_model):
     # one wave, landing at step 0, with long decode budgets: the dispatch
     # count is decode-bound, not arrival/prefill-bound — the axis
     # speculation moves
-    arrivals = [(0, tpl + rng.randint(1, 128, size=2).tolist(), 24)
+    arrivals = [(0, tpl + rng.randint(1, 128, size=2).tolist(), 16)
                 for _ in range(4)]
 
     def run(spec):
@@ -313,7 +256,8 @@ def test_spec_rejects_bad_knobs(llama_model):
 
 # -- sharded bit-identity matrix ---------------------------------------------
 # fast tier: the two cheapest corners; slow tier completes n∈{1,2,4} ×
-# K∈{1,4} (every combo runs the full 50-request forced-preemption trace
+# K∈{1,4} (n=1 runs the forced-preemption trace, n>1 its first four on the
+# N4_PAGES pool,
 # against the one spec-off n=1 golden — the cross-mesh contract makes a
 # single golden serve every mesh size).
 
@@ -323,14 +267,18 @@ _SLOW = [(1, 1, 1, 1), (1, 1, 2, 1), (1, 2, 2, 1), (1, 2, 2, 4)]
 
 @pytest.fixture(scope="module")
 def sharded_golden(moe_model):
-    eng = _sharded(moe_model, 1, 1, 1)
-    return eng.run(max_steps=MAX_STEPS, arrivals=_trace())
+    eng = sharded_engine(moe_model, 1, 1, 1)
+    return eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N_REQUESTS))
 
 
 def _run_matrix_case(moe_model, sharded_golden, tp, sp, ep, k):
-    eng = _sharded(moe_model, tp, sp, ep, speculate=k)
-    tokens = eng.run(max_steps=MAX_STEPS, arrivals=_trace())
-    _assert_identical(tokens, sharded_golden)
+    n, kw = N_REQUESTS, {}
+    if tp * sp * ep > 1:
+        n, kw = N4_REQUESTS, {"num_pages": N4_PAGES}
+    eng = sharded_engine(moe_model, tp, sp, ep, speculate=k, **kw)
+    tokens = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(n))
+    assert_replay_identical(tokens, sharded_golden, n)
+    assert eng.metrics.counters["preemptions"] >= 1
     assert eng.compile_stats == ONE_OF_EACH, eng.compile_stats
     assert eng.spec_k == k
 
@@ -352,26 +300,23 @@ def test_spec_bit_identical_sharded_full(moe_model, sharded_golden,
 # -- chaos replay with speculation on ----------------------------------------
 
 @pytest.mark.mesh
-def test_chaos_digest_skew_replay_with_spec(moe_model):
+def test_chaos_digest_skew_replay_with_spec(moe_model, sharded_golden):
     """A seeded fault schedule (transient digest skew through the PR 9
-    restore rung) replayed with speculation ON: the divergence is
-    absorbed exactly once, the restore re-seeds every drafter window
-    from the replayed prompts, and the tokens still match the spec-off
-    run of the SAME schedule."""
-    arrivals = _trace(20)
-
-    def run(spec):
-        eng = _sharded(moe_model, 1, 1, 2, journal=ControlJournal(),
-                       checkpoint_every=4, digest_every=1, speculate=spec,
-                       fault_plan=FaultPlan(seed=5, digest_skew_at=(9,)))
-        toks = eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
-        return toks, eng.metrics.counters
-
-    toks_off, _ = run(None)
-    toks_on, c = run(4)
+    restore rung) replayed with speculation ON across two chips (the
+    trace's first four requests, preempting on the N4_PAGES pool): the
+    divergence is absorbed exactly once, the restore re-seeds every
+    drafter window from the replayed prompts, and the tokens still match
+    the fault-free spec-off n=1 golden."""
+    eng = sharded_engine(moe_model, 1, 1, 2, journal=ControlJournal(),
+                         checkpoint_every=2, digest_every=1, speculate=4,
+                         num_pages=N4_PAGES,
+                         fault_plan=FaultPlan(seed=5, digest_skew_at=(5,)))
+    toks = eng.run(max_steps=MAX_STEPS, arrivals=seeded_trace(N4_REQUESTS))
+    c = eng.metrics.counters
     assert c["digest_recoveries"] == 1
     assert c["faults_injected"] >= 1
-    assert toks_on == toks_off
+    assert c["preemptions"] >= 1
+    assert_replay_identical(toks, sharded_golden, N4_REQUESTS)
 
 
 # -- tuned-key gate ----------------------------------------------------------
@@ -402,11 +347,13 @@ def test_spec_k_tuned_key_gated_and_consumed(moe_model):
 
     set_default_registry(reg)
     try:
-        eng = _sharded(moe_model, 1, 1, 1, speculate="auto", spec_bucket=2)
+        eng = sharded_engine(moe_model, 1, 1, 1, speculate="auto",
+                             spec_bucket=2)
         assert eng.spec_k == 2            # the tuned K won over default 4
-        eng2 = _sharded(moe_model, 1, 1, 1, speculate=3, spec_bucket=2)
+        eng2 = sharded_engine(moe_model, 1, 1, 1, speculate=3, spec_bucket=2)
         assert eng2.spec_k == 3           # explicit overrides the registry
-        eng3 = _sharded(moe_model, 1, 1, 1, speculate="auto", spec_bucket=0)
+        eng3 = sharded_engine(moe_model, 1, 1, 1, speculate="auto",
+                              spec_bucket=0)
         assert eng3.spec_k == SPEC_K_DEFAULT   # bucket miss → default
     finally:
         set_default_registry(None)

@@ -897,6 +897,5 @@ def moe_ffn_local(tokens: jax.Array, ids: jax.Array, w_up: jax.Array,
 
 
 __all__ = ["align_tokens_by_expert", "used_block_count", "emit_grouped_gemm",
-           "fit_block_k",
            "grouped_gemm", "grouped_gemm_gated", "pack_gated_weights",
            "PackedGatedWeights", "apply_grouped", "moe_ffn_local"]
