@@ -51,11 +51,9 @@ OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 # One chip: Mistral-7B widths are 0.44 GB of bf16 weights per layer + 0.52 GB
 # of embedding/head; 32 layers (14.5 GB) leave no room for a KV pool in
 # 16 GB, so DEPTH ONLY is cut. The pool is sized for the traffic (4 slots x
-# 9 pages x 128 tokens = 0.5 GiB at 28 layers) and costs ~4.6x that at the
-# decode program's peak: the layer scan reads it as xs and writes it as ys,
-# and the decode-horizon scan carries further copies (XLA memory analysis
-# for v5e: 11.9 GiB weights + 0.5 pool + 1.8 temp = 14.2 GiB at 28 layers;
-# 30 layers would need 15.2 of the chip's 15.75).
+# 9 pages x 128 tokens = 0.5 GiB at 28 layers). The programs carry the pool
+# and write it in place, so they hold no copy of it; the depth is PR 21's
+# and more would fit (`benchmark/tools/fit.py` is the way to find out).
 ONE_CHIP = dict(
     preset="mistral_7b", layers=28,
     argv=["--slots", "4", "--page-size", "128", "--pages", "36",

@@ -518,3 +518,83 @@ def test_ring_attention_flat_walk_lowers_1dev(ctx_single):
     compile_ok(lambda a, b, c: ring_attention(
         ctx_single, a, b, c, axis="x", causal=True,
         block_q=256, block_k=256), q, kv, kv)
+
+
+# -- the KV pool stays in one layout and in place (ISSUE 25) -----------------
+
+POOL_L, POOL_P, POOL_PAGE, POOL_PPS = 2, 209, 128, 13
+POOL_HKV, POOL_D = 8, 128                   # Mistral-7B: 8 KV heads of 128
+
+
+@pytest.fixture(scope="module")
+def paged_programs(topo):
+    """The engine's two programs at Mistral-7B widths, 2 layers, at the
+    benchmark cell's sizes (209 pages of 128, 16 slots, K = 4, chunk 256),
+    lowered for one described v5e the way ``benchmark/tools/fit.py`` lowers
+    them (pool donated). Name -> (optimised HLO text, memory analysis)."""
+    import dataclasses
+    from jax.sharding import SingleDeviceSharding
+    from triton_dist_tpu.models.llama import (LlamaConfig,
+                                              decode_multistep_paged,
+                                              init_page_pool, init_params,
+                                              prefill_chunk_paged)
+    cfg = dataclasses.replace(LlamaConfig.mistral_7b(), n_layers=POOL_L)
+    assert (cfg.n_kv_heads, cfg.head_dim) == (POOL_HKV, POOL_D)
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
+    params = on(jax.eval_shape(lambda k: init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pool = on(jax.eval_shape(
+        lambda: init_page_pool(cfg, POOL_P, POOL_PAGE)))
+    B, K, C = 16, 4, 256
+    lowered = {
+        "decode": jax.jit(
+            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
+                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
+            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
+                                       i32(B, POOL_PPS), i32(B)),
+        "chunk": jax.jit(
+            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
+                p, t, s, n, cfg, pages, bt),
+            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
+                                       i32(POOL_PPS))}
+    out = {}
+    for name, low in lowered.items():
+        exe = low.compile()
+        out[name] = (exe.as_text(), exe.memory_analysis())
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_pool_is_not_copied_sliced_or_relaid(paged_programs, program):
+    """No ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` (alone, as
+    a fusion's root or asynchronous) yields an array of the pool's or of one
+    layer's pool's shape, in any layout, and the program's temporaries stay
+    under half the pool: the pool is carried, written and read where it lies.
+    The window scatter ``K.at[layer, page, :, slot]`` on the carried stack
+    fails this: it makes the compiler hold the pool slot-major of head and
+    re-lay ALL of it out for the kernel inside the layer loop."""
+    import re
+    text, mem = paged_programs[program]
+    layer_pool = f"{POOL_P},{POOL_HKV},{POOL_PAGE},{POOL_D}]"
+    pool_shapes = [f"[{POOL_L},{layer_pool}", f"[1,{layer_pool}",
+                   f"[{layer_pool}"]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        kind = name if opcode == "fusion" else opcode
+        # "slice" takes in dynamic-slice, dynamic-update-slice and the
+        # asynchronous slice-start; "copy" the asynchronous copy-start
+        if any(s in result for s in pool_shapes) and re.search(
+                r"copy|slice", kind):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    pool_bytes = 2 * POOL_L * POOL_P * POOL_HKV * POOL_PAGE * POOL_D * 2
+    assert mem.temp_size_in_bytes < pool_bytes / 2, (
+        mem.temp_size_in_bytes, pool_bytes)
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"

@@ -424,15 +424,88 @@ def decode_step(params: dict, token: jax.Array, pos: jax.Array,
 
 
 def init_page_pool(cfg: LlamaConfig, num_pages: int, page_size: int) -> dict:
-    """Paged KV pool: per-layer page-major arrays [L, P, Hkv, page_size, D]
-    — each page is the tiling-aligned DMA slice ``gqa_decode_paged``
-    streams by block-table index. The serving runtime
-    (``triton_dist_tpu.serving``) owns page accounting; this is just the
-    device memory."""
+    """Paged KV pool: stacked page-major arrays [L, P, Hkv, page_size, D]
+    — each page of each layer is the tiling-aligned DMA slice
+    ``gqa_decode_paged`` streams by (layer, block-table index). The pool
+    has ONE layout (row-major) and lives in ONE place: the decode and
+    chunk programs carry it whole through their layer loop, write new
+    rows into it in place (``paged_kv_write(layer=)``) and read pages out
+    of it in place (``gqa_decode_paged(layer=)``); with the pool donated
+    nothing pool-shaped is sliced, stacked, copied or re-laid out (see
+    ``_paged_layers``). The serving runtime (``triton_dist_tpu.serving``)
+    owns page accounting; this is just the device memory."""
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     shape = (cfg.n_layers, num_pages, Hkv, page_size, Dh)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
+                  kv_len: jax.Array, active: jax.Array | None,
+                  cfg: LlamaConfig, pages: dict, block_table: jax.Array,
+                  ffn, attn_io, linear) -> tuple[jax.Array, dict]:
+    """The layer loop of both paged programs: ``x`` [R, D] is R rows of
+    decode (R batch slots, or a chunk's C tokens), row r at position
+    ``pos[r]`` attending ``kv_len[r]`` keys through ``block_table[r]``.
+    Returns (x after the last block, updated pages).
+
+    The pool is the loop's CARRY, never its per-layer input or output:
+    ``lax.scan`` cannot alias an ``xs`` to a ``ys``, so scanning over the
+    pool copies all of it every call, and a per-layer pool sliced out of
+    the stack is copied again for the kernel. Carried whole, written in
+    place by ``paged_kv_write(layer=)`` and read in place by
+    ``gqa_decode_paged(layer=)``, it is never copied at all.
+
+    Any hook (``ffn`` / ``attn_io`` / ``linear``, see
+    ``decode_step_paged``) unrolls the loop in Python over the same body.
+    ``attn_io`` keeps its per-layer contract: it is handed ``K[i]``,
+    ``V[i]`` and its result is put back at the static index."""
+    from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
+                                                  paged_kv_write)
+
+    lin = linear or (lambda h, w, name: h @ w)
+    R = x.shape[0]
+    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    positions = pos[:, None].astype(jnp.int32)             # [R, 1]
+
+    def body(carry, layer):
+        x, kp, vp = carry
+        p, i = layer
+        h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
+        q = rope(lin(h, p["wq"], "wq").reshape(R, 1, Hq, Dh), positions,
+                 cfg.rope_theta)[:, 0]                     # [R, Hq, Dh]
+        k = rope(lin(h, p["wk"], "wk").reshape(R, 1, Hkv, Dh), positions,
+                 cfg.rope_theta)[:, 0]                     # [R, Hkv, Dh]
+        v = lin(h, p["wv"], "wv").reshape(R, 1, Hkv, Dh)[:, 0]
+        if attn_io is None:
+            kp, vp = paged_kv_write(kp, vp, k, v, block_table, pos,
+                                    active=active, layer=i)
+            attn, _lse = gqa_decode_paged(q, kp, vp, block_table, kv_len,
+                                          layer=i)
+        else:
+            attn, kl, vl = attn_io(q, k, v, kp[i], vp[i], block_table, pos,
+                                   kv_len, active)
+            kp, vp = kp.at[i].set(kl), vp.at[i].set(vl)
+        x = x + lin(attn.reshape(R, Hq * Dh), p["wo"], "wo")
+        h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
+        if ffn is None:
+            ff = (jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
+                              ).astype(h.dtype) * (h @ p["w_up"])
+                  ) @ p["w_down"]
+        else:
+            ff = ffn(h, p)
+        x = x + ff.astype(x.dtype)
+        return (x, kp, vp), None
+
+    carry = (x, pages["k"], pages["v"])
+    if ffn is None and attn_io is None and linear is None:
+        carry, _ = lax.scan(body, carry, (
+            params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+    else:
+        for i in range(cfg.n_layers):
+            carry, _ = body(carry, (LayerParams(params["blocks"], i), i))
+    x, kp, vp = carry
+    return x, {"k": kp, "v": vp}
 
 
 def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
@@ -475,58 +548,16 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     overrides every dense projection (wq/wk/wv/wo/lm_head — the TP
     serving path plugs ``ops.allgather_gemm.tp_column_linear``). Either
     hook unrolls the layer loop like ``ffn`` does."""
-    from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
-                                                  paged_kv_write)
-
     lin = linear or (lambda h, w, name: h @ w)
-    B = token.shape[0]
-    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][token].astype(cfg.dtype)          # [B, D]
-    positions = pos[:, None].astype(jnp.int32)            # [B, 1]
     kv_len = (pos + 1).astype(jnp.int32)
-
-    def body(x, layer):
-        p, kp, vp = layer
-        h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-        q = rope(lin(h, p["wq"], "wq").reshape(B, 1, Hq, Dh), positions,
-                 cfg.rope_theta)[:, 0]                     # [B, Hq, Dh]
-        k = rope(lin(h, p["wk"], "wk").reshape(B, 1, Hkv, Dh), positions,
-                 cfg.rope_theta)[:, 0]                     # [B, Hkv, Dh]
-        v = lin(h, p["wv"], "wv").reshape(B, 1, Hkv, Dh)[:, 0]
-        if attn_io is None:
-            kp, vp = paged_kv_write(kp, vp, k, v, block_table, pos,
-                                    active=active)
-            attn, _lse = gqa_decode_paged(q, kp, vp, block_table, kv_len)
-        else:
-            attn, kp, vp = attn_io(q, k, v, kp, vp, block_table, pos,
-                                   kv_len, active)
-        x = x + lin(attn.reshape(B, Hq * Dh), p["wo"], "wo")
-        h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-        if ffn is None:
-            ff = (jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
-                              ).astype(h.dtype) * (h @ p["w_up"])
-                  ) @ p["w_down"]
-        else:
-            ff = ffn(h, p)
-        x = x + ff.astype(x.dtype)
-        return x, (kp, vp)
-
-    if ffn is None and attn_io is None and linear is None:
-        x, (ks, vs) = lax.scan(body, x, (params["blocks"], pages["k"],
-                                         pages["v"]))
-    else:
-        ks_l, vs_l = [], []
-        for i in range(cfg.n_layers):
-            p = LayerParams(params["blocks"], i)
-            x, (kp, vp) = body(x, (p, pages["k"][i], pages["v"][i]))
-            ks_l.append(kp)
-            vs_l.append(vp)
-        ks, vs = jnp.stack(ks_l), jnp.stack(vs_l)
+    x, pages = _paged_layers(params, x, pos, kv_len, active, cfg, pages,
+                             block_table, ffn, attn_io, linear)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lin(x, params["lm_head"], "lm_head").astype(jnp.float32)
     if sample:
-        return jnp.argmax(logits, -1).astype(jnp.int32), {"k": ks, "v": vs}
-    return logits, {"k": ks, "v": vs}
+        return jnp.argmax(logits, -1).astype(jnp.int32), pages
+    return logits, pages
 
 
 def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
@@ -578,12 +609,8 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     projections exactly as in ``decode_step_paged`` (the chunk's C rows
     play the batch-row role; ``active`` is the padded-tail mask).
     """
-    from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
-                                                  paged_kv_write)
-
     lin = linear or (lambda h, w, name: h @ w)
     C = tokens.shape[0]
-    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     idx = start.astype(jnp.int32) + jnp.arange(C, dtype=jnp.int32)   # [C]
     valid = idx < prompt_len                                         # [C]
     # padded rows park on the scratch page: position 0 keeps the block-
@@ -592,43 +619,8 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     kv_len = jnp.where(valid, idx + 1, 0).astype(jnp.int32)
     bt = jnp.broadcast_to(block_table[None, :], (C, block_table.shape[0]))
     x = params["embed"][tokens].astype(cfg.dtype)                    # [C, D]
-    positions = pos[:, None]                                         # [C, 1]
-
-    def body(x, layer):
-        p, kp, vp = layer
-        h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-        q = rope(lin(h, p["wq"], "wq").reshape(C, 1, Hq, Dh), positions,
-                 cfg.rope_theta)[:, 0]                    # [C, Hq, Dh]
-        k = rope(lin(h, p["wk"], "wk").reshape(C, 1, Hkv, Dh), positions,
-                 cfg.rope_theta)[:, 0]
-        v = lin(h, p["wv"], "wv").reshape(C, 1, Hkv, Dh)[:, 0]
-        if attn_io is None:
-            kp, vp = paged_kv_write(kp, vp, k, v, bt, pos, active=valid)
-            attn, _lse = gqa_decode_paged(q, kp, vp, bt, kv_len)
-        else:
-            attn, kp, vp = attn_io(q, k, v, kp, vp, bt, pos, kv_len, valid)
-        x = x + lin(attn.reshape(C, Hq * Dh), p["wo"], "wo")
-        h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-        if ffn is None:
-            ff = (jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
-                              ).astype(h.dtype) * (h @ p["w_up"])
-                  ) @ p["w_down"]
-        else:
-            ff = ffn(h, p)
-        x = x + ff.astype(x.dtype)
-        return x, (kp, vp)
-
-    if ffn is None and attn_io is None and linear is None:
-        x, (ks, vs) = lax.scan(body, x, (params["blocks"], pages["k"],
-                                         pages["v"]))
-    else:
-        ks_l, vs_l = [], []
-        for i in range(cfg.n_layers):
-            p = LayerParams(params["blocks"], i)
-            x, (kp, vp) = body(x, (p, pages["k"][i], pages["v"][i]))
-            ks_l.append(kp)
-            vs_l.append(vp)
-        ks, vs = jnp.stack(ks_l), jnp.stack(vs_l)
+    x, pages = _paged_layers(params, x, pos, kv_len, valid, cfg, pages, bt,
+                             ffn, attn_io, linear)
     # one-row head: the prompt's last token sits at chunk row
     # prompt_len - 1 - start when this is the final chunk (clamped into
     # range otherwise — the result is then garbage the engine discards)
@@ -637,7 +629,7 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     h_last = rmsnorm(h_last, params["final_norm"], cfg.norm_eps)
     logits = lin(h_last, params["lm_head"], "lm_head").astype(jnp.float32)
     tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
-    return tok, {"k": ks, "v": vs}
+    return tok, pages
 
 
 def decode_multistep_paged(params: dict, token: jax.Array, pos: jax.Array,

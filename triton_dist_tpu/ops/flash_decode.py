@@ -107,13 +107,15 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
                          sm_scale=sm_scale, n_kv_heads=n_kv_heads)
 
 
-def _decode_paged_kernel(kv_len_ref, bt_ref, q_ref, k_ref, v_ref, out_ref,
-                         lse_ref, acc, m_i, l_i, *, block_s: int,
+def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
+                         out_ref, lse_ref, acc, m_i, l_i, *, block_s: int,
                          sm_scale: float, n_kv_heads: int):
-    """Grid (B, pages_per_seq) over a paged KV pool; ``bt_ref`` is the
-    block table (scalar-prefetch — the index_map streams page
-    ``bt[b, s]``). Analog of the reference's block_table-driven split-KV
-    kernel (flash_decode.py:129-280 `page` indexing)."""
+    """Grid (B, pages_per_seq) over a paged KV pool; ``bt_ref`` (block
+    table) and ``layer_ref`` are scalar-prefetch operands read only by the
+    index_map, which streams page ``bt[b, s]`` of layer ``layer`` straight
+    out of the stacked pool. Analog of the reference's block_table-driven
+    split-KV kernel (flash_decode.py:129-280 `page` indexing)."""
+    del bt_ref, layer_ref
     b = pl.program_id(0)
     s = pl.program_id(1)
     _online_softmax_body(s, kv_len_ref[b], q_ref, k_ref, v_ref, out_ref,
@@ -183,12 +185,20 @@ def gqa_decode_partial(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      block_table: jax.Array, kv_len: jax.Array,
-                     sm_scale: float | None = None):
+                     sm_scale: float | None = None, layer=None):
     """Paged-attention decode over a shared KV page pool (the serving-side
     cache layout; parity with the reference's block_table path and its
     ``ref_paged_attn`` golden, test_sp_decode_attn.py:81-134).
 
-    q [B, Hq, D]; k_pages/v_pages [P, Hkv, page_size, D] (page-major pool);
+    q [B, Hq, D]; k_pages/v_pages [P, Hkv, page_size, D] (page-major pool)
+    or, with ``layer`` (a traced or Python int), the whole stacked pool
+    [L, P, Hkv, page_size, D] of ``models.llama.init_page_pool``: the same
+    kernel then streams its pages from ``pool[layer]`` IN PLACE (``layer``
+    rides as a scalar-prefetch operand of the index map), so a layer loop
+    never slices a per-layer pool out of the stack — XLA cannot fuse a
+    slice into a Pallas operand, it would copy the layer's pool per call.
+    The input's rank picks the form; the result is bitwise the 4-D call on
+    ``pool[layer]``.
     block_table [B, pages_per_seq] int32 page ids — entries past
     ceil(kv_len/page_size) may be ARBITRARY values (even out of range):
     the index map never dereferences them. kv_len [B] (0 allowed: the row
@@ -209,38 +219,49 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pages at ``kv_len = pos_b + i + 1``, exactly like the chunked-prefill
     C-rows-of-decode idiom.
     """
+    assert (layer is not None) == (k_pages.ndim == 5), (
+        "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
+    if layer is None:
+        # a per-layer pool is the L = 1 stack: adding a leading 1 is a
+        # bitcast, never a copy
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     B, Hq, D = q.shape
-    P_pool, Hkv, page_size, _ = k_pages.shape
+    _, P_pool, Hkv, page_size, _ = k_pages.shape
     assert Hq % Hkv == 0
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     pages_per_seq = block_table.shape[1]
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
 
-    def page_index(b, s, kl, bt):
+    def page_index(b, s, kl, bt, ly):
         # last valid page for row b (0 when kv_len == 0 — any real page
         # works, the compute mask kills its contribution); steps past it
         # revisit it (DMA-free), and the clamp keeps even garbage block-
         # table entries inside the pool so the DMA can never read OOB
         last = jnp.maximum((kl[b] + page_size - 1) // page_size - 1, 0)
         page = bt[b, jnp.minimum(s, last)]
-        return (jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
+        return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
 
     kernel = functools.partial(_decode_paged_kernel, block_s=page_size,
                                sm_scale=sm_scale, n_kv_heads=Hkv)
     grid = (B, pages_per_seq)
+    # the layer dim is squeezed out of the block: the body sees the same
+    # [1, Hkv, page_size, D] page block whatever the pool's depth
+    page_block = pl.BlockSpec((None, 1, Hkv, page_size, D), page_index)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, Hq, D), lambda b, s, kl, bt: (b, 0, 0)),
-                pl.BlockSpec((1, Hkv, page_size, D), page_index),
-                pl.BlockSpec((1, Hkv, page_size, D), page_index),
+                pl.BlockSpec((1, Hq, D), lambda b, s, kl, bt, ly: (b, 0, 0)),
+                page_block,
+                page_block,
             ],
             out_specs=[
-                pl.BlockSpec((1, Hq, D), lambda b, s, kl, bt: (b, 0, 0)),
-                pl.BlockSpec((1, Hq, 128), lambda b, s, kl, bt: (b, 0, 0)),
+                pl.BlockSpec((1, Hq, D), lambda b, s, kl, bt, ly: (b, 0, 0)),
+                pl.BlockSpec((1, Hq, 128),
+                             lambda b, s, kl, bt, ly: (b, 0, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((Hq, D), jnp.float32),
@@ -258,18 +279,21 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                             + B * pages_per_seq * Hkv * page_size * D * 2),
             transcendentals=B * Hq * pages_per_seq * page_size),
         interpret=default_interpret(),
-    )(kv_len, block_table, q, k_pages, v_pages)
+    )(kv_len, block_table, layer, q, k_pages, v_pages)
 
 
 def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
                    block_table: jax.Array, pos: jax.Array,
-                   active: jax.Array | None = None
+                   active: jax.Array | None = None, layer=None
                    ) -> tuple[jax.Array, jax.Array]:
     """Scatter one new (k, v) row per batch slot into the page pool:
     page ``block_table[b, pos_b // page_size]``, row ``pos_b % page_size``.
 
-    k/v_pages [P, Hkv, page_size, D]; k/v_new [B, Hkv, D]; pos [B] int32.
+    k/v_pages [P, Hkv, page_size, D], or with ``layer`` (a traced or Python
+    int) the whole stacked pool [L, P, Hkv, page_size, D], written IN PLACE
+    at ``pool[layer]`` and returned whole; k/v_new [B, Hkv, D]; pos [B]
+    int32.
     ``active`` [B] bool (optional) PARKS the write of masked-off rows on
     the scratch page (page 0, the id the serving engine reserves): a slot
     frozen mid-scan by the multi-token decode loop (done on EOS/budget, or
@@ -284,18 +308,41 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     rejected suffix's rows simply become garbage past the accepted
     cursor — overwritten by the next dispatch's writes before any read,
     the same argument that makes in-page padding tails safe.
+
+    The write is a scatter of ROWS of the pool's row-major 2-D view
+    ``[(L*)P*Hkv*page_size, D]`` (the reshape is a bitcast, and a 2-D array
+    leaves the TPU layout pass nothing to choose), so the pool keeps the
+    one layout ``gqa_decode_paged`` reads. The window scatter
+    ``pool.at[(layer,) page, :, slot].set(new)`` writes the same rows, but
+    makes the compiler hold the pool slot-major of head and re-lay it out
+    around every kernel call: half of the decode program's device time
+    until PR 25 (``tests/test_aot_topology.py`` holds the compiled programs
+    to "no pool-shaped copy"). It keeps that form's rule for stray page
+    ids: one outside the pool drops the write, a negative one counts from
+    the end.
     """
+    assert (layer is not None) == (k_pages.ndim == 5), (
+        "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
     B = pos.shape[0]
-    page_size = k_pages.shape[2]
-    rows = jnp.arange(B)
-    page = block_table[rows, pos // page_size]              # [B]
+    P_pool, Hkv, page_size, D = k_pages.shape[-4:]
+    n_rows = k_pages.size // D
+    page = block_table[jnp.arange(B), pos // page_size]     # [B]
     if active is not None:
         page = jnp.where(active, page, 0)
-    slot = pos % page_size                                  # [B]
-    # advanced indices (page, slot) around the head slice put the batch
-    # dim in front — [B, Hkv, D] rows
-    return (k_pages.at[page, :, slot].set(k_new),
-            v_pages.at[page, :, slot].set(v_new))
+    page = jnp.where(page < 0, page + P_pool, page)
+    in_pool = jnp.logical_and(page >= 0, page < P_pool)
+    if layer is not None:
+        page = jnp.asarray(layer, jnp.int32) * P_pool + page
+    # row of (page, head h, slot) = (page * Hkv + h) * page_size + slot
+    idx = ((page * Hkv)[:, None] + jnp.arange(Hkv, dtype=jnp.int32)
+           ) * page_size + (pos % page_size)[:, None]       # [B, Hkv]
+    idx = jnp.where(in_pool[:, None], idx, n_rows).reshape(B * Hkv)
+
+    def write(pool, new):
+        return pool.reshape(n_rows, D).at[idx].set(
+            new.reshape(B * Hkv, D), mode="drop").reshape(pool.shape)
+
+    return write(k_pages, k_new), write(v_pages, v_new)
 
 
 def _combine_kernel(outs_ref, lses_ref, out_ref):

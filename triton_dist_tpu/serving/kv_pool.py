@@ -1,11 +1,16 @@
-"""Paged KV block allocator over the ``[P, Hkv, page_size, D]`` page pool
-that ``ops.flash_decode.gqa_decode_paged`` consumes.
+"""Paged KV block allocator over the stacked ``[L, P, Hkv, page_size, D]``
+page pool that ``ops.flash_decode.gqa_decode_paged`` consumes.
 
 Two cleanly separated halves:
 
 - **device memory**: ``models.llama.init_page_pool`` arrays — plain jax
-  arrays the engine threads through its jitted step (donated, so the hot
-  loop updates pages in place). Nothing here ever looks at their values.
+  arrays the engine threads through its jitted step, donated. The pool has
+  one layout (row-major) and one home: the programs carry it whole through
+  their layer loop, ``paged_kv_write(layer=)`` writes the new rows into it
+  in place and ``gqa_decode_paged(layer=)`` streams pages out of it in
+  place, so a step moves the rows it writes and the pages it reads and no
+  other byte of the pool (``tests/test_aot_topology.py`` holds the compiled
+  programs to that). Nothing here ever looks at the arrays' values.
 - **host accounting** (this module): ``KVPagePool`` — a free-list over
   page ids with per-sequence ownership, allocate-on-decode growth and
   free-on-finish. Pure Python, deterministic (LIFO free list), microsecond
