@@ -598,3 +598,78 @@ def test_pool_is_not_copied_sliced_or_relaid(paged_programs, program):
     assert mem.temp_size_in_bytes < pool_bytes / 2, (
         mem.temp_size_in_bytes, pool_bytes)
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+
+
+# -- the latent family's programs at published widths (ISSUE 26) -------------
+
+# pages: a layer of the pool (184 MB) must not fit the chip's 128 MB of VMEM,
+# or XLA prefetches it there whole, which the cell's 2,241 pages never allow
+LAT_P, LAT_HELD = 1121, 12
+
+
+@pytest.fixture(scope="module")
+def latent_programs(topo):
+    """The engine's two programs for ``models.mla`` at Kimi-K2 widths (3
+    layers: the dense one and two sparse ones holding 12 of 384 experts), at
+    the benchmark cell's batch sizes (32 slots, K = 4, chunk 512, 70 pages a
+    sequence), lowered for one described v5e, pool donated."""
+    import dataclasses
+    from jax.sharding import SingleDeviceSharding
+    from triton_dist_tpu.models import mla
+    cfg = dataclasses.replace(mla.LatentMoEConfig(), n_layers=3,
+                              vocab_size=20480, n_experts_held=LAT_HELD)
+    fam = cfg.paged
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
+    params = on(jax.eval_shape(lambda k: mla.init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pool = on(jax.eval_shape(lambda: fam.init_pool(cfg, LAT_P, 128)))
+    B, K, C, PPS = 32, 4, 512, 70
+    lowered = {
+        "decode": jax.jit(
+            lambda p, t, pos, pages, bt, lim: fam.decode_multistep(
+                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
+            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
+                                       i32(B, PPS), i32(B)),
+        "chunk": jax.jit(
+            lambda p, t, s, n, pages, bt: fam.prefill_chunk(
+                p, t, s, n, cfg, pages, bt),
+            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
+                                       i32(PPS))}
+    out = {}
+    for name, low in lowered.items():
+        exe = low.compile()
+        out[name] = (exe.as_text(), exe.memory_analysis(), cfg)
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_latent_pool_and_expert_tables_stay_in_place(latent_programs,
+                                                     program):
+    """Mosaic takes the latent kernel and the grouped GEMMs at the published
+    widths, the trace will find them by name, and nothing shaped like the
+    latent pool, a layer of it, or an expert table (one layer's or the
+    stack's) comes out of a ``copy`` or a slice: the pool is carried and the
+    tables are read in place through the flattened [layers x held] view."""
+    import re
+    text, mem, cfg = latent_programs[program]
+    for kernel in ("mla_decode_paged", "grouped_gemm_gated", "grouped_gemm"):
+        assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), kernel
+    W, D, F = cfg.cache_width, cfg.d_model, cfg.moe_d_ff
+    big = [f"{LAT_P},128,{W}]", f"{LAT_HELD},{D},{F}]", f"{LAT_HELD},{F},{D}]",
+           f"{2 * LAT_HELD},{D},{F}]", f"{2 * LAT_HELD},{F},{D}]"]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        kind = name if opcode == "fusion" else opcode
+        if any(s in result for s in big) and re.search(r"copy|slice", kind):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    pool_bytes = cfg.n_layers * LAT_P * 128 * W * 2
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes

@@ -296,6 +296,20 @@ def _fd_paged_kv_write():
                    jnp.array([3], i32))
 
 
+def _fd_paged_rows_write():
+    from ..ops import paged_rows_write
+    paged_rows_write(jnp.zeros((8, 8, 128), f32), jnp.zeros((1, 128), f32),
+                     jnp.zeros((1, 4), i32), jnp.array([3], i32))
+
+
+def _fd_mla_decode_paged():
+    from ..ops import mla_decode_paged
+    mla_decode_paged(jnp.zeros((1, 2, 256), f32),
+                     jnp.zeros((1, 8, 8, 256), f32), jnp.zeros((1, 4), i32),
+                     jnp.array([20], i32), layer=0, latent_dim=128,
+                     sm_scale=0.1)
+
+
 def _fd_decode_combine():
     from ..ops import decode_combine
     decode_combine(jnp.zeros((2, 1, 4, 128), f32),
@@ -489,6 +503,10 @@ _ENTRIES = [
     RegistryEntry("gqa_decode_paged", _local(_fd_gqa_decode_paged),
                   meshes=MESH_LOCAL),
     RegistryEntry("paged_kv_write", _local(_fd_paged_kv_write),
+                  meshes=MESH_LOCAL),
+    RegistryEntry("paged_rows_write", _local(_fd_paged_rows_write),
+                  meshes=MESH_LOCAL),
+    RegistryEntry("mla_decode_paged", _local(_fd_mla_decode_paged),
                   meshes=MESH_LOCAL),
     RegistryEntry("decode_combine", _local(_fd_decode_combine),
                   meshes=MESH_LOCAL),

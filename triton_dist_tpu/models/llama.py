@@ -51,6 +51,12 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def paged(self) -> "PagedFamily":
+        """What the paged programs and the serving engine ask of this
+        config's family (see ``PagedFamily``)."""
+        return GQA_DENSE
+
     # -- benchmark shape presets (cf. test_ag_gemm_intra_node.py:153-160) --
     @classmethod
     def llama_7b(cls):
@@ -440,80 +446,160 @@ def init_page_pool(cfg: LlamaConfig, num_pages: int, page_size: int) -> dict:
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
+@dataclasses.dataclass(frozen=True)
+class PagedFamily:
+    """A model family, as the paged programs and ``serving.engine`` see it:
+    the kind of cache, of attention and of FFN are DATA here, and the
+    config's type picks the record (``cfg.paged``). The layer loop
+    (``_paged_layers``), the decode, multistep and chunk programs, the
+    scheduler and the page ledger are one for every family.
+
+    - ``init_pool(cfg, num_pages, page_size)``: the page pool, a pytree of
+      arrays ``[layer, page, ...]`` (the engine's page copy, export and
+      import map over its leaves; the ledger never sees its shape).
+    - ``segments(cfg, params)``: the runs of layers that share a body, in
+      order: ``[(blocks, first_layer, n_layers, ffn)]`` with ``blocks`` the
+      run's stacked per-layer params and ``ffn(cfg, p, h, layer, active) ->
+      (out [R, D], counts)``; ``counts`` is a tuple of int32 scalars, one per
+      name in ``counters``, or ``()``.
+    - ``attention(cfg, p, h, layer, pool, block_table, pos, kv_len, active,
+      shared_table, lin, attn_io) -> (out [R, D], pool)``: projections, the
+      pool write, the paged walk and the output projection of one layer.
+    - ``counters``: names of the per-dispatch counters the FFNs return; the
+      multistep program sums them over layers and inner steps and appends
+      one row each to its token slab.
+    - the programs the engine jits: ``decode_multistep``, ``prefill_chunk``,
+      and where the family has them ``decode_speculate`` and the contiguous
+      prefill pair ``prefill`` / ``init_kv_cache``.
+    - ``lacks``: the engine options this family does not take, of
+      ``inline_prefill`` (``prefill_chunk=None``), ``speculate``,
+      ``prefix_cache``, ``hooks`` (``ffn`` / ``attn_io`` / ``linear``); the
+      engine refuses them by name."""
+    name: str
+    init_pool: Any
+    segments: Any
+    attention: Any
+    decode_multistep: Any
+    prefill_chunk: Any
+    decode_speculate: Any = None
+    prefill: Any = None
+    init_kv_cache: Any = None
+    counters: tuple = ()
+    lacks: tuple = ()
+
+
+def require_config(cfg, kind: type, who: str) -> None:
+    """``who`` is written against one family's pool and layer; any other
+    config is refused by name rather than mis-run."""
+    if not isinstance(cfg, kind):
+        raise NotImplementedError(
+            f"{who} serves {kind.__name__} models only (a K/V page pool, "
+            f"GQA attention); got {type(cfg).__name__}")
+
+
+def swiglu_ffn(cfg, p, h: jax.Array, layer=None, active=None):
+    """The dense FFN of a layer, as a ``PagedFamily`` segment's ``ffn``."""
+    del cfg, layer, active
+    ff = (jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
+                      ).astype(h.dtype) * (h @ p["w_up"])) @ p["w_down"]
+    return ff, ()
+
+
+def _gqa_segments(cfg: LlamaConfig, params: dict) -> list:
+    return [(params["blocks"], 0, cfg.n_layers, swiglu_ffn)]
+
+
+def _gqa_attention(cfg: LlamaConfig, p, h, layer, pool, block_table, pos,
+                   kv_len, active, shared_table, lin, attn_io):
+    """GQA over the K/V pool: rows x heads of ``gqa_decode_paged``."""
+    from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
+                                                  paged_kv_write)
+    del shared_table
+    R = h.shape[0]
+    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    positions = pos[:, None].astype(jnp.int32)             # [R, 1]
+    kp, vp = pool["k"], pool["v"]
+    q = rope(lin(h, p["wq"], "wq").reshape(R, 1, Hq, Dh), positions,
+             cfg.rope_theta)[:, 0]                         # [R, Hq, Dh]
+    k = rope(lin(h, p["wk"], "wk").reshape(R, 1, Hkv, Dh), positions,
+             cfg.rope_theta)[:, 0]                         # [R, Hkv, Dh]
+    v = lin(h, p["wv"], "wv").reshape(R, 1, Hkv, Dh)[:, 0]
+    if attn_io is None:
+        kp, vp = paged_kv_write(kp, vp, k, v, block_table, pos,
+                                active=active, layer=layer)
+        attn, _lse = gqa_decode_paged(q, kp, vp, block_table, kv_len,
+                                      layer=layer)
+    else:
+        attn, kl, vl = attn_io(q, k, v, kp[layer], vp[layer], block_table,
+                               pos, kv_len, active)
+        kp, vp = kp.at[layer].set(kl), vp.at[layer].set(vl)
+    return lin(attn.reshape(R, Hq * Dh), p["wo"], "wo"), {"k": kp, "v": vp}
+
+
 def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
                   kv_len: jax.Array, active: jax.Array | None,
-                  cfg: LlamaConfig, pages: dict, block_table: jax.Array,
-                  ffn, attn_io, linear) -> tuple[jax.Array, dict]:
-    """The layer loop of both paged programs: ``x`` [R, D] is R rows of
-    decode (R batch slots, or a chunk's C tokens), row r at position
-    ``pos[r]`` attending ``kv_len[r]`` keys through ``block_table[r]``.
-    Returns (x after the last block, updated pages).
+                  cfg, pages: dict, block_table: jax.Array,
+                  ffn, attn_io, linear, shared_table: bool = False
+                  ) -> tuple[jax.Array, dict, tuple]:
+    """The layer loop of both paged programs, for every family: ``x``
+    [R, D] is R rows of decode (R batch slots, or a chunk's C tokens), row
+    r at position ``pos[r]`` attending ``kv_len[r]`` keys through
+    ``block_table[r]`` (``shared_table``: all rows have the same one).
+    What a layer IS comes from ``cfg.paged`` (``PagedFamily``): its
+    attention over its kind of pool, and per run of layers its FFN.
+    Returns (x after the last block, updated pages, the family's counters
+    summed over the layers).
 
     The pool is the loop's CARRY, never its per-layer input or output:
     ``lax.scan`` cannot alias an ``xs`` to a ``ys``, so scanning over the
     pool copies all of it every call, and a per-layer pool sliced out of
     the stack is copied again for the kernel. Carried whole, written in
-    place by ``paged_kv_write(layer=)`` and read in place by
-    ``gqa_decode_paged(layer=)``, it is never copied at all.
+    place (``paged_kv_write(layer=)``) and read in place
+    (``gqa_decode_paged(layer=)``), it is never copied at all.
 
     Any hook (``ffn`` / ``attn_io`` / ``linear``, see
     ``decode_step_paged``) unrolls the loop in Python over the same body.
     ``attn_io`` keeps its per-layer contract: it is handed ``K[i]``,
     ``V[i]`` and its result is put back at the static index."""
-    from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
-                                                  paged_kv_write)
-
+    fam = cfg.paged
     lin = linear or (lambda h, w, name: h @ w)
-    R = x.shape[0]
-    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    positions = pos[:, None].astype(jnp.int32)             # [R, 1]
+    hooked = not (ffn is None and attn_io is None and linear is None)
+    carry = (x, pages, tuple(jnp.int32(0) for _ in fam.counters))
+    for blocks, first, n, seg_ffn in fam.segments(cfg, params):
+        def body(carry, layer, seg_ffn=seg_ffn):
+            x, pool, counts = carry
+            p, i = layer
+            h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
+            attn, pool = fam.attention(cfg, p, h, i, pool, block_table, pos,
+                                       kv_len, active, shared_table, lin,
+                                       attn_io)
+            x = x + attn
+            h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
+            if ffn is None:
+                ff, new = seg_ffn(cfg, p, h, i, active)
+                if new:
+                    counts = tuple(a + b for a, b in zip(counts, new))
+            else:
+                ff = ffn(h, p)
+            x = x + ff.astype(x.dtype)
+            return (x, pool, counts), None
 
-    def body(carry, layer):
-        x, kp, vp = carry
-        p, i = layer
-        h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-        q = rope(lin(h, p["wq"], "wq").reshape(R, 1, Hq, Dh), positions,
-                 cfg.rope_theta)[:, 0]                     # [R, Hq, Dh]
-        k = rope(lin(h, p["wk"], "wk").reshape(R, 1, Hkv, Dh), positions,
-                 cfg.rope_theta)[:, 0]                     # [R, Hkv, Dh]
-        v = lin(h, p["wv"], "wv").reshape(R, 1, Hkv, Dh)[:, 0]
-        if attn_io is None:
-            kp, vp = paged_kv_write(kp, vp, k, v, block_table, pos,
-                                    active=active, layer=i)
-            attn, _lse = gqa_decode_paged(q, kp, vp, block_table, kv_len,
-                                          layer=i)
+        if hooked:
+            for j in range(n):
+                carry, _ = body(carry, (LayerParams(blocks, j), first + j))
         else:
-            attn, kl, vl = attn_io(q, k, v, kp[i], vp[i], block_table, pos,
-                                   kv_len, active)
-            kp, vp = kp.at[i].set(kl), vp.at[i].set(vl)
-        x = x + lin(attn.reshape(R, Hq * Dh), p["wo"], "wo")
-        h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-        if ffn is None:
-            ff = (jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
-                              ).astype(h.dtype) * (h @ p["w_up"])
-                  ) @ p["w_down"]
-        else:
-            ff = ffn(h, p)
-        x = x + ff.astype(x.dtype)
-        return (x, kp, vp), None
-
-    carry = (x, pages["k"], pages["v"])
-    if ffn is None and attn_io is None and linear is None:
-        carry, _ = lax.scan(body, carry, (
-            params["blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    else:
-        for i in range(cfg.n_layers):
-            carry, _ = body(carry, (LayerParams(params["blocks"], i), i))
-    x, kp, vp = carry
-    return x, {"k": kp, "v": vp}
+            carry, _ = lax.scan(body, carry, (
+                blocks, jnp.arange(first, first + n, dtype=jnp.int32)))
+    return carry
 
 
 def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
-                      cfg: LlamaConfig, pages: dict,
+                      cfg, pages: dict,
                       block_table: jax.Array, ffn=None,
                       active: jax.Array | None = None,
                       sample: bool = False, attn_io=None,
-                      linear=None) -> tuple[jax.Array, dict]:
+                      linear=None, counters: bool = False
+                      ) -> tuple[jax.Array, dict]:
     """One-token decode over the paged KV pool — the continuous-batching
     twin of ``decode_step``. Differences that make it a serving hot loop:
 
@@ -547,21 +633,27 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     arrays then stay sharded on their page dim). ``linear(h, w, name)``
     overrides every dense projection (wq/wk/wv/wo/lm_head — the TP
     serving path plugs ``ops.allgather_gemm.tp_column_linear``). Either
-    hook unrolls the layer loop like ``ffn`` does."""
+    hook unrolls the layer loop like ``ffn`` does.
+
+    ``cfg`` is any config with a ``paged`` family (``PagedFamily``): the
+    pool's kind, the attention and the FFNs are the family's.
+    ``counters=True`` appends a third result, the family's counters of
+    this step (a tuple of int32 scalars, ``cfg.paged.counters`` names
+    them; rows masked off by ``active`` are not counted)."""
     lin = linear or (lambda h, w, name: h @ w)
     x = params["embed"][token].astype(cfg.dtype)          # [B, D]
     kv_len = (pos + 1).astype(jnp.int32)
-    x, pages = _paged_layers(params, x, pos, kv_len, active, cfg, pages,
-                             block_table, ffn, attn_io, linear)
+    x, pages, counts = _paged_layers(params, x, pos, kv_len, active, cfg,
+                                     pages, block_table, ffn, attn_io,
+                                     linear)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = lin(x, params["lm_head"], "lm_head").astype(jnp.float32)
-    if sample:
-        return jnp.argmax(logits, -1).astype(jnp.int32), pages
-    return logits, pages
+    out = jnp.argmax(logits, -1).astype(jnp.int32) if sample else logits
+    return (out, pages, counts) if counters else (out, pages)
 
 
 def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
-                        prompt_len: jax.Array, cfg: LlamaConfig,
+                        prompt_len: jax.Array, cfg,
                         pages: dict, block_table: jax.Array,
                         ffn=None, attn_io=None,
                         linear=None) -> tuple[jax.Array, dict]:
@@ -619,8 +711,8 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     kv_len = jnp.where(valid, idx + 1, 0).astype(jnp.int32)
     bt = jnp.broadcast_to(block_table[None, :], (C, block_table.shape[0]))
     x = params["embed"][tokens].astype(cfg.dtype)                    # [C, D]
-    x, pages = _paged_layers(params, x, pos, kv_len, valid, cfg, pages, bt,
-                             ffn, attn_io, linear)
+    x, pages, _ = _paged_layers(params, x, pos, kv_len, valid, cfg, pages,
+                                bt, ffn, attn_io, linear, shared_table=True)
     # one-row head: the prompt's last token sits at chunk row
     # prompt_len - 1 - start when this is the final chunk (clamped into
     # range otherwise — the result is then garbage the engine discards)
@@ -633,7 +725,7 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
 
 
 def decode_multistep_paged(params: dict, token: jax.Array, pos: jax.Array,
-                           cfg: LlamaConfig, pages: dict,
+                           cfg, pages: dict,
                            block_table: jax.Array, limit: jax.Array,
                            horizon: int, eos_id: int | None = None,
                            ffn=None, attn_io=None, linear=None
@@ -661,45 +753,56 @@ def decode_multistep_paged(params: dict, token: jax.Array, pos: jax.Array,
     states (advanced exactly as many steps as the row was live) the
     engine keeps device-resident for the next dispatch. ``horizon=1``
     is exactly one fused ``decode_step_paged`` — today's per-token
-    semantics."""
+    semantics.
+
+    A family with ``counters`` (``cfg.paged.counters``) gets one more row
+    of ``toks`` for each, after the ``horizon`` token rows: row
+    ``horizon + j`` holds counter j summed over the layers and the inner
+    steps of this dispatch (live rows only), in every column. They ride
+    the slab the host downloads anyway: no further transfer."""
     assert horizon >= 1
     limit = limit.astype(jnp.int32)
     stopped0 = jnp.zeros(token.shape, jnp.bool_)
+    counts0 = tuple(jnp.int32(0) for _ in cfg.paged.counters)
 
     def one(carry, i):
-        tok, pos_c, stopped, pages_c = carry
+        tok, pos_c, stopped, pages_c, counts = carry
         act = jnp.logical_and(i < limit, ~stopped)         # [B] bool
-        nxt, pages_c = decode_step_paged(params, tok, pos_c, cfg, pages_c,
-                                         block_table, ffn=ffn, active=act,
-                                         sample=True, attn_io=attn_io,
-                                         linear=linear)
+        nxt, pages_c, new = decode_step_paged(
+            params, tok, pos_c, cfg, pages_c, block_table, ffn=ffn,
+            active=act, sample=True, attn_io=attn_io, linear=linear,
+            counters=True)
+        counts = tuple(a + b for a, b in zip(counts, new))
         tok = jnp.where(act, nxt, tok)
         pos_c = jnp.where(act, pos_c + 1, pos_c)
         if eos_id is not None:
             stopped = jnp.logical_or(stopped,
                                      jnp.logical_and(act, nxt == eos_id))
-        return (tok, pos_c, stopped, pages_c), nxt
+        return (tok, pos_c, stopped, pages_c, counts), nxt
 
     if ffn is None and attn_io is None and linear is None and horizon > 1:
-        (token, pos, _, pages), toks = lax.scan(
-            one, (token, pos, stopped0, pages),
+        (token, pos, _, pages, counts), toks = lax.scan(
+            one, (token, pos, stopped0, pages, counts0),
             jnp.arange(horizon, dtype=jnp.int32))
     else:
         # custom ffn may close over shard_map'd kernels that don't compose
         # with scan on every backend — unroll (same reason as the layer
         # loop above); horizon=1 skips the scan machinery entirely
         toks_l = []
-        carry = (token, pos, stopped0, pages)
+        carry = (token, pos, stopped0, pages, counts0)
         for i in range(horizon):
             carry, nxt = one(carry, jnp.int32(i))
             toks_l.append(nxt)
-        token, pos, _, pages = carry
+        token, pos, _, pages, counts = carry
         toks = jnp.stack(toks_l)
+    if counts:
+        toks = jnp.concatenate([toks, jnp.broadcast_to(
+            jnp.stack(counts)[:, None], (len(counts), toks.shape[1]))])
     return toks, token, pos, pages
 
 
 def decode_speculate_paged(params: dict, token: jax.Array, pos: jax.Array,
-                           cfg: LlamaConfig, pages: dict,
+                           cfg, pages: dict,
                            block_table: jax.Array, limit: jax.Array,
                            horizon: int, hist: jax.Array,
                            hist_len: jax.Array, eos_id: int | None = None,
@@ -945,7 +1048,16 @@ def forward_tp_overlap(ctx: ShmemContext, params: dict, tokens: jax.Array,
     return (x @ params["lm_head"]).astype(jnp.float32)
 
 
-__all__ = ["LlamaConfig", "LayerParams", "init_params", "param_specs",
+GQA_DENSE = PagedFamily(
+    name="gqa_dense", init_pool=init_page_pool, segments=_gqa_segments,
+    attention=_gqa_attention, decode_multistep=decode_multistep_paged,
+    prefill_chunk=prefill_chunk_paged,
+    decode_speculate=decode_speculate_paged, prefill=prefill,
+    init_kv_cache=init_kv_cache)
+
+
+__all__ = ["LlamaConfig", "LayerParams", "PagedFamily", "GQA_DENSE",
+           "swiglu_ffn", "require_config", "init_params", "param_specs",
            "forward",
            "forward_tp_overlap", "mlp_tp_overlap", "rmsnorm", "rope",
            "block_apply", "init_kv_cache", "init_page_pool", "prefill",

@@ -323,9 +323,17 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     """
     assert (layer is not None) == (k_pages.ndim == 5), (
         "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
+    idx = _page_row_index(k_pages.shape, block_table, pos, active, layer)
+    return _write_rows(k_pages, k_new, idx), _write_rows(v_pages, v_new, idx)
+
+
+def _page_row_index(pool_shape, block_table, pos, active, layer):
+    """Rows of a pool's row-major 2-D view that ``paged_kv_write`` writes:
+    [B * Hkv] int32, one per (batch slot, head); a write that must be
+    dropped gets the index one past the last row."""
     B = pos.shape[0]
-    P_pool, Hkv, page_size, D = k_pages.shape[-4:]
-    n_rows = k_pages.size // D
+    P_pool, Hkv, page_size, D = pool_shape[-4:]
+    n_rows = math.prod(pool_shape) // D
     page = block_table[jnp.arange(B), pos // page_size]     # [B]
     if active is not None:
         page = jnp.where(active, page, 0)
@@ -336,13 +344,28 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     # row of (page, head h, slot) = (page * Hkv + h) * page_size + slot
     idx = ((page * Hkv)[:, None] + jnp.arange(Hkv, dtype=jnp.int32)
            ) * page_size + (pos % page_size)[:, None]       # [B, Hkv]
-    idx = jnp.where(in_pool[:, None], idx, n_rows).reshape(B * Hkv)
+    return jnp.where(in_pool[:, None], idx, n_rows).reshape(B * Hkv)
 
-    def write(pool, new):
-        return pool.reshape(n_rows, D).at[idx].set(
-            new.reshape(B * Hkv, D), mode="drop").reshape(pool.shape)
 
-    return write(k_pages, k_new), write(v_pages, v_new)
+def _write_rows(pool, new, idx):
+    D = pool.shape[-1]
+    return pool.reshape(-1, D).at[idx].set(
+        new.reshape(-1, D), mode="drop").reshape(pool.shape)
+
+
+def paged_rows_write(pool: jax.Array, new: jax.Array,
+                     block_table: jax.Array, pos: jax.Array,
+                     active: jax.Array | None = None, layer=None
+                     ) -> jax.Array:
+    """``paged_kv_write`` for a pool that holds ONE row a token: pool
+    [(L,) P, page_size, W] (a latent cache: no head dim, keys and values in
+    one row), new [B, W]. Same row scatter, same rules for ``active``,
+    ``layer`` and stray page ids."""
+    assert (layer is not None) == (pool.ndim == 4), (
+        "layer= goes with a stacked [L, P, page_size, W] pool")
+    headed = pool.shape[:-2] + (1,) + pool.shape[-2:]        # Hkv = 1
+    idx = _page_row_index(headed, block_table, pos, active, layer)
+    return _write_rows(pool, new, idx)
 
 
 def _combine_kernel(outs_ref, lses_ref, out_ref):
@@ -1114,6 +1137,7 @@ def flash_decode_dist(ctx: ShmemContext, q: jax.Array,
 
 
 __all__ = ["gqa_decode_partial", "gqa_decode_paged", "paged_kv_write",
+           "paged_rows_write",
            "decode_combine", "ll_ag_merge", "sp_gqa_flash_decode",
            "sp_paged_attend_write", "pool_ag_start_local",
            "flash_decode_dist"]

@@ -395,6 +395,7 @@ def grouped_gemm(tokens: jax.Array, weights: jax.Array,
                 bytes_accessed=(P * H + E * H * N + P * N)
                 * jnp.dtype(tokens.dtype).itemsize,
                 transcendentals=0),
+            name="grouped_gemm",
             interpret=default_interpret(),
         )(block_expert, tokens, weights, *(() if sc2d is None else (sc2d,)))
 
@@ -429,6 +430,7 @@ def grouped_gemm(tokens: jax.Array, weights: jax.Array,
             bytes_accessed=(P * H + E * H * N + P * N)
             * jnp.dtype(tokens.dtype).itemsize,
             transcendentals=0),
+        name="grouped_gemm",
         interpret=default_interpret(),
     )(block_expert, nb, tokens, weights,
       *(() if sc2d is None else (sc2d,)))
@@ -583,6 +585,7 @@ def grouped_gemm_gated(tokens: jax.Array, w_gate: jax.Array,
             ),
             out_shape=jax.ShapeDtypeStruct((P, F), out_dtype),
             cost_estimate=cost,
+            name="grouped_gemm_gated",
             interpret=default_interpret(),
         )(block_expert, tokens, w_gate, w_up,
           *(() if sc2d is None else (sc2d,)))
@@ -820,6 +823,7 @@ def grouped_gemm_gated(tokens: jax.Array, w_gate: jax.Array,
                if deep else [])),
         out_shape=jax.ShapeDtypeStruct((P, F), out_dtype),
         cost_estimate=cost,
+        name="grouped_gemm_gated",
         interpret=default_interpret(),
     )(block_expert, nb, tokens, *w_args,
       *(() if sc2d is None else (sc2d,)))

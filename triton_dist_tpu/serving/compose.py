@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from triton_dist_tpu.models.llama import init_page_pool
+from triton_dist_tpu.models.llama import init_page_pool, require_config
 from triton_dist_tpu.models.moe import MoEConfig
 from triton_dist_tpu.ops.allgather_gemm import GemmConfig
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
@@ -125,6 +125,7 @@ class DisaggShardedEngine:
                  prefix_cache: bool = False,
                  slo: SLOPolicy | None = None,
                  artifact=None, artifact_key: str | None = None):
+        require_config(cfg, MoEConfig, type(self).__name__)
         assert prefill_chunk is not None, (
             "the composed engine requires prefill_chunk: chunks are the "
             "migration unit AND the sharded engine's only prefill path")

@@ -65,7 +65,8 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.models.llama import (LlamaConfig,
                                           decode_multistep_paged,
                                           init_page_pool,
-                                          prefill_chunk_paged)
+                                          prefill_chunk_paged,
+                                          require_config)
 from triton_dist_tpu.ops.page_migrate import migrate_pages
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
 from triton_dist_tpu.serving.deadline import (Backoff, Deadline,
@@ -431,6 +432,7 @@ class DisaggServingEngine:
                  prefix_cache: bool = False,
                  slo: SLOPolicy | None = None,
                  artifact=None, artifact_key: str | None = None):
+        require_config(cfg, LlamaConfig, "DisaggServingEngine")
         assert prefill_chunk >= 1 and decode_horizon >= 1
         assert signal_deadline_steps >= 1 and max_retries >= 0
         assert checkpoint_every is None or checkpoint_every >= 1

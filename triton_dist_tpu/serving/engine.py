@@ -58,11 +58,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from triton_dist_tpu.models.llama import (LlamaConfig,
-                                          decode_multistep_paged,
-                                          decode_speculate_paged,
-                                          init_kv_cache, init_page_pool,
-                                          prefill, prefill_chunk_paged)
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
 from triton_dist_tpu.serving.deadline import Deadline, EngineStallError
 from triton_dist_tpu.serving.journal import ControlJournal
@@ -148,7 +143,7 @@ class ServingEngine:
     bit-for-bit.
     """
 
-    def __init__(self, params: dict, cfg: LlamaConfig, num_slots: int = 4,
+    def __init__(self, params: dict, cfg, num_slots: int = 4,
                  page_size: int = 16, num_pages: int = 64,
                  pages_per_seq: int = 8, ffn=None,
                  max_prefills_per_step: int | None = None,
@@ -179,6 +174,22 @@ class ServingEngine:
         assert checkpoint_every is None or checkpoint_every >= 1
         assert queue_cap is None or queue_cap >= 1
         assert ttl_steps is None or ttl_steps >= 1
+        # the model family (models.llama.PagedFamily), picked by the
+        # config's type: its kind of page pool and the programs this engine
+        # jits. What a family lacks is refused here, by name.
+        fam = self._family = cfg.paged
+        asked = {
+            "inline_prefill": prefill_chunk is None,
+            "speculate": speculate not in (None, 0, "off"),
+            "prefix_cache": bool(prefix_cache),
+            "hooks": any(h is not None
+                         for h in (ffn, ffn_chunk, attn_io, linear))}
+        for option in fam.lacks:
+            if asked[option]:
+                raise NotImplementedError(
+                    f"the {fam.name!r} model family ({type(cfg).__name__}) "
+                    f"does not support {option} (inline_prefill: set "
+                    "prefill_chunk)")
         self.params = params
         self.cfg = cfg
         self.page_size = page_size
@@ -186,6 +197,8 @@ class ServingEngine:
         self.num_slots = num_slots
         self.max_prefills_per_step = max_prefills_per_step
         self.metrics = metrics or ServingMetrics()
+        for name in fam.counters:
+            self.metrics.counters.setdefault(name, 0)
         self.decode_horizon = decode_horizon
         self.eos_id = eos_id
         self._stall_steps = stall_deadline_steps
@@ -217,7 +230,7 @@ class ServingEngine:
             assert prefill_buckets, "bucket list must be non-empty"
         self.prefill_buckets = prefill_buckets
 
-        self.pool = init_page_pool(cfg, num_pages + 1, page_size)
+        self.pool = fam.init_pool(cfg, num_pages + 1, page_size)
         # unified pool contract (ISSUE 12): subclasses that shard the pool
         # arrays over SP set _pool_sp_ranks BEFORE super().__init__ so the
         # ledger knows the padded device page range (padding pages are
@@ -297,13 +310,13 @@ class ServingEngine:
         K = self.decode_horizon
         if self.spec_k:
             def step(p, t, pos, pages, bt, lim, hist, hlen):
-                return decode_speculate_paged(
+                return fam.decode_speculate(
                     p, t, pos, cfg, pages, bt, lim, horizon=K, hist=hist,
                     hist_len=hlen, eos_id=eos_id, ffn=ffn, attn_io=attn_io,
                     linear=linear)
         else:
             def step(p, t, pos, pages, bt, lim):
-                return decode_multistep_paged(
+                return fam.decode_multistep(
                     p, t, pos, cfg, pages, bt, lim, horizon=K,
                     eos_id=eos_id, ffn=ffn, attn_io=attn_io, linear=linear)
         # pool-output sharding pin (sharded engine sets _pool_out_sharding
@@ -336,7 +349,7 @@ class ServingEngine:
             # the only shape; cursor and prompt length ride as runtime
             # scalars (same trick as the decode limit argument)
             def chunk(p, t, s, n, pages, bt):
-                return prefill_chunk_paged(
+                return fam.prefill_chunk(
                     p, t, s, n, cfg, pages, bt, ffn=ffn_chunk or ffn,
                     attn_io=attn_io, linear=linear)
             chunk_kw = {} if ps is None else {
@@ -527,7 +540,7 @@ class ServingEngine:
                     f"not in the artifact's program set for "
                     f"{self._aot_key!r} — rebuild the artifact with this "
                     f"bucket declared")
-            cfg = self.cfg
+            cfg, prefill = self.cfg, self._family.prefill
             if self.prefill_buckets is None:
                 # exact mode: the legacy no-length trace, bit-for-bit
                 self._prefill_jit[key] = jax.jit(
@@ -551,7 +564,7 @@ class ServingEngine:
         pages = self.alloc.alloc(req.rid, n_pages)
         assert pages is not None, "admissible() guaranteed the pages"
         cache_len = -(-bucket // self.page_size) * self.page_size
-        cache = init_kv_cache(self.cfg, 1, cache_len)
+        cache = self._family.init_kv_cache(self.cfg, 1, cache_len)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :sp] = req.prompt
         logits, cache = self._prefill_fn(bucket, cache_len)(
@@ -561,10 +574,9 @@ class ServingEngine:
         # rows hold padded K/V but decode overwrites position p before
         # any read of kv_len > p sees it
         bt_row = jnp.asarray(self._device_rows(pages)[None])
-        self.pool = {
-            "k": cache_to_pages(cache["k"], self.pool["k"], bt_row),
-            "v": cache_to_pages(cache["v"], self.pool["v"], bt_row),
-        }
+        self.pool = jax.tree.map(
+            lambda c, pages: cache_to_pages(c, pages, bt_row), cache,
+            self.pool)
         tok0 = int(np.argmax(np.asarray(logits[0])))
         self.sched.activate(slot, req)
         self._jlog("admit", rid=req.rid, slot=slot)
@@ -645,12 +657,33 @@ class ServingEngine:
         old, new = res
         # the chunk's attention reads this page's earlier rows through
         # the patched block-table row, so the copy must precede dispatch
-        o, w = self.alloc.device_row(old), self.alloc.device_row(new)
-        self.pool = {
-            "k": self.pool["k"].at[:, w].set(self.pool["k"][:, o]),
-            "v": self.pool["v"].at[:, w].set(self.pool["v"][:, o]),
-        }
+        self._copy_page(old, new)
         self.metrics.inc("cow_copies")
+
+    # -- the pool's bytes, whatever its kind ------------------------------
+    # The pool is a pytree of arrays [layer, page, ...] (K and V of a GQA
+    # family, one latent array of an MLA one): these three map over its
+    # leaves. Eager array ops, NOT jitted programs, so the one-program-per-
+    # path compile contract is untouched.
+
+    def _copy_page(self, old: int, new: int) -> None:
+        """Copy ledger page ``old`` onto ``new``, every layer."""
+        o, w = self.alloc.device_row(old), self.alloc.device_row(new)
+        self.pool = jax.tree.map(lambda a: a.at[:, w].set(a[:, o]),
+                                 self.pool)
+
+    def _export_pages(self, page_ids):
+        """The bytes of ``page_ids`` (ledger ids): the pool's pytree with
+        the page dim gathered, [layer, len(page_ids), ...] a leaf."""
+        rows = self._device_rows(page_ids)
+        return jax.tree.map(lambda a: a[:, rows], self.pool)
+
+    def _import_pages(self, page_ids, payload) -> None:
+        """Land ``payload`` (as ``_export_pages`` gives it) on
+        ``page_ids``."""
+        rows = self._device_rows(page_ids)
+        self.pool = jax.tree.map(lambda a, b: a.at[:, rows].set(b),
+                                 self.pool, payload)
 
     # -- cluster page lending (ISSUE 17, serving/lending.py drives) -------
     def export_prefix(self, prompt, payload: bool = True):
@@ -673,10 +706,7 @@ class ServingEngine:
             return 0, [], None
         if not payload:
             return n * self.page_size, hit[:n], None
-        ids = self._device_rows(hit[:n])
-        kv = {"k": self.pool["k"][:, ids],
-              "v": self.pool["v"][:, ids]}
-        return n * self.page_size, hit[:n], kv
+        return n * self.page_size, hit[:n], self._export_pages(hit[:n])
 
     def adopt_prefix(self, prompt, n_tokens: int, payload=None) -> int:
         """Borrower half: land a peer's prefix pages locally. Fresh pages
@@ -711,13 +741,8 @@ class ServingEngine:
         if payload is not None:
             # the lender exported `want` pages; ours start past the
             # local hit depth
-            idx = self._device_rows(got)
-            self.pool = {
-                "k": self.pool["k"].at[:, idx].set(
-                    payload["k"][:, len(have):want]),
-                "v": self.pool["v"].at[:, idx].set(
-                    payload["v"][:, len(have):want]),
-            }
+            self._import_pages(got, jax.tree.map(
+                lambda b: b[:, len(have):want], payload))
         # first len(have) entries ride existing trie edges (insert is
         # first-writer-wins); the fresh pages take the deeper runs
         cache.insert(prompt[:want * self.page_size], have + got)
@@ -1149,6 +1174,10 @@ class ServingEngine:
             accepted = None
         slab = np.asarray(toks)            # [horizon, B] — blocks on device
         t_done = time.perf_counter()
+        # a family's own counters ride the same slab, one row each after
+        # the token rows (decode_multistep_paged): no further download
+        for j, name in enumerate(self._family.counters):
+            self.metrics.inc(name, int(slab[self.decode_horizon + j, 0]))
 
         self._steps += 1
         self.metrics.inc("dispatches")

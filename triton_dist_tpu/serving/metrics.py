@@ -120,6 +120,13 @@ class ServingMetrics:
     per step), pool occupancy (fraction, sampled per step), batch
     occupancy (active slots per step), per-dispatch device time and host
     overhead (s) — the device/host split bench.py reports.
+    A model family's own counters (``PagedFamily.counters``: for a share of
+    an expert-parallel layer, ``moe_local_rows`` = routed assignments that
+    landed on the experts held here and ``moe_experts_touched`` = held
+    experts with at least one row, both summed over the layers and inner
+    steps of every decode dispatch, live rows only) are added by the engine
+    that serves the family; they come off the token slab, not a transfer
+    of their own.
     """
 
     def __init__(self):

@@ -49,6 +49,7 @@ from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.aot.registry import TunedKey, get_default_registry
 from triton_dist_tpu.layers.ep_a2a_layer import EPAll2AllLayer
+from triton_dist_tpu.models.llama import require_config
 from triton_dist_tpu.models.moe import MoEConfig, moe_mlp_ep_overlap
 from triton_dist_tpu.ops.all_to_all import _DEFAULT_WIRE_FIT, a2a_wire_bytes
 from triton_dist_tpu.ops.allgather_gemm import GemmConfig, tp_column_linear
@@ -196,6 +197,7 @@ class ShardedServingEngine(ServingEngine):
                  long_context: bool = False,
                  speculate: int | str | None = None,
                  spec_hist: int = 64, spec_bucket: int = 0):
+        require_config(cfg, MoEConfig, "ShardedServingEngine")
         for ax in MESH_AXES:
             assert ax in ctx.axis_names, (
                 f"mesh is missing axis {ax!r} — build it with "

@@ -143,9 +143,8 @@ def default_interpret():
     path regardless of the live backend — used when lowering against an
     *abstract TPU topology* (AOT deployment, the CI topology-compile gate in
     tests/test_aot_topology.py) from a process whose default backend is CPU."""
-    if os.environ.get("TDT_FORCE_COMPILED") == "1":
+    if on_cpu():    # emit_pipeline asks the LIVE backend's tpu_info, forced
+        _register_cpu_tpu_info()                    # compile or not
+    if os.environ.get("TDT_FORCE_COMPILED") == "1" or not on_cpu():
         return False
-    if on_cpu():
-        _register_cpu_tpu_info()
-        return interpret_params()
-    return False
+    return interpret_params()
