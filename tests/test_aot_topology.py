@@ -600,6 +600,23 @@ def test_pool_is_not_copied_sliced_or_relaid(paged_programs, program):
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
 
 
+def test_chunk_rows_share_one_walk_decode_rows_do_not(paged_programs):
+    """The only Pallas kernel of the compiled chunk program is the one named
+    ``gqa_prefill_paged`` (once: the layer loop is a scan), so no attention
+    over a chunk runs as rows of decode; the decode program holds its
+    decode-rows kernel and none of that name (ISSUE 27)."""
+    import re
+    kernels = {name: re.findall(
+        r"(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+        for name, (text, _) in paged_programs.items()}
+    assert len(kernels["chunk"]) == 1 and re.fullmatch(
+        r"%gqa_prefill_paged[.\d]*", kernels["chunk"][0]), kernels["chunk"]
+    # (by instruction name: the module's table of source frames may name
+    # any function this process has traced)
+    assert kernels["decode"] and not any(
+        "gqa_prefill_paged" in k for k in kernels["decode"]), kernels["decode"]
+
+
 # -- the latent family's programs at published widths (ISSUE 26) -------------
 
 # pages: a layer of the pool (184 MB) must not fit the chip's 128 MB of VMEM,

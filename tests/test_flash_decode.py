@@ -14,6 +14,7 @@ from conftest import TEST_WORLD
 from triton_dist_tpu.ops.flash_decode import (NEG_INF, decode_combine,
                                               gqa_decode_paged,
                                               gqa_decode_partial,
+                                              gqa_prefill_paged,
                                               sp_gqa_flash_decode)
 from triton_dist_tpu.shmem.context import initialize_distributed
 from triton_dist_tpu.utils import assert_allclose
@@ -149,6 +150,54 @@ def test_paged_decode_kv_len_zero():
     golden = _paged_golden(q, kp, vp, np.asarray(bt), np.asarray(kv_len))
     assert_allclose(out[1], golden[1], atol=1e-3, rtol=1e-3)
     assert np.all(lse[1, :, 0] > -1e29)
+
+
+# a chunk of 16 rows in blocks of 8 over pages of 8, 6 pages a sequence:
+# (first position, prompt length); rows at or past the prompt are padding
+PREFILL_CASES = {
+    "page-start": (16, 32),            # starts on a page, ends on one
+    "mid-page": (5, 21),               # starts and ends inside pages
+    "padded-tail": (5, 18),            # the second block ends in padding
+    "padded-block": (3, 9),            # the second block is all padding
+    "one-page": (0, 5),                # context of 1 page
+    "three-pages": (8, 24),            # ... of 3: the blocks' pages differ
+    "all-pages": (32, 48),             # ... of every page of the table
+    "one-token": (40, 41),
+}
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("case", PREFILL_CASES)
+def test_prefill_paged_equals_decode_rows(case, layer):
+    """``gqa_prefill_paged`` (rows of ONE sequence share a walk of its pages)
+    against ``gqa_decode_paged`` run row by row on the same stacked pool: the
+    same keys, scores and softmax, so float32's last bits in interpret mode.
+    Block-table entries past the live pages are garbage, out of range too."""
+    C, Rb, Hq, Hkv, D, ps, pps, pool, L = 16, 8, 4, 2, 64, 8, 6, 16, 2
+    start, prompt_len = PREFILL_CASES[case]
+    q = jax.random.normal(jax.random.key(0), (C, Hq, D), jnp.float32)
+    kp = jax.random.normal(jax.random.key(1), (L, pool, Hkv, ps, D),
+                           jnp.float32)
+    vp = jax.random.normal(jax.random.key(2), (L, pool, Hkv, ps, D),
+                           jnp.float32)
+    idx = start + np.arange(C)
+    kv_len = jnp.asarray(np.where(idx < prompt_len, idx + 1, 0), jnp.int32)
+    live = -(-prompt_len // ps)
+    bt = np.array([3, 7, 1, 12, 5, 9], np.int32)
+    bt[live:] = [10 ** 6, -5, 2 ** 31 - 1, -(2 ** 31), pool][:pps - live]
+    bt = jnp.asarray(bt)
+    got = jax.jit(lambda ly: gqa_prefill_paged(
+        q, kp, vp, bt, kv_len, layer=ly, rows_per_block=Rb))(jnp.int32(layer))
+    want, _ = gqa_decode_paged(q, kp, vp, jnp.broadcast_to(bt, (C, pps)),
+                               kv_len, layer=layer)
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    padded = np.asarray(kv_len) == 0
+    assert padded.sum() == max(0, start + C - prompt_len)
+    np.testing.assert_array_equal(got[padded], 0.0)
+    # the per-layer form reads the same pages of a [P, ...] pool
+    np.testing.assert_array_equal(np.asarray(gqa_prefill_paged(
+        q, kp[layer], vp[layer], bt, kv_len, rows_per_block=Rb)), got)
 
 
 @pytest.mark.parametrize("ag_method", ["push", "fused"])

@@ -288,6 +288,14 @@ def _fd_gqa_decode_paged():
                      jnp.array([20], i32))
 
 
+def _fd_gqa_prefill_paged():
+    from ..ops import gqa_prefill_paged
+    q = jnp.zeros((8, 4, 128), f32)
+    pages = jnp.zeros((8, 2, 8, 128), f32)
+    gqa_prefill_paged(q, pages, pages, jnp.zeros((4,), i32),
+                      jnp.arange(13, 21, dtype=i32), rows_per_block=4)
+
+
 def _fd_paged_kv_write():
     from ..ops import paged_kv_write
     pages = jnp.zeros((8, 2, 8, 128), f32)
@@ -501,6 +509,8 @@ _ENTRIES = [
     RegistryEntry("gqa_decode_partial", _local(_fd_gqa_decode_partial),
                   meshes=MESH_LOCAL),
     RegistryEntry("gqa_decode_paged", _local(_fd_gqa_decode_paged),
+                  meshes=MESH_LOCAL),
+    RegistryEntry("gqa_prefill_paged", _local(_fd_gqa_prefill_paged),
                   meshes=MESH_LOCAL),
     RegistryEntry("paged_kv_write", _local(_fd_paged_kv_write),
                   meshes=MESH_LOCAL),

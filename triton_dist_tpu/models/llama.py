@@ -511,10 +511,14 @@ def _gqa_segments(cfg: LlamaConfig, params: dict) -> list:
 
 def _gqa_attention(cfg: LlamaConfig, p, h, layer, pool, block_table, pos,
                    kv_len, active, shared_table, lin, attn_io):
-    """GQA over the K/V pool: rows x heads of ``gqa_decode_paged``."""
+    """GQA over the K/V pool. Rows that each have their own block table
+    (decode slots, speculative verify rows) are rows x heads of
+    ``gqa_decode_paged``; rows that share one (``shared_table``: a prefill
+    chunk) walk it together in ``gqa_prefill_paged``. The ``attn_io`` hook
+    keeps the decode rows either way."""
     from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
+                                                  gqa_prefill_paged,
                                                   paged_kv_write)
-    del shared_table
     R = h.shape[0]
     Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     positions = pos[:, None].astype(jnp.int32)             # [R, 1]
@@ -527,8 +531,12 @@ def _gqa_attention(cfg: LlamaConfig, p, h, layer, pool, block_table, pos,
     if attn_io is None:
         kp, vp = paged_kv_write(kp, vp, k, v, block_table, pos,
                                 active=active, layer=layer)
-        attn, _lse = gqa_decode_paged(q, kp, vp, block_table, kv_len,
-                                      layer=layer)
+        if shared_table:
+            attn = gqa_prefill_paged(q, kp, vp, block_table[0], kv_len,
+                                     layer=layer)
+        else:
+            attn, _lse = gqa_decode_paged(q, kp, vp, block_table, kv_len,
+                                          layer=layer)
     else:
         attn, kl, vl = attn_io(q, k, v, kp[layer], vp[layer], block_table,
                                pos, kv_len, active)
@@ -668,21 +676,29 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     O(1). ``block_table`` [pages_per_seq] int32 is the sequence's block-
     table row (fill entries past the owned pages are never dereferenced).
 
-    The chunk rides the PAGED machinery end to end, treating its C tokens
-    as C batch rows of ``ops.flash_decode``:
+    The chunk rides the PAGED machinery end to end: its C tokens are C
+    rows of the layer loop decode uses (``_paged_layers``), rows that all
+    share ONE block-table row:
 
     - KV lands straight in the pool via ``paged_kv_write`` (pos = the
       absolute token position, ``active`` masks the padded tail onto the
       scratch page) — no temporary contiguous cache, no
       ``cache_to_pages`` converter copy on the admit path.
-    - attention is ``gqa_decode_paged`` with per-row
-      ``kv_len = position + 1``: each query walks the block table over
-      ALL pages filled so far — the pages of every previous chunk plus
-      this chunk's own causal prefix (written just above). The chunk-
-      boundary attention state therefore never crosses the host: it IS
-      the pages, re-read through the same online-softmax walk decode
-      uses, instead of an (m, l, acc) carry threaded between chunk
-      calls. Padded rows run with ``kv_len = 0`` (the empty-shard
+    - attention is the family's paged walk with per-row
+      ``kv_len = position + 1``: each query attends ALL pages filled so
+      far — the pages of every previous chunk plus this chunk's own
+      causal prefix (written just above). The chunk-boundary attention
+      state therefore never crosses the host: it IS the pages, re-read
+      through the same online-softmax walk decode uses, instead of an
+      (m, l, acc) carry threaded between chunk calls. Because the rows
+      share their table, they share the walk too: the K/V family runs
+      ``ops.flash_decode.gqa_prefill_paged`` (a page leaves HBM once a
+      row block, not once a row, and meets the MXU as a block's rows x
+      its query heads; as C rows of ``gqa_decode_paged`` the walk was
+      most of a Mistral-7B chunk's device time: PERF.md section 6,
+      PR 27), the latent family ``mla_decode_paged(rows_per_block=)``.
+      Only the ``attn_io`` hook still takes the chunk as C rows of
+      decode. Padded rows run with ``kv_len = 0`` (the empty-shard
       convention — zeros out, masked writes) and their residual-stream
       garbage is never read.
 
@@ -825,7 +841,7 @@ def decode_speculate_paged(params: dict, token: jax.Array, pos: jax.Array,
       ``pos_b + i``. Per layer, ``paged_kv_write`` scatters ALL rows'
       KV before ``gqa_decode_paged`` reads, and row (b, i)'s
       ``kv_len = pos_b + i + 1`` masks everything deeper — exactly
-      ``prefill_chunk_paged``'s C-rows-of-decode intra-call causality,
+      ``prefill_chunk_paged``'s write-then-read intra-call causality,
       so row i attends the KV rows 0..i-1 wrote THIS call. Rows past
       ``limit`` park on the scratch page (``active`` mask), same as a
       frozen multistep row.

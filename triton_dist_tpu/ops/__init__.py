@@ -33,7 +33,8 @@ from triton_dist_tpu.ops.all_to_all import (  # noqa: F401
     route_tokens, route_tokens_2d, dispatch, dispatch_2d, combine, combine_2d,
     expected_capacity)
 from triton_dist_tpu.ops.flash_decode import (  # noqa: F401
-    gqa_decode_partial, gqa_decode_paged, paged_kv_write, paged_rows_write,
+    gqa_decode_partial, gqa_decode_paged, gqa_prefill_paged, paged_kv_write,
+    paged_rows_write,
     decode_combine, ll_ag_merge, sp_gqa_flash_decode, sp_paged_attend_write,
     pool_ag_start_local, flash_decode_dist)
 from triton_dist_tpu.ops.mla_decode import mla_decode_paged  # noqa: F401

@@ -183,6 +183,18 @@ def gqa_decode_partial(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     )(kv_len, q, k_cache, v_cache)
 
 
+def _as_stack(k_pages, v_pages, layer):
+    """The paged kernels' pool operands: the stacked pool and ``layer`` as the
+    [1] int32 scalar-prefetch operand of their index maps."""
+    assert (layer is not None) == (k_pages.ndim == 5), (
+        "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
+    if layer is None:
+        # a per-layer pool is the L = 1 stack: adding a leading 1 is a
+        # bitcast, never a copy
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      block_table: jax.Array, kv_len: jax.Array,
                      sm_scale: float | None = None, layer=None):
@@ -216,16 +228,11 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     rows are (block_table, kv_len) pairs, so several rows may walk the
     SAME pages at staggered ``kv_len`` — the speculative verify dispatch
     (ISSUE 20) runs B*K rows this way, row (b, i) attending its slot's
-    pages at ``kv_len = pos_b + i + 1``, exactly like the chunked-prefill
-    C-rows-of-decode idiom.
+    pages at ``kv_len = pos_b + i + 1``. A prefill chunk's rows, which
+    ALL share one block-table row, have ``gqa_prefill_paged`` instead:
+    the same walk, shared by a block of rows.
     """
-    assert (layer is not None) == (k_pages.ndim == 5), (
-        "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
-    if layer is None:
-        # a per-layer pool is the L = 1 stack: adding a leading 1 is a
-        # bitcast, never a copy
-        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
     B, Hq, D = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
     assert Hq % Hkv == 0
@@ -280,6 +287,143 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             transcendentals=B * Hq * pages_per_seq * page_size),
         interpret=default_interpret(),
     )(kv_len, block_table, layer, q, k_pages, v_pages)
+
+
+# Rows of a prefill chunk that share one walk of the sequence's pages: with
+# Mistral's 4 query heads a KV head, 64 rows are a [256, 128] operand a head.
+# Chosen on the v5e (PERF.md section 6, PR 27; a 256-row chunk at 1,280
+# tokens of context, us a layer): 16 rows 227, 32 rows 151, 64 rows 129; 128
+# rows read 116 but need 18 MB of scoped VMEM, over Mosaic's 16 MB default.
+PREFILL_ROWS_PER_BLOCK = 64
+
+
+def _prefill_paged_kernel(kl_ref, bt_ref, layer_ref, q_ref, klr_ref, k_ref,
+                          v_ref, out_ref, acc, m_i, l_i, *, page_size: int,
+                          sm_scale: float):
+    """Grid (row blocks, pages_per_seq). ``q_ref`` [Hkv, M, D] is a block of
+    Rb rows x G heads a KV head (M = Rb * G), ``klr_ref`` [M, 1] their
+    ``kv_len``, ``kl_ref[i]`` the largest of block i; ``k_ref`` / ``v_ref``
+    [1, Hkv, page_size, D] the page. The online-softmax update is
+    ``_online_softmax_body``'s, with M query rows a head against the page
+    where decode has G, and the ``kv_len`` mask per row."""
+    del bt_ref, layer_ref
+    i, s = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(s == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        m_i[...] = jnp.full_like(m_i, NEG_INF)
+        l_i[...] = jnp.zeros_like(l_i)
+
+    # a page that no row of the block can see: no compute (and no DMA, the
+    # index map revisits the block's last live page)
+    @pl.when(s * page_size < kl_ref[i])
+    def _():
+        q, k, v = q_ref[...], k_ref[0], v_ref[0]
+        scores = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale  # [Hkv, M, page]
+        M = scores.shape[1]
+        pos = s * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (M, page_size), 1)
+        # a row sees key 0 on page 0 or sees nothing at all, so a page
+        # wholly masked for a row finds its running max already real and
+        # adds exp(NEG_INF - m) = 0; rows that see nothing are zeroed below
+        scores = jnp.where((pos < klr_ref[...])[None], scores, NEG_INF)
+        m_new = jnp.maximum(m_i[...], jnp.max(scores, axis=2, keepdims=True))
+        alpha = jnp.exp(m_i[...] - m_new)
+        p = jnp.exp(scores - m_new)
+        l_i[...] = l_i[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)             # [Hkv, M, D]
+        acc[...] = acc[...] * alpha + pv
+        m_i[...] = m_new
+
+    @pl.when(s == pl.num_programs(1) - 1)
+    def _():
+        l_safe = jnp.where(l_i[...] > 0, l_i[...], 1.0)
+        out = jnp.where((klr_ref[...] > 0)[None], acc[...] / l_safe, 0.0)
+        out_ref[...] = out.astype(out_ref.dtype)
+
+
+def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+                      block_table: jax.Array, kv_len: jax.Array,
+                      sm_scale: float | None = None, layer=None,
+                      rows_per_block: int = PREFILL_ROWS_PER_BLOCK
+                      ) -> jax.Array:
+    """``gqa_decode_paged`` for rows that all belong to ONE sequence (a
+    prefill chunk's queries): the same online-softmax walk of the block
+    table, shared by ``rows_per_block`` rows at a time, so that a page of the
+    sequence leaves HBM once a row block and meets the MXU as a
+    ``[rows_per_block * G, D]`` operand a KV head, not once a row as
+    ``[G, D]``.
+
+    q [C, Hq, D]; k_pages/v_pages as in ``gqa_decode_paged`` (4-D, or with
+    ``layer`` the stacked pool read in place); block_table [pages_per_seq]
+    int32, the ONE row all C rows share (entries past the live pages may be
+    arbitrary); kv_len [C] int32, any values: the chunk may start anywhere
+    in a page and end in padding (0: the row returns zeros). Returns out
+    [C, Hq, D], equal to ``gqa_decode_paged`` on C copies of the row up to
+    summation order; no lse (nothing merges a chunk's partials).
+
+    Grid (row blocks, pages): a (block, page) pair past the block's largest
+    ``kv_len`` — the pages above a block's own last position, not only
+    those past the prompt — revisits the block's last live page (no DMA)
+    and skips its compute."""
+    k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
+    C, Hq, D = q.shape
+    _, P_pool, Hkv, page_size, _ = k_pages.shape
+    assert Hq % Hkv == 0 and block_table.ndim == 1, (q.shape, block_table.shape)
+    assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
+    G = Hq // Hkv
+    Rb = math.gcd(C, rows_per_block)
+    n_blk, M = C // Rb, Rb * G
+    pages_per_seq = block_table.shape[0]
+    sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    kv_len = kv_len.astype(jnp.int32)
+    kl_blk = kv_len.reshape(n_blk, Rb).max(axis=1)
+    kl_rows = jnp.repeat(kv_len, G)[:, None]                # [C * G, 1]
+    # head-major rows: a KV head's operand is its G query heads of every row
+    q_hm = q.reshape(C, Hkv, G, D).swapaxes(0, 1).reshape(Hkv, C * G, D)
+
+    def page_index(i, s, kl, bt, ly):
+        last = jnp.maximum((kl[i] + page_size - 1) // page_size - 1, 0)
+        page = bt[jnp.minimum(s, last)]
+        return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
+
+    rows = lambda i, s, kl, bt, ly: (0, i, 0)               # noqa: E731
+    page_block = pl.BlockSpec((None, 1, Hkv, page_size, D), page_index)
+    live = C * pages_per_seq * page_size
+    out = pl.pallas_call(
+        functools.partial(_prefill_paged_kernel, page_size=page_size,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_blk, pages_per_seq),
+            in_specs=[
+                pl.BlockSpec((Hkv, M, D), rows),
+                pl.BlockSpec((M, 1), lambda i, s, kl, bt, ly: (i, 0)),
+                page_block,
+                page_block,
+            ],
+            out_specs=pl.BlockSpec((Hkv, M, D), rows),
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, M, D), jnp.float32),
+                pltpu.VMEM((Hkv, M, 1), jnp.float32),
+                pltpu.VMEM((Hkv, M, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((Hkv, C * G, D), q.dtype),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * live * Hq * D,
+            bytes_accessed=(2 * q.size + n_blk * pages_per_seq * Hkv
+                            * page_size * D * 2) * q.dtype.itemsize,
+            transcendentals=live * Hq),
+        name="gqa_prefill_paged",
+        interpret=default_interpret(),
+    )(kl_blk, block_table, layer, q_hm, kl_rows, k_pages, v_pages)
+    return out.reshape(Hkv, C, G, D).swapaxes(0, 1).reshape(C, Hq, D)
 
 
 def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,

@@ -10,8 +10,8 @@ hardware. Draft-verify breaks the bound WITHOUT a draft model:
   bigram and replay what followed it. Pure jnp over an int32 ``[B, H]``
   ring of recent tokens: no host sync, no extra model, no new weights.
 - **verify** (``models.llama.decode_speculate_paged``): ONE paged-
-  attention pass scores all K positions as K batch rows (the
-  ``prefill_chunk_paged`` C-rows-of-decode idiom), greedy-argmaxes each,
+  attention pass scores all K positions as K batch rows (each at its
+  own ``kv_len``, as a prefill chunk's rows are), greedy-argmaxes each,
   and ``spec_accept`` keeps the longest prefix where draft == argmax.
 - **rewind**: rejected positions' KV is already past the accepted
   cursor; the engine frees whole rejected pages via the existing
