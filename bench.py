@@ -1094,12 +1094,11 @@ def bench_serving(ctx, i1: int, i2: int, B: int = 1, Hq: int = 32,
       ``serving_dispatches`` vs ``serving_dispatches_k1`` (the >=K-times
       launch-count win), ``serving_host_syncs``, ``serving_compiles``.
 
-    - chunked-prefill rows (ISSUE 5) from the same trace replayed with
-      ``prefill_chunk``: ``serving_prefill_stall_us`` (per-chunk dispatch
-      latency), ``serving_decode_stall_us`` vs ``_inline_us`` (admission
-      time ahead of the decode dispatch, chunk-bounded vs whole-prompt),
-      ``serving_ttft_split_us`` (queue wait vs prefill latency, both
-      paths), ``serving_prefill_chunks``, ``serving_compiles_chunked``.
+    - prefill rows (ISSUE 5) from the same run:
+      ``serving_prefill_stall_us`` (per-chunk dispatch latency),
+      ``serving_decode_stall_us`` (admission and chunk time ahead of the
+      decode dispatch, bounded by one chunk), ``serving_ttft_split_us``
+      (queue wait vs prefill latency), ``serving_prefill_chunks``.
 
     Knobs mirror ``scripts/serve_sim.py``
     (--slots/--page-size/--layers/--decode-horizon/--prefill-chunk).
@@ -1168,11 +1167,12 @@ def bench_serving(ctx, i1: int, i2: int, B: int = 1, Hq: int = 32,
     # 3. real engine on a seeded trace: horizon K vs the K=1 baseline -------
     import numpy as _np
 
-    def _engine_trace(horizon: int, chunk: int | None = None):
+    def _engine_trace(horizon: int):
         rng = _np.random.RandomState(0)
         eng = ServingEngine(params, cfg, num_slots=num_slots, page_size=16,
                             num_pages=8 * num_slots + 8, pages_per_seq=8,
-                            decode_horizon=horizon, prefill_chunk=chunk)
+                            decode_horizon=horizon,
+                            prefill_chunk=prefill_chunk)
         for _ in range(3 * num_slots):
             plen = int(rng.randint(4, 24))
             prompt = [int(t) for t in
@@ -1195,30 +1195,21 @@ def bench_serving(ctx, i1: int, i2: int, B: int = 1, Hq: int = 32,
     out["serving_host_syncs"] = snap["host_syncs"]
     out["serving_compiles"] = eng.compile_stats
 
-    # 4. chunked paged prefill (ISSUE 5): same trace with admission split
-    # into co-scheduled chunks — the stall rows are the point: per-step
+    # 4. chunked paged prefill (ISSUE 5), from the same run: per-step
     # decode stall bounded by one chunk, TTFT split into queue wait vs
-    # prefill latency, zero contiguous-cache converter traffic
-    eng_c, snap_c, wall_c = _engine_trace(K, chunk=prefill_chunk)
+    # prefill latency
     us = lambda h, k="mean": round((h[k] or 0.0) * 1e6, 1)
-    out["serving_tok_per_s_chunked"] = round(
-        snap_c["tokens_generated"] / wall_c, 1)
-    out["serving_prefill_chunks"] = snap_c["prefill_chunks"]
-    out["serving_prefill_stall_us"] = us(snap_c["prefill_stall_s"])
-    out["serving_prefill_stall_p99_us"] = us(snap_c["prefill_stall_s"], "p99")
-    # decode stall: admission+prefill time ahead of the decode dispatch,
-    # chunked vs the inline-prefill baseline (same trace, same horizon)
-    out["serving_decode_stall_us"] = us(snap_c["decode_stall_s"])
-    out["serving_decode_stall_inline_us"] = us(snap["decode_stall_s"])
+    out["serving_prefill_chunks"] = snap["prefill_chunks"]
+    out["serving_prefill_stall_us"] = us(snap["prefill_stall_s"])
+    out["serving_prefill_stall_p99_us"] = us(snap["prefill_stall_s"], "p99")
+    # decode stall: admission+prefill time ahead of the decode dispatch
+    out["serving_decode_stall_us"] = us(snap["decode_stall_s"])
     out["serving_step_prefill_tokens_max"] = (
-        snap_c["step_prefill_tokens"]["max"])
+        snap["step_prefill_tokens"]["max"])
     out["serving_ttft_split_us"] = {
-        "queue": us(snap_c["ttft_queue_s"]),
-        "prefill": us(snap_c["ttft_prefill_s"]),
-        "queue_inline": us(snap["ttft_queue_s"]),
-        "prefill_inline": us(snap["ttft_prefill_s"]),
+        "queue": us(snap["ttft_queue_s"]),
+        "prefill": us(snap["ttft_prefill_s"]),
     }
-    out["serving_compiles_chunked"] = eng_c.compile_stats
     out["serving_knobs"] = {"num_slots": num_slots, "page_size": page_size,
                             "n_layers": n_layers, "attn_B": B, "attn_S": S,
                             "decode_horizon": K,

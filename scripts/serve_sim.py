@@ -86,18 +86,14 @@ p.add_argument("--speculate", default=None, metavar="K",
                     "(accepted/dispatch, draft hit rate, rewinds) to "
                     "stderr. Owns the horizon (needs --decode-horizon 1); "
                     "not plumbed through --disagg")
-p.add_argument("--prefill-buckets", default="pow2",
-               help='"pow2" (default), "exact", or a comma-separated '
-                    "ascending list of bucket lengths, e.g. 8,16,32")
-p.add_argument("--prefill-chunk", type=int, default=None,
-               help="chunked paged prefill: tokens per co-scheduled chunk "
-                    "(≤1 chunk per step rides beside the decode dispatch; "
-                    "omit for the bucketed inline prefill path)")
+p.add_argument("--prefill-chunk", type=int, default=16,
+               help="prompt tokens per prefill chunk: the one chunk "
+                    "program's row count (≤1 chunk per step rides beside "
+                    "the decode dispatch)")
 p.add_argument("--disagg", action="store_true",
                help="disaggregated prefill/decode over a 2-rank role mesh "
                     "(KV handed off by page migration; needs >= 2 devices; "
-                    "--prefill-chunk defaults to 2*page_size here — chunks "
-                    "ARE the migration unit)")
+                    "a prefill chunk is the migration unit)")
 p.add_argument("--model", choices=("llama", "moe"), default=None,
                help="'moe' serves the MoE preset through the sharded "
                     "engine (EP MoE FFN; defaults --mesh to 1x1x1); "
@@ -105,9 +101,8 @@ p.add_argument("--model", choices=("llama", "moe"), default=None,
 p.add_argument("--mesh", default=None, metavar="TPxSPxEP",
                help="serve under shard_map on this TP/SP/EP mesh, e.g. "
                     "2x2x2 (implies --model moe; needs tp*sp*ep devices "
-                    "— real ones, or simulated under --sim; "
-                    "--prefill-chunk defaults to 8 — the sharded engine "
-                    "REQUIRES the chunked path). Combine with --disagg "
+                    "— real ones, or simulated under --sim). "
+                    "Combine with --disagg "
                     "for the COMPOSED engine: disaggregated prefill "
                     "feeding a sharded decode fleet on this one mesh")
 p.add_argument("--wire", choices=("auto", "fp8", "none"), default="auto",
@@ -248,23 +243,12 @@ if args.speculate is not None:
         p.error("--speculate owns the decode horizon (the verify row "
                 "block IS the multistep machinery): needs "
                 "--decode-horizon 1")
-if (args.prefix_cache and args.prefill_chunk is None
-        and not args.disagg and args.mesh is None):
-    # the cache rides the chunked path (adoption = cursor jump)
-    args.prefill_chunk = 2 * args.page_size
 if args.lend_warm is not None and (
         not args.prefix_cache or args.prompt_zipf is None
         or args.disagg or args.mesh is not None):
     p.error("--lend-warm needs --prefix-cache + --prompt-zipf on the "
             "plain engine (no --mesh/--disagg): lending moves CACHED "
             "prefix pages between two engines of the same model")
-if args.prefill_buckets == "pow2":
-    buckets = "pow2"
-elif args.prefill_buckets == "exact":
-    buckets = None
-else:
-    buckets = tuple(int(b) for b in args.prefill_buckets.split(","))
-
 configure_compile_cache()
 
 # device gate: with --disagg on top of --mesh the composed engine runs
@@ -385,7 +369,7 @@ def mk_engine(fresh=False):
         wire = {"auto": "auto", "fp8": jnp.float8_e4m3fn,
                 "none": None}[args.wire]
         eng = DisaggShardedEngine(params, cfg, mesh_ctx,
-                                  prefill_chunk=args.prefill_chunk or 8,
+                                  prefill_chunk=args.prefill_chunk,
                                   wire_dtype=wire, **common)
         if not fresh:
             print(json.dumps({"mesh": eng.mesh_desc, "disagg": True,
@@ -400,7 +384,7 @@ def mk_engine(fresh=False):
         wire = {"auto": "auto", "fp8": jnp.float8_e4m3fn,
                 "none": None}[args.wire]
         eng = ShardedServingEngine(params, cfg, mesh_ctx,
-                                   prefill_chunk=args.prefill_chunk or 8,
+                                   prefill_chunk=args.prefill_chunk,
                                    wire_dtype=wire, overlap=args.overlap,
                                    long_context=args.long_context,
                                    **spec_kwargs, **common)
@@ -419,15 +403,13 @@ def mk_engine(fresh=False):
                   file=sys.stderr)
     elif args.disagg:
         from triton_dist_tpu.serving import DisaggServingEngine  # noqa: E402
-        chunk = args.prefill_chunk or 2 * args.page_size
-        eng = DisaggServingEngine(params, cfg, prefill_chunk=chunk,
-                                  **common)
+        eng = DisaggServingEngine(params, cfg,
+                                  prefill_chunk=args.prefill_chunk, **common)
         if args.chaos is not None and not fresh:
             print(json.dumps({"chaos": eng._fault_plan.describe()}),
                   file=sys.stderr)
     else:
-        eng = ServingEngine(params, cfg, prefill_buckets=buckets,
-                            prefill_chunk=args.prefill_chunk,
+        eng = ServingEngine(params, cfg, prefill_chunk=args.prefill_chunk,
                             **spec_kwargs, **common)
     return eng
 
@@ -495,8 +477,7 @@ if args.lend_warm is not None:
     lender = ServingEngine(params, cfg, num_slots=args.slots,
                            page_size=args.page_size, num_pages=args.pages,
                            pages_per_seq=args.pages_per_seq,
-                           prefill_chunk=args.prefill_chunk
-                           or 2 * args.page_size,
+                           prefill_chunk=args.prefill_chunk,
                            prefix_cache=True)
     n_warm = min(args.lend_warm, len(pool))
     for pre in pool[:n_warm]:

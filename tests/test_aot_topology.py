@@ -633,6 +633,8 @@ def latent_programs(topo):
     import dataclasses
     from jax.sharding import SingleDeviceSharding
     from triton_dist_tpu.models import mla
+    from triton_dist_tpu.models.llama import (decode_multistep_paged,
+                                              prefill_chunk_paged)
     cfg = dataclasses.replace(mla.LatentMoEConfig(), n_layers=3,
                               vocab_size=20480, n_experts_held=LAT_HELD)
     fam = cfg.paged
@@ -646,12 +648,12 @@ def latent_programs(topo):
     B, K, C, PPS = 32, 4, 512, 70
     lowered = {
         "decode": jax.jit(
-            lambda p, t, pos, pages, bt, lim: fam.decode_multistep(
+            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
                 p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
             donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
                                        i32(B, PPS), i32(B)),
         "chunk": jax.jit(
-            lambda p, t, s, n, pages, bt: fam.prefill_chunk(
+            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
                 p, t, s, n, cfg, pages, bt),
             donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
                                        i32(PPS))}

@@ -361,12 +361,19 @@ def test_host_syncs_stay_flat_while_the_counters_move(model):
 
 
 @pytest.mark.parametrize("option", [
-    {"prefix_cache": True}, {"speculate": 2}, {"prefill_chunk": None},
-    {"ffn": lambda h, p: h}])
+    {"prefix_cache": True}, {"speculate": 2}, {"ffn": lambda h, p: h}])
 def test_the_engine_refuses_what_the_family_lacks(model, option):
     fc, pc, w = model
     with pytest.raises(NotImplementedError, match="latent_moe"):
         ServingEngine(w, pc, **{**fc["engine"], **option})
+
+
+def test_prefill_chunk_is_a_shape_for_this_family_too(model):
+    """No family has another admission path for ``None`` to select: the
+    engine's one check refuses it, by the option's name."""
+    fc, pc, w = model
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        ServingEngine(w, pc, **{**fc["engine"], "prefill_chunk": None})
 
 
 @pytest.mark.parametrize("engine", ["ShardedServingEngine",

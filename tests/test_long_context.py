@@ -313,7 +313,6 @@ def _colocated(micro_model, **kw):
     kw.setdefault("num_pages", 16)
     kw.setdefault("pages_per_seq", 6)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("prefill_buckets", None)
     return ServingEngine(params, cfg, **kw)
 
 

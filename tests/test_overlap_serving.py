@@ -46,8 +46,7 @@ MAX_STEPS = 100_000
 
 # exactly one compiled program per path, regardless of overlap mode —
 # overlap must not fork the program cache
-ONE_OF_EACH = {"decode_compiles": 1, "prefill_compiles": 0,
-               "prefill_programs": 0, "prefill_chunk_compiles": 1}
+ONE_OF_EACH = {"decode_compiles": 1, "prefill_chunk_compiles": 1}
 
 
 def _serve(moe_model, tp, sp, ep, **kw):

@@ -62,7 +62,6 @@ def _colocated(micro_model, **kw):
     kw.setdefault("num_pages", 5)         # tight: forces preemption
     kw.setdefault("pages_per_seq", 4)      # the chunk grid is rows x pages
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("prefill_buckets", None)
     return ServingEngine(params, cfg, **kw)
 
 

@@ -4,8 +4,8 @@ restart reaches its first token with ZERO fresh jit traces.
 
 Two mechanisms compose (probed on this toolchain, both required):
 
-1. **Serialized programs** — every engine program (prefill chunk, bucketed
-   prefills, decode multistep, migrate, and the sharded variants at each
+1. **Serialized programs** — every engine program (prefill chunk, decode
+   multistep or speculate, migrate, and the sharded variants at each
    declared mesh shape) is exported through ``jax.export`` at build time
    with the exact dispatch-time argument signature, recorded by driving a
    tiny probe workload through the real engine. Loading deserializes the
@@ -93,7 +93,7 @@ class ArtifactSpec:
         {"kind": "colocated" | "sharded" | "disagg" | "disagg_sharded",
          "mesh": {"tp": 1, "sp": 2, "ep": 2},     # sharded kinds only
          ...engine ctor kwargs (num_slots, page_size, num_pages,
-            pages_per_seq, prefill_chunk, prefill_buckets, ...)}
+            pages_per_seq, prefill_chunk, ...)}
 
     ``model`` is ``{"kind": "llama"|"moe", ...config fields}`` (dtype as a
     string). The spec digest keys artifact staleness: change the fleet
@@ -255,28 +255,14 @@ def _instrument(engine) -> Dict[str, _Recorder]:
         return recs
 
     wrap(engine, "_step", "decode")
-    if engine._chunk_step is not None:
-        wrap(engine, "_chunk_step", "chunk")
-
-    orig_prefill_fn = engine._prefill_fn
-
-    def rec_prefill(bucket, cache_len):
-        key = (bucket, cache_len)
-        fn = orig_prefill_fn(bucket, cache_len)
-        if not isinstance(fn, _Recorder):
-            fn = _Recorder(fn, mesh)
-            engine._prefill_jit[key] = fn
-            recs[f"prefill:{bucket}x{cache_len}"] = fn
-        return fn
-
-    engine._prefill_fn = rec_prefill
+    wrap(engine, "_chunk_step", "chunk")
     return recs
 
 
 def _drive(engine, prompts: List[List[int]], max_new: int = 2,
            max_steps: int = 600) -> None:
     """Probe workload: run every prompt to completion so each program the
-    engine owns dispatches at least once (chunked prefill, decode, and —
+    engine owns dispatches at least once (prefill chunk, decode, and —
     on the disagg engines — the migration kernel)."""
     for p in prompts:
         engine.submit(p, max_new)
@@ -289,21 +275,13 @@ def _drive(engine, prompts: List[List[int]], max_new: int = 2,
             f"after {steps} steps ({len(engine._finished)}/{len(prompts)})")
 
 
-def _probe_prompts(decl: dict) -> List[List[int]]:
-    """One prompt per program the declaration implies: chunked engines get
-    a single chunk-spanning prompt; bucketed engines get one prompt per
-    declared bucket (the bucket list IS the compiled-program set)."""
+def _probe_prompts(decl: dict, chunk: int) -> List[List[int]]:
+    """The declaration's own ``probe`` prompts, else one prompt that spans
+    a chunk boundary: the chunk program and the decode program are the
+    whole set, and one such request dispatches both."""
     if decl.get("probe"):
         return [list(p) for p in decl["probe"]]
-    buckets = decl.get("prefill_buckets", "pow2")
-    chunk = decl.get("prefill_chunk")
-    if chunk is not None:
-        return [[(i % 30) + 1 for i in range(chunk + 3)]]
-    assert isinstance(buckets, (list, tuple)), (
-        "a non-chunked engine declaration must carry an explicit "
-        "prefill_buckets list — 'pow2' is open-ended and cannot be "
-        "enumerated into a closed compiled-program set")
-    return [[(i % 30) + 1 for i in range(b)] for b in buckets]
+    return [[(i % 30) + 1 for i in range(chunk + 3)]]
 
 
 # -- build -------------------------------------------------------------------
@@ -345,7 +323,7 @@ def build_artifact(spec: ArtifactSpec, out_dir: str,
             log(f"[aot] building {ekey}")
             engine = make_engine(decl, params, cfg)
             recs = _instrument(engine)
-            _drive(engine, _probe_prompts(decl))
+            _drive(engine, _probe_prompts(decl, engine.prefill_chunk))
             programs[ekey] = {}
             for name, rec in sorted(recs.items()):
                 assert rec.avals is not None, (
@@ -353,7 +331,7 @@ def build_artifact(spec: ArtifactSpec, out_dir: str,
                     f"{ekey} — widen the probe (see ArtifactSpec docs)")
                 exp = jax_export.export(rec._fn)(*rec.avals)
                 data = exp.serialize()
-                fname = f"{ekey.replace(':', '_')}--{name.replace(':', '_')}.stablehlo"
+                fname = f"{ekey.replace(':', '_')}--{name}.stablehlo"
                 with open(os.path.join(out_dir, _PROGRAMS, fname),
                           "wb") as f:
                     f.write(data)
@@ -498,16 +476,6 @@ class ServingArtifact:
 
     def program_names(self, ekey: str) -> List[str]:
         return sorted(self.manifest["programs"].get(ekey, {}).keys())
-
-    def prefill_keys(self, ekey: str) -> List[Tuple[int, int]]:
-        """(bucket, cache_len) pairs the artifact holds bucketed prefill
-        programs for under ``ekey``."""
-        out = []
-        for name in self.program_names(ekey):
-            if name.startswith("prefill:"):
-                b, c = name.split(":", 1)[1].split("x")
-                out.append((int(b), int(c)))
-        return sorted(out)
 
     def program(self, ekey: str, name: str) -> LoadedProgram:
         """Deserialize (once) and return the program; a missing key is a

@@ -191,13 +191,8 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
                            axis=-1).astype(x.dtype)
 
 
-def _attention(q, k, v, sm_scale: float, kv_len=None) -> jax.Array:
-    """Causal GQA attention. q [B,S,Hq,Dh]; k,v [B,S,Hkv,Dh]. ``kv_len``
-    [B] int32 (optional) additionally masks keys at/after each row's
-    length — the bucketed-prefill guard against padded tail positions
-    (causality already shields queries < kv_len; the extra mask keeps the
-    padded queries' rows finite too, same -1e30 fill as the causal mask,
-    so valid rows are bit-identical with or without it)."""
+def _attention(q, k, v, sm_scale: float) -> jax.Array:
+    """Causal GQA attention. q [B,S,Hq,Dh]; k,v [B,S,Hkv,Dh]."""
     B, S, Hq, Dh = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -205,9 +200,6 @@ def _attention(q, k, v, sm_scale: float, kv_len=None) -> jax.Array:
     scores = jnp.einsum("bshgd,bthd->bhgst", q, k,
                         preferred_element_type=jnp.float32) * sm_scale
     mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None, None]
-    if kv_len is not None:
-        valid = jnp.arange(S)[None] < kv_len[:, None]      # [B, S] keys
-        mask = jnp.logical_and(mask, valid[:, None, None, None])
     scores = jnp.where(mask, scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgst,bthd->bshgd", p.astype(v.dtype), v,
@@ -306,31 +298,26 @@ def mlp_tp_overlap(ctx, x2d: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# decode / serving path (KV cache + flash-decode kernel)
+# the plain contiguous reference (KV cache + flash-decode kernel):
+# ``init_kv_cache`` / ``prefill`` / ``decode_step`` / ``generate``. No engine
+# runs them; they are what the tests hold the paged programs below to.
 # ---------------------------------------------------------------------------
 
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_seq: int) -> dict:
-    """Head-major cache layout [L, B, Hkv, S, D] — KV blocks are
-    tiling-aligned DMA slices for the decode kernel (ops.flash_decode)."""
+    """The reference's contiguous cache, head-major [L, B, Hkv, S, D] — KV
+    blocks are tiling-aligned DMA slices for the decode kernel
+    (ops.flash_decode)."""
     Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
     shape = (cfg.n_layers, batch, Hkv, max_seq, Dh)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
 def prefill(params: dict, tokens: jax.Array, cfg: LlamaConfig,
-            cache: dict, length: jax.Array | None = None
-            ) -> tuple[jax.Array, dict]:
+            cache: dict) -> tuple[jax.Array, dict]:
     """Full-sequence forward that also writes K/V into ``cache[:, :, :S]``.
-    Returns (last-position logits [B, V], cache).
-
-    ``length`` [B] int32 (optional) is the per-row VALID prompt length for
-    bucketed prefill: ``tokens`` is padded to a bucket size S ≥ length, an
-    attention length mask hides the padded tail from every query row, and
-    the returned logits are taken at position ``length - 1`` per row (not
-    ``S - 1``). Cache rows at/after ``length`` hold padding K/V — callers
-    hand off only the first ``length`` positions (the serving engine's
-    page handoff already copies exactly the prompt's pages). ``None``
-    keeps the original exact-length code path unchanged."""
+    Returns (last-position logits [B, V], cache). The reference that
+    ``prefill_chunk_paged`` (what the engines admit through) is tested
+    against: same rows, position for position, in a contiguous cache."""
     B, S = tokens.shape
     Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = params["embed"][tokens].astype(cfg.dtype)
@@ -348,7 +335,7 @@ def prefill(params: dict, tokens: jax.Array, cfg: LlamaConfig,
             ck, k.transpose(0, 2, 1, 3), (0, 0, 0, 0))
         cv = lax.dynamic_update_slice(
             cv, v.transpose(0, 2, 1, 3), (0, 0, 0, 0))
-        attn = _attention(q, k, v, 1.0 / math.sqrt(Dh), kv_len=length)
+        attn = _attention(q, k, v, 1.0 / math.sqrt(Dh))
         x = x + attn.reshape(B, S, Hq * Dh) @ p["wo"]
         h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
         ff = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
@@ -358,12 +345,7 @@ def prefill(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 
     x, (ks, vs) = lax.scan(body, x, (params["blocks"], cache["k"],
                                      cache["v"]))
-    if length is None:
-        last = x[:, -1]
-    else:
-        last = jnp.take_along_axis(
-            x, (length - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    x = rmsnorm(last, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x[:, -1], params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": ks, "v": vs}
 
@@ -371,9 +353,10 @@ def prefill(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 def decode_step(params: dict, token: jax.Array, pos: jax.Array,
                 cfg: LlamaConfig, cache: dict,
                 ffn=None) -> tuple[jax.Array, dict]:
-    """One-token decode via the flash-decode kernel. ``token`` [B] int32,
-    ``pos`` scalar int32 (cache slots filled so far). Returns
-    (logits [B, V], cache). Attention = ops.flash_decode.gqa_decode_partial
+    """One-token decode of the contiguous reference via the flash-decode
+    kernel. ``token`` [B] int32, ``pos`` scalar int32 (cache slots filled
+    so far). Returns (logits [B, V], cache).
+    Attention = ops.flash_decode.gqa_decode_partial
     over the cache (the single-rank half of SpGQAFlashDecodeAttention).
 
     ``ffn(h, p) -> [B, D]`` overrides the per-layer FFN block (same hook as
@@ -468,24 +451,26 @@ class PagedFamily:
     - ``counters``: names of the per-dispatch counters the FFNs return; the
       multistep program sums them over layers and inner steps and appends
       one row each to its token slab.
-    - the programs the engine jits: ``decode_multistep``, ``prefill_chunk``,
-      and where the family has them ``decode_speculate`` and the contiguous
-      prefill pair ``prefill`` / ``init_kv_cache``.
+    - ``decode_speculate``: the speculative decode program, where the
+      family has one. The other two programs the engine jits,
+      ``decode_multistep_paged`` and ``prefill_chunk_paged``, are the same
+      functions for every family (they take the family from ``cfg.paged``)
+      and are not part of the record.
     - ``lacks``: the engine options this family does not take, of
-      ``inline_prefill`` (``prefill_chunk=None``), ``speculate``,
-      ``prefix_cache``, ``hooks`` (``ffn`` / ``attn_io`` / ``linear``); the
-      engine refuses them by name."""
+      ``speculate``, ``prefix_cache``, ``hooks`` (``ffn`` / ``attn_io`` /
+      ``linear``); the engine refuses them by name."""
     name: str
     init_pool: Any
     segments: Any
     attention: Any
-    decode_multistep: Any
-    prefill_chunk: Any
     decode_speculate: Any = None
-    prefill: Any = None
-    init_kv_cache: Any = None
     counters: tuple = ()
     lacks: tuple = ()
+
+    # ``benchmark/tools/fit_paged.py`` reads the two shared programs off the
+    # record; they are the module's functions, whatever the family.
+    decode_multistep = property(lambda self: decode_multistep_paged)
+    prefill_chunk = property(lambda self: prefill_chunk_paged)
 
 
 def require_config(cfg, kind: type, who: str) -> None:
@@ -671,9 +656,8 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     ``tokens`` [C] int32 is chunk ``[start, start + C)`` of the prompt,
     zero-padded past ``prompt_len``; ``start`` and ``prompt_len`` are
     runtime scalars, so ONE compiled program (keyed only by the chunk
-    size C) serves every prompt length and every chunk position — the
-    prefill jit cache shrinks from O(log max_prompt) bucket programs to
-    O(1). ``block_table`` [pages_per_seq] int32 is the sequence's block-
+    size C) serves every prompt length and every chunk position.
+    ``block_table`` [pages_per_seq] int32 is the sequence's block-
     table row (fill entries past the owned pages are never dereferenced).
 
     The chunk rides the PAGED machinery end to end: its C tokens are C
@@ -682,8 +666,7 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
 
     - KV lands straight in the pool via ``paged_kv_write`` (pos = the
       absolute token position, ``active`` masks the padded tail onto the
-      scratch page) — no temporary contiguous cache, no
-      ``cache_to_pages`` converter copy on the admit path.
+      scratch page) — no temporary contiguous cache.
     - attention is the family's paged walk with per-row
       ``kv_len = position + 1``: each query attends ALL pages filled so
       far — the pages of every previous chunk plus this chunk's own
@@ -973,8 +956,10 @@ def decode_step_sp(ctx, params: dict, token: jax.Array, pos: jax.Array,
 
 def generate(params: dict, prompt: jax.Array, cfg: LlamaConfig,
              max_new_tokens: int, max_seq: int | None = None) -> jax.Array:
-    """Greedy generation: prefill + scanned decode loop (batch decode, the
-    reference's target regime, SURVEY.md §5.7). Returns [B, max_new_tokens].
+    """Greedy generation over the contiguous cache: prefill + scanned
+    decode loop (batch decode, the reference's target regime, SURVEY.md
+    §5.7). Returns [B, max_new_tokens]. The golden the serving tests hold
+    the engine's tokens to: it shares no paged code with it.
     """
     B, S0 = prompt.shape
     max_seq = max_seq or cfg.max_seq_len
@@ -1066,10 +1051,7 @@ def forward_tp_overlap(ctx: ShmemContext, params: dict, tokens: jax.Array,
 
 GQA_DENSE = PagedFamily(
     name="gqa_dense", init_pool=init_page_pool, segments=_gqa_segments,
-    attention=_gqa_attention, decode_multistep=decode_multistep_paged,
-    prefill_chunk=prefill_chunk_paged,
-    decode_speculate=decode_speculate_paged, prefill=prefill,
-    init_kv_cache=init_kv_cache)
+    attention=_gqa_attention, decode_speculate=decode_speculate_paged)
 
 
 __all__ = ["LlamaConfig", "LayerParams", "PagedFamily", "GQA_DENSE",

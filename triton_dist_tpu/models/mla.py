@@ -45,9 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from triton_dist_tpu.models.llama import (PagedFamily, decode_multistep_paged,
-                                          prefill_chunk_paged, rmsnorm,
-                                          swiglu_ffn)
+from triton_dist_tpu.models.llama import PagedFamily, rmsnorm, swiglu_ffn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -396,10 +394,9 @@ def forward(params: dict, tokens: jax.Array, cfg: LatentMoEConfig
 
 LATENT_MOE = PagedFamily(
     name="latent_moe", init_pool=init_latent_pool, segments=_segments,
-    attention=_latent_attention, decode_multistep=decode_multistep_paged,
-    prefill_chunk=prefill_chunk_paged,
+    attention=_latent_attention,
     counters=("moe_local_rows", "moe_experts_touched"),
-    lacks=("inline_prefill", "speculate", "prefix_cache", "hooks"))
+    lacks=("speculate", "prefix_cache", "hooks"))
 
 
 __all__ = ["LatentMoEConfig", "LATENT_MOE", "init_params", "forward",

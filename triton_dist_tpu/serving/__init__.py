@@ -71,8 +71,7 @@ from triton_dist_tpu.serving.engine import ServingEngine
 from triton_dist_tpu.serving.journal import (EVENT_KINDS, SCHEMA_VERSION,
                                              ControlJournal)
 from triton_dist_tpu.serving.kv_pool import (KVPagePool, PageLedgerError,
-                                             cache_to_pages, page_pool_pspec,
-                                             pages_to_cache,
+                                             page_pool_pspec,
                                              shard_pool_arrays)
 from triton_dist_tpu.serving.lending import PageLendingTier
 from triton_dist_tpu.serving.metrics import (AttainmentWindow, Histogram,
@@ -143,8 +142,6 @@ __all__ = [
     "PrefixCache",
     "ReplicaPrefixIndex",
     "page_pool_pspec",
-    "cache_to_pages",
-    "pages_to_cache",
     "ContinuousBatchingScheduler",
     "Request",
     "RequestState",

@@ -89,9 +89,8 @@ from triton_dist_tpu.shmem.context import ShmemContext
 class DisaggShardedEngine:
     """Disaggregated serving with a :class:`ShardedServingEngine` decode
     fleet (module docstring). Constructor knobs are the union of the
-    disagg ladder knobs and the sharded mesh knobs; ``prefill_chunk`` is
-    mandatory (chunks are both the migration unit and the sharded
-    engine's only prefill path).
+    disagg ladder knobs and the sharded mesh knobs; a chunk is both the
+    migration unit and the sharded engine's prefill dispatch.
 
     Request lifecycle mirrors disagg: QUEUED (prefill queue) →
     PREFILLING (prefill fleet seat; decode pages reserved at admission;
@@ -109,7 +108,7 @@ class DisaggShardedEngine:
                  metrics: ServingMetrics | None = None,
                  metrics_decode: ServingMetrics | None = None,
                  decode_horizon: int = 1, eos_id: int | None = None,
-                 prefill_chunk: int | None = None,
+                 prefill_chunk: int = 16,
                  signal_deadline_steps: int = 8, max_retries: int = 3,
                  allow_degradation: bool = True, max_degradations: int = 1,
                  stall_deadline_steps: int | None = None,
@@ -126,9 +125,6 @@ class DisaggShardedEngine:
                  slo: SLOPolicy | None = None,
                  artifact=None, artifact_key: str | None = None):
         require_config(cfg, MoEConfig, type(self).__name__)
-        assert prefill_chunk is not None, (
-            "the composed engine requires prefill_chunk: chunks are the "
-            "migration unit AND the sharded engine's only prefill path")
         assert signal_deadline_steps >= 1 and max_retries >= 0
         assert checkpoint_every is None or journal is not None, (
             "checkpoint_every needs a journal to record into")

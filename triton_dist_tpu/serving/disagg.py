@@ -373,7 +373,7 @@ class DisaggServingEngine:
     ``num_pages``/``page_size`` size EACH role's pool (plus one scratch
     page per role). ``num_slots`` is the decode batch width;
     ``num_prefill_slots`` bounds concurrent chunked prefills.
-    ``prefill_chunk`` is mandatory here — chunks ARE the migration unit.
+    ``prefill_chunk`` sizes a chunk, which is also the migration unit.
 
     Recovery ladder (ISSUE 7): a MIGRATING request's wait for covering
     signals runs against a ``Deadline`` of ``signal_deadline_steps``

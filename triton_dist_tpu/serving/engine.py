@@ -11,17 +11,14 @@ Shape discipline (the TPU contract):
 - the page pool rides the jitted step as a DONATED argument (on backends
   that support donation), so the per-layer scatter of the new (k, v)
   updates pages in place — no pool-sized copy per token.
-- prefill runs per request OUTSIDE the batch into a small contiguous
-  cache — the layout the full-sequence kernels want — then
-  ``cache_to_pages`` hands the pages to the pool. Prompts are padded to
-  BUCKET lengths (power-of-two by default) with an attention length mask,
-  so the prefill compile cache is O(log max_prompt), not one program per
-  distinct prompt length. With ``prefill_chunk`` set the admit path is
-  CHUNKED instead: ``prefill_chunk_paged`` writes each chunk's KV
-  straight into pages through the block table (no contiguous cache, no
-  converter copies, device-fused first-token argmax), at most one chunk
-  per engine step co-scheduled with the decode dispatch — see the class
-  docstring.
+- admission does no model math: an admitted request takes its prompt's
+  pages and a slot in PREFILLING, and each ``step()`` runs AT MOST ONE
+  ``prefill_chunk``-token chunk of the oldest such slot
+  (``models.llama.prefill_chunk_paged``) beside the batched decode
+  dispatch. The chunk writes its KV straight into pages through the block
+  table and argmaxes the first token on device. Chunk size is the only
+  shape (cursor and prompt length are runtime scalars), so ONE chunk
+  program serves every prompt length — see the class docstring.
 
 Device-resident hot loop (the host/device split):
 
@@ -58,10 +55,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from triton_dist_tpu.models.llama import (decode_multistep_paged,
+                                          prefill_chunk_paged)
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
 from triton_dist_tpu.serving.deadline import Deadline, EngineStallError
 from triton_dist_tpu.serving.journal import ControlJournal
-from triton_dist_tpu.serving.kv_pool import KVPagePool, _fnv1a, cache_to_pages
+from triton_dist_tpu.serving.kv_pool import KVPagePool, _fnv1a
 from triton_dist_tpu.serving.metrics import ServingMetrics
 from triton_dist_tpu.serving.prefix_cache import PrefixCache
 from triton_dist_tpu.serving.scheduler import (AdmissionRejected,
@@ -110,6 +109,23 @@ def record_first_token(req: Request, metrics: ServingMetrics,
                               req.first_token_time - req.submit_time)
 
 
+def check_prefill_chunk(prefill_chunk) -> int:
+    """``prefill_chunk`` is a compiled shape, like ``page_size``: the rows
+    of the one chunk program every prompt is prefilled through. There is
+    no other admission path for ``None`` to select, so anything but a
+    positive int is refused here, by name (the sharded engine sizes its
+    A2A layers from the value before the base constructor runs, and asks
+    first)."""
+    if (isinstance(prefill_chunk, bool)
+            or not isinstance(prefill_chunk, (int, np.integer))
+            or prefill_chunk < 1):
+        raise ValueError(
+            f"prefill_chunk must be a positive int (the rows of the chunk "
+            f"program every prompt is prefilled through); got "
+            f"{prefill_chunk!r}")
+    return int(prefill_chunk)
+
+
 class ServingEngine:
     """Continuous-batching serving engine over the paged decode step.
 
@@ -121,37 +137,30 @@ class ServingEngine:
     same hook ``decode_step``/``decode_step_sp`` expose).
 
     ``decode_horizon`` is K, the inner scanned steps per dispatch (see
-    module docstring). ``prefill_buckets`` is ``"pow2"`` (pad prompts to
-    the next power of two, floor 8), an explicit ascending tuple of
-    bucket lengths, or ``None`` for exact-length prefill (one compile per
-    distinct prompt length — the pre-bucketing behavior, bit-exact).
-    ``eos_id`` enables early finish: a slot freezes on device the step it
-    emits ``eos_id`` and the host finishes the request at reconcile.
+    module docstring). ``eos_id`` enables early finish: a slot freezes on
+    device the step it emits ``eos_id`` and the host finishes the request
+    at reconcile.
 
-    ``prefill_chunk`` (ISSUE 5 tentpole) switches admission to CHUNKED
-    PAGED prefill: an admitted slot enters PREFILLING holding its pages
-    and a chunk cursor, and each ``step()`` dispatches AT MOST ONE
-    ``prefill_chunk``-token chunk (``models.llama.prefill_chunk_paged``)
-    alongside the batched decode dispatch — Sarathi-style co-scheduling
-    that bounds the per-step decode stall by one chunk instead of a
-    whole prompt. KV goes straight into pages through the block table
-    (no contiguous cache, no ``cache_to_pages`` copies) and the first
-    token's argmax is fused on device (no host logits download). One
-    compiled chunk program serves every prompt length — with chunking on
-    the prefill jit cache is O(1) and ``prefill_buckets`` is unused.
-    ``prefill_chunk=None`` (default) keeps the bucketed inline path
-    bit-for-bit.
+    ``prefill_chunk`` is a compiled shape like ``page_size``: the rows of
+    the ONE chunk program every prompt is prefilled through. An admitted
+    slot enters PREFILLING holding its pages and a chunk cursor, and each
+    ``step()`` dispatches AT MOST ONE chunk
+    (``models.llama.prefill_chunk_paged``) alongside the batched decode
+    dispatch — Sarathi-style co-scheduling that bounds the per-step
+    decode stall by one chunk instead of a whole prompt. KV goes straight
+    into pages through the block table and the first token's argmax is
+    fused on device (no host logits download). A mid-prefill preemptee
+    keeps its filled pages and resumes at its cursor; a prefix-cache hit
+    jumps the cursor the same way.
     """
 
     def __init__(self, params: dict, cfg, num_slots: int = 4,
                  page_size: int = 16, num_pages: int = 64,
                  pages_per_seq: int = 8, ffn=None,
-                 max_prefills_per_step: int | None = None,
                  metrics: ServingMetrics | None = None,
                  decode_horizon: int = 1,
-                 prefill_buckets="pow2",
                  eos_id: int | None = None,
-                 prefill_chunk: int | None = None,
+                 prefill_chunk: int = 16,
                  stall_deadline_steps: int = 256,
                  ffn_chunk=None, attn_io=None, linear=None,
                  journal: ControlJournal | None = None,
@@ -165,11 +174,7 @@ class ServingEngine:
                  speculate: int | str | None = None,
                  spec_hist: int = 64, spec_bucket: int = 0):
         assert decode_horizon >= 1
-        assert prefill_chunk is None or prefill_chunk >= 1
-        assert not prefix_cache or prefill_chunk is not None, (
-            "prefix_cache needs prefill_chunk set — a cache hit resumes "
-            "chunked prefill at its cursor; the bucketed inline path has "
-            "no cursor to resume at")
+        prefill_chunk = check_prefill_chunk(prefill_chunk)
         assert stall_deadline_steps >= 1
         assert checkpoint_every is None or checkpoint_every >= 1
         assert queue_cap is None or queue_cap >= 1
@@ -179,7 +184,6 @@ class ServingEngine:
         # jits. What a family lacks is refused here, by name.
         fam = self._family = cfg.paged
         asked = {
-            "inline_prefill": prefill_chunk is None,
             "speculate": speculate not in (None, 0, "off"),
             "prefix_cache": bool(prefix_cache),
             "hooks": any(h is not None
@@ -188,14 +192,12 @@ class ServingEngine:
             if asked[option]:
                 raise NotImplementedError(
                     f"the {fam.name!r} model family ({type(cfg).__name__}) "
-                    f"does not support {option} (inline_prefill: set "
-                    "prefill_chunk)")
+                    f"does not support {option}")
         self.params = params
         self.cfg = cfg
         self.page_size = page_size
         self.pages_per_seq = pages_per_seq
         self.num_slots = num_slots
-        self.max_prefills_per_step = max_prefills_per_step
         self.metrics = metrics or ServingMetrics()
         for name in fam.counters:
             self.metrics.counters.setdefault(name, 0)
@@ -225,10 +227,6 @@ class ServingEngine:
                 speculate, getattr(self, "_spec_mesh_shape", ()),
                 str(jnp.dtype(cfg.dtype)), spec_bucket)
             self.decode_horizon = self.spec_k
-        if prefill_buckets is not None and prefill_buckets != "pow2":
-            prefill_buckets = tuple(sorted(int(b) for b in prefill_buckets))
-            assert prefill_buckets, "bucket list must be non-empty"
-        self.prefill_buckets = prefill_buckets
 
         self.pool = fam.init_pool(cfg, num_pages + 1, page_size)
         # unified pool contract (ISSUE 12): subclasses that shard the pool
@@ -298,15 +296,10 @@ class ServingEngine:
         self._sync_mirrors()
         self._dirty = False                 # mirrors diverged from device
 
-        # hooked paths (attn_io/linear — the sharded engine's SP attention
-        # and TP projections; ffn_chunk — a chunk-row-count FFN distinct
+        # the hooks: attn_io/linear are the sharded engine's SP attention
+        # and TP projections; ffn_chunk is a chunk-row-count FFN distinct
         # from the decode one, needed when the FFN is shape-specialized
-        # like the EP a2a dispatch) ride only through the CHUNKED admit
-        # path: the bucketed inline prefill has no hook plumbing
-        assert (attn_io is None and linear is None) or \
-            prefill_chunk is not None, (
-            "attn_io/linear hooks need prefill_chunk set — the bucketed "
-            "inline prefill path does not thread them")
+        # like the EP a2a dispatch
         K = self.decode_horizon
         if self.spec_k:
             def step(p, t, pos, pages, bt, lim, hist, hlen):
@@ -316,7 +309,7 @@ class ServingEngine:
                     linear=linear)
         else:
             def step(p, t, pos, pages, bt, lim):
-                return fam.decode_multistep(
+                return decode_multistep_paged(
                     p, t, pos, cfg, pages, bt, lim, horizon=K,
                     eos_id=eos_id, ffn=ffn, attn_io=attn_io, linear=linear)
         # pool-output sharding pin (sharded engine sets _pool_out_sharding
@@ -340,25 +333,23 @@ class ServingEngine:
             self._step = jax.jit(step, **step_kw)  # CPU: no donation
         else:
             self._step = jax.jit(step, donate_argnums=(3,), **step_kw)
-        self._prefill_jit = {}              # keyed by (bucket, cache_len)
 
         self.prefill_chunk = prefill_chunk
-        self._chunk_step = None
-        if prefill_chunk is not None:
-            # ONE program for every prompt length/position: chunk size is
-            # the only shape; cursor and prompt length ride as runtime
-            # scalars (same trick as the decode limit argument)
-            def chunk(p, t, s, n, pages, bt):
-                return fam.prefill_chunk(
-                    p, t, s, n, cfg, pages, bt, ffn=ffn_chunk or ffn,
-                    attn_io=attn_io, linear=linear)
-            chunk_kw = {} if ps is None else {
-                "out_shardings": (None, {"k": ps, "v": ps})}
-            if jax.default_backend() == "cpu":
-                self._chunk_step = jax.jit(chunk, **chunk_kw)
-            else:
-                self._chunk_step = jax.jit(chunk, donate_argnums=(4,),
-                                           **chunk_kw)
+
+        # ONE program for every prompt length/position: chunk size is the
+        # only shape; cursor and prompt length ride as runtime scalars
+        # (same trick as the decode limit argument)
+        def chunk(p, t, s, n, pages, bt):
+            return prefill_chunk_paged(
+                p, t, s, n, cfg, pages, bt, ffn=ffn_chunk or ffn,
+                attn_io=attn_io, linear=linear)
+        chunk_kw = {} if ps is None else {
+            "out_shardings": (None, {"k": ps, "v": ps})}
+        if jax.default_backend() == "cpu":
+            self._chunk_step = jax.jit(chunk, **chunk_kw)
+        else:
+            self._chunk_step = jax.jit(chunk, donate_argnums=(4,),
+                                       **chunk_kw)
 
         # TDT_SIGCHECK=1: lint the engine's compiled programs against the
         # trace-determinism contract at BUILD time (sigcheck rung 0 — see
@@ -391,10 +382,9 @@ class ServingEngine:
                     abstract(self.params), i32(num_slots), i32(num_slots),
                     pool_abs, i32(num_slots, pages_per_seq),
                     i32(num_slots)))}
-            if prefill_chunk is not None:
-                programs["prefill_chunk_paged"] = (chunk, (
-                    abstract(self.params), i32(prefill_chunk), i32(), i32(),
-                    pool_abs, i32(pages_per_seq)))
+            programs["prefill_chunk_paged"] = (chunk, (
+                abstract(self.params), i32(prefill_chunk), i32(), i32(),
+                pool_abs, i32(pages_per_seq)))
             lint_engine_programs(programs, type(self).__name__)
 
         # AOT artifact seeding (ISSUE 15): swap the freshly-built jit
@@ -413,13 +403,8 @@ class ServingEngine:
 
     def _seed_from_artifact(self, artifact, artifact_key: str | None) -> None:
         key = artifact_key or self._default_artifact_key()
-        self._aot_key = key
         self._step = artifact.program(key, "decode")
-        if self._chunk_step is not None:
-            self._chunk_step = artifact.program(key, "chunk")
-        for bucket, cache_len in artifact.prefill_keys(key):
-            self._prefill_jit[(bucket, cache_len)] = artifact.program(
-                key, f"prefill:{bucket}x{cache_len}")
+        self._chunk_step = artifact.program(key, "chunk")
 
     def _sync_mirrors(self) -> None:
         """Upload the host slot mirrors to the device copies. The sharded
@@ -510,89 +495,10 @@ class ServingEngine:
                    tenant=req.tenant, cls=req.cls)
         return rid
 
-    # -- prefill + admission ----------------------------------------------
-    def _bucket_len(self, prompt_len: int) -> int:
-        """Bucket (padded) length for a prompt — the compile-cache key."""
-        if self.prefill_buckets is None:
-            return prompt_len
-        if self.prefill_buckets == "pow2":
-            b = 8
-            while b < prompt_len:
-                b *= 2
-            return b
-        for b in self.prefill_buckets:
-            if b >= prompt_len:
-                return b
-        raise ValueError(
-            f"prompt length {prompt_len} exceeds the largest prefill "
-            f"bucket {self.prefill_buckets[-1]}")
-
-    def _prefill_fn(self, bucket: int, cache_len: int):
-        key = (bucket, cache_len)
-        if key not in self._prefill_jit:
-            if self._aot_artifact is not None:
-                # artifact-seeded engines never trace: a bucket outside
-                # the artifact's program set is a typed loud miss, not a
-                # silent fresh compile on the serving path
-                from triton_dist_tpu.aot.artifact import ArtifactMissError
-                raise ArtifactMissError(
-                    f"prefill bucket {bucket} (cache_len {cache_len}) is "
-                    f"not in the artifact's program set for "
-                    f"{self._aot_key!r} — rebuild the artifact with this "
-                    f"bucket declared")
-            cfg, prefill = self.cfg, self._family.prefill
-            if self.prefill_buckets is None:
-                # exact mode: the legacy no-length trace, bit-for-bit
-                self._prefill_jit[key] = jax.jit(
-                    lambda p, t, c, n: prefill(p, t, cfg, c))
-            else:
-                self._prefill_jit[key] = jax.jit(
-                    lambda p, t, c, n: prefill(p, t, cfg, c, length=n))
-        return self._prefill_jit[key]
-
+    # -- admission + chunked paged prefill (the PREFILLING state machine) -
     def _mark_prefill_start(self, req: Request) -> None:
         mark_prefill_start(req, self.metrics, self._steps)
 
-    def _admit(self, slot: int, req: Request) -> None:
-        if self.prefill_chunk is not None:
-            self._admit_chunked(slot, req)
-            return
-        sp = len(req.prompt)
-        bucket = self._bucket_len(sp)
-        self._mark_prefill_start(req)
-        n_pages = -(-sp // self.page_size)
-        pages = self.alloc.alloc(req.rid, n_pages)
-        assert pages is not None, "admissible() guaranteed the pages"
-        cache_len = -(-bucket // self.page_size) * self.page_size
-        cache = self._family.init_kv_cache(self.cfg, 1, cache_len)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :sp] = req.prompt
-        logits, cache = self._prefill_fn(bucket, cache_len)(
-            self.params, jnp.asarray(toks), cache,
-            jnp.asarray([sp], np.int32))
-        # only the prompt's pages are handed off; in-page padding tail
-        # rows hold padded K/V but decode overwrites position p before
-        # any read of kv_len > p sees it
-        bt_row = jnp.asarray(self._device_rows(pages)[None])
-        self.pool = jax.tree.map(
-            lambda c, pages: cache_to_pages(c, pages, bt_row), cache,
-            self.pool)
-        tok0 = int(np.argmax(np.asarray(logits[0])))
-        self.sched.activate(slot, req)
-        self._jlog("admit", rid=req.rid, slot=slot)
-        req.generated.append(tok0)
-        self.metrics.inc("prefills")
-        self.metrics.inc("tokens_generated")
-        record_first_token(req, self.metrics, self._steps)
-        self._token[slot] = tok0
-        self._pos[slot] = sp
-        self._bt[slot] = self._device_bt_row(req.rid)
-        self._seed_hist(slot, req)
-        self._dirty = True
-        if req.done:            # max_new_tokens == 1 or tok0 == eos_id
-            self._finish(slot)
-
-    # -- chunked paged prefill (the PREFILLING state machine) -------------
     def _cache_adopt(self, req: Request) -> None:
         """Prefix-cache admission half (ISSUE 13): match the prompt
         against the radix index and ADOPT the hit pages — refcounts bump,
@@ -751,8 +657,8 @@ class ServingEngine:
         self._jlog("lend", tokens=want * self.page_size, pages=need)
         return need
 
-    def _admit_chunked(self, slot: int, req: Request) -> None:
-        """Chunked admission does NO prefill math: adopt any cached
+    def _admit(self, slot: int, req: Request) -> None:
+        """Admission does NO prefill math: adopt any cached
         prefix pages (refcount bump + cursor jump), allocate the prompt's
         remaining pages (only the ones the request does not already own —
         a mid-prefill preemptee kept its filled pages and resumes at its
@@ -1063,10 +969,9 @@ class ServingEngine:
             return False
 
         def can_hold(req: Request) -> bool:
-            need = -(-len(req.prompt) // self.page_size)
-            if self.prefill_chunk is not None:
-                # a mid-prefill preemptee kept its filled pages
-                need -= len(self.alloc.pages_of(req.rid))
+            # a mid-prefill preemptee kept its filled pages
+            need = (-(-len(req.prompt) // self.page_size)
+                    - len(self.alloc.pages_of(req.rid)))
             avail = self.alloc.free_pages
             if self.prefix_cache is not None:
                 # cached (refcount-0) pages are reclaimable on demand —
@@ -1075,23 +980,13 @@ class ServingEngine:
                 avail += self.prefix_cache.evictable
             return avail >= need
 
-        admitted = 0
-        prefilled_tokens = 0
-        while (self.max_prefills_per_step is None
-               or admitted < self.max_prefills_per_step):
-            adm = self.sched.admissible(can_hold)
-            if adm is None:
-                break
-            if self.prefill_chunk is None:
-                prefilled_tokens += len(adm[1].prompt)   # inline prefill
+        while (adm := self.sched.admissible(can_hold)) is not None:
             self._admit(*adm)
-            admitted += 1
 
         # ≤1 prefill chunk co-scheduled with the decode dispatch
-        # (Sarathi-style): with chunking on, the decode stall this step
-        # is bounded by prefill_chunk tokens, not a whole prompt
-        if self.prefill_chunk is not None:
-            prefilled_tokens = self._dispatch_prefill_chunk()
+        # (Sarathi-style): the decode stall this step is bounded by
+        # prefill_chunk tokens, not a whole prompt
+        prefilled_tokens = self._dispatch_prefill_chunk()
         self.metrics.observe("decode_stall_s",
                              time.perf_counter() - t_begin)
         self.metrics.observe("step_prefill_tokens", prefilled_tokens)
@@ -1139,7 +1034,7 @@ class ServingEngine:
         active = [(s, r) for s, r in self.sched.active
                   if r.state is RequestState.ACTIVE]
         if not active:
-            if prefilled_tokens and self.prefill_chunk is not None:
+            if prefilled_tokens:
                 # the step did real work (a prefill chunk) even with no
                 # decodable row — count it and keep the loop hot
                 self._steps += 1
@@ -1493,34 +1388,28 @@ class ServingEngine:
     # -- introspection ----------------------------------------------------
     @property
     def compile_stats(self) -> dict:
-        """Compile counts for the hot loop: the decode program (should be
-        exactly 1 however mixed the traffic) and the prefill programs
-        (bounded by the bucket count). Uses the jit-internal cache size
-        when available, falling back to the program-key count."""
+        """Compile counts for the hot loop: the decode program and the
+        chunk program, each exactly 1 however mixed the traffic. Uses the
+        jit-internal cache size when available, falling back to whether
+        the program has run."""
         def n(fn, fallback):
             try:
                 return int(fn._cache_size())
             except Exception:
                 return fallback
 
-        prefills = sum(n(f, 1) for f in self._prefill_jit.values())
-        chunk = 0
-        if self._chunk_step is not None:
-            chunk = n(self._chunk_step,
-                      1 if self.metrics.counters["prefill_chunks"] else 0)
         stats = {
             "decode_compiles": n(self._step, 1 if self._steps else 0),
-            "prefill_compiles": prefills,
-            "prefill_programs": len(self._prefill_jit),
-            # chunked mode: exactly one program for ALL prompt lengths
-            "prefill_chunk_compiles": chunk,
+            # exactly one program for ALL prompt lengths
+            "prefill_chunk_compiles": n(
+                self._chunk_step,
+                1 if self.metrics.counters["prefill_chunks"] else 0),
         }
         if self._aot_artifact is not None:
             from triton_dist_tpu.aot.artifact import LoadedProgram
             stats["aot_programs"] = sum(
                 isinstance(f, LoadedProgram)
-                for f in (self._step, self._chunk_step,
-                          *self._prefill_jit.values()))
+                for f in (self._step, self._chunk_step))
         return stats
 
 

@@ -39,12 +39,6 @@ ONE pool contract (ISSUE 12): a single ``KVPagePool`` is simultaneously
 ledger digest describes allocation DECISIONS, which the device layout
 must never influence — pools driving meshes of different SP widths over
 the same trace digest identically (test-pinned at n ∈ {1, 2, 4}).
-
-``cache_to_pages`` / ``pages_to_cache`` convert between the head-major
-contiguous ``init_kv_cache`` layout and the page pool — pure data
-movement (gather/scatter by block table), bit-exact round trip — so
-prefill can fill a contiguous cache (the layout the prefill kernels like)
-and hand the pages off to the pool.
 """
 
 from __future__ import annotations
@@ -665,43 +659,5 @@ def shard_pool_arrays(pool: dict, sp_ranks: int, sharding=None) -> dict:
     return pool
 
 
-# ---------------------------------------------------------------------------
-# contiguous cache <-> page pool converters
-# ---------------------------------------------------------------------------
-
-def cache_to_pages(cache: jax.Array, pages: jax.Array,
-                   block_table: jax.Array) -> jax.Array:
-    """Scatter a head-major contiguous cache into the page pool.
-
-    cache [L, B, Hkv, S, D] (``init_kv_cache`` layout, one of k/v);
-    pages [L, P, Hkv, page_size, D] (``init_page_pool`` layout);
-    block_table [B, n_pages] int32 with n_pages * page_size <= S.
-    Writes cache[:, b, :, p*ps:(p+1)*ps] into pages[:, bt[b, p]] for every
-    (b, p) — whole pages, pure data movement (prefill zero-pads the tail
-    of its last page; decode overwrites those rows one token at a time).
-    """
-    L, B, Hkv, S, D = cache.shape
-    ps = pages.shape[3]
-    n_pages = block_table.shape[1]
-    assert n_pages * ps <= S, (n_pages, ps, S)
-    src = cache[:, :, :, :n_pages * ps].reshape(L, B, Hkv, n_pages, ps, D)
-    src = src.transpose(0, 1, 3, 2, 4, 5).reshape(L, B * n_pages, Hkv, ps, D)
-    return pages.at[:, block_table.reshape(-1)].set(src)
-
-
-def pages_to_cache(pages: jax.Array, block_table: jax.Array) -> jax.Array:
-    """Gather pool pages back into a contiguous head-major cache — the
-    exact inverse of ``cache_to_pages`` (bit-compare round trip is a
-    test). pages [L, P, Hkv, ps, D]; block_table [B, n_pages] →
-    [L, B, Hkv, n_pages*ps, D]."""
-    L = pages.shape[0]
-    Hkv, ps, D = pages.shape[2:]
-    B, n_pages = block_table.shape
-    g = pages[:, block_table.reshape(-1)]          # [L, B*n_pages, Hkv, ps, D]
-    g = g.reshape(L, B, n_pages, Hkv, ps, D).transpose(0, 1, 3, 2, 4, 5)
-    return g.reshape(L, B, Hkv, n_pages * ps, D)
-
-
 __all__ = ["KVPagePool", "PageLedgerError", "page_pool_pspec",
-           "shard_pool_arrays", "cache_to_pages", "pages_to_cache",
-           "_fnv1a"]
+           "shard_pool_arrays", "_fnv1a"]

@@ -56,7 +56,8 @@ from triton_dist_tpu.ops.allgather_gemm import GemmConfig, tp_column_linear
 from triton_dist_tpu.ops.flash_decode import (flash_decode_dist,
                                               sp_paged_attend_write)
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
-from triton_dist_tpu.serving.engine import ServingEngine
+from triton_dist_tpu.serving.engine import (ServingEngine,
+                                            check_prefill_chunk)
 from triton_dist_tpu.serving.journal import ControlJournal
 from triton_dist_tpu.serving.kv_pool import page_pool_pspec, shard_pool_arrays
 from triton_dist_tpu.serving.metrics import ServingMetrics
@@ -146,9 +147,8 @@ class ShardedServingEngine(ServingEngine):
     valid golden for n>1.
 
     Requirements beyond the base engine:
-    - ``prefill_chunk`` is MANDATORY (the bucketed inline prefill has no
-      hook plumbing, and the EP FFN is shape-specialized per row count —
-      decode serves ``num_slots`` rows, a chunk serves ``prefill_chunk``);
+    - the EP FFN is shape-specialized per row count — decode serves
+      ``num_slots`` rows, a chunk serves ``prefill_chunk``;
     - ``num_slots % ep == 0`` and ``prefill_chunk % ep == 0`` (the A2A
       context splits token rows evenly over EP ranks);
     - ``d_model % 128 == 0`` (A2A wire lane alignment, asserted there).
@@ -176,10 +176,9 @@ class ShardedServingEngine(ServingEngine):
     def __init__(self, params: dict, cfg: MoEConfig, ctx: ShmemContext,
                  num_slots: int = 4, page_size: int = 16,
                  num_pages: int = 64, pages_per_seq: int = 8,
-                 max_prefills_per_step: int | None = None,
                  metrics: ServingMetrics | None = None,
                  decode_horizon: int = 1, eos_id: int | None = None,
-                 prefill_chunk: int | None = None,
+                 prefill_chunk: int = 16,
                  stall_deadline_steps: int = 256,
                  wire_dtype: str | None = "auto", tp_impl: str = "xla",
                  tp_cfg: GemmConfig | None = None, moe_block_m: int = 128,
@@ -202,9 +201,7 @@ class ShardedServingEngine(ServingEngine):
             assert ax in ctx.axis_names, (
                 f"mesh is missing axis {ax!r} — build it with "
                 f"serving_mesh(tp, sp, ep); got {ctx.axis_names}")
-        assert prefill_chunk is not None, (
-            "sharded serving requires prefill_chunk: the bucketed inline "
-            "prefill path has no attn_io/linear/ffn-chunk plumbing")
+        prefill_chunk = check_prefill_chunk(prefill_chunk)
         self.ctx = ctx
         self.moe_cfg = cfg
         n_tp = ctx.axis_size("tp")
@@ -383,7 +380,6 @@ class ShardedServingEngine(ServingEngine):
                          ffn=moe_ffn(self.a2a_decode),
                          ffn_chunk=moe_ffn(self.a2a_chunk),
                          attn_io=attn_io, linear=linear,
-                         max_prefills_per_step=max_prefills_per_step,
                          metrics=metrics, decode_horizon=decode_horizon,
                          eos_id=eos_id, prefill_chunk=prefill_chunk,
                          stall_deadline_steps=stall_deadline_steps,
