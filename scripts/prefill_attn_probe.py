@@ -1,4 +1,6 @@
-"""On-chip probe of a prefill chunk's attention at Mistral-7B widths (ISSUE 27):
+"""On-chip probe of the paged attention kernels at Mistral-7B widths.
+
+A prefill chunk's attention (ISSUE 27, the default):
 ``gqa_prefill_paged`` (the chunk's rows share one walk of the sequence's pages)
 at several ``rows_per_block``, beside the 256 rows of ``gqa_decode_paged`` it
 replaces, over the benchmark cell's pool (20 layers, 209 pages of 128, 13 pages
@@ -12,11 +14,23 @@ One JSON line a (context, variant): ``ms_layer`` is the host's clock around
 the kernel's own device time a layer from a profiler trace, ``gap`` the largest
 difference from the decode rows' result. The lines are also appended to
 ``chiprun_out/prefill_attn_probe.jsonl``.
+
+``--decode`` probes the decode rows' walk instead (ISSUE 29): 16 slots of which
+4 / 1 / 16 decode at 3 / 10 / 13 pages of context (``--cases`` for others),
+``gqa_decode_paged`` as the tree it runs in ships it, so that the same command
+in a checkout of another commit is the comparison. ``dmas_live`` is the number
+of page blocks (K and V) that are live, which is what the loop over live pages
+fetches; ``dmas_clamped`` what the (row, page) grid before ISSUE 29 fetched (a
+dead step clamped to the row's last live page, an idle row to its entry 0: a
+block is fetched when its index changes); ``sha1`` is of the result after 20
+layers, equal across trees where the kernels agree bit for bit.
+``--in-flight 1,2,3`` tries several depths of the page prefetch.
 """
 
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import os
 import sys
@@ -47,10 +61,80 @@ def layers(attend):
     return jax.jit(run)
 
 
+def measure(fn, args, reps, trace_dir):
+    """(result, host ms a layer, kernel us a layer) of ``fn(*args)``."""
+    got = np.asarray(fn(*args).astype(jnp.float32))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        r = fn(*args)
+    r.block_until_ready()
+    ms = (time.perf_counter() - t0) / reps / L * 1e3
+    T.start(trace_dir)
+    fn(*args).block_until_ready()
+    tr = T.load(T.stop(trace_dir))
+    ops = tr.ops[min(tr.ops)] if tr.ops else []
+    kern = sum(t for n, _, t in ops if "custom-call" in n and (
+        "closed_call" in n or "gqa_" in n))
+    return got, ms, kern / L * 1e6
+
+
+# (slots decoding of 16, pages of context each)
+DECODE_CASES = [(4, 3), (1, 10), (16, 13)]
+SLOTS = 16
+
+
+def clamped_dmas(bt, kv):
+    """Page blocks (K and V) a layer call of the one-page-a-step walk fetches
+    when a dead step revisits its row's last live page."""
+    last = np.maximum(-(-kv // PAGE) - 1, 0)
+    idx = [bt[b, min(s, last[b])] for b in range(len(kv)) for s in range(PPS)]
+    return 2 * (1 + int(np.sum(np.diff(idx) != 0)))
+
+
+def decode_probe(a, dev, kp, vp, trace_dir):
+    from triton_dist_tpu.ops import flash_decode as fd
+    q = jax.random.normal(jax.random.PRNGKey(1), (SLOTS, HQ, D), jnp.bfloat16)
+    cases = [tuple(int(x) for x in c.split("x"))
+             for c in a.cases.split(",")] if a.cases else DECODE_CASES
+    # pages in flight ahead of the one attended: the tree's own, or several
+    depths = [int(x) for x in a.in_flight.split(",")] if a.in_flight else [
+        getattr(fd, "DECODE_PAGES_IN_FLIGHT", None)]
+    lines = []
+    for depth in depths:
+        if a.in_flight:
+            fd.DECODE_PAGES_IN_FLIGHT = depth
+        fn = layers(lambda q, kp, vp, bt, kv, ly: gqa_decode_paged(
+            q, kp, vp, bt, kv, layer=ly)[0])
+        for live, pages in cases:
+            rng = np.random.default_rng(live)
+            bt = (rng.permutation(P - 1)[:SLOTS * PPS] + 1).reshape(SLOTS, PPS)
+            kv = np.zeros(SLOTS, np.int64)
+            if live:
+                kv[np.arange(live) * (SLOTS // live)
+                   + (5 if live == 1 else 0)] = pages * PAGE - 37
+            got, ms, kern = measure(
+                fn, (q, kp, vp, jnp.asarray(bt, jnp.int32),
+                     jnp.asarray(kv, jnp.int32)), a.reps, trace_dir)
+            lines.append({"case": "decode", "live_slots": live,
+                          "pages": pages, "in_flight": depth, "ms_layer": ms,
+                          "kernel_us": kern, "dmas_live": 2 * live * pages,
+                          "dmas_clamped": clamped_dmas(bt, kv),
+                          "sha1": hashlib.sha1(got.tobytes()).hexdigest(),
+                          "device": dev.device_kind})
+            print(json.dumps(lines[-1]), flush=True)
+    return lines
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", default="16,32,64")
     ap.add_argument("--reps", type=int, default=10)
+    ap.add_argument("--decode", action="store_true",
+                    help="probe the decode rows' walk, not a chunk's")
+    ap.add_argument("--cases", default=None,
+                    help="--decode: live slots x pages, e.g. 4x3,1x10,16x13")
+    ap.add_argument("--in-flight", default=None,
+                    help="--decode: DECODE_PAGES_IN_FLIGHT values to try")
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -59,6 +143,14 @@ def main():
     shape = (L, P, HKV, PAGE, D)
     kp = jax.jit(lambda k: jax.random.normal(k, shape, jnp.bfloat16))(kk)
     vp = jax.jit(lambda k: jax.random.normal(k, shape, jnp.bfloat16))(kv_)
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    trace_dir = os.path.join(ROOT, ".bench_trace", "prefill_attn_probe")
+    if a.decode:
+        lines = decode_probe(a, dev, kp, vp, trace_dir)
+        with open(os.path.join(out_dir, "decode_attn_probe.jsonl"), "a") as f:
+            f.writelines(json.dumps(ln) + "\n" for ln in lines)
+        return
     q = jax.random.normal(kq, (C, HQ, D), jnp.bfloat16)
     bt = jnp.asarray(np.random.default_rng(0).permutation(P - 1)[:PPS] + 1,
                      jnp.int32)
@@ -69,31 +161,18 @@ def main():
         variants[f"prefill_rb{rb}"] = layers(
             lambda q, kp, vp, bt, kv, ly, rb=rb: gqa_prefill_paged(
                 q, kp, vp, bt, kv, layer=ly, rows_per_block=rb))
-    out_dir = os.path.join(ROOT, "chiprun_out")
-    os.makedirs(out_dir, exist_ok=True)
-    trace_dir = os.path.join(ROOT, ".bench_trace", "prefill_attn_probe")
     lines = []
     for start, real in CONTEXTS:
         idx = start + np.arange(C)
         kv = jnp.asarray(np.where(idx < start + real, idx + 1, 0), jnp.int32)
         want = None
         for name, fn in variants.items():
-            got = np.asarray(fn(q, kp, vp, bt, kv).astype(jnp.float32))
+            got, ms, kern = measure(fn, (q, kp, vp, bt, kv), a.reps,
+                                    trace_dir)
             want = got if want is None else want
-            t0 = time.perf_counter()
-            for _ in range(a.reps):
-                r = fn(q, kp, vp, bt, kv)
-            r.block_until_ready()
-            ms = (time.perf_counter() - t0) / a.reps / L * 1e3
-            T.start(trace_dir)
-            fn(q, kp, vp, bt, kv).block_until_ready()
-            tr = T.load(T.stop(trace_dir))
-            ops = tr.ops[min(tr.ops)] if tr.ops else []
-            kern = sum(t for n, _, t in ops if "custom-call" in n and (
-                "closed_call" in n or "gqa_prefill" in n))
             lines.append({
                 "context": start, "real": real, "variant": name,
-                "ms_layer": ms, "kernel_us": kern / L * 1e6,
+                "ms_layer": ms, "kernel_us": kern,
                 "gap": float(np.abs(got - want).max()),
                 "device": dev.device_kind})
             print(json.dumps(lines[-1]), flush=True)

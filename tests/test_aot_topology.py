@@ -531,7 +531,8 @@ def paged_programs(topo):
     """The engine's two programs at Mistral-7B widths, 2 layers, at the
     benchmark cell's sizes (209 pages of 128, 16 slots, K = 4, chunk 256),
     lowered for one described v5e the way ``benchmark/tools/fit.py`` lowers
-    them (pool donated). Name -> (optimised HLO text, memory analysis)."""
+    them (pool donated). Name -> (optimised HLO text, memory analysis, the
+    grid of each Pallas kernel of the traced program by its ``name=``)."""
     import dataclasses
     from jax.sharding import SingleDeviceSharding
     from triton_dist_tpu.models.llama import (LlamaConfig,
@@ -549,21 +550,32 @@ def paged_programs(topo):
     pool = on(jax.eval_shape(
         lambda: init_page_pool(cfg, POOL_P, POOL_PAGE)))
     B, K, C = 16, 4, 256
-    lowered = {
+    traced = {
         "decode": jax.jit(
             lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
                 p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
-            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
+            donate_argnums=(3,)).trace(params, i32(B), i32(B), pool,
                                        i32(B, POOL_PPS), i32(B)),
         "chunk": jax.jit(
             lambda p, t, s, n, pages, bt: prefill_chunk_paged(
                 p, t, s, n, cfg, pages, bt),
-            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
+            donate_argnums=(4,)).trace(params, i32(C), i32(), i32(), pool,
                                        i32(POOL_PPS))}
+
+    def kernel_grids(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                kernel_grids(sub, found)
+        return found
+
     out = {}
-    for name, low in lowered.items():
-        exe = low.compile()
-        out[name] = (exe.as_text(), exe.memory_analysis())
+    for name, tr in traced.items():
+        exe = tr.lower().compile()
+        out[name] = (exe.as_text(), exe.memory_analysis(),
+                     kernel_grids(tr.jaxpr.jaxpr, {}))
     return out
 
 
@@ -577,7 +589,7 @@ def test_pool_is_not_copied_sliced_or_relaid(paged_programs, program):
     fails this: it makes the compiler hold the pool slot-major of head and
     re-lay ALL of it out for the kernel inside the layer loop."""
     import re
-    text, mem = paged_programs[program]
+    text, mem, _ = paged_programs[program]
     layer_pool = f"{POOL_P},{POOL_HKV},{POOL_PAGE},{POOL_D}]"
     pool_shapes = [f"[{POOL_L},{layer_pool}", f"[1,{layer_pool}",
                    f"[{layer_pool}"]
@@ -608,13 +620,30 @@ def test_chunk_rows_share_one_walk_decode_rows_do_not(paged_programs):
     import re
     kernels = {name: re.findall(
         r"(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
-        for name, (text, _) in paged_programs.items()}
+        for name, (text, _, _) in paged_programs.items()}
     assert len(kernels["chunk"]) == 1 and re.fullmatch(
         r"%gqa_prefill_paged[.\d]*", kernels["chunk"][0]), kernels["chunk"]
     # (by instruction name: the module's table of source frames may name
     # any function this process has traced)
     assert kernels["decode"] and not any(
         "gqa_prefill_paged" in k for k in kernels["decode"]), kernels["decode"]
+
+
+def test_decode_rows_walk_live_pages_only(paged_programs):
+    """The only Pallas kernel of the compiled decode program is the one named
+    ``gqa_decode_paged`` (the name the trace and ``gqa_attn_ms`` find it by),
+    and its grid is ONE step for the 16 slots: the walk over the live pages
+    is a loop inside the kernel, not 16 x 13 = 208 grid steps a layer call of
+    which most do nothing (ISSUE 29). Mosaic takes its hand-made page DMAs
+    out of the stacked pool, and the pool left in HBM brings no pool-shaped
+    copy: the guard above holds."""
+    import re
+    text, _, grids = paged_programs["decode"]
+    kernels = re.findall(
+        r"(%[\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert len(kernels) == 1 and re.fullmatch(
+        r"%gqa_decode_paged[.\d]*", kernels[0]), kernels
+    assert grids == {"gqa_decode_paged": (1,)}, grids
 
 
 # -- the latent family's programs at published widths (ISSUE 26) -------------

@@ -2,16 +2,22 @@
 test/nvidia/test_decode_attn.py and test_sp_decode_attn.py — the latter
 checks the full SP pipeline against a paged-attention reference)."""
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from conftest import TEST_WORLD
-from triton_dist_tpu.ops.flash_decode import (NEG_INF, decode_combine,
+from triton_dist_tpu.ops.flash_decode import (NEG_INF, _as_stack,
+                                              _softmax_finish,
+                                              _softmax_init, _softmax_update,
+                                              decode_combine,
                                               gqa_decode_paged,
                                               gqa_decode_partial,
                                               gqa_prefill_paged,
@@ -150,6 +156,128 @@ def test_paged_decode_kv_len_zero():
     golden = _paged_golden(q, kp, vp, np.asarray(bt), np.asarray(kv_len))
     assert_allclose(out[1], golden[1], atol=1e-3, rtol=1e-3)
     assert np.all(lse[1, :, 0] > -1e29)
+
+
+# rows of a decode batch over pages of 8, 7 pages a sequence: ``kv_len`` a
+# row, and whether consecutive rows share one block-table row (the
+# speculative form)
+WALK_PS, WALK_PPS = 8, 7
+WALK_CASES = {
+    "idle-between-live": ([19, 0, 0, 44, 0, 7], False),
+    "all-idle-but-last": ([0, 0, 0, 0, 0, 33], False),
+    "all-idle": ([0, 0, 0, 0, 0, 0], False),
+    "page-boundaries": ([8, 16, 24, 32, 48, 56], False),   # ends ON a page
+    "off-boundaries": ([1, 9, 17, 31, 41, 55], False),
+    "full-table": ([56, 56, 50, 56, 49, 56], False),       # every page live
+    "staggered-shared-table": ([21, 22, 23, 24, 25, 26], True),
+    "shared-table-idle-tail": ([40, 41, 0, 0, 15, 16], True),
+    "past-the-table": ([56, 90, 0, 57, 3, 64], False),     # kv_len > 7 pages
+    "two-row-blocks": ([9] + [0] * 15 + [0, 50] + [0] * 12 + [17, 0], False),
+    "idle-row-block": ([0] * 16 + [23, 0] * 8, False),
+}
+
+
+def _walk_inputs(case, pool_pages=48):
+    kv_len, shared = WALK_CASES[case]
+    B = len(kv_len)
+    rng = np.random.default_rng(sorted(WALK_CASES).index(case))
+    bt = rng.integers(0, pool_pages, (B, WALK_PPS))
+    if shared:                           # rows 2i and 2i + 1 walk one table
+        bt = np.repeat(bt[::2], 2, axis=0)
+    bt = bt.astype(np.int32)
+    garbage = np.array([10 ** 6, -5, 2 ** 31 - 1, -(2 ** 31), pool_pages,
+                        -1, 999999], np.int32)
+    for b, kl in enumerate(kv_len):      # entries past the live pages
+        live = min(-(-kl // WALK_PS), WALK_PPS)
+        bt[b, live:] = garbage[:WALK_PPS - live]
+    return jnp.asarray(bt), jnp.asarray(kv_len, jnp.int32)
+
+
+def _grid_walk_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
+                      out_ref, lse_ref, acc, m_i, l_i, *, page_size, sm_scale,
+                      n_kv_heads):
+    del bt_ref, layer_ref
+    b, s = pl.program_id(0), pl.program_id(1)
+    kv_len = kv_len_ref[b]
+    pl.when(s == 0)(lambda: _softmax_init(acc, m_i, l_i))
+
+    @pl.when(s * page_size < kv_len)
+    def _():
+        _softmax_update(s * page_size, kv_len, q_ref[0], k_ref[0], v_ref[0],
+                        acc, m_i, l_i, block_s=page_size, sm_scale=sm_scale,
+                        n_kv_heads=n_kv_heads)
+
+    pl.when(s == pl.num_programs(1) - 1)(
+        lambda: _softmax_finish(out_ref, lse_ref, acc, m_i, l_i))
+
+
+def _grid_walk(q, k_pages, v_pages, block_table, kv_len, layer=None):
+    """``gqa_decode_paged`` as it was before ISSUE 29, kept as the tests'
+    reference: a (B, pages_per_seq) grid, one page a grid step through
+    BlockSpec index maps, a dead step revisiting the row's last live page."""
+    k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
+    B, Hq, D = q.shape
+    _, P_pool, Hkv, page_size, _ = k_pages.shape
+    pps = block_table.shape[1]
+
+    def page_index(b, s, kl, bt, ly):
+        last = jnp.maximum((kl[b] + page_size - 1) // page_size - 1, 0)
+        page = bt[b, jnp.minimum(s, last)]
+        return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
+
+    row = lambda b, s, kl, bt, ly: (b, 0, 0)                # noqa: E731
+    page_block = pl.BlockSpec((None, 1, Hkv, page_size, D), page_index)
+    return pl.pallas_call(
+        functools.partial(_grid_walk_kernel, page_size=page_size,
+                          sm_scale=1.0 / math.sqrt(D), n_kv_heads=Hkv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, pps),
+            in_specs=[pl.BlockSpec((1, Hq, D), row), page_block, page_block],
+            out_specs=[pl.BlockSpec((1, Hq, D), row),
+                       pl.BlockSpec((1, Hq, 128), row)],
+            scratch_shapes=[pltpu.VMEM((Hq, D), jnp.float32),
+                            pltpu.VMEM((Hq, 1), jnp.float32),
+                            pltpu.VMEM((Hq, 1), jnp.float32)]),
+        out_shape=(jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+                   jax.ShapeDtypeStruct((B, Hq, 128), jnp.float32)),
+        interpret=True,
+    )(kv_len, block_table, layer, q, k_pages, v_pages)
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["pool4d", "stack"])
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_paged_decode_live_page_walk_is_bitwise_the_grid_walk(case, stacked):
+    """The loop over live pages against the (row, page) grid it replaced
+    (``_grid_walk``): the same page meets the same query in the same order
+    with one softmax update a page, so out and lse are BITWISE equal,
+    whatever is idle, shared, garbage or on a page boundary, in one row block
+    or two, for the per-layer pool and for the stacked one."""
+    Hq, Hkv, D, pool, L, layer = 4, 2, 64, 48, 3, 1
+    bt, kv_len = _walk_inputs(case, pool)
+    B = bt.shape[0]
+    q = jax.random.normal(jax.random.key(0), (B, Hq, D), jnp.float32)
+    kp = jax.random.normal(jax.random.key(1), (L, pool, Hkv, WALK_PS, D),
+                           jnp.float32)
+    vp = jax.random.normal(jax.random.key(2), (L, pool, Hkv, WALK_PS, D),
+                           jnp.float32)
+    seen = jnp.minimum(kv_len, WALK_PPS * WALK_PS)
+    if stacked:
+        args = (q, kp, vp, bt)
+        kw = {"layer": jnp.int32(layer)}
+    else:
+        args = (q, kp[layer], vp[layer], bt)
+        kw = {}
+    out, lse = jax.jit(lambda kl: gqa_decode_paged(*args, kl, **kw))(kv_len)
+    out1, lse1 = jax.jit(lambda kl: _grid_walk(*args, kl, **kw))(seen)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out1))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse1))
+    idle = np.asarray(kv_len) == 0
+    np.testing.assert_array_equal(np.asarray(out)[idle], 0.0)
+    np.testing.assert_array_equal(np.asarray(lse)[idle], np.float32(NEG_INF))
+    if not stacked:                      # and both are the right answer
+        golden = _paged_golden(q, kp[layer], vp[layer], np.asarray(bt),
+                               np.asarray(seen))
+        assert_allclose(np.asarray(out), golden, atol=1e-3, rtol=1e-3)
 
 
 # a chunk of 16 rows in blocks of 8 over pages of 8, 6 pages a sequence:

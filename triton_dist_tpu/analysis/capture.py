@@ -715,6 +715,12 @@ def _fake_collectives(state: CaptureState):
             carry = body_fun(jnp.int32(i), carry)
         return carry
 
+    def while_loop(cond_fun, body_fun, init_val):
+        carry = init_val
+        while bool(np.asarray(cond_fun(carry))):
+            carry = body_fun(carry)
+        return carry
+
     def cond(pred, true_fun, false_fun, *operands, **_kw):
         return true_fun(*operands) if bool(np.asarray(pred)) \
             else false_fun(*operands)
@@ -722,7 +728,7 @@ def _fake_collectives(state: CaptureState):
     return dict(all_gather=all_gather, psum=psum, psum_scatter=psum_scatter,
                 ppermute=ppermute, all_to_all=all_to_all,
                 axis_index=axis_index, axis_size=axis_size,
-                fori_loop=fori_loop, cond=cond)
+                fori_loop=fori_loop, while_loop=while_loop, cond=cond)
 
 
 def _fake_when(condition):

@@ -282,10 +282,12 @@ def _fd_gqa_decode_partial():
 
 def _fd_gqa_decode_paged():
     from ..ops import gqa_decode_paged
-    q = jnp.zeros((1, 4, 128), f32)
+    # two live rows around two idle ones: every page DMA the kernel's loop
+    # starts (3 + 2 pages, K and V) is waited, and an idle row starts none
+    q = jnp.zeros((4, 4, 128), f32)
     pages = jnp.zeros((8, 2, 8, 128), f32)
-    gqa_decode_paged(q, pages, pages, jnp.zeros((1, 4), i32),
-                     jnp.array([20], i32))
+    gqa_decode_paged(q, pages, pages, jnp.zeros((4, 4), i32),
+                     jnp.array([20, 0, 0, 9], i32))
 
 
 def _fd_gqa_prefill_paged():

@@ -41,86 +41,152 @@ from triton_dist_tpu.utils import default_interpret
 NEG_INF = -1e30
 
 
-def _online_softmax_body(s, kv_len, q_ref, k_ref, v_ref, out_ref, lse_ref,
-                         acc, m_i, l_i, *, block_s: int, sm_scale: float,
-                         n_kv_heads: int):
-    """Shared grid-step body for the decode kernels: init at s==0, one
-    online-softmax update per KV block, finalize (incl. lse) at the last
-    step. All Hq query heads are processed per step as a [Hkv, G, ·] batched
+def _softmax_init(acc, m_i, l_i):
+    acc[...] = jnp.zeros_like(acc)
+    m_i[...] = jnp.full_like(m_i, NEG_INF)
+    l_i[...] = jnp.zeros_like(l_i)
+
+
+def _softmax_update(start, kv_len, q, k, v, acc, m_i, l_i, *, block_s: int,
+                    sm_scale: float, n_kv_heads: int):
+    """One online-softmax update: the KV block ``k`` / ``v`` [Hkv, block_s,
+    D], whose first key sits at position ``start``, against all Hq query
+    heads ``q`` [Hq, D] of one row, as a [Hkv, G, ·] batched
     contraction (Mosaic needs the last-two block dims full/aligned, so heads
     are not split). Analog of kernel_gqa_fwd_batch_decode_split_kv
     (flash_decode.py:129-280) with the split-KV dimension replaced by
     sequential KV-block pipelining."""
-    n_s = pl.num_programs(1)
+    Hq, D = acc.shape
+    G = Hq // n_kv_heads
+    # operands stay in the input dtype (f32 accumulate): upcasting
+    # bf16 first would run the MXU at its slower f32 rate (see the
+    # ring-attention pipeline note)
+    q = q.reshape(n_kv_heads, G, D)
+    scores = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * sm_scale  # [Hkv, G, bs]
+    scores = scores.reshape(Hq, block_s)
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    scores = jnp.where(pos < kv_len, scores, NEG_INF)
+    m_new = jnp.maximum(m_i[...], jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_i[...] - m_new)
+    p = jnp.exp(scores - m_new)                  # [Hq, block_s]
+    l_i[...] = l_i[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.reshape(n_kv_heads, G, block_s).astype(v.dtype), v,
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32).reshape(Hq, D)
+    acc[...] = acc[...] * alpha + pv
+    m_i[...] = m_new
 
-    @pl.when(s == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_i[...] = jnp.full_like(m_i, NEG_INF)
-        l_i[...] = jnp.zeros_like(l_i)
 
-    @pl.when(s * block_s < kv_len)
-    def _():
-        Hq, D = acc.shape
-        G = Hq // n_kv_heads
-        # operands stay in the input dtype (f32 accumulate): upcasting
-        # bf16 first would run the MXU at its slower f32 rate (see the
-        # ring-attention pipeline note)
-        q = q_ref[0].reshape(n_kv_heads, G, D)
-        k = k_ref[0]                                 # [Hkv, block_s, D]
-        v = v_ref[0]                                 # [Hkv, block_s, D]
-        scores = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * sm_scale  # [Hkv, G, bs]
-        scores = scores.reshape(Hq, block_s)
-        pos = s * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        scores = jnp.where(pos < kv_len, scores, NEG_INF)
-        m_new = jnp.maximum(m_i[...], jnp.max(scores, axis=1, keepdims=True))
-        alpha = jnp.exp(m_i[...] - m_new)
-        p = jnp.exp(scores - m_new)                  # [Hq, block_s]
-        l_i[...] = l_i[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.reshape(n_kv_heads, G, block_s).astype(v.dtype), v,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32).reshape(Hq, D)
-        acc[...] = acc[...] * alpha + pv
-        m_i[...] = m_new
-
-    @pl.when(s == n_s - 1)
-    def _():
-        l_safe = jnp.where(l_i[...] > 0, l_i[...], 1.0)
-        out_ref[0] = (acc[...] / l_safe).astype(out_ref.dtype)
-        # lse = m + log(l); empty shard -> NEG_INF so combine ignores it
-        lse = jnp.where(l_i[...] > 0, m_i[...] + jnp.log(l_safe), NEG_INF)
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+def _softmax_finish(out_ref, lse_ref, acc, m_i, l_i, row=0):
+    l_safe = jnp.where(l_i[...] > 0, l_i[...], 1.0)
+    out_ref[row] = (acc[...] / l_safe).astype(out_ref.dtype)
+    # lse = m + log(l); empty shard -> NEG_INF so combine ignores it
+    lse = jnp.where(l_i[...] > 0, m_i[...] + jnp.log(l_safe), NEG_INF)
+    lse_ref[row] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
                    acc, m_i, l_i, *, block_s: int, sm_scale: float,
                    n_kv_heads: int):
-    """Grid (B, S//block_s) over a contiguous KV shard."""
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-    _online_softmax_body(s, kv_len_ref[b], q_ref, k_ref, v_ref, out_ref,
-                         lse_ref, acc, m_i, l_i, block_s=block_s,
-                         sm_scale=sm_scale, n_kv_heads=n_kv_heads)
+    """Grid (B, S//block_s) over a contiguous KV shard: init at s == 0, one
+    update a live KV block, finalize (incl. lse) at the last step."""
+    b, s = pl.program_id(0), pl.program_id(1)
+    kv_len = kv_len_ref[b]
+    pl.when(s == 0)(lambda: _softmax_init(acc, m_i, l_i))
+
+    @pl.when(s * block_s < kv_len)
+    def _():
+        _softmax_update(s * block_s, kv_len, q_ref[0], k_ref[0], v_ref[0],
+                        acc, m_i, l_i, block_s=block_s, sm_scale=sm_scale,
+                        n_kv_heads=n_kv_heads)
+
+    pl.when(s == pl.num_programs(1) - 1)(
+        lambda: _softmax_finish(out_ref, lse_ref, acc, m_i, l_i))
 
 
-def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
-                         out_ref, lse_ref, acc, m_i, l_i, *, block_s: int,
-                         sm_scale: float, n_kv_heads: int):
-    """Grid (B, pages_per_seq) over a paged KV pool; ``bt_ref`` (block
-    table) and ``layer_ref`` are scalar-prefetch operands read only by the
-    index_map, which streams page ``bt[b, s]`` of layer ``layer`` straight
-    out of the stacked pool. Analog of the reference's block_table-driven
-    split-KV kernel (flash_decode.py:129-280 `page` indexing)."""
-    del bt_ref, layer_ref
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-    _online_softmax_body(s, kv_len_ref[b], q_ref, k_ref, v_ref, out_ref,
-                         lse_ref, acc, m_i, l_i, block_s=block_s,
-                         sm_scale=sm_scale, n_kv_heads=n_kv_heads)
+def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         out_ref, lse_ref, k_buf, v_buf, sem, acc, m_i, l_i,
+                         *, n_pool: int, page_size: int, sm_scale: float,
+                         n_kv_heads: int):
+    """Grid (row blocks,) over a paged KV pool left in HBM: ONE loop over the
+    block's LIVE pages alone, rows in order and a row's pages in order, each
+    fetched by hand (``bt_ref[row, idx]`` of layer ``layer_ref[0]``, straight
+    out of the stacked pool) into one of two VMEM buffers while the page
+    before it is attended. One online-softmax update a page, in page order,
+    exactly as a grid of (row, page) steps makes them; an idle row and a page
+    past ``kv_len`` are not steps at all. ``q_ref`` / ``out_ref`` /
+    ``lse_ref`` hold the block's rows. Analog of the reference's
+    block_table-driven split-KV kernel (flash_decode.py:129-280 `page`
+    indexing)."""
+    rows, pages_per_seq = q_ref.shape[0], bt_ref.shape[1]
+    row0 = pl.program_id(0) * rows
+    end = row0 + rows
+    layer = layer_ref[0]
+
+    def live_pages(row):
+        # a key past the table's last page does not exist, whatever kv_len says
+        return jnp.minimum((kv_len_ref[row] + page_size - 1) // page_size,
+                           pages_per_seq)
+
+    def next_live(row):
+        return lax.while_loop(
+            lambda r: (r < end) & (kv_len_ref[jnp.minimum(r, end - 1)] <= 0),
+            lambda r: r + 1, row)
+
+    def fetch(row, idx, buf):
+        # the clamp keeps even a garbage block-table entry inside the pool
+        page = jnp.clip(bt_ref[row, idx], 0, n_pool - 1)
+        return (pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, page], v_buf.at[buf],
+                                      sem.at[1, buf]))
+
+    def start(row, idx, buf):
+        @pl.when(row < end)
+        def _():
+            for copy in fetch(row, idx, buf):
+                copy.start()
+
+    def after(row, idx):
+        """The live page that follows (row, idx); row ``end`` when none."""
+        return lax.cond(
+            idx + 1 >= live_pages(jnp.minimum(row, end - 1)),
+            lambda: (next_live(row + 1), 0), lambda: (row, idx + 1))
+
+    # the first ``depth - 1`` live pages are in flight before the loop, and
+    # every turn of it starts one more
+    depth = k_buf.shape[0]
+    pages = [(next_live(row0), 0)]
+    for _ in range(depth - 2):
+        pages.append(after(*pages[-1]))
+    for buf, page in enumerate(pages):
+        start(*page, buf)
+    # what no page writes: an idle row's zeros and empty-shard lse
+    out_ref[...] = jnp.zeros_like(out_ref)
+    lse_ref[...] = jnp.full_like(lse_ref, NEG_INF)
+
+    def attend(carry):
+        w, *pages = carry                 # the page attended, those in flight
+        pages.append(after(*pages[-1]))
+        start(*pages[-1], (w + depth - 1) % depth)
+        (row, idx), buf = pages[0], w % depth
+        pl.when(idx == 0)(lambda: _softmax_init(acc, m_i, l_i))
+        for copy in fetch(row, idx, buf):
+            copy.wait()
+        r = row - row0
+        _softmax_update(
+            idx * page_size,
+            jnp.minimum(kv_len_ref[row], pages_per_seq * page_size),
+            q_ref[r], k_buf[buf], v_buf[buf], acc, m_i, l_i,
+            block_s=page_size, sm_scale=sm_scale, n_kv_heads=n_kv_heads)
+        pl.when(pages[1][0] != row)(             # the row's last live page
+            lambda: _softmax_finish(out_ref, lse_ref, acc, m_i, l_i, row=r))
+        return (w + 1, *pages[1:])
+
+    lax.while_loop(lambda c: c[1][0] < end, attend, (0, *pages))
 
 
 def gqa_decode_partial(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -195,6 +261,37 @@ def _as_stack(k_pages, v_pages, layer):
     return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
+# The decode rows' walk is ONE grid step a block of rows, a loop over its live
+# pages inside. Chosen on the v5e (PERF.md section 6, PR 29,
+# scripts/prefill_attn_probe.py --decode: 16 slots at Mistral-7B widths, the
+# kernel alone, us a layer call; live slots x pages of context each):
+#                                        0x0   4x3   1x10  16x1  8x6   16x13
+#   (16, 13) grid, one page a step,
+#     dead steps clamped (before PR 29)  50.4  59.4  57.0  64.0  82.8  179.3
+#   the grid, a dead operand keeping its
+#     page (forward-filled index table),
+#     1 / 2 / 4 / 7 pages a grid step:
+#       1                                23.7  38.7  35.4  44.4   -    173.2
+#       2                                23.0  35.8  32.8  41.7   -    161.1
+#       4                                26.2  40.5  36.4  41.7   -    173.2
+#       7                                26.3  37.3  35.6  36.3   -    148.5
+#     7, idle rows moving no q / out      -    36.8  34.5   -     -    148.5
+#     7, 16 rows to a q / out block      26.3  36.6  34.9  36.3   -    148.7
+#   loop over live pages, 1 in flight     1.7  12.2  10.4  16.1  41.5  170.4
+#   loop over live pages, 2 in flight     -    10.8   9.4  13.7  35.7  146.5
+#   loop over live pages, 3 in flight     -    10.9   9.5  13.7  35.8  146.6
+# A grid pays ~25 us a call for its (step x operand) bookkeeping whatever is
+# live, so pages a step moved nothing; every result above is bitwise the same.
+
+# Rows of a decode batch whose q, out and lse sit in VMEM as one block while
+# their live pages stream past (the batch is cut into blocks of gcd(B, this);
+# 16 rows of Mistral's 32 heads x 128: 0.5 MB, each block double-buffered).
+DECODE_ROWS_PER_BLOCK = 16
+# Live pages whose DMAs run ahead of the page being attended (a ring of one
+# more K and V buffer than this: 3 x 0.5 MB at Mistral's 8 heads x 128 x 128).
+DECODE_PAGES_IN_FLIGHT = 2
+
+
 def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      block_table: jax.Array, kv_len: jax.Array,
                      sm_scale: float | None = None, layer=None):
@@ -206,23 +303,29 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     or, with ``layer`` (a traced or Python int), the whole stacked pool
     [L, P, Hkv, page_size, D] of ``models.llama.init_page_pool``: the same
     kernel then streams its pages from ``pool[layer]`` IN PLACE (``layer``
-    rides as a scalar-prefetch operand of the index map), so a layer loop
+    is a scalar-prefetch operand of the page DMAs), so a layer loop
     never slices a per-layer pool out of the stack — XLA cannot fuse a
     slice into a Pallas operand, it would copy the layer's pool per call.
     The input's rank picks the form; the result is bitwise the 4-D call on
     ``pool[layer]``.
     block_table [B, pages_per_seq] int32 page ids — entries past
     ceil(kv_len/page_size) may be ARBITRARY values (even out of range):
-    the index map never dereferences them. kv_len [B] (0 allowed: the row
+    nothing dereferences them. kv_len [B] (0 allowed: the row
     returns zeros with lse = NEG_INF, the "empty shard" convention the SP
     combine already honors). Returns (out [B, Hq, D], lse [B, Hq, 128] f32).
 
-    Dead pages are free twice over: their grid steps revisit the LAST
-    valid page (same block index as the previous step ⇒ the pipeline
-    skips the HBM→VMEM DMA entirely — the causal-attention kv-clamp
-    trick, docs/benchmarks.md) and their compute is skipped by the
-    ``s * page_size < kv_len`` mask, so a short sequence in a long
-    ``pages_per_seq`` batch costs its own length, not the batch max.
+    The kernel walks the batch's LIVE pages and nothing else: one loop
+    over (row, page) pairs, rows and pages in order, with the next live
+    page's DMA in flight behind the current page's online-softmax update
+    (``kv_len`` and the block table are scalar-prefetch operands the loop
+    reads). A dead page is no grid step, no index-map
+    evaluation and no byte, so a short sequence in a long ``pages_per_seq``
+    batch costs its own length, not the batch max, and an idle slot
+    (``kv_len`` 0) nothing (before ISSUE 29 the kernel stepped a (B,
+    pages_per_seq) grid, 208 steps a call of which nine in ten did nothing,
+    and an idle row still fetched a page). Every page meets the same query
+    in the same order with one update a page: the result is bitwise that
+    grid's.
 
     Nothing here assumes distinct batch rows mean distinct sequences:
     rows are (block_table, kv_len) pairs, so several rows may walk the
@@ -238,39 +341,28 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     assert Hq % Hkv == 0
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     pages_per_seq = block_table.shape[1]
+    Rb = math.gcd(B, DECODE_ROWS_PER_BLOCK)
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-
-    def page_index(b, s, kl, bt, ly):
-        # last valid page for row b (0 when kv_len == 0 — any real page
-        # works, the compute mask kills its contribution); steps past it
-        # revisit it (DMA-free), and the clamp keeps even garbage block-
-        # table entries inside the pool so the DMA can never read OOB
-        last = jnp.maximum((kl[b] + page_size - 1) // page_size - 1, 0)
-        page = bt[b, jnp.minimum(s, last)]
-        return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
-
-    kernel = functools.partial(_decode_paged_kernel, block_s=page_size,
-                               sm_scale=sm_scale, n_kv_heads=Hkv)
-    grid = (B, pages_per_seq)
-    # the layer dim is squeezed out of the block: the body sees the same
-    # [1, Hkv, page_size, D] page block whatever the pool's depth
-    page_block = pl.BlockSpec((None, 1, Hkv, page_size, D), page_index)
+    rows = lambda i, *_: (i, 0, 0)                          # noqa: E731
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    page_buf = pltpu.VMEM((DECODE_PAGES_IN_FLIGHT + 1, Hkv, page_size, D),
+                          k_pages.dtype)
+    kernel = functools.partial(_decode_paged_kernel, n_pool=P_pool,
+                               page_size=page_size, sm_scale=sm_scale,
+                               n_kv_heads=Hkv)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, Hq, D), lambda b, s, kl, bt, ly: (b, 0, 0)),
-                page_block,
-                page_block,
-            ],
+            grid=(B // Rb,),
+            in_specs=[pl.BlockSpec((Rb, Hq, D), rows), in_hbm, in_hbm],
             out_specs=[
-                pl.BlockSpec((1, Hq, D), lambda b, s, kl, bt, ly: (b, 0, 0)),
-                pl.BlockSpec((1, Hq, 128),
-                             lambda b, s, kl, bt, ly: (b, 0, 0)),
+                pl.BlockSpec((Rb, Hq, D), rows),
+                pl.BlockSpec((Rb, Hq, 128), rows),
             ],
             scratch_shapes=[
+                page_buf, page_buf,
+                pltpu.SemaphoreType.DMA((2, DECODE_PAGES_IN_FLIGHT + 1)),
                 pltpu.VMEM((Hq, D), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
@@ -285,8 +377,9 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             bytes_accessed=(q.size
                             + B * pages_per_seq * Hkv * page_size * D * 2),
             transcendentals=B * Hq * pages_per_seq * page_size),
+        name="gqa_decode_paged",
         interpret=default_interpret(),
-    )(kv_len, block_table, layer, q, k_pages, v_pages)
+    )(kv_len.astype(jnp.int32), block_table, layer, q, k_pages, v_pages)
 
 
 # Rows of a prefill chunk that share one walk of the sequence's pages: with
@@ -309,11 +402,7 @@ def _prefill_paged_kernel(kl_ref, bt_ref, layer_ref, q_ref, klr_ref, k_ref,
     del bt_ref, layer_ref
     i, s = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(s == 0)
-    def _():
-        acc[...] = jnp.zeros_like(acc)
-        m_i[...] = jnp.full_like(m_i, NEG_INF)
-        l_i[...] = jnp.zeros_like(l_i)
+    pl.when(s == 0)(lambda: _softmax_init(acc, m_i, l_i))
 
     # a page that no row of the block can see: no compute (and no DMA, the
     # index map revisits the block's last live page)

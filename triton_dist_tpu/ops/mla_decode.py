@@ -11,10 +11,10 @@ to the attention output afterwards), so ONE read of a page serves keys and
 values: the value operand of the kernel is the first ``latent_dim`` columns
 of the same VMEM block.
 
-The walk is ``ops.flash_decode.gqa_decode_paged``'s: pages streamed through
-the block table out of the stacked pool in place (``layer`` is a
-scalar-prefetch operand of the index map), online softmax, dead pages
-revisit the last live one (no DMA) and skip their compute.
+The walk is the (row, page) grid that ``ops.flash_decode.gqa_decode_paged``
+had until ISSUE 29: pages streamed through the block table out of the stacked
+pool in place (``layer`` is a scalar-prefetch operand of the index map),
+online softmax, dead pages revisit the last live one (no DMA), compute skipped.
 
 Two things differ, both so that a prefill CHUNK reads each page of its
 sequence once a row block and not once a row:
