@@ -25,6 +25,14 @@ dead step clamped to the row's last live page, an idle row to its entry 0: a
 block is fetched when its index changes); ``sha1`` is of the result after 20
 layers, equal across trees where the kernels agree bit for bit.
 ``--in-flight 1,2,3`` tries several depths of the page prefetch.
+
+Other widths and a sliding window (ISSUE 30): ``--group 16`` is 16 query heads
+a KV head (128 x 128 under 8 KV heads), ``--chunk 2048`` the chunk's rows,
+``--layers`` / ``--pages`` / ``--pps`` the pool, ``--contexts 4096:2048,...``
+(tokens before the chunk : real tokens in it), ``--window 4096`` the kernels'
+``window=`` over a RING of ``--pps`` pages (the context's pages wrap into it),
+``--vmem-mb`` the chunk kernel's scoped-VMEM limit, ``--no-baseline`` leaves
+out the rows-of-decode variant (``gap`` is then against the first variant).
 """
 
 from __future__ import annotations
@@ -93,6 +101,7 @@ def clamped_dmas(bt, kv):
 
 def decode_probe(a, dev, kp, vp, trace_dir):
     from triton_dist_tpu.ops import flash_decode as fd
+    win = {"window": a.window} if a.window else {}
     q = jax.random.normal(jax.random.PRNGKey(1), (SLOTS, HQ, D), jnp.bfloat16)
     cases = [tuple(int(x) for x in c.split("x"))
              for c in a.cases.split(",")] if a.cases else DECODE_CASES
@@ -104,9 +113,10 @@ def decode_probe(a, dev, kp, vp, trace_dir):
         if a.in_flight:
             fd.DECODE_PAGES_IN_FLIGHT = depth
         fn = layers(lambda q, kp, vp, bt, kv, ly: gqa_decode_paged(
-            q, kp, vp, bt, kv, layer=ly)[0])
+            q, kp, vp, bt, kv, layer=ly, **win)[0])
         for live, pages in cases:
             rng = np.random.default_rng(live)
+            # under a window every slot has a ring of PPS pages of its own
             bt = (rng.permutation(P - 1)[:SLOTS * PPS] + 1).reshape(SLOTS, PPS)
             kv = np.zeros(SLOTS, np.int64)
             if live:
@@ -116,7 +126,8 @@ def decode_probe(a, dev, kp, vp, trace_dir):
                 fn, (q, kp, vp, jnp.asarray(bt, jnp.int32),
                      jnp.asarray(kv, jnp.int32)), a.reps, trace_dir)
             lines.append({"case": "decode", "live_slots": live,
-                          "pages": pages, "in_flight": depth, "ms_layer": ms,
+                          "pages": pages, "in_flight": depth, "group": HQ // HKV,
+                          "window": a.window, "ms_layer": ms,
                           "kernel_us": kern, "dmas_live": 2 * live * pages,
                           "dmas_clamped": clamped_dmas(bt, kv),
                           "sha1": hashlib.sha1(got.tobytes()).hexdigest(),
@@ -126,6 +137,7 @@ def decode_probe(a, dev, kp, vp, trace_dir):
 
 
 def main():
+    global L, P, HQ, PPS, C, SLOTS, CONTEXTS
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", default="16,32,64")
     ap.add_argument("--reps", type=int, default=10)
@@ -135,7 +147,24 @@ def main():
                     help="--decode: live slots x pages, e.g. 4x3,1x10,16x13")
     ap.add_argument("--in-flight", default=None,
                     help="--decode: DECODE_PAGES_IN_FLIGHT values to try")
+    ap.add_argument("--group", type=int, default=HQ // HKV)
+    ap.add_argument("--window", type=int, default=None)
+    ap.add_argument("--chunk", type=int, default=C)
+    ap.add_argument("--layers", type=int, default=L)
+    ap.add_argument("--pages", type=int, default=P, help="pages of the pool")
+    ap.add_argument("--pps", type=int, default=PPS,
+                    help="pages a sequence (the ring, under --window)")
+    ap.add_argument("--slots", type=int, default=SLOTS)
+    ap.add_argument("--contexts", default=None,
+                    help="tokens before the chunk : real tokens in it, ...")
+    ap.add_argument("--vmem-mb", type=int, default=None)
+    ap.add_argument("--no-baseline", action="store_true")
     a = ap.parse_args()
+    L, P, HQ, PPS, C, SLOTS = (a.layers, a.pages, HKV * a.group, a.pps,
+                               a.chunk, a.slots)
+    if a.contexts:
+        CONTEXTS = [tuple(int(x) for x in c.split(":"))
+                    for c in a.contexts.split(",")]
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"a chip run: found {dev.platform}")
@@ -154,13 +183,17 @@ def main():
     q = jax.random.normal(kq, (C, HQ, D), jnp.bfloat16)
     bt = jnp.asarray(np.random.default_rng(0).permutation(P - 1)[:PPS] + 1,
                      jnp.int32)
-    variants = {"decode_rows": layers(
+    win = {"window": a.window} if a.window else {}
+    variants = {} if a.no_baseline else {"decode_rows": layers(
         lambda q, kp, vp, bt, kv, ly: gqa_decode_paged(
-            q, kp, vp, jnp.broadcast_to(bt, (C, PPS)), kv, layer=ly)[0])}
+            q, kp, vp, jnp.broadcast_to(bt, (C, PPS)), kv, layer=ly,
+            **win)[0])}
+    if a.vmem_mb:
+        win = dict(win, vmem_limit_bytes=a.vmem_mb << 20)
     for rb in (int(r) for r in a.rows.split(",")):
         variants[f"prefill_rb{rb}"] = layers(
             lambda q, kp, vp, bt, kv, ly, rb=rb: gqa_prefill_paged(
-                q, kp, vp, bt, kv, layer=ly, rows_per_block=rb))
+                q, kp, vp, bt, kv, layer=ly, rows_per_block=rb, **win))
     lines = []
     for start, real in CONTEXTS:
         idx = start + np.arange(C)
@@ -172,6 +205,7 @@ def main():
             want = got if want is None else want
             lines.append({
                 "context": start, "real": real, "variant": name,
+                "group": a.group, "window": a.window, "chunk": C,
                 "ms_layer": ms, "kernel_us": kern,
                 "gap": float(np.abs(got - want).max()),
                 "device": dev.device_kind})

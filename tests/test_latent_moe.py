@@ -262,11 +262,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
         fc = file_cfg(held=4, first=first, layers=2)
         pc = Adapter(fc)._program_config()
         part = tuple(t[:, first:first + 4] for t in tables)
-        out, (n_rows, n_touched) = mla.sparse_ffn(pc, p, h, 1, None,
-                                                  tables=part)
+        out, counts = mla.sparse_ffn(pc, p, h, 1, None, tables=part)
         total = total + out - shared
-        rows += int(n_rows)
-        assert 0 < int(n_touched) <= 4
+        rows += int(counts["moe_local_rows"])
+        assert 0 < int(counts["moe_experts_touched"]) <= 4
         # and the reference handed the same share gives the same part
         zs = ref.sizes(fc)
         np.testing.assert_allclose(

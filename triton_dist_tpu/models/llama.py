@@ -432,9 +432,9 @@ def init_page_pool(cfg: LlamaConfig, num_pages: int, page_size: int) -> dict:
 @dataclasses.dataclass(frozen=True)
 class PagedFamily:
     """A model family, as the paged programs and ``serving.engine`` see it:
-    the kind of cache, of attention and of FFN are DATA here, and the
-    config's type picks the record (``cfg.paged``). The layer loop
-    (``_paged_layers``), the decode, multistep and chunk programs, the
+    the kind of cache, of attention, of FFN, of norm and of block are DATA
+    here, and the config's type picks the record (``cfg.paged``). The layer
+    loop (``_paged_layers``), the decode, multistep and chunk programs, the
     scheduler and the page ledger are one for every family.
 
     - ``init_pool(cfg, num_pages, page_size)``: the page pool, a pytree of
@@ -443,14 +443,28 @@ class PagedFamily:
     - ``segments(cfg, params)``: the runs of layers that share a body, in
       order: ``[(blocks, first_layer, n_layers, ffn)]`` with ``blocks`` the
       run's stacked per-layer params and ``ffn(cfg, p, h, layer, active) ->
-      (out [R, D], counts)``; ``counts`` is a tuple of int32 scalars, one per
-      name in ``counters``, or ``()``.
+      (out [R, D], counts)``; ``counts`` maps names of ``counters`` to int32
+      scalars (``{}``: none).
     - ``attention(cfg, p, h, layer, pool, block_table, pos, kv_len, active,
-      shared_table, lin, attn_io) -> (out [R, D], pool)``: projections, the
-      pool write, the paged walk and the output projection of one layer.
-    - ``counters``: names of the per-dispatch counters the FFNs return; the
-      multistep program sums them over layers and inner steps and appends
-      one row each to its token slab.
+      shared_table, lin, attn_io) -> (out [R, D], pool[, counts])``:
+      projections, the pool write, the paged walk and the output projection
+      of one layer.
+    - ``period(cfg)``: for a family whose layers are not all of one kind, the
+      attentions of ONE PERIOD of its layer pattern, in order (each as
+      ``attention`` above; say three window layers and a full one). The
+      period is what the layer loop scans: its body is ``len(period)``
+      layers, so 32 layers of period 4 are 8 trips of one compiled body.
+      None: every layer runs ``attention`` (a period of one).
+    - ``norm(x, w, eps)``: the layers' and the head's norm (``rmsnorm``).
+    - ``parallel``: the block form. False: ``x + attn(norm(x))`` then ``x +
+      ffn(norm(x))``, two norms a layer. True: ``x + attn(u) + ffn(u)`` with
+      ``u = norm(x)``, ONE norm (``attn_norm``) a layer.
+    - ``head(cfg, params, x, lin) -> logits float32``: the output head on
+      normed rows; None: ``x @ params["lm_head"]``. A tied head contracts
+      with the embedding table here.
+    - ``counters``: names of the per-dispatch counters the FFNs and
+      attentions return; the multistep program sums them over layers and
+      inner steps and appends one row each to its token slab.
     - ``decode_speculate``: the speculative decode program, where the
       family has one. The other two programs the engine jits,
       ``decode_multistep_paged`` and ``prefill_chunk_paged``, are the same
@@ -458,19 +472,42 @@ class PagedFamily:
       and are not part of the record.
     - ``lacks``: the engine options this family does not take, of
       ``speculate``, ``prefix_cache``, ``hooks`` (``ffn`` / ``attn_io`` /
-      ``linear``); the engine refuses them by name."""
+      ``linear``); the engine refuses them by name.
+    - ``slot_ring(cfg, page_size)``: pages of the RING every engine slot owns
+      in a second kind of pool leaf (layers whose cache is bounded, such as a
+      sliding window's), beside the ledger's pages; None: the family has
+      none. The ring is the slot's, not the ledger's: slot s owns ring pages
+      ``1 + s * ring ..`` (page 0 is scratch, as the ledger's), the engine
+      appends that first page to the slot's block-table row (the programs'
+      tables are then ``pages_per_seq + 1`` wide), and ``bind(cfg, num_slots,
+      prefill_chunk)`` returns the config sized for an engine's slots and
+      chunk (the ring spans the window and a chunk; ``init_pool`` makes
+      ``num_slots`` rings)."""
     name: str
     init_pool: Any
     segments: Any
-    attention: Any
+    attention: Any = None
+    period: Any = None
+    norm: Any = None
+    parallel: bool = False
+    head: Any = None
     decode_speculate: Any = None
     counters: tuple = ()
     lacks: tuple = ()
+    slot_ring: Any = None
+    bind: Any = None
 
     # ``benchmark/tools/fit_paged.py`` reads the two shared programs off the
     # record; they are the module's functions, whatever the family.
     decode_multistep = property(lambda self: decode_multistep_paged)
     prefill_chunk = property(lambda self: prefill_chunk_paged)
+
+    def logits(self, cfg, params, x, lin) -> jax.Array:
+        """The head on ``x`` [R, D]: final norm, then the family's head."""
+        x = (self.norm or rmsnorm)(x, params["final_norm"], cfg.norm_eps)
+        if self.head is not None:
+            return self.head(cfg, params, x, lin)
+        return lin(x, params["lm_head"], "lm_head").astype(jnp.float32)
 
 
 def require_config(cfg, kind: type, who: str) -> None:
@@ -478,16 +515,20 @@ def require_config(cfg, kind: type, who: str) -> None:
     config is refused by name rather than mis-run."""
     if not isinstance(cfg, kind):
         raise NotImplementedError(
-            f"{who} serves {kind.__name__} models only (a K/V page pool, "
-            f"GQA attention); got {type(cfg).__name__}")
+            f"{who} serves {kind.__name__} models only; got "
+            f"{type(cfg).__name__}")
+
+
+def gated_ffn(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
+    """silu(h Wg) * (h Wu), then Wd: a dense FFN, or a shared expert."""
+    return (jax.nn.silu((h @ w_gate).astype(jnp.float32)).astype(h.dtype)
+            * (h @ w_up)) @ w_down
 
 
 def swiglu_ffn(cfg, p, h: jax.Array, layer=None, active=None):
     """The dense FFN of a layer, as a ``PagedFamily`` segment's ``ffn``."""
     del cfg, layer, active
-    ff = (jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32)
-                      ).astype(h.dtype) * (h @ p["w_up"])) @ p["w_down"]
-    return ff, ()
+    return gated_ffn(h, p["w_gate"], p["w_up"], p["w_down"]), {}
 
 
 def _gqa_segments(cfg: LlamaConfig, params: dict) -> list:
@@ -538,10 +579,17 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     [R, D] is R rows of decode (R batch slots, or a chunk's C tokens), row
     r at position ``pos[r]`` attending ``kv_len[r]`` keys through
     ``block_table[r]`` (``shared_table``: all rows have the same one).
-    What a layer IS comes from ``cfg.paged`` (``PagedFamily``): its
+    What a layer IS comes from ``cfg.paged`` (``PagedFamily``): its norm,
+    its block form (sequential or parallel), per layer of a period its
     attention over its kind of pool, and per run of layers its FFN.
     Returns (x after the last block, updated pages, the family's counters
-    summed over the layers).
+    summed over the layers, in ``counters``' order).
+
+    The body that is scanned is ONE PERIOD of the family's layer pattern
+    (``PagedFamily.period``; one layer where every layer is of one kind):
+    its params are the run's stacked ``[n, ...]`` arrays seen as
+    ``[n / period, period, ...]`` (a free view), its layer index the period's
+    first.
 
     The pool is the loop's CARRY, never its per-layer input or output:
     ``lax.scan`` cannot alias an ``xs`` to a ``ys``, so scanning over the
@@ -555,35 +603,61 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     ``attn_io`` keeps its per-layer contract: it is handed ``K[i]``,
     ``V[i]`` and its result is put back at the static index."""
     fam = cfg.paged
+    norm = fam.norm or rmsnorm
+    period = fam.period(cfg) if fam.period else (fam.attention,)
+    P = len(period)
     lin = linear or (lambda h, w, name: h @ w)
     hooked = not (ffn is None and attn_io is None and linear is None)
-    carry = (x, pages, tuple(jnp.int32(0) for _ in fam.counters))
-    for blocks, first, n, seg_ffn in fam.segments(cfg, params):
-        def body(carry, layer, seg_ffn=seg_ffn):
-            x, pool, counts = carry
-            p, i = layer
-            h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-            attn, pool = fam.attention(cfg, p, h, i, pool, block_table, pos,
-                                       kv_len, active, shared_table, lin,
-                                       attn_io)
+
+    def add(counts, new):
+        return {**counts, **{k: counts[k] + v for k, v in new.items()}}
+
+    def layer(carry, p, i, attention, seg_ffn):
+        x, pool, counts = carry
+        h = norm(x, p["attn_norm"], cfg.norm_eps)
+        attn, pool, *new = attention(cfg, p, h, i, pool, block_table, pos,
+                                     kv_len, active, shared_table, lin,
+                                     attn_io)
+        if new:
+            counts = add(counts, new[0])
+        if not fam.parallel:
             x = x + attn
-            h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-            if ffn is None:
-                ff, new = seg_ffn(cfg, p, h, i, active)
-                if new:
-                    counts = tuple(a + b for a, b in zip(counts, new))
-            else:
-                ff = ffn(h, p)
-            x = x + ff.astype(x.dtype)
-            return (x, pool, counts), None
+            h = norm(x, p["mlp_norm"], cfg.norm_eps)
+        if ffn is None:
+            ff, new = seg_ffn(cfg, p, h, i, active)
+            if new:
+                counts = add(counts, new)
+        else:
+            ff = ffn(h, p)
+        if fam.parallel:
+            x = x + attn
+        x = x + ff.astype(x.dtype)
+        return x, pool, counts
+
+    carry = (x, pages, {name: jnp.int32(0) for name in fam.counters})
+    for blocks, first, n, seg_ffn in fam.segments(cfg, params):
+        assert n % P == 0, f"{n} layers are no whole number of periods of {P}"
+
+        def body(carry, xs, seg_ffn=seg_ffn):
+            p, i = xs               # a period's params [P, ...], its first
+            for j, attention in enumerate(period):
+                carry = layer(carry, LayerParams(p, j) if P > 1 else p,
+                              i + j if j else i, attention, seg_ffn)
+            return carry, None
 
         if hooked:
             for j in range(n):
-                carry, _ = body(carry, (LayerParams(blocks, j), first + j))
+                carry = layer(carry, LayerParams(blocks, j), first + j,
+                              period[j % P], seg_ffn)
         else:
-            carry, _ = lax.scan(body, carry, (
-                blocks, jnp.arange(first, first + n, dtype=jnp.int32)))
-    return carry
+            firsts = jnp.arange(first, first + n, dtype=jnp.int32)
+            if P > 1:
+                blocks = jax.tree.map(
+                    lambda a: a.reshape((n // P, P) + a.shape[1:]), blocks)
+                firsts = firsts[::P]
+            carry, _ = lax.scan(body, carry, (blocks, firsts))
+    x, pages, counts = carry
+    return x, pages, tuple(counts[name] for name in fam.counters)
 
 
 def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
@@ -639,8 +713,7 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     x, pages, counts = _paged_layers(params, x, pos, kv_len, active, cfg,
                                      pages, block_table, ffn, attn_io,
                                      linear)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = lin(x, params["lm_head"], "lm_head").astype(jnp.float32)
+    logits = cfg.paged.logits(cfg, params, x, lin)
     out = jnp.argmax(logits, -1).astype(jnp.int32) if sample else logits
     return (out, pages, counts) if counters else (out, pages)
 
@@ -717,8 +790,7 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     # range otherwise — the result is then garbage the engine discards)
     last = jnp.clip(prompt_len - 1 - start, 0, C - 1).astype(jnp.int32)
     h_last = lax.dynamic_slice_in_dim(x, last, 1)                    # [1, D]
-    h_last = rmsnorm(h_last, params["final_norm"], cfg.norm_eps)
-    logits = lin(h_last, params["lm_head"], "lm_head").astype(jnp.float32)
+    logits = cfg.paged.logits(cfg, params, h_last, lin)
     tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
     return tok, pages
 
@@ -1055,8 +1127,8 @@ GQA_DENSE = PagedFamily(
 
 
 __all__ = ["LlamaConfig", "LayerParams", "PagedFamily", "GQA_DENSE",
-           "swiglu_ffn", "require_config", "init_params", "param_specs",
-           "forward",
+           "swiglu_ffn", "gated_ffn", "require_config", "init_params",
+           "param_specs", "forward",
            "forward_tp_overlap", "mlp_tp_overlap", "rmsnorm", "rope",
            "block_apply", "init_kv_cache", "init_page_pool", "prefill",
            "decode_step", "decode_step_paged", "decode_multistep_paged",

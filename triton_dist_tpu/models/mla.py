@@ -43,9 +43,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
-from triton_dist_tpu.models.llama import PagedFamily, rmsnorm, swiglu_ffn
+from triton_dist_tpu.models.expert_share import (COUNTERS, held_experts,
+                                                 held_ids, sigmoid_route)
+from triton_dist_tpu.models.llama import (PagedFamily, gated_ffn, rmsnorm,
+                                          swiglu_ffn)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -291,12 +293,9 @@ def route(cfg: LatentMoEConfig, h: jax.Array, w_router: jax.Array,
     """(expert ids [R, k], weights [R, k] float32): sigmoid scores in
     float32 over ALL routed experts, the k largest of score + bias chosen,
     weighed by their scores (without the bias) over their sum, times the
-    scaling factor."""
-    g = jax.nn.sigmoid(h.astype(jnp.float32) @ w_router)
-    _, ids = lax.top_k(g + bias, cfg.topk)
-    w = jnp.take_along_axis(g, ids, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return ids, w * cfg.routed_scaling_factor
+    scaling factor (``expert_share.sigmoid_route``)."""
+    return sigmoid_route(h, w_router, cfg.topk, bias,
+                         cfg.routed_scaling_factor)
 
 
 def sparse_ffn(cfg: LatentMoEConfig, p, h: jax.Array, layer, active=None,
@@ -305,52 +304,19 @@ def sparse_ffn(cfg: LatentMoEConfig, p, h: jax.Array, layer, active=None,
     routed sum plus the shared expert. ``p`` is the layer's params without
     its expert tables; ``tables`` are the STACKED gate, up and down tables
     [Lm, held, ., .] of all sparse layers, read in place at ``layer`` (the
-    model's layer index, traced or not): XLA cannot fuse a slice into a
-    Pallas operand, a per-layer table would be copied every call. Rows
+    model's layer index, traced or not: ``expert_share.held_experts``). Rows
     masked off by ``active`` are routed nowhere and not counted. Returns
-    (out, (assignments that landed on held experts, held experts with at
-    least one row))."""
-    from triton_dist_tpu.ops.group_gemm import (apply_grouped, fit_block_k,
-                                                grouped_gemm,
-                                                grouped_gemm_gated)
-    R, D = h.shape
-    Eh, k, Fe = cfg.n_experts_held, cfg.topk, cfg.moe_d_ff
+    (out, {assignments that landed on held experts, held experts with at
+    least one row}: ``expert_share.COUNTERS``)."""
+    Eh = cfg.n_experts_held
     with jax.named_scope("moe_router"):
         ids, w = route(cfg, h, p["w_router"], p["router_bias"])
-        lid = ids - cfg.first_held_expert
-        held = jnp.logical_and(lid >= 0, lid < Eh)
-        if active is not None:
-            held = jnp.logical_and(held, active[:, None])
-        lid = jnp.where(held, lid, -1).astype(jnp.int32)
-        touched = jnp.any(lid[..., None] == jnp.arange(Eh), axis=(0, 1))
-        counts = (jnp.sum(held).astype(jnp.int32),
-                  jnp.sum(touched).astype(jnp.int32))
+        lid, counts = held_ids(ids, Eh, cfg.first_held_expert, active)
     with jax.named_scope("moe_routed_experts"):
-        # [Lm, held, ., .] -> [Lm * held, ., .]: a free view; this layer's
-        # experts start at row (layer - dense layers) * held of it
-        wg, wu, wd = (t.reshape((-1,) + t.shape[2:]) for t in tables)
-        first = (layer - cfg.n_dense_layers) * Eh
-        size = jnp.dtype(wd.dtype).itemsize
-        bn, dbn = math.gcd(128, Fe), math.gcd(512, D)
-
-        def experts(xs, be, nb):
-            be = be + first
-            hh = grouped_gemm_gated(
-                xs, wg, wu, be, block_m=block_m, block_n=bn,
-                n_blocks_used=nb, masked=False,
-                block_k=fit_block_k(D, block_m, bn, size, n_weights=2))
-            return grouped_gemm(
-                hh, wd, be, block_m=block_m, block_n=dbn, n_blocks_used=nb,
-                masked=False, block_k=fit_block_k(Fe, block_m, dbn, size))
-
-        y = apply_grouped(jnp.repeat(h, k, axis=0), lid.reshape(R * k), Eh,
-                          experts, block_m=block_m)
-        routed = jnp.sum(y.reshape(R, k, D).astype(jnp.float32)
-                         * w[..., None], axis=1)
+        routed = held_experts(h, lid, w, tables,
+                              (layer - cfg.n_dense_layers) * Eh, Eh, block_m)
     with jax.named_scope("moe_shared_expert"):
-        shared = (jax.nn.silu((h @ p["ws_gate"]).astype(jnp.float32)
-                              ).astype(h.dtype) * (h @ p["ws_up"])
-                  ) @ p["ws_down"]
+        shared = gated_ffn(h, p["ws_gate"], p["ws_up"], p["ws_down"])
     return (routed + shared.astype(jnp.float32)).astype(h.dtype), counts
 
 
@@ -395,8 +361,7 @@ def forward(params: dict, tokens: jax.Array, cfg: LatentMoEConfig
 LATENT_MOE = PagedFamily(
     name="latent_moe", init_pool=init_latent_pool, segments=_segments,
     attention=_latent_attention,
-    counters=("moe_local_rows", "moe_experts_touched"),
-    lacks=("speculate", "prefix_cache", "hooks"))
+    counters=COUNTERS, lacks=("speculate", "prefix_cache", "hooks"))
 
 
 __all__ = ["LatentMoEConfig", "LATENT_MOE", "init_params", "forward",

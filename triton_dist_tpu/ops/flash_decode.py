@@ -48,12 +48,14 @@ def _softmax_init(acc, m_i, l_i):
 
 
 def _softmax_update(start, kv_len, q, k, v, acc, m_i, l_i, *, block_s: int,
-                    sm_scale: float, n_kv_heads: int):
+                    sm_scale: float, n_kv_heads: int, lo=None):
     """One online-softmax update: the KV block ``k`` / ``v`` [Hkv, block_s,
     D], whose first key sits at position ``start``, against all Hq query
     heads ``q`` [Hq, D] of one row, as a [Hkv, G, ·] batched
     contraction (Mosaic needs the last-two block dims full/aligned, so heads
-    are not split). Analog of kernel_gqa_fwd_batch_decode_split_kv
+    are not split). Keys at ``kv_len`` and beyond are masked, and with ``lo``
+    (a sliding window's bound) those below it. Analog of
+    kernel_gqa_fwd_batch_decode_split_kv
     (flash_decode.py:129-280) with the split-KV dimension replaced by
     sequential KV-block pipelining."""
     Hq, D = acc.shape
@@ -67,7 +69,10 @@ def _softmax_update(start, kv_len, q, k, v, acc, m_i, l_i, *, block_s: int,
         preferred_element_type=jnp.float32) * sm_scale  # [Hkv, G, bs]
     scores = scores.reshape(Hq, block_s)
     pos = start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    scores = jnp.where(pos < kv_len, scores, NEG_INF)
+    seen = pos < kv_len
+    if lo is not None:
+        seen = jnp.logical_and(seen, pos >= lo)
+    scores = jnp.where(seen, scores, NEG_INF)
     m_new = jnp.maximum(m_i[...], jnp.max(scores, axis=1, keepdims=True))
     alpha = jnp.exp(m_i[...] - m_new)
     p = jnp.exp(scores - m_new)                  # [Hq, block_s]
@@ -110,7 +115,7 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
 def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
                          out_ref, lse_ref, k_buf, v_buf, sem, acc, m_i, l_i,
                          *, n_pool: int, page_size: int, sm_scale: float,
-                         n_kv_heads: int):
+                         n_kv_heads: int, window: int | None = None):
     """Grid (row blocks,) over a paged KV pool left in HBM: ONE loop over the
     block's LIVE pages alone, rows in order and a row's pages in order, each
     fetched by hand (``bt_ref[row, idx]`` of layer ``layer_ref[0]``, straight
@@ -120,16 +125,31 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
     past ``kv_len`` are not steps at all. ``q_ref`` / ``out_ref`` /
     ``lse_ref`` hold the block's rows. Analog of the reference's
     block_table-driven split-KV kernel (flash_decode.py:129-280 `page`
-    indexing)."""
+    indexing).
+
+    With ``window`` a row attends its last ``window`` keys alone: its walk
+    starts at the page of key ``kv_len - window`` (no page before it is a
+    step or a DMA), that first page is masked below the bound, and the block
+    table is a RING: logical page ``idx`` lives in column ``idx % columns``."""
     rows, pages_per_seq = q_ref.shape[0], bt_ref.shape[1]
     row0 = pl.program_id(0) * rows
     end = row0 + rows
     layer = layer_ref[0]
 
     def live_pages(row):
-        # a key past the table's last page does not exist, whatever kv_len says
-        return jnp.minimum((kv_len_ref[row] + page_size - 1) // page_size,
-                           pages_per_seq)
+        # a key past the table's last page does not exist, whatever kv_len
+        # says; a ring has no last page
+        n = (kv_len_ref[row] + page_size - 1) // page_size
+        return n if window else jnp.minimum(n, pages_per_seq)
+
+    def bound(row):
+        return jnp.maximum(kv_len_ref[row] - window, 0)
+
+    def first_page(row):
+        """(row, its first live page); row ``end`` when no row is left."""
+        if not window:
+            return row, 0
+        return row, bound(jnp.minimum(row, end - 1)) // page_size
 
     def next_live(row):
         return lax.while_loop(
@@ -138,7 +158,8 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
     def fetch(row, idx, buf):
         # the clamp keeps even a garbage block-table entry inside the pool
-        page = jnp.clip(bt_ref[row, idx], 0, n_pool - 1)
+        col = idx % pages_per_seq if window else idx
+        page = jnp.clip(bt_ref[row, col], 0, n_pool - 1)
         return (pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[buf],
                                       sem.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[layer, page], v_buf.at[buf],
@@ -154,12 +175,12 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
         """The live page that follows (row, idx); row ``end`` when none."""
         return lax.cond(
             idx + 1 >= live_pages(jnp.minimum(row, end - 1)),
-            lambda: (next_live(row + 1), 0), lambda: (row, idx + 1))
+            lambda: first_page(next_live(row + 1)), lambda: (row, idx + 1))
 
     # the first ``depth - 1`` live pages are in flight before the loop, and
     # every turn of it starts one more
     depth = k_buf.shape[0]
-    pages = [(next_live(row0), 0)]
+    pages = [first_page(next_live(row0))]
     for _ in range(depth - 2):
         pages.append(after(*pages[-1]))
     for buf, page in enumerate(pages):
@@ -173,15 +194,18 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
         pages.append(after(*pages[-1]))
         start(*pages[-1], (w + depth - 1) % depth)
         (row, idx), buf = pages[0], w % depth
-        pl.when(idx == 0)(lambda: _softmax_init(acc, m_i, l_i))
+        pl.when(idx == first_page(row)[1])(
+            lambda: _softmax_init(acc, m_i, l_i))
         for copy in fetch(row, idx, buf):
             copy.wait()
         r = row - row0
         _softmax_update(
             idx * page_size,
-            jnp.minimum(kv_len_ref[row], pages_per_seq * page_size),
+            kv_len_ref[row] if window
+            else jnp.minimum(kv_len_ref[row], pages_per_seq * page_size),
             q_ref[r], k_buf[buf], v_buf[buf], acc, m_i, l_i,
-            block_s=page_size, sm_scale=sm_scale, n_kv_heads=n_kv_heads)
+            block_s=page_size, sm_scale=sm_scale, n_kv_heads=n_kv_heads,
+            lo=bound(row) if window else None)
         pl.when(pages[1][0] != row)(             # the row's last live page
             lambda: _softmax_finish(out_ref, lse_ref, acc, m_i, l_i, row=r))
         return (w + 1, *pages[1:])
@@ -249,6 +273,19 @@ def gqa_decode_partial(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     )(kv_len, q, k_cache, v_cache)
 
 
+def _window_pages(window: int, page_size: int, rows: int) -> int:
+    """Pages that ``rows`` consecutive positions' windows can touch together:
+    ``window + rows - 1`` keys, starting anywhere in a page."""
+    return -(-(window + rows - 1) // page_size) + 1
+
+
+def _check_ring(window, ring: int, page_size: int, rows: int) -> None:
+    assert window is None or (window >= 1 and ring >= _window_pages(
+        window, page_size, rows)), (
+        f"a ring of {ring} pages of {page_size} cannot hold a window of "
+        f"{window} keys for {rows} consecutive rows")
+
+
 def _as_stack(k_pages, v_pages, layer):
     """The paged kernels' pool operands: the stacked pool and ``layer`` as the
     [1] int32 scalar-prefetch operand of their index maps."""
@@ -294,7 +331,8 @@ DECODE_PAGES_IN_FLIGHT = 2
 
 def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      block_table: jax.Array, kv_len: jax.Array,
-                     sm_scale: float | None = None, layer=None):
+                     sm_scale: float | None = None, layer=None,
+                     window: int | None = None):
     """Paged-attention decode over a shared KV page pool (the serving-side
     cache layout; parity with the reference's block_table path and its
     ``ref_paged_attn`` golden, test_sp_decode_attn.py:81-134).
@@ -334,6 +372,16 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pages at ``kv_len = pos_b + i + 1``. A prefill chunk's rows, which
     ALL share one block-table row, have ``gqa_prefill_paged`` instead:
     the same walk, shared by a block of rows.
+
+    ``window`` (static; None = all of the above, the same program to the
+    bit): row b attends keys ``max(0, kv_len_b - window) .. kv_len_b - 1``
+    alone, and ``block_table`` [B, ring] is a RING of pages: logical page p
+    of the sequence lives in column ``p % ring`` (the writer wraps the same
+    way), so a sequence holds ``ring`` pages whatever its length. The walk
+    starts at the page that holds the bound, masks the keys below it there,
+    and touches no page before it: a row costs its window, not its context.
+    ``ring`` pages must span the window plus one page. The kernel's name in
+    a trace is then ``gqa_decode_paged_window``.
     """
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
     B, Hq, D = q.shape
@@ -341,6 +389,7 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     assert Hq % Hkv == 0
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     pages_per_seq = block_table.shape[1]
+    _check_ring(window, pages_per_seq, page_size, rows=1)
     Rb = math.gcd(B, DECODE_ROWS_PER_BLOCK)
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     rows = lambda i, *_: (i, 0, 0)                          # noqa: E731
@@ -349,7 +398,10 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                           k_pages.dtype)
     kernel = functools.partial(_decode_paged_kernel, n_pool=P_pool,
                                page_size=page_size, sm_scale=sm_scale,
-                               n_kv_heads=Hkv)
+                               n_kv_heads=Hkv, window=window)
+    # what a row can read at most: its window's pages, or the whole table
+    live = min(pages_per_seq, _window_pages(window, page_size, 1)) \
+        if window else pages_per_seq
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -373,11 +425,11 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             jax.ShapeDtypeStruct((B, Hq, 128), jnp.float32),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * Hq * pages_per_seq * page_size * D,
+            flops=4 * B * Hq * live * page_size * D,
             bytes_accessed=(q.size
-                            + B * pages_per_seq * Hkv * page_size * D * 2),
-            transcendentals=B * Hq * pages_per_seq * page_size),
-        name="gqa_decode_paged",
+                            + B * live * Hkv * page_size * D * 2),
+            transcendentals=B * Hq * live * page_size),
+        name="gqa_decode_paged_window" if window else "gqa_decode_paged",
         interpret=default_interpret(),
     )(kv_len.astype(jnp.int32), block_table, layer, q, k_pages, v_pages)
 
@@ -390,35 +442,49 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 PREFILL_ROWS_PER_BLOCK = 64
 
 
-def _prefill_paged_kernel(kl_ref, bt_ref, layer_ref, q_ref, klr_ref, k_ref,
-                          v_ref, out_ref, acc, m_i, l_i, *, page_size: int,
-                          sm_scale: float):
-    """Grid (row blocks, pages_per_seq). ``q_ref`` [Hkv, M, D] is a block of
+def _prefill_paged_kernel(*refs, page_size: int, sm_scale: float,
+                          window: int | None = None):
+    """Grid (row blocks, pages). ``q_ref`` [Hkv, M, D] is a block of
     Rb rows x G heads a KV head (M = Rb * G), ``klr_ref`` [M, 1] their
     ``kv_len``, ``kl_ref[i]`` the largest of block i; ``k_ref`` / ``v_ref``
     [1, Hkv, page_size, D] the page. The online-softmax update is
     ``_online_softmax_body``'s, with M query rows a head against the page
-    where decode has G, and the ``kv_len`` mask per row."""
-    del bt_ref, layer_ref
+    where decode has G, and the ``kv_len`` mask per row. With ``window``
+    grid step s is logical page ``first_ref[i] + s`` (the page of the
+    block's lowest bound) and a row's keys below ``kv_len - window`` are
+    masked too."""
+    if window:
+        (kl_ref, _, _, first_ref, q_ref, klr_ref, k_ref, v_ref, out_ref,
+         acc, m_i, l_i) = refs
+    else:
+        (kl_ref, _, _, q_ref, klr_ref, k_ref, v_ref, out_ref,
+         acc, m_i, l_i) = refs
     i, s = pl.program_id(0), pl.program_id(1)
+    page = first_ref[i] + s if window else s
 
     pl.when(s == 0)(lambda: _softmax_init(acc, m_i, l_i))
 
     # a page that no row of the block can see: no compute (and no DMA, the
     # index map revisits the block's last live page)
-    @pl.when(s * page_size < kl_ref[i])
+    @pl.when(page * page_size < kl_ref[i])
     def _():
         q, k, v = q_ref[...], k_ref[0], v_ref[0]
         scores = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * sm_scale  # [Hkv, M, page]
         M = scores.shape[1]
-        pos = s * page_size + jax.lax.broadcasted_iota(
+        pos = page * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (M, page_size), 1)
         # a row sees key 0 on page 0 or sees nothing at all, so a page
         # wholly masked for a row finds its running max already real and
-        # adds exp(NEG_INF - m) = 0; rows that see nothing are zeroed below
-        scores = jnp.where((pos < klr_ref[...])[None], scores, NEG_INF)
+        # adds exp(NEG_INF - m) = 0; rows that see nothing are zeroed below.
+        # (Under a window a later row of the block may see nothing of the
+        # block's first pages: it adds exp(0) there, and its first real key
+        # scales all of that by exp(NEG_INF - m) = 0.)
+        seen = pos < klr_ref[...]
+        if window:
+            seen = jnp.logical_and(seen, pos >= klr_ref[...] - window)
+        scores = jnp.where(seen[None], scores, NEG_INF)
         m_new = jnp.maximum(m_i[...], jnp.max(scores, axis=2, keepdims=True))
         alpha = jnp.exp(m_i[...] - m_new)
         p = jnp.exp(scores - m_new)
@@ -439,8 +505,9 @@ def _prefill_paged_kernel(kl_ref, bt_ref, layer_ref, q_ref, klr_ref, k_ref,
 def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       block_table: jax.Array, kv_len: jax.Array,
                       sm_scale: float | None = None, layer=None,
-                      rows_per_block: int = PREFILL_ROWS_PER_BLOCK
-                      ) -> jax.Array:
+                      rows_per_block: int = PREFILL_ROWS_PER_BLOCK,
+                      window: int | None = None,
+                      vmem_limit_bytes: int | None = None) -> jax.Array:
     """``gqa_decode_paged`` for rows that all belong to ONE sequence (a
     prefill chunk's queries): the same online-softmax walk of the block
     table, shared by ``rows_per_block`` rows at a time, so that a page of the
@@ -459,7 +526,18 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Grid (row blocks, pages): a (block, page) pair past the block's largest
     ``kv_len`` — the pages above a block's own last position, not only
     those past the prompt — revisits the block's last live page (no DMA)
-    and skips its compute."""
+    and skips its compute.
+
+    ``window`` (static; None = the above, the same program to the bit) as in
+    ``gqa_decode_paged``: a row attends its last ``window`` keys, the table
+    is a ring, and the ring must span the window AND the C rows (the chunk
+    writes its rows before it walks). A block's live rows are then
+    CONSECUTIVE positions (a chunk's are): its grid runs over the
+    ``ceil((window + rows_per_block - 1) / page) + 1`` pages their windows can
+    touch, from the page of the lowest bound, never over the pages before.
+    The kernel's name in a trace is then ``gqa_prefill_paged_window``.
+    ``vmem_limit_bytes`` raises Mosaic's scoped-VMEM limit for a block that
+    needs more than its 16 MB default."""
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
     C, Hq, D = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
@@ -469,6 +547,7 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     Rb = math.gcd(C, rows_per_block)
     n_blk, M = C // Rb, Rb * G
     pages_per_seq = block_table.shape[0]
+    _check_ring(window, pages_per_seq, page_size, rows=C)
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
     kv_len = kv_len.astype(jnp.int32)
     kl_blk = kv_len.reshape(n_blk, Rb).max(axis=1)
@@ -476,23 +555,38 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # head-major rows: a KV head's operand is its G query heads of every row
     q_hm = q.reshape(C, Hkv, G, D).swapaxes(0, 1).reshape(Hkv, C * G, D)
 
-    def page_index(i, s, kl, bt, ly):
+    def page_index(i, s, kl, bt, ly, *first):
         last = jnp.maximum((kl[i] + page_size - 1) // page_size - 1, 0)
-        page = bt[jnp.minimum(s, last)]
+        if window:
+            page = bt[jnp.minimum(first[0][i] + s, last) % pages_per_seq]
+        else:
+            page = bt[jnp.minimum(s, last)]
         return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
 
-    rows = lambda i, s, kl, bt, ly: (0, i, 0)               # noqa: E731
+    rows = lambda i, s, *_: (0, i, 0)                       # noqa: E731
     page_block = pl.BlockSpec((None, 1, Hkv, page_size, D), page_index)
-    live = C * pages_per_seq * page_size
+    scalars = (kl_blk, block_table, layer)
+    n_pages = pages_per_seq
+    if window:
+        # the page of the lowest bound among the block's live rows
+        bound = jnp.where(kv_len > 0, jnp.maximum(kv_len - window, 0),
+                          jnp.iinfo(jnp.int32).max)
+        lo = bound.reshape(n_blk, Rb).min(axis=1)
+        scalars += (jnp.where(kl_blk > 0, lo, 0) // page_size,)
+        n_pages = min(pages_per_seq, _window_pages(window, page_size, Rb))
+    live = C * n_pages * page_size
+    params = {} if vmem_limit_bytes is None else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes)}
     out = pl.pallas_call(
         functools.partial(_prefill_paged_kernel, page_size=page_size,
-                          sm_scale=sm_scale),
+                          sm_scale=sm_scale, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n_blk, pages_per_seq),
+            num_scalar_prefetch=len(scalars),
+            grid=(n_blk, n_pages),
             in_specs=[
                 pl.BlockSpec((Hkv, M, D), rows),
-                pl.BlockSpec((M, 1), lambda i, s, kl, bt, ly: (i, 0)),
+                pl.BlockSpec((M, 1), lambda i, s, *_: (i, 0)),
                 page_block,
                 page_block,
             ],
@@ -506,12 +600,13 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((Hkv, C * G, D), q.dtype),
         cost_estimate=pl.CostEstimate(
             flops=4 * live * Hq * D,
-            bytes_accessed=(2 * q.size + n_blk * pages_per_seq * Hkv
+            bytes_accessed=(2 * q.size + n_blk * n_pages * Hkv
                             * page_size * D * 2) * q.dtype.itemsize,
             transcendentals=live * Hq),
-        name="gqa_prefill_paged",
+        name="gqa_prefill_paged_window" if window else "gqa_prefill_paged",
         interpret=default_interpret(),
-    )(kl_blk, block_table, layer, q_hm, kl_rows, k_pages, v_pages)
+        **params,
+    )(*scalars, q_hm, kl_rows, k_pages, v_pages)
     return out.reshape(Hkv, C, G, D).swapaxes(0, 1).reshape(C, Hq, D)
 
 

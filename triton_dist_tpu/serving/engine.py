@@ -61,7 +61,7 @@ from triton_dist_tpu.serving import checkpoint as ckpt_mod
 from triton_dist_tpu.serving.deadline import Deadline, EngineStallError
 from triton_dist_tpu.serving.journal import ControlJournal
 from triton_dist_tpu.serving.kv_pool import KVPagePool, _fnv1a
-from triton_dist_tpu.serving.metrics import ServingMetrics
+from triton_dist_tpu.serving.metrics import Histogram, ServingMetrics
 from triton_dist_tpu.serving.prefix_cache import PrefixCache
 from triton_dist_tpu.serving.scheduler import (AdmissionRejected,
                                                ContinuousBatchingScheduler,
@@ -183,6 +183,10 @@ class ServingEngine:
         # config's type: its kind of page pool and the programs this engine
         # jits. What a family lacks is refused here, by name.
         fam = self._family = cfg.paged
+        if fam.bind is not None:
+            # a family with a second kind of page (PagedFamily.slot_ring)
+            # sizes it for this engine's slots and chunk
+            cfg = fam.bind(cfg, num_slots, prefill_chunk)
         asked = {
             "speculate": speculate not in (None, 0, "off"),
             "prefix_cache": bool(prefix_cache),
@@ -201,6 +205,15 @@ class ServingEngine:
         self.metrics = metrics or ServingMetrics()
         for name in fam.counters:
             self.metrics.counters.setdefault(name, 0)
+        # pages of the ring each slot owns in the family's bounded layers
+        # (0: none). The ring is the slot's, not the ledger's: admission
+        # needs a slot (which brings its ring) and ledger pages, and the
+        # slot's first ring page rides one more column of its table row.
+        self._ring = int(fam.slot_ring(cfg, page_size)) if fam.slot_ring \
+            else 0
+        if self._ring:
+            self.metrics.hist.setdefault("kv_pages_full", Histogram())
+            self.metrics.hist.setdefault("kv_pages_window", Histogram())
         self.decode_horizon = decode_horizon
         self.eos_id = eos_id
         self._stall_steps = stall_deadline_steps
@@ -286,7 +299,8 @@ class ServingEngine:
         # the device copies below are authoritative between dispatches
         self._token = np.zeros(num_slots, np.int32)
         self._pos = np.zeros(num_slots, np.int32)
-        self._bt = np.zeros((num_slots, pages_per_seq), np.int32)
+        self._bt = np.zeros((num_slots, pages_per_seq + bool(self._ring)),
+                            np.int32)
         # drafter history window [B, H] (newest token at column H-1) +
         # valid-suffix lengths. Device-carried between dispatches when
         # speculation is on; the host mirrors the device's roll bitwise
@@ -374,17 +388,17 @@ class ServingEngine:
             if self.spec_k:
                 programs = {"decode_speculate_paged": (step, (
                     abstract(self.params), i32(num_slots), i32(num_slots),
-                    pool_abs, i32(num_slots, pages_per_seq),
+                    pool_abs, i32(*self._bt.shape),
                     i32(num_slots), i32(num_slots, self.spec_hist),
                     i32(num_slots)))}
             else:
                 programs = {"decode_multistep_paged": (step, (
                     abstract(self.params), i32(num_slots), i32(num_slots),
-                    pool_abs, i32(num_slots, pages_per_seq),
+                    pool_abs, i32(*self._bt.shape),
                     i32(num_slots)))}
             programs["prefill_chunk_paged"] = (chunk, (
                 abstract(self.params), i32(prefill_chunk), i32(), i32(),
-                pool_abs, i32(pages_per_seq)))
+                pool_abs, i32(self._bt.shape[1])))
             lint_engine_programs(programs, type(self).__name__)
 
         # AOT artifact seeding (ISSUE 15): swap the freshly-built jit
@@ -433,9 +447,15 @@ class ServingEngine:
         return np.asarray([self.alloc.device_row(int(p)) for p in ids],
                           np.int32)
 
-    def _device_bt_row(self, rid) -> np.ndarray:
-        return self._device_rows(
+    def _device_bt_row(self, rid, slot: int) -> np.ndarray:
+        """The table row the programs get for ``rid`` in ``slot``: its
+        ledger pages, then (a family with per-slot rings) the first page of
+        the slot's ring, ``1 + slot * ring`` (page 0 is scratch there too)."""
+        row = self._device_rows(
             self.alloc.block_table_row(rid, self.pages_per_seq))
+        if self._ring:
+            row = np.append(row, np.int32(1 + slot * self._ring))
+        return row
 
     # -- request intake ---------------------------------------------------
     def _ttl_for(self, req: Request) -> int | None:
@@ -754,7 +774,7 @@ class ServingEngine:
             for i in range(start // self.page_size,
                            (end - 1) // self.page_size + 1):
                 self._cow_writable(req, i)
-        row = self._device_bt_row(req.rid)
+        row = self._device_bt_row(req.rid, slot)
         t0 = time.perf_counter()
         tok_dev, self.pool = self._chunk_step(
             self.params, jnp.asarray(toks),
@@ -826,7 +846,10 @@ class ServingEngine:
         hook = self._preempt_hook
         if hook is not None and hook(slot, req):
             return
-        if req.state is RequestState.PREFILLING and req.prefill_cursor > 0:
+        # (a family with per-slot rings restarts instead: what the ring
+        # layers computed stays behind in the slot the victim leaves)
+        if (req.state is RequestState.PREFILLING and req.prefill_cursor > 0
+                and not self._ring):
             filled = -(-req.prefill_cursor // self.page_size)
             if filled < len(self.alloc.pages_of(req.rid)):
                 # mid-prefill victim: keep the pages already holding
@@ -1018,7 +1041,7 @@ class ServingEngine:
             limits[slot] = lim
             # refresh AFTER growth — the kernel writes this scan's (k, v)
             # into pages ensure() may just have allocated
-            row = self._device_bt_row(req.rid)
+            row = self._device_bt_row(req.rid, slot)
             if not np.array_equal(row, self._bt[slot]):
                 self._bt[slot] = row
                 self._dirty = True
@@ -1086,6 +1109,14 @@ class ServingEngine:
         self.metrics.observe("queue_depth", self.sched.queue_depth)
         self.metrics.observe("pool_occupancy", self.alloc.occupancy())
         self.metrics.observe("active_slots", len(active))
+        if self._ring:
+            # pages held by kind: a seated sequence's ledger pages (a full
+            # layer's), and what a ring layer holds of them at most
+            held = [len(self.alloc.pages_of(r.rid))
+                    for r in self.sched.slots if r is not None]
+            self.metrics.observe("kv_pages_full", sum(held))
+            self.metrics.observe("kv_pages_window",
+                                 sum(min(n, self._ring) for n in held))
 
         n_tokens = 0
         emitted_by_slot = {}

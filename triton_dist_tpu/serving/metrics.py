@@ -126,7 +126,15 @@ class ServingMetrics:
     experts with at least one row, both summed over the layers and inner
     steps of every decode dispatch, live rows only) are added by the engine
     that serves the family; they come off the token slab, not a transfer
-    of their own.
+    of their own. A family with window and full attention layers adds
+    ``attn_window_keys`` (min(context, window) summed over live rows, inner
+    steps and window layers: the keys its windowed walks had to attend) and
+    ``attn_full_keys`` (the context, summed the same way over full layers).
+    For a family whose slots own rings of pages (``PagedFamily.slot_ring``)
+    the engine samples two gauges every decode dispatch, pages held by kind:
+    ``kv_pages_full`` (the seated sequences' ledger pages: what a full layer
+    holds) and ``kv_pages_window`` (min(those, ring) a sequence: what a
+    window layer holds of them).
     """
 
     def __init__(self):
