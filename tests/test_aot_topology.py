@@ -526,6 +526,16 @@ POOL_L, POOL_P, POOL_PAGE, POOL_PPS = 2, 209, 128, 13
 POOL_HKV, POOL_D = 8, 128                   # Mistral-7B: 8 KV heads of 128
 
 
+def _kernel_grids(jaxpr, found):
+    """The grid of each Pallas kernel of a traced program, by its ``name=``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_grids(sub, found)
+    return found
+
+
 @pytest.fixture(scope="module")
 def paged_programs(topo):
     """The engine's two programs at Mistral-7B widths, 2 layers, at the
@@ -562,20 +572,11 @@ def paged_programs(topo):
             donate_argnums=(4,)).trace(params, i32(C), i32(), i32(), pool,
                                        i32(POOL_PPS))}
 
-    def kernel_grids(jaxpr, found):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found[eqn.params["name"]] = tuple(
-                    eqn.params["grid_mapping"].grid)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                kernel_grids(sub, found)
-        return found
-
     out = {}
     for name, tr in traced.items():
         exe = tr.lower().compile()
         out[name] = (exe.as_text(), exe.memory_analysis(),
-                     kernel_grids(tr.jaxpr.jaxpr, {}))
+                     _kernel_grids(tr.jaxpr.jaxpr, {}))
     return out
 
 
@@ -658,7 +659,9 @@ def latent_programs(topo):
     """The engine's two programs for ``models.mla`` at Kimi-K2 widths (3
     layers: the dense one and two sparse ones holding 12 of 384 experts), at
     the benchmark cell's batch sizes (32 slots, K = 4, chunk 512, 70 pages a
-    sequence), lowered for one described v5e, pool donated."""
+    sequence), lowered for one described v5e, pool donated. Name -> (optimised
+    HLO text, memory analysis, configuration, the grid of each Pallas kernel
+    by its ``name=``)."""
     import dataclasses
     from jax.sharding import SingleDeviceSharding
     from triton_dist_tpu.models import mla
@@ -675,21 +678,22 @@ def latent_programs(topo):
                                jax.random.PRNGKey(0)))
     pool = on(jax.eval_shape(lambda: fam.init_pool(cfg, LAT_P, 128)))
     B, K, C, PPS = 32, 4, 512, 70
-    lowered = {
+    traced = {
         "decode": jax.jit(
             lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
                 p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
-            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
+            donate_argnums=(3,)).trace(params, i32(B), i32(B), pool,
                                        i32(B, PPS), i32(B)),
         "chunk": jax.jit(
             lambda p, t, s, n, pages, bt: prefill_chunk_paged(
                 p, t, s, n, cfg, pages, bt),
-            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
+            donate_argnums=(4,)).trace(params, i32(C), i32(), i32(), pool,
                                        i32(PPS))}
     out = {}
-    for name, low in lowered.items():
-        exe = low.compile()
-        out[name] = (exe.as_text(), exe.memory_analysis(), cfg)
+    for name, tr in traced.items():
+        exe = tr.lower().compile()
+        out[name] = (exe.as_text(), exe.memory_analysis(), cfg,
+                     _kernel_grids(tr.jaxpr.jaxpr, {}))
     return out
 
 
@@ -702,7 +706,7 @@ def test_latent_pool_and_expert_tables_stay_in_place(latent_programs,
     stack's) comes out of a ``copy`` or a slice: the pool is carried and the
     tables are read in place through the flattened [layers x held] view."""
     import re
-    text, mem, cfg = latent_programs[program]
+    text, mem, cfg, _ = latent_programs[program]
     for kernel in ("mla_decode_paged", "grouped_gemm_gated", "grouped_gemm"):
         assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), kernel
     W, D, F = cfg.cache_width, cfg.d_model, cfg.moe_d_ff
@@ -721,3 +725,17 @@ def test_latent_pool_and_expert_tables_stay_in_place(latent_programs,
     pool_bytes = cfg.n_layers * LAT_P * 128 * W * 2
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
     assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+
+
+def test_latent_decode_rows_loop_chunk_rows_keep_the_grid(latent_programs):
+    """The decode program's latent kernel is the in-kernel loop over the
+    batch's live pages (ISSUE 31): its grid is the row blocks alone, 2 x 16
+    of the 32 slots, where the (row, page step) grid made 32 x 10 steps a
+    layer call; Mosaic takes its hand-made page DMAs out of the stacked pool
+    (left in HBM: the guard above finds no pool-shaped copy) and its ring of
+    three [7 x 128, 640] operands inside the default 16 MB of scoped VMEM
+    (8.9 MB by the compiler's own count). The chunk program's rows share a
+    table and keep the grid: 32 blocks of 16 rows x 10 steps of 7 pages."""
+    grids = {name: prog[3]["mla_decode_paged"]
+             for name, prog in latent_programs.items()}
+    assert grids == {"decode": (2,), "chunk": (32, 10)}, grids

@@ -34,6 +34,7 @@ from benchmark.references import latent_moe_lm as ref
 from triton_dist_tpu.models import mla
 from triton_dist_tpu.models.llama import (decode_step_paged,
                                           prefill_chunk_paged)
+from triton_dist_tpu.ops import mla_decode as mla_kernel
 from triton_dist_tpu.ops.mla_decode import mla_decode_paged
 from triton_dist_tpu.serving import ServingEngine
 
@@ -147,33 +148,120 @@ def attend_ref(q, pool, layer, bt, kv_len, latent, scale):
     return out
 
 
-@pytest.mark.parametrize("rows_per_block,pages_per_step", [
-    (1, 1), (1, 3), (4, 1), (4, 2)])
-def test_kernel_matches_numpy_ragged_and_inactive(rows_per_block,
-                                                  pages_per_step):
-    R, H, W, latent, L, P = 8, 2, 256, 128, 2, 11
+G = mla_kernel.DECODE_PAGES_PER_GROUP
+PPS_D = 2 * G + 3          # the decode cases' table: two groups and a short one
+IDLE = [0] * 8
+
+
+def _decode(kv_len, **kw):
+    return dict(kv_len=kv_len, pps=PPS_D, **kw)
+
+
+KERNEL_CASES = {
+    # the (row block, page step) grid: one row a block, then a chunk's rows
+    "grid-1-1": dict(rows_per_block=1, pages_per_step=1),
+    "grid-1-3": dict(rows_per_block=1, pages_per_step=3),
+    "chunk-4-1": dict(rows_per_block=4, pages_per_step=1),
+    "chunk-4-2": dict(rows_per_block=4, pages_per_step=2),
+    # the decode rows' loop over live pages, G pages an update
+    "loop-ragged": dict(),
+    "loop-short-last-group": _decode(
+        [(G + 3) * PAGE, (2 * G + 1) * PAGE - 5, 2 * PAGE, 0,
+         (G + 1) * PAGE - 1, 0, 3 * PAGE + 2, (G - 1) * PAGE]),
+    "loop-one-token": _decode([1, 0, 1, 1, 0, 0, 1, 0]),
+    "loop-exactly-a-group": _decode([G * PAGE] * 3 + [0, 2 * G * PAGE, 0,
+                                                      G * PAGE, G * PAGE - 1]),
+    "loop-every-page": _decode([PPS_D * PAGE] * 2 + [0] * 5 + [PPS_D * PAGE]),
+    "loop-page-boundary": _decode(
+        [PAGE, PAGE + 1, G * PAGE, G * PAGE + 1, 2 * G * PAGE,
+         2 * G * PAGE + 1, (G + 2) * PAGE, (G + 2) * PAGE + 1]),
+    "loop-idle-first-last-between": _decode(
+        [0, 0, 5 * PAGE - 2, 0, (G + 2) * PAGE, 9, 0, 0]),
+    "loop-all-idle": _decode(IDLE),
+    "loop-layer-0": _decode([40, 0, (G + 1) * PAGE + 3, 7, 0, 200, 16, 1],
+                            layer=0),
+    "loop-layer-1": _decode([40, 0, (G + 1) * PAGE + 3, 7, 0, 200, 16, 1],
+                            layer=1),
+    "loop-garbage-table": _decode(
+        [3 * PAGE, 1, 0, G * PAGE, (G + 1) * PAGE, 0, 2 * G * PAGE + 1, 30],
+        garbage=True),
+    "loop-unread-inf-nan": _decode(
+        [3 * PAGE - 4, 1, 0, G * PAGE, (G + 1) * PAGE + 1, 0,
+         2 * G * PAGE + 1, 30], garbage=True, poison=True),
+    "loop-published-widths": _decode([(G + 1) * PAGE + 5, 0], dims=(64, 640, 512)),
+    # two blocks of DECODE_ROWS_PER_BLOCK rows: the second starts on an idle
+    # row, and its short groups find the ring as the first block left it
+    "loop-two-row-blocks": _decode(
+        [(G + 2) * PAGE - 3, 0, 5, 2 * G * PAGE] * 4
+        + [0, 0, 3 * PAGE + 1, 0, PPS_D * PAGE, 1, 0, (G + 1) * PAGE] * 2),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_kernel_matches_numpy_ragged_and_inactive(case):
+    c = KERNEL_CASES[case]
+    rows_per_block = c.get("rows_per_block", 1)
+    pps, layer = c.get("pps", PPS), c.get("layer", 1)
+    H, W, latent = c.get("dims", (2, 256, 128))
+    kv_len = np.asarray(c.get("kv_len", [1, 16, 17, 0, 64, 33, 0, 48]),
+                        np.int32)
+    R, L = len(kv_len), 2
+    P = 1 + R * pps
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (R, H, W))
-    pool = jax.random.normal(ks[1], (L, P, PAGE, W))
+    pool = np.array(jax.random.normal(ks[1], (L, P, PAGE, W)))
+    n_live = -(-kv_len // PAGE)
     if rows_per_block == 1:            # decode: a table a row, any lengths
-        bt = np.array(jax.random.randint(ks[2], (R, PPS), 1, P))
-        kv_len = np.asarray([1, 16, 17, 0, 64, 33, 0, 48], np.int32)
-        bt[5, 3] = 10_000              # past a row's live pages: never read
-        bt[1, 1:] = -7
+        bt = 1 + np.array(jax.random.permutation(ks[2], R * pps)
+                          ).reshape(R, pps)
+        if "kv_len" not in c:          # (the seed's garbage, kept)
+            bt[5, 3] = 10_000          # past a row's live pages: never read
+            bt[1, 1:] = -7
     else:                              # a chunk: one table, staggered lengths
-        bt = np.broadcast_to(np.asarray([4, 9, 2, 6]), (R, PPS)).copy()
+        bt = np.broadcast_to(np.asarray([4, 9, 2, 6]), (R, pps)).copy()
         kv_len = np.asarray([30, 31, 32, 33, 34, 0, 0, 0], np.int32)
-    got = mla_decode_paged(q, pool, jnp.asarray(bt, jnp.int32),
-                           jnp.asarray(kv_len), layer=1, latent_dim=latent,
-                           sm_scale=0.1, rows_per_block=rows_per_block,
-                           pages_per_step=pages_per_step)
-    want = attend_ref(q, pool, 1, np.clip(bt, 0, P - 1), kv_len, latent, 0.1)
+    if c.get("poison"):                # whatever no row reads: inf and NaN
+        unread = np.ones((L, P), bool)
+        for r in range(R):
+            unread[layer, bt[r, :n_live[r]]] = False
+        pool[unread] = np.where(np.arange(W) % 2, np.inf, np.nan)
+    if c.get("garbage"):               # past the live pages: never read
+        junk = np.asarray([10_000, -7, P, 2**31 - 1, -2**31, P + 3])
+        for r in range(R):
+            bt[r, n_live[r]:] = np.resize(junk, pps - n_live[r])
+    got = mla_decode_paged(q, jnp.asarray(pool), jnp.asarray(bt, jnp.int32),
+                           jnp.asarray(kv_len), layer=layer,
+                           latent_dim=latent, sm_scale=0.1,
+                           rows_per_block=rows_per_block,
+                           pages_per_step=c.get("pages_per_step"))
+    want = attend_ref(q, pool, layer, np.clip(bt, 0, P - 1), kv_len, latent,
+                      0.1)
     live = kv_len > 0
     np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=2e-5,
                                rtol=1e-4)
     assert np.isfinite(np.asarray(got)).all()     # dead rows: finite, unread
     if rows_per_block == 1:
         assert not np.asarray(got)[~live].any()   # and zero when alone
+
+
+def test_the_loop_at_one_page_a_group_is_the_grid_walk_to_the_bit(
+        monkeypatch):
+    """The loop alone changes no arithmetic: with a group of ONE page it makes
+    the (row, page) grid's updates in the grid's order. What a larger group
+    changes is the order of the float32 sums, held by the tolerance above."""
+    R, H, W, latent, L, P, pps = 8, 2, 256, 128, 2, 41, 5
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (R, H, W))
+    pool = jax.random.normal(ks[1], (L, P, PAGE, W))
+    bt = 1 + jax.random.permutation(ks[2], R * pps).reshape(R, pps)
+    kv_len = jnp.asarray([1, 16, 17, 0, 80, 33, 0, 48], jnp.int32)
+    walk = lambda **kw: np.asarray(mla_decode_paged(          # noqa: E731
+        q, pool, bt.astype(jnp.int32), kv_len, layer=1, latent_dim=latent,
+        sm_scale=0.1, **kw))
+    grid = walk(pages_per_step=1)
+    assert not np.array_equal(walk(), grid), "a group's sums are reordered"
+    monkeypatch.setattr(mla_kernel, "DECODE_PAGES_PER_GROUP", 1)
+    assert np.array_equal(walk(), grid)
 
 
 # -- literal transcriptions -------------------------------------------------------

@@ -13,7 +13,9 @@ weights, float32, interpret-mode kernels:
   at and just past the window, a bound inside a page, idle rows;
 - (d) with ``window=None`` both kernels and ``jit_step`` / ``jit_chunk`` of the
   dense and the latent tiny presets give the PARENT's result (pins taken on
-  commit 6bf7760 by ``tests/fixtures/parent_pins.py``);
+  commit 6bf7760 by ``tests/fixtures/parent_pins.py``; the latent decode
+  rows' loop, ISSUE 31, at one page an update: a larger group reorders the
+  float32 sums and nothing else);
 - (e) a window layer never holds more than its ring, a ring that another
   sequence filled is never attended, and through ``ServingEngine`` a sequence
   preempted mid-prefill or mid-decode replays its tokens; what the family
@@ -36,6 +38,7 @@ from benchmark.references import window_moe_lm as ref
 from triton_dist_tpu.models import window_moe as wm
 from triton_dist_tpu.models.llama import (decode_step_paged,
                                           prefill_chunk_paged)
+from triton_dist_tpu.ops import mla_decode
 from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
                                               gqa_prefill_paged)
 from triton_dist_tpu.serving import ServingEngine
@@ -337,7 +340,11 @@ def recomputed():
 
     def get(case):
         if case not in cache:
-            cache[case] = parent_pins.CASES[case]()
+            # the pinned tree made one online-softmax update a page: so does
+            # the latent decode loop with a group of one page
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(mla_decode, "DECODE_PAGES_PER_GROUP", 1)
+                cache[case] = parent_pins.CASES[case]()
         return cache[case]
     return get
 

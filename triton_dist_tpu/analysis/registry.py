@@ -314,9 +314,12 @@ def _fd_paged_rows_write():
 
 def _fd_mla_decode_paged():
     from ..ops import mla_decode_paged
-    mla_decode_paged(jnp.zeros((1, 2, 256), f32),
-                     jnp.zeros((1, 8, 8, 256), f32), jnp.zeros((1, 4), i32),
-                     jnp.array([20], i32), layer=0, latent_dim=128,
+    # the decode rows' loop, two live rows around two idle ones over a table
+    # longer than a group: every page DMA it starts (a short group's 3 pages;
+    # a full group then a short one, 9) is waited, and an idle row starts none
+    mla_decode_paged(jnp.zeros((4, 2, 256), f32),
+                     jnp.zeros((1, 12, 8, 256), f32), jnp.zeros((4, 10), i32),
+                     jnp.array([20, 0, 0, 72], i32), layer=0, latent_dim=128,
                      sm_scale=0.1)
 
 
