@@ -739,3 +739,95 @@ def test_latent_decode_rows_loop_chunk_rows_keep_the_grid(latent_programs):
     grids = {name: prog[3]["mla_decode_paged"]
              for name, prog in latent_programs.items()}
     assert grids == {"decode": (2,), "chunk": (32, 10)}, grids
+
+
+# -- the mixer-beside-attention family's programs at published widths (ISSUE 32)
+
+HYB_P, HYB_SLOTS = 1282, 64
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(topo):
+    """The engine's two programs for ``models.hybrid_ssm`` at Falcon-H1-34B
+    widths (the cell's 6 layers, every width and the whole vocabulary as
+    published), at the benchmark cell's sizes (64 slots, K = 4, chunk 512, 20 pages a
+    sequence and the slot's column), lowered for one described v5e, pool
+    donated. Name -> (optimised HLO text, memory analysis, configuration)."""
+    import dataclasses
+    from jax.sharding import SingleDeviceSharding
+    from triton_dist_tpu.models import hybrid_ssm as hm
+    from triton_dist_tpu.models.llama import (decode_multistep_paged,
+                                              prefill_chunk_paged)
+    cfg = hm.bind(dataclasses.replace(hm.HybridSSMConfig(), n_layers=6),
+                  HYB_SLOTS, 512)
+    fam = cfg.paged
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
+    params = on(jax.eval_shape(lambda k: hm.init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pool = on(jax.eval_shape(lambda: fam.init_pool(cfg, HYB_P, 128)))
+    B, K, C, W = HYB_SLOTS, 4, 512, 21
+    progs = {
+        "decode": jax.jit(
+            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
+                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
+            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
+                                       i32(B, W), i32(B)),
+        "chunk": jax.jit(
+            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
+                p, t, s, n, cfg, pages, bt),
+            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
+                                       i32(W))}
+    out = {}
+    for name, low in progs.items():
+        exe = low.compile()
+        out[name] = (exe.as_text(), exe.memory_analysis(), cfg)
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_hybrid_state_and_pages_stay_in_place(hybrid_programs, program):
+    """Mosaic takes ``ssm_decode_update`` (its in-kernel transposes, its
+    hand-made DMAs out of and back into the aliased state leaf) and the paged
+    GQA kernels at a query group of FIVE at the published widths; the trace
+    will find them by name; and nothing shaped like the state leaf, a layer
+    of it, or the K/V pool comes out of a ``copy`` or a slice: the leaves are
+    carried, written and read where they lie (one slot's state, [1, 1, 32,
+    256, 128], is what a chunk reads and writes; the conv leaf, 12 MB, is
+    small enough that XLA prefetches thirds of it into VMEM: not held here)."""
+    import re
+    text, mem, cfg = hybrid_programs[program]
+    kernels = {"decode": ("ssm_decode_update", "gqa_decode_paged"),
+               "chunk": ("gqa_prefill_paged",)}[program]
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), kernel
+    assert (program == "chunk") == (
+        re.search(r"%ssm_decode_update[.\d]* = ", text) is None)
+    S = HYB_SLOTS + 1
+    state = f"{S},{cfg.ssm_heads},{cfg.ssm_state},{cfg.ssm_head_dim}]"
+    kv = f"{HYB_P},{cfg.n_kv_heads},128,{cfg.head_dim}]"
+    big = [f"[{cfg.n_layers},{s}" for s in (state, kv)] \
+        + [f"[1,{state}", f"[{state}", f"[1,{kv}", f"[{kv}"]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        kind = name if opcode == "fusion" else opcode
+        # (a dynamic-update-slice of a carried leaf is written in place and
+        # has the leaf's shape by definition: the chunk's one-slot write;
+        # a copy of the state would show in the temporaries below)
+        if any(s in result for s in big) and re.search(
+                r"copy|slice", kind) and not re.search(r"update.slice", kind):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    pool_bytes = cfg.n_layers * (
+        S * (cfg.ssm_heads * cfg.ssm_state * cfg.ssm_head_dim * 4
+             + 3 * cfg.d_xbc * 2)
+        + 2 * HYB_P * cfg.n_kv_heads * 128 * cfg.head_dim * 2)
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+    # the state leaf is 1.64 GB: no copy of it fits under this
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes

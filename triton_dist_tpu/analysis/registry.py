@@ -323,6 +323,18 @@ def _fd_mla_decode_paged():
                      sm_scale=0.1)
 
 
+def _fd_ssm_decode_update():
+    from ..ops import ssm_decode_update
+    # two live rows around an idle one, two head blocks a row: every block
+    # fetched out of the aliased state leaf is waited and written back
+    # (4 fetches, 4 stores), and the idle row starts none
+    ssm_decode_update(jnp.zeros((2, 4, 4, 8, 128), f32), 1,
+                      jnp.array([1, 2, 3], i32),
+                      jnp.array([True, False, True]),
+                      jnp.zeros((3, 4, 128), f32), jnp.zeros((3, 4), f32),
+                      jnp.zeros((3, 2, 8), f32), jnp.zeros((3, 2, 8), f32))
+
+
 def _fd_decode_combine():
     from ..ops import decode_combine
     decode_combine(jnp.zeros((2, 1, 4, 128), f32),
@@ -525,6 +537,11 @@ _ENTRIES = [
                   meshes=MESH_LOCAL),
     RegistryEntry("decode_combine", _local(_fd_decode_combine),
                   meshes=MESH_LOCAL),
+    # the recurrent state beside the pages (ISSUE 32)
+    RegistryEntry("ssm_decode_update", _local(_fd_ssm_decode_update),
+                  meshes=MESH_LOCAL),
+    RegistryEntry("ssd_chunk_scan",
+                  skip="plain jnp scan in blocks; " + _SKIP_PURE),
     RegistryEntry("ll_ag_merge", _run_ll_ag_merge),
     RegistryEntry("sp_gqa_flash_decode", _run_sp_gqa_flash_decode),
     RegistryEntry("sp_paged_attend_write", _run_sp_paged_attend_write),

@@ -482,7 +482,22 @@ class PagedFamily:
       tables are then ``pages_per_seq + 1`` wide), and ``bind(cfg, num_slots,
       prefill_chunk)`` returns the config sized for an engine's slots and
       chunk (the ring spans the window and a chunk; ``init_pool`` makes
-      ``num_slots`` rings)."""
+      ``num_slots`` rings).
+    - ``slot_state(cfg)``: bytes of STATE THAT IS NOT PAGES every engine slot
+      owns over all layers (a recurrent layer's fixed block a sequence,
+      rewritten every token), in pool leaves of ``1 + num_slots`` rows a
+      layer; None: the family has none. Slot s owns row ``1 + s`` (row 0 is
+      scratch), which rides one more column of its block-table row exactly
+      as a ring's first page does; ``bind`` sizes the leaves. ``attention``
+      is then the layer's whole reader of the normed rows (say a mixer
+      beside an attention): it takes the rows' slot off that column, starts
+      a chunk whose first row sits at position 0 from a zero state, leaves
+      every row that is not live untouched to the bit, and returns the
+      rewritten leaves with the pool. A state cannot be rewound, shared by
+      reference or copied by page: the engine restarts a preempted request
+      and refuses page copy, export and import by name.
+    - ``embed(cfg, params, tokens) -> x``: the rows the layer loop starts
+      from; None: ``params["embed"][tokens]``."""
     name: str
     init_pool: Any
     segments: Any
@@ -496,6 +511,8 @@ class PagedFamily:
     lacks: tuple = ()
     slot_ring: Any = None
     bind: Any = None
+    slot_state: Any = None
+    embed: Any = None
 
     # ``benchmark/tools/fit_paged.py`` reads the two shared programs off the
     # record; they are the module's functions, whatever the family.
@@ -508,6 +525,12 @@ class PagedFamily:
         if self.head is not None:
             return self.head(cfg, params, x, lin)
         return lin(x, params["lm_head"], "lm_head").astype(jnp.float32)
+
+    def embedded(self, cfg, params, tokens) -> jax.Array:
+        """The rows ``tokens`` enter the layer loop as."""
+        if self.embed is not None:
+            return self.embed(cfg, params, tokens)
+        return params["embed"][tokens].astype(cfg.dtype)
 
 
 def require_config(cfg, kind: type, who: str) -> None:
@@ -708,7 +731,7 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     this step (a tuple of int32 scalars, ``cfg.paged.counters`` names
     them; rows masked off by ``active`` are not counted)."""
     lin = linear or (lambda h, w, name: h @ w)
-    x = params["embed"][token].astype(cfg.dtype)          # [B, D]
+    x = cfg.paged.embedded(cfg, params, token)            # [B, D]
     kv_len = (pos + 1).astype(jnp.int32)
     x, pages, counts = _paged_layers(params, x, pos, kv_len, active, cfg,
                                      pages, block_table, ffn, attn_io,
@@ -782,7 +805,7 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     pos = jnp.where(valid, idx, 0).astype(jnp.int32)
     kv_len = jnp.where(valid, idx + 1, 0).astype(jnp.int32)
     bt = jnp.broadcast_to(block_table[None, :], (C, block_table.shape[0]))
-    x = params["embed"][tokens].astype(cfg.dtype)                    # [C, D]
+    x = cfg.paged.embedded(cfg, params, tokens)                      # [C, D]
     x, pages, _ = _paged_layers(params, x, pos, kv_len, valid, cfg, pages,
                                 bt, ffn, attn_io, linear, shared_table=True)
     # one-row head: the prompt's last token sits at chunk row

@@ -38,6 +38,7 @@ from triton_dist_tpu.ops.flash_decode import (  # noqa: F401
     decode_combine, ll_ag_merge, sp_gqa_flash_decode, sp_paged_attend_write,
     pool_ag_start_local, flash_decode_dist)
 from triton_dist_tpu.ops.mla_decode import mla_decode_paged  # noqa: F401
+from triton_dist_tpu.ops.ssm import ssm_decode_update, ssd_chunk_scan  # noqa: F401
 from triton_dist_tpu.ops.group_gemm import (  # noqa: F401
     PackedGatedWeights, align_tokens_by_expert, used_block_count,
     emit_grouped_gemm, grouped_gemm, pack_gated_weights, grouped_gemm_gated,

@@ -214,6 +214,17 @@ class ServingEngine:
         if self._ring:
             self.metrics.hist.setdefault("kv_pages_full", Histogram())
             self.metrics.hist.setdefault("kv_pages_window", Histogram())
+        # bytes of state that is not pages each slot owns (0: none): a
+        # recurrent layer's fixed block a sequence (PagedFamily.slot_state).
+        # Like a ring it is the slot's: slot s owns row 1 + s of the
+        # family's state leaves, which rides the same extra table column.
+        self._state_bytes = int(fam.slot_state(cfg)) if fam.slot_state \
+            else 0
+        assert not (self._ring and self._state_bytes), (
+            "one extra table column: a ring or a state, not both")
+        if self._state_bytes:
+            self.metrics.hist.setdefault("state_bytes", Histogram())
+        self._slot_owned = bool(self._ring or self._state_bytes)
         self.decode_horizon = decode_horizon
         self.eos_id = eos_id
         self._stall_steps = stall_deadline_steps
@@ -299,7 +310,7 @@ class ServingEngine:
         # the device copies below are authoritative between dispatches
         self._token = np.zeros(num_slots, np.int32)
         self._pos = np.zeros(num_slots, np.int32)
-        self._bt = np.zeros((num_slots, pages_per_seq + bool(self._ring)),
+        self._bt = np.zeros((num_slots, pages_per_seq + self._slot_owned),
                             np.int32)
         # drafter history window [B, H] (newest token at column H-1) +
         # valid-suffix lengths. Device-carried between dispatches when
@@ -450,11 +461,12 @@ class ServingEngine:
     def _device_bt_row(self, rid, slot: int) -> np.ndarray:
         """The table row the programs get for ``rid`` in ``slot``: its
         ledger pages, then (a family with per-slot rings) the first page of
-        the slot's ring, ``1 + slot * ring`` (page 0 is scratch there too)."""
+        the slot's ring, ``1 + slot * ring`` (page 0 is scratch there too),
+        or (a family with per-slot state) the slot's state row, ``1 + slot``."""
         row = self._device_rows(
             self.alloc.block_table_row(rid, self.pages_per_seq))
-        if self._ring:
-            row = np.append(row, np.int32(1 + slot * self._ring))
+        if self._slot_owned:
+            row = np.append(row, np.int32(1 + slot * (self._ring or 1)))
         return row
 
     # -- request intake ---------------------------------------------------
@@ -592,8 +604,17 @@ class ServingEngine:
     # leaves. Eager array ops, NOT jitted programs, so the one-program-per-
     # path compile contract is untouched.
 
+    def _pages_alone(self, what: str) -> None:
+        """A sequence of a family with per-slot state is its pages AND its
+        slot's state: an operation that moves pages alone is refused."""
+        if self._state_bytes:
+            raise NotImplementedError(
+                f"the {self._family.name!r} model family keeps state that "
+                f"is not pages: {what} is not supported")
+
     def _copy_page(self, old: int, new: int) -> None:
         """Copy ledger page ``old`` onto ``new``, every layer."""
+        self._pages_alone("page copy")
         o, w = self.alloc.device_row(old), self.alloc.device_row(new)
         self.pool = jax.tree.map(lambda a: a.at[:, w].set(a[:, o]),
                                  self.pool)
@@ -601,12 +622,14 @@ class ServingEngine:
     def _export_pages(self, page_ids):
         """The bytes of ``page_ids`` (ledger ids): the pool's pytree with
         the page dim gathered, [layer, len(page_ids), ...] a leaf."""
+        self._pages_alone("page export")
         rows = self._device_rows(page_ids)
         return jax.tree.map(lambda a: a[:, rows], self.pool)
 
     def _import_pages(self, page_ids, payload) -> None:
         """Land ``payload`` (as ``_export_pages`` gives it) on
         ``page_ids``."""
+        self._pages_alone("page import")
         rows = self._device_rows(page_ids)
         self.pool = jax.tree.map(lambda a, b: a.at[:, rows].set(b),
                                  self.pool, payload)
@@ -846,10 +869,11 @@ class ServingEngine:
         hook = self._preempt_hook
         if hook is not None and hook(slot, req):
             return
-        # (a family with per-slot rings restarts instead: what the ring
-        # layers computed stays behind in the slot the victim leaves)
+        # (a family with per-slot rings or state restarts instead: what
+        # those layers computed stays behind in the slot the victim leaves,
+        # and a state cannot be rewound to a cursor)
         if (req.state is RequestState.PREFILLING and req.prefill_cursor > 0
-                and not self._ring):
+                and not self._slot_owned):
             filled = -(-req.prefill_cursor // self.page_size)
             if filled < len(self.alloc.pages_of(req.rid)):
                 # mid-prefill victim: keep the pages already holding
@@ -1117,6 +1141,11 @@ class ServingEngine:
             self.metrics.observe("kv_pages_full", sum(held))
             self.metrics.observe("kv_pages_window",
                                  sum(min(n, self._ring) for n in held))
+        if self._state_bytes:
+            # state held beside the pages: a seated sequence's, whatever
+            # its context
+            self.metrics.observe("state_bytes", self._state_bytes * sum(
+                r is not None for r in self.sched.slots))
 
         n_tokens = 0
         emitted_by_slot = {}
