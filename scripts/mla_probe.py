@@ -1,39 +1,46 @@
 """On-chip probe of the latent (MLA) paged attention kernel at Kimi-K2 widths
-(ISSUE 31): ``ops.mla_decode.mla_decode_paged`` alone, 64 heads of width 640
-(latent 512), pages of 128 rows, 32 decode rows over a 70-page table, an
-8-layer stacked pool of 2,241 pages, one kernel call a layer.
+(ISSUE 31, ISSUE 34): ``ops.mla_decode.mla_decode_paged`` alone, 64 heads of
+width 640 (latent 512), pages of 128 rows, 32 decode rows or a prefill
+chunk's 512 over a 70-page table, an 8-layer stacked pool of 2,241 pages, one
+kernel call a layer.
 
 Run on the real chip (one process per chip):
-  python scripts/mla_probe.py [--variants grid:7,loop:1:2,loop:7:2] [--reps 5]
+  python scripts/mla_probe.py [--variants loop:7:2,chunk:16:1,chunk:16:4]
+      [--context 0,1536,3584,8448] [--reps 5]
 
 One JSON line a (case, variant), also appended to
 ``chiprun_out/mla_probe.jsonl``: ``kernel_us`` is the kernel's own device time
 a layer call from a profiler trace, ``ms_layer`` the host's clock around
-``reps`` calls of 8 layers, ``us_page`` ``kernel_us`` over the live pages,
-``sha1`` of the summed result (equal where two walks agree bit for bit) and
-``gap`` its largest difference from the case's first variant.
+``reps`` calls of 8 layers, ``us_page`` ``kernel_us`` over the live pages (for
+a chunk: over the live (row block, page) pairs), ``sha1`` of the summed result
+(equal where two walks agree bit for bit) and ``gap`` its largest difference
+from the case's first variant.
 
 Cases (``--cases``) are live rows x pages of context each, the live rows
 spread evenly over the 32: ``0x0`` (nothing live), ``4x8``, ``18x27`` (the
 benchmark cell's mean), ``20x28`` and ``32x28`` (PR 26's two points), ``32x70``.
 
 Variants:
-  ``grid:N``       the (row, page step) grid at one row a block, N pages a
-                   step: the decode rows' walk before ISSUE 31 at N = 7
   ``loop:G:F[:R]`` the in-kernel loop over live pages, G pages an update, F
                    groups in flight, R rows a q / out block (the tree's own
                    constants where left out; ``loop`` alone = as shipped)
-  ``chunk:Rb:N``   a prefill chunk's 512 rows instead of the cases: one shared
-                   table, blocks of Rb rows, N pages a step, at ``--context``
-                   tokens before the chunk
-A tree without the loop (the parent of ISSUE 31) runs the ``grid`` and
-``chunk`` variants: the same command there is the comparison.
+  ``chunk:Rb:G[:F]`` a prefill chunk's 512 rows instead of the cases: one
+                   shared table, blocks of Rb rows, at each of ``--context``
+                   tokens cached before the chunk (8,448 ends the table):
+                   the loop over a block's live pages, G pages an update, F
+                   groups in flight (as shipped where left out). On a tree
+                   from before ISSUE 34 it is the (row block, page step) grid
+                   at G pages a step, whatever F: the same command there is
+                   the comparison
+A tree from before ISSUE 34 also takes ``grid:N``, the (row, page step) grid
+at one row a block, N pages a step: the decode rows' walk before ISSUE 31.
 """
 
 from __future__ import annotations
 
 import argparse
 import hashlib
+import inspect
 import json
 import os
 import sys
@@ -52,10 +59,19 @@ from triton_dist_tpu.ops import mla_decode as md  # noqa: E402
 L, P, H, W, LATENT, PAGE, PPS, ROWS, CHUNK = 8, 2241, 64, 640, 512, 128, 70, 32, 512
 SM_SCALE = 0.13086
 CASES = "0x0,4x8,18x27,20x28,32x28,32x70"
-VARIANTS = "grid:7,grid:1,loop:1:2,loop:2:2,loop:4:2,loop:7:2,loop:7:1"
+VARIANTS = "loop:1:2,loop:4:2,loop:7:2,chunk:16:1,chunk:16:4,chunk:16:6"
 LOOP = ("DECODE_PAGES_PER_GROUP", "DECODE_GROUPS_IN_FLIGHT",
         "DECODE_ROWS_PER_BLOCK")
-SHIPPED = tuple(getattr(md, name, None) for name in LOOP)
+CHUNK_LOOP = ("CHUNK_PAGES_PER_GROUP", "CHUNK_GROUPS_IN_FLIGHT")
+SHIPPED = {name: getattr(md, name, None) for name in LOOP + CHUNK_LOOP}
+# a tree from before ISSUE 34: the chunk's rows walk a grid
+GRID = "pages_per_step" in inspect.signature(md.mla_decode_paged).parameters
+
+
+def as_shipped(names, nums):
+    """Set the walk's constants: what the variant leaves out, as shipped."""
+    for name, n in zip(names, nums + [SHIPPED[m] for m in names[len(nums):]]):
+        setattr(md, name, n)
 
 
 def walk(variant: str, args):
@@ -65,12 +81,16 @@ def walk(variant: str, args):
     nums = [int(n) for n in nums]
     kw = {}
     if kind == "grid":
+        if not GRID:
+            raise SystemExit(f"{variant}: a tree from before ISSUE 34 has it")
         kw = {"rows_per_block": 1, "pages_per_step": nums[0]}
-    elif kind == "chunk":
+    elif kind == "chunk" and GRID:
         kw = {"rows_per_block": nums[0], "pages_per_step": nums[1]}
-    else:                      # what the variant leaves out: as shipped
-        for name, n in zip(LOOP, nums + list(SHIPPED[len(nums):])):
-            setattr(md, name, n)
+    elif kind == "chunk":
+        kw = {"rows_per_block": nums[0]}
+        as_shipped(CHUNK_LOOP, nums[1:])
+    else:
+        as_shipped(LOOP, nums)
 
     def run(q, pool, bt, kv):
         def body(acc, layer):
@@ -112,18 +132,18 @@ def decode_case(case: str):
     return bt, kv, live * pages + (ROWS - live) * parked
 
 
-def chunk_case(context: int):
+def chunk_case(context: int, rows_per_block: int):
     bt = np.random.default_rng(0).permutation(P - 1)[:PPS] + 1
     kv = context + np.arange(CHUNK) + 1
-    return (np.broadcast_to(bt, (CHUNK, PPS)), kv,
-            -(-(context + CHUNK) // PAGE))
+    live = -(-kv.reshape(-1, rows_per_block).max(axis=1) // PAGE)
+    return np.broadcast_to(bt, (CHUNK, PPS)), kv, int(live.sum())
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--variants", default=VARIANTS)
     ap.add_argument("--cases", default=CASES)
-    ap.add_argument("--context", type=int, default=3584,
+    ap.add_argument("--context", default="0,1536,3584,8448",
                     help="chunk variants: tokens cached before the chunk")
     ap.add_argument("--reps", type=int, default=5)
     a = ap.parse_args()
@@ -142,15 +162,18 @@ def main():
     lines = []
     for chunk in (False, True):
         names = [v for v in variants if v.startswith("chunk") == chunk]
-        cases = ([f"chunk@{a.context}"] if chunk else a.cases.split(","))
+        cases = ([f"chunk@{c}" for c in a.context.split(",")] if chunk
+                 else a.cases.split(","))
         fns = {}
         for case in cases if names else []:
-            bt, kv, live = chunk_case(a.context) if chunk else \
-                decode_case(case)
-            args = (q_chunk if chunk else q, pool,
-                    jnp.asarray(bt, jnp.int32), jnp.asarray(kv, jnp.int32))
             want = None
             for v in names:
+                bt, kv, live = chunk_case(
+                    int(case.split("@")[1]), int(v.split(":")[1])) \
+                    if chunk else decode_case(case)
+                args = (q_chunk if chunk else q, pool,
+                        jnp.asarray(bt, jnp.int32),
+                        jnp.asarray(kv, jnp.int32))
                 if v not in fns:
                     fns[v] = walk(v, args)
                 got, ms, kern = timed(fns[v], args, a.reps, trace_dir)

@@ -727,18 +727,26 @@ def test_latent_pool_and_expert_tables_stay_in_place(latent_programs,
     assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
 
 
-def test_latent_decode_rows_loop_chunk_rows_keep_the_grid(latent_programs):
-    """The decode program's latent kernel is the in-kernel loop over the
-    batch's live pages (ISSUE 31): its grid is the row blocks alone, 2 x 16
-    of the 32 slots, where the (row, page step) grid made 32 x 10 steps a
-    layer call; Mosaic takes its hand-made page DMAs out of the stacked pool
-    (left in HBM: the guard above finds no pool-shaped copy) and its ring of
-    three [7 x 128, 640] operands inside the default 16 MB of scoped VMEM
-    (8.9 MB by the compiler's own count). The chunk program's rows share a
-    table and keep the grid: 32 blocks of 16 rows x 10 steps of 7 pages."""
+def test_latent_walks_are_loops_over_live_pages(latent_programs):
+    """Both programs' latent kernel is the in-kernel loop over live pages
+    (ISSUE 31 the decode rows, ISSUE 34 a chunk's): its grid is the row blocks
+    alone, 2 x 16 of the 32 slots and 32 x 16 of the chunk's 512 rows, where
+    the (row block, page step) grid made 32 x 10 steps a layer call of either;
+    Mosaic takes its hand-made page DMAs out of the stacked pool (left in
+    HBM: the guard above finds no pool-shaped copy), the decode rows' ring of
+    three [7 x 128, 640] operands (8.9 MB of scoped VMEM by the compiler's
+    own count) and a row block's [1024, 640] queries, [1024, 4 x 128] float32
+    scores and ring of two [4 x 128, 640] operands (12.1 MB by that count)
+    inside the default 16 MB: neither call asks for a limit of its own, or the
+    fixture's compile would have refused it."""
+    import re
     grids = {name: prog[3]["mla_decode_paged"]
              for name, prog in latent_programs.items()}
-    assert grids == {"decode": (2,), "chunk": (32, 10)}, grids
+    assert grids == {"decode": (2,), "chunk": (32,)}, grids
+    for name, prog in latent_programs.items():
+        asked = re.findall(r"%mla_decode_paged[.\d]* = [^\n]*custom-call[^\n]*"
+                           r"\"scoped_memory_configs\":\[([^\]]*)\]", prog[0])
+        assert asked and not any(asked), (name, asked)
 
 
 # -- the mixer-beside-attention family's programs at published widths (ISSUE 32)

@@ -6,8 +6,9 @@ of the program) at a small size on the CPU, seeded weights, float32:
 - the family's forward and its chunk-then-decode through the latent cache
   against the reference's full forward, on logits;
 - the absorbed and the plain attention forms agree on one cache;
-- the latent kernel (interpret mode) against ``jax.numpy``, ragged lengths,
-  inactive rows, row blocks that share a table, several pages a step;
+- the latent kernel (interpret mode) against ``jax.numpy``: decode rows of
+  ragged lengths and idle rows, a prefill chunk's row blocks that share a
+  table, groups of pages an update, short last groups;
 - the router with a non-zero bias and YaRN's frequencies against literal
   transcriptions of the published code;
 - THE SHARES ADD UP: 16 experts over 4 shares, the routed parts of all shares
@@ -149,7 +150,8 @@ def attend_ref(q, pool, layer, bt, kv_len, latent, scale):
 
 
 G = mla_kernel.DECODE_PAGES_PER_GROUP
-PPS_D = 2 * G + 3          # the decode cases' table: two groups and a short one
+GC = mla_kernel.CHUNK_PAGES_PER_GROUP
+PPS_D = 2 * max(G, GC) + 3  # the table: two groups and a short one
 IDLE = [0] * 8
 
 
@@ -157,13 +159,39 @@ def _decode(kv_len, **kw):
     return dict(kv_len=kv_len, pps=PPS_D, **kw)
 
 
+def _chunk(start, rows=16, valid=None, rows_per_block=4, **kw):
+    """A prefill chunk's ``rows`` rows, ``start`` tokens cached before them:
+    one table, row t attends ``start + t + 1`` keys, rows from ``valid`` on
+    are padding (``kv_len`` 0)."""
+    valid = rows if valid is None else valid
+    kv_len = np.where(np.arange(rows) < valid, start + np.arange(rows) + 1, 0)
+    return dict(kv_len=kv_len, pps=PPS_D, rows_per_block=rows_per_block, **kw)
+
+
 KERNEL_CASES = {
-    # the (row block, page step) grid: one row a block, then a chunk's rows
-    "grid-1-1": dict(rows_per_block=1, pages_per_step=1),
-    "grid-1-3": dict(rows_per_block=1, pages_per_step=3),
-    "chunk-4-1": dict(rows_per_block=4, pages_per_step=1),
-    "chunk-4-2": dict(rows_per_block=4, pages_per_step=2),
-    # the decode rows' loop over live pages, G pages an update
+    # a chunk's rows: row blocks that share the table, GC pages an update
+    "chunk-2-rows-a-block": _chunk(2 * PAGE - 3, rows_per_block=2),
+    "chunk-4-rows-a-block": _chunk(2 * PAGE - 3),
+    "chunk-8-rows-a-block": _chunk(2 * PAGE - 3, rows_per_block=8),
+    "chunk-one-block": _chunk(2 * PAGE - 3, rows_per_block=16),
+    "chunk-first": _chunk(0),
+    "chunk-first-of-two-pages": _chunk(0, rows=32),
+    "chunk-starts-mid-page": _chunk(3 * PAGE + 5),
+    "chunk-block-straddles-a-page": _chunk(PAGE - 2),
+    "chunk-padding-tail-and-padding-block": _chunk(PAGE + 3, valid=10),
+    "chunk-all-padding": _chunk(5 * PAGE, valid=0),
+    "chunk-short-last-group": _chunk((GC + 2) * PAGE),
+    "chunk-exactly-a-group": _chunk(GC * PAGE - 16),
+    "chunk-two-groups-and-a-page": _chunk(2 * GC * PAGE - 9),
+    "chunk-every-page": _chunk(PPS_D * PAGE - 16),
+    "chunk-layer-0": _chunk((GC + 1) * PAGE + 3, valid=13, layer=0),
+    "chunk-layer-1": _chunk((GC + 1) * PAGE + 3, valid=13, layer=1),
+    "chunk-garbage-table": _chunk(GC * PAGE + 7, valid=14, garbage=True),
+    "chunk-unread-inf-nan": _chunk(GC * PAGE + 7, valid=14, garbage=True,
+                                   poison=True),
+    "chunk-published-widths": _chunk((GC + 1) * PAGE + 5, rows=32, valid=27,
+                                     rows_per_block=16, dims=(64, 640, 512)),
+    # the decode rows: a table a row, G pages an update
     "loop-ragged": dict(),
     "loop-short-last-group": _decode(
         [(G + 3) * PAGE, (2 * G + 1) * PAGE - 5, 2 * PAGE, 0,
@@ -200,7 +228,7 @@ KERNEL_CASES = {
 @pytest.mark.parametrize("case", KERNEL_CASES)
 def test_kernel_matches_numpy_ragged_and_inactive(case):
     c = KERNEL_CASES[case]
-    rows_per_block = c.get("rows_per_block", 1)
+    Rb = c.get("rows_per_block", 1)
     pps, layer = c.get("pps", PPS), c.get("layer", 1)
     H, W, latent = c.get("dims", (2, 256, 128))
     kv_len = np.asarray(c.get("kv_len", [1, 16, 17, 0, 64, 33, 0, 48]),
@@ -210,17 +238,16 @@ def test_kernel_matches_numpy_ragged_and_inactive(case):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (R, H, W))
     pool = np.array(jax.random.normal(ks[1], (L, P, PAGE, W)))
-    n_live = -(-kv_len // PAGE)
-    if rows_per_block == 1:            # decode: a table a row, any lengths
-        bt = 1 + np.array(jax.random.permutation(ks[2], R * pps)
-                          ).reshape(R, pps)
-        if "kv_len" not in c:          # (the seed's garbage, kept)
-            bt[5, 3] = 10_000          # past a row's live pages: never read
-            bt[1, 1:] = -7
-    else:                              # a chunk: one table, staggered lengths
-        bt = np.broadcast_to(np.asarray([4, 9, 2, 6]), (R, pps)).copy()
-        kv_len = np.asarray([30, 31, 32, 33, 34, 0, 0, 0], np.int32)
-    if c.get("poison"):                # whatever no row reads: inf and NaN
+    bt = 1 + np.array(jax.random.permutation(ks[2], R * pps)).reshape(R, pps)
+    if "kv_len" not in c:              # (the seed's garbage, kept)
+        bt[5, 3] = 10_000              # past a row's live pages: never read
+        bt[1, 1:] = -7
+    if Rb > 1:                         # a chunk: every row the first's table
+        bt[:] = bt[0]
+    # what a row's walk may fetch: its own pages, or its row block's
+    reach = kv_len.reshape(-1, Rb).max(axis=1).repeat(Rb)
+    n_live = -(-reach // PAGE)
+    if c.get("poison"):                # whatever no walk reads: inf and NaN
         unread = np.ones((L, P), bool)
         for r in range(R):
             unread[layer, bt[r, :n_live[r]]] = False
@@ -232,36 +259,44 @@ def test_kernel_matches_numpy_ragged_and_inactive(case):
     got = mla_decode_paged(q, jnp.asarray(pool), jnp.asarray(bt, jnp.int32),
                            jnp.asarray(kv_len), layer=layer,
                            latent_dim=latent, sm_scale=0.1,
-                           rows_per_block=rows_per_block,
-                           pages_per_step=c.get("pages_per_step"))
+                           rows_per_block=Rb)
     want = attend_ref(q, pool, layer, np.clip(bt, 0, P - 1), kv_len, latent,
                       0.1)
     live = kv_len > 0
     np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=2e-5,
                                rtol=1e-4)
-    assert np.isfinite(np.asarray(got)).all()     # dead rows: finite, unread
-    if rows_per_block == 1:
-        assert not np.asarray(got)[~live].any()   # and zero when alone
+    # a dead row: finite and unread; zero when its whole walk is dead
+    assert np.isfinite(np.asarray(got)).all()
+    assert not np.asarray(got)[reach == 0].any()
 
 
-def test_the_loop_at_one_page_a_group_is_the_grid_walk_to_the_bit(
+def test_the_chunk_walk_at_one_page_a_group_is_the_decode_loop_to_the_bit(
         monkeypatch):
-    """The loop alone changes no arithmetic: with a group of ONE page it makes
-    the (row, page) grid's updates in the grid's order. What a larger group
-    changes is the order of the float32 sums, held by the tolerance above."""
+    """Sharing the walk changes no arithmetic: rows that share a table, four
+    a block and ONE page a group, get what the decode loop gives them at one
+    page a group as rows with (equal) tables of their own, which PR 31 held
+    to the (row, page) grid's updates in the grid's order. What a larger
+    group changes, in either walk, is the order of the float32 sums inside
+    it, held by the tolerance above."""
     R, H, W, latent, L, P, pps = 8, 2, 256, 128, 2, 41, 5
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (R, H, W))
     pool = jax.random.normal(ks[1], (L, P, PAGE, W))
-    bt = 1 + jax.random.permutation(ks[2], R * pps).reshape(R, pps)
-    kv_len = jnp.asarray([1, 16, 17, 0, 80, 33, 0, 48], jnp.int32)
+    bt = jnp.broadcast_to(1 + jax.random.permutation(ks[2], P - 1)[:pps],
+                          (R, pps))
+    kv_len = jnp.asarray([38, 39, 40, 41, 42, 43, 0, 0], jnp.int32)
     walk = lambda **kw: np.asarray(mla_decode_paged(          # noqa: E731
         q, pool, bt.astype(jnp.int32), kv_len, layer=1, latent_dim=latent,
         sm_scale=0.1, **kw))
-    grid = walk(pages_per_step=1)
-    assert not np.array_equal(walk(), grid), "a group's sums are reordered"
+    grouped = {rows: walk(rows_per_block=rows) for rows in (1, 4)}
     monkeypatch.setattr(mla_kernel, "DECODE_PAGES_PER_GROUP", 1)
-    assert np.array_equal(walk(), grid)
+    monkeypatch.setattr(mla_kernel, "CHUNK_PAGES_PER_GROUP", 1)
+    paged = {rows: walk(rows_per_block=rows) for rows in (1, 4)}
+    live = np.asarray(kv_len) > 0
+    assert np.array_equal(paged[4][live], paged[1][live])
+    for rows in (1, 4):
+        assert not np.array_equal(grouped[rows], paged[rows]), (
+            "a group's sums are reordered")
 
 
 # -- literal transcriptions -------------------------------------------------------

@@ -341,9 +341,11 @@ def recomputed():
     def get(case):
         if case not in cache:
             # the pinned tree made one online-softmax update a page: so does
-            # the latent decode loop with a group of one page
+            # the latent loop, a chunk's or the decode rows', with a group
+            # of one page
             with pytest.MonkeyPatch.context() as patch:
                 patch.setattr(mla_decode, "DECODE_PAGES_PER_GROUP", 1)
+                patch.setattr(mla_decode, "CHUNK_PAGES_PER_GROUP", 1)
                 cache[case] = parent_pins.CASES[case]()
         return cache[case]
     return get

@@ -775,7 +775,9 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
       row block, not once a row, and meets the MXU as a block's rows x
       its query heads; as C rows of ``gqa_decode_paged`` the walk was
       most of a Mistral-7B chunk's device time: PERF.md section 6,
-      PR 27), the latent family ``mla_decode_paged(rows_per_block=)``.
+      PR 27), the latent family ``mla_decode_paged(rows_per_block=)``
+      (the same in-kernel loop over live pages as its decode rows, a row
+      block's rows x heads as one operand: PR 34).
       Only the ``attn_io`` hook still takes the chunk as C rows of
       decode. Padded rows run with ``kv_len = 0`` (the empty-shard
       convention — zeros out, masked writes) and their residual-stream

@@ -250,14 +250,14 @@ def init_latent_pool(cfg: LatentMoEConfig, num_pages: int,
                               cfg.cache_width), cfg.dtype)}
 
 
-# How the chunk program walks the latent pool (``ops.mla_decode``'s grid), from
-# the kernel alone on a v5e at the published widths (PERF.md, PR 26, call 2):
-# a chunk's rows in blocks of 16 (x 64 heads = 1,024 rows of one MXU operand:
-# 3.36 ms a layer at 4k tokens of context against 4.09 at 8), and 7 pages a
-# grid step (5, 7, 10 and 14 read alike). The decode rows' walk is the
-# kernel's own loop, with its constants beside it (PR 31).
+# Rows of a prefill chunk that share ONE walk of the sequence's latent pages
+# (``ops.mla_decode``: a row block x 64 heads = 1,024 rows of one MXU operand
+# meet a group of live pages in one online-softmax update; the pages a group
+# stand beside the kernel). From the kernel alone on a v5e at the published
+# widths (PERF.md section 6, PR 34, ``scripts/mla_probe.py``, us a layer call
+# at 3,584 tokens of context): 8 rows a block 2,152, 16 rows 1,949, 32 rows
+# 1,874 at twice the VMEM (over Mosaic's 16 MB default) and twice the code.
 CHUNK_ROWS_PER_BLOCK = 16
-PAGES_PER_STEP = 7
 
 
 def _latent_attention(cfg: LatentMoEConfig, p, h, layer, pool, block_table,
@@ -277,16 +277,13 @@ def _latent_attention(cfg: LatentMoEConfig, p, h, layer, pool, block_table,
         q_cat = jnp.concatenate(
             [q_abs, q_rope, jnp.zeros((R, cfg.n_heads, pad), q_abs.dtype)],
             axis=-1)                                       # [R, H, W]
-        # a chunk's rows share one table and the grid walk; decode rows, a
-        # table each, take the kernel's loop over their live pages
-        walk = {}
-        if shared_table and R % CHUNK_ROWS_PER_BLOCK == 0:
-            walk = {"rows_per_block": CHUNK_ROWS_PER_BLOCK,
-                    "pages_per_step": min(PAGES_PER_STEP,
-                                          block_table.shape[1])}
+        # a chunk's rows share one table, and so the walk of its pages, a
+        # row block at a time; decode rows, a table each, walk alone
+        shared = shared_table and R % CHUNK_ROWS_PER_BLOCK == 0
         o = mla_decode_paged(
             q_cat, ckv, block_table, kv_len, layer=layer,
-            latent_dim=cfg.kv_lora_rank, sm_scale=cfg.sm_scale, **walk)
+            latent_dim=cfg.kv_lora_rank, sm_scale=cfg.sm_scale,
+            rows_per_block=CHUNK_ROWS_PER_BLOCK if shared else 1)
         out = jnp.einsum("rhc,hcv->rhv", o, p["w_uv"])
         return lin(out.reshape(R, -1), p["wo"], "wo"), {"ckv": ckv}
 

@@ -11,30 +11,28 @@ to the attention output afterwards), so ONE read of a page serves keys and
 values: the value operand of the kernel is the first ``latent_dim`` columns
 of the same VMEM block.
 
-Two walks of the stacked pool, read in place (``layer`` is a scalar-prefetch
-operand of both):
+ONE walk of the stacked pool, read in place (``layer`` is a scalar-prefetch
+operand): a loop inside the kernel over the live pages alone, the pattern of
+``ops.flash_decode.gqa_decode_paged`` since ISSUE 29, a GROUP of pages a turn
+(ISSUE 31 for the decode rows, ISSUE 34 for a prefill chunk's). Consecutive
+live pages of one table row are fetched by hand into adjacent slices of one
+VMEM operand and meet their query rows in ONE online-softmax update, the next
+groups' copies in flight behind it: what a one-page update pays beside its
+two matrix products (the accumulator loaded, rescaled and stored, two
+cross-lane reductions, a chain the next update waits for) is paid once a
+group. A dead page is no step, no index map and no byte; an idle row costs
+nothing. The walk's block shape follows what the caller says of the tables:
 
-The DECODE rows (a block table a row) are ONE loop inside the kernel over the
-batch's live pages, the pattern of ``ops.flash_decode.gqa_decode_paged`` since
-ISSUE 29, a GROUP of pages a turn (ISSUE 31): ``DECODE_PAGES_PER_GROUP``
-consecutive live pages of one row are fetched by hand into adjacent slices of
-one VMEM operand and meet the row's heads in ONE online-softmax update, with
-the next groups' copies in flight behind it. A dead page is no step, no index
-map and no byte; an idle row costs nothing.
-
-A prefill CHUNK's rows keep the (row block, page step) grid that
-``gqa_decode_paged`` had until ISSUE 29: pages streamed through the block
-table by index maps, dead steps revisit the last live page (no DMA), compute
-skipped. Two things make it read each page of the sequence once a row block
-and not once a row:
-
-- ``rows_per_block`` consecutive rows form one grid row: they share the
-  block table of the block's first row (the caller's promise: a chunk's rows
-  all belong to one sequence) and differ only in ``kv_len``, which masks per
-  row. Decode uses 1 (every row has its own table).
-- ``pages_per_step`` pages are read a grid step (the same pool operand that
-  many times over, each with its own index map): fewer, fatter grid steps
-  for long block tables.
+- DECODE rows have a block table each: a row's 64 heads are the update's
+  query operand, ``DECODE_PAGES_PER_GROUP`` pages its keys.
+- A prefill CHUNK's rows all belong to one sequence: ``rows_per_block``
+  consecutive rows share the block table of the block's first row (the
+  caller's promise) and differ only in ``kv_len``, which masks per row. A row
+  block x its heads are one [1024, 640] operand at the published widths, a
+  page leaves HBM once a row block and not once a row, and the walk ends at
+  the block's own last position (causality: later rows' pages, and everything
+  past the prompt, are no steps). Here an update's time grows with its keys,
+  so a block's short last group is attended at its own size.
 """
 
 from __future__ import annotations
@@ -84,83 +82,81 @@ def _softmax_finish(acc, l_i):
     return acc[...] / jnp.where(l_i[...] > 0, l_i[...], 1.0)
 
 
-def _mla_kernel(kl_ref, bt_ref, layer_ref, q_ref, klr_ref, *rest,
-                n_pages: int, page_size: int, latent_dim: int,
-                sm_scale: float):
-    """Grid (row blocks, page steps). ``q_ref`` [M, W] is the block's rows x
-    heads, ``klr_ref`` [M, 1] their ``kv_len``; ``rest`` = the step's page
-    blocks [page_size, W], the output [M, latent_dim], and the scratch
-    (acc, m, l)."""
-    del bt_ref, layer_ref
-    pages, out_ref, (acc, m_i, l_i) = (rest[:n_pages], rest[n_pages],
-                                       rest[n_pages + 1:])
-    b, s = pl.program_id(0), pl.program_id(1)
-
-    pl.when(s == 0)(lambda: _softmax_init(acc, m_i, l_i))
-
-    for j, page in enumerate(pages):
-        start = (s * n_pages + j) * page_size
-
-        @pl.when(start < kl_ref[b])
-        def _(page=page, start=start):
-            _softmax_update(q_ref[...], page[...], start, klr_ref[...], acc,
-                            m_i, l_i, latent_dim=latent_dim,
-                            sm_scale=sm_scale)
-
-    @pl.when(s == pl.num_programs(1) - 1)
-    def _():
-        out_ref[...] = _softmax_finish(acc, l_i).astype(out_ref.dtype)
-
-
 def _mla_loop_kernel(kl_ref, bt_ref, layer_ref, q_ref, pool_hbm, out_ref,
-                     buf, sem, acc, m_i, l_i, *, group: int, n_pool: int,
-                     page_size: int, latent_dim: int, sm_scale: float):
-    """Grid (row blocks,) over a latent pool left in HBM: ONE loop over the
-    block's live pages alone, rows in order and a row's pages in order,
-    ``group`` pages a turn. ``q_ref`` / ``out_ref`` hold the block's rows
-    [rows, H, .]; ``buf`` [ring, group * page_size, W] is a ring of group
-    operands, ``sem`` [ring, group] a DMA semaphore a page of it.
+                     buf, sem, acc, m_i, l_i, *, rows: int, group: int,
+                     n_pool: int, page_size: int, latent_dim: int,
+                     sm_scale: float):
+    """Grid (blocks of walks,) over a latent pool left in HBM. A WALK is
+    ``rows`` consecutive rows of the batch that share one table row,
+    ``bt_ref[walk]``, and meet its pages as one [rows x H, W] operand: a
+    decode row (``rows`` 1) or a row block of a prefill chunk. ``kl_ref``
+    holds every row's ``kv_len``; a walk reaches as far as the largest of its
+    rows', and each row masks for itself. ONE loop over the block's live pages
+    alone, walks in order and a walk's pages in order, ``group`` pages a turn.
+    ``q_ref`` / ``out_ref`` hold the block's walks [walks, rows x H, .];
+    ``buf`` [ring, group * page_size, W] is a ring of group operands, ``sem``
+    [ring, group] a DMA semaphore a page of it.
 
-    A turn fetches the group's LIVE pages (``bt_ref[row, page]`` of layer
+    A turn fetches the group's LIVE pages (``bt_ref[walk, page]`` of layer
     ``layer_ref[0]``, straight out of the stacked pool) into adjacent
     [page_size, W] slices of one ring entry, the groups after it already in
-    flight into the others, and makes one online-softmax update of the row's
-    heads against the whole entry. A row's last group may hold fewer live
-    pages than ``group``: the others are not fetched, their keys are masked
-    (``pos < kv_len``) to weight 0, and the rows they leave in the entry are
-    those of an earlier group or the zeros THE RING IS FILLED WITH ONCE A
-    CALL, finite either way (0 x inf in the value product would be NaN). An
-    idle row and a page past ``kv_len`` are not steps at all; a table entry
-    past a row's live pages is never read and a live one is clamped into the
-    pool."""
-    rows, pages_per_seq = q_ref.shape[0], bt_ref.shape[1]
-    row0 = pl.program_id(0) * rows
-    end = row0 + rows
+    flight into the others, and makes one online-softmax update of the walk's
+    rows x heads against the entry. A walk's last group may hold fewer live
+    pages than ``group``: the others are not fetched. A decode row (64 query
+    rows: the update costs its chain, whatever its keys) still attends the
+    whole entry: the missing pages' keys are masked (``pos < kv_len``) to
+    weight 0, and the rows they leave in the entry are those of an earlier
+    group or the zeros THE RING IS FILLED WITH ONCE A CALL, finite either way
+    (0 x inf in the value product would be NaN). A row block (1,024 query
+    rows: the update costs the MXU's time for its keys) attends the live
+    pages' slices alone, one branch a size: a key masked to weight 0 adds
+    exactly 0, so the result is the whole entry's. A walk none of whose rows
+    is live and a page past its reach are not steps at all; a table entry
+    past a walk's live pages is never read and a live one is clamped into the
+    pool. A row with ``kv_len`` 0 inside a live walk (a chunk's padding)
+    weighs every key the walk attended alike: a finite mean nobody reads."""
+    walks, pages_per_seq = q_ref.shape[0], bt_ref.shape[1]
+    walk0 = pl.program_id(0) * walks
+    end = walk0 + walks
     layer = layer_ref[0]
     depth = buf.shape[0]
 
-    def live_pages(row):
+    def reach(walk):
+        """The furthest any row of the walk attends (a loop: it is traced at
+        every use, inside the engine's set-up time)."""
+        if rows == 1:
+            return kl_ref[walk]
+        return lax.fori_loop(
+            0, rows, lambda r, n: jnp.maximum(n, kl_ref[walk * rows + r]), 0)
+
+    def live_pages(walk):
         # a key past the table's last page does not exist, whatever kv_len says
-        return jnp.minimum((kl_ref[row] + page_size - 1) // page_size,
+        return jnp.minimum((reach(walk) + page_size - 1) // page_size,
                            pages_per_seq)
 
-    def next_live(row):
+    def next_live(walk):
         return lax.while_loop(
-            lambda r: (r < end) & (kl_ref[jnp.minimum(r, end - 1)] <= 0),
-            lambda r: r + 1, row)
+            lambda w: (w < end) & (reach(jnp.minimum(w, end - 1)) <= 0),
+            lambda w: w + 1, walk)
 
-    def after(row, g):
-        """The group that follows (row, g); row ``end`` when none is left."""
-        n = live_pages(jnp.minimum(row, end - 1))
+    def after(walk, g):
+        """The group that follows (walk, g); walk ``end`` when none is left."""
+        n = live_pages(jnp.minimum(walk, end - 1))
         return lax.cond((g + 1) * group >= n,
-                        lambda: (next_live(row + 1), 0), lambda: (row, g + 1))
+                        lambda: (next_live(walk + 1), 0),
+                        lambda: (walk, g + 1))
 
-    def each_live_page(row, g, slot, do):
-        """``do`` the copy of every live page of group (row, g)."""
+    def pages_from(walk, first):
+        """The live pages of the walk's group that starts at page ``first``:
+        ``group``, or a last group's few."""
+        return jnp.minimum(live_pages(walk) - first, group)
+
+    def each_live_page(walk, g, slot, do):
+        """``do`` the copy of every live page of group (walk, g)."""
         first = g * group
 
         def one(j, _):
-            page = jnp.clip(bt_ref[row, first + j], 0, n_pool - 1)
+            page = jnp.clip(bt_ref[walk, first + j], 0, n_pool - 1)
             at = pl.multiple_of(j * page_size, page_size)
             do(pltpu.make_async_copy(pool_hbm.at[layer, page],
                                      buf.at[slot, pl.ds(at, page_size)],
@@ -168,14 +164,13 @@ def _mla_loop_kernel(kl_ref, bt_ref, layer_ref, q_ref, pool_hbm, out_ref,
 
         # a loop, not ``group`` conditionals: the trace and Mosaic's compile
         # are inside the serving engine's set-up time
-        lax.fori_loop(0, jnp.minimum(live_pages(row) - first, group), one,
-                      None)
+        lax.fori_loop(0, pages_from(walk, first), one, None)
 
-    def start(row, g, slot):
-        pl.when(row < end)(lambda: each_live_page(
-            row, g, slot, lambda copy: copy.start()))
+    def start(walk, g, slot):
+        pl.when(walk < end)(lambda: each_live_page(
+            walk, g, slot, lambda copy: copy.start()))
 
-    # what no group writes: an idle row's zeros, and (once a call: scratch
+    # what no group writes: an idle walk's zeros, and (once a call: scratch
     # outlives a grid step) the ring rows a short group leaves unfetched
     out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -185,7 +180,7 @@ def _mla_loop_kernel(kl_ref, bt_ref, layer_ref, q_ref, pool_hbm, out_ref,
 
     # the first ``depth - 1`` groups are in flight before the loop, and every
     # turn of it starts one more
-    groups = [(next_live(row0), 0)]
+    groups = [(next_live(walk0), 0)]
     for _ in range(depth - 2):
         groups.append(after(*groups[-1]))
     for slot, grp in enumerate(groups):
@@ -195,18 +190,35 @@ def _mla_loop_kernel(kl_ref, bt_ref, layer_ref, q_ref, pool_hbm, out_ref,
         w, *groups = carry                # the group attended, those in flight
         groups.append(after(*groups[-1]))
         start(*groups[-1], (w + depth - 1) % depth)
-        (row, g), slot = groups[0], w % depth
+        (walk, g), slot = groups[0], w % depth
         pl.when(g == 0)(lambda: _softmax_init(acc, m_i, l_i))
-        each_live_page(row, g, slot, lambda copy: copy.wait())
-        r = row - row0
-        _softmax_update(
-            q_ref[r], buf[slot], g * group * page_size,
-            jnp.minimum(kl_ref[row], pages_per_seq * page_size), acc, m_i,
-            l_i, latent_dim=latent_dim, sm_scale=sm_scale)
+        each_live_page(walk, g, slot, lambda copy: copy.wait())
+        i = walk - walk0
+        cap = pages_per_seq * page_size
+        if rows > 1:   # [rows x H, 1]: a row's own kv_len for each of its heads
+            kv_lens = jnp.concatenate([
+                jnp.full((q_ref.shape[1] // rows, 1),
+                         jnp.minimum(kl_ref[walk * rows + r], cap), jnp.int32)
+                for r in range(rows)])
 
-        @pl.when(groups[1][0] != row)                # the row's last group
+        def update(pages):
+            q, kv = q_ref[i], buf[slot, :pages * page_size]
+            start = g * group * page_size
+            # (a decode row's is read here, as its kernel has read it since
+            # ISSUE 31: the trace of that call stays the pinned one)
+            kv_len = jnp.minimum(kl_ref[walk], cap) if rows == 1 else kv_lens
+            _softmax_update(q, kv, start, kv_len, acc, m_i, l_i,
+                            latent_dim=latent_dim, sm_scale=sm_scale)
+
+        if rows == 1:
+            update(group)
+        else:          # a short last group at its own size
+            lax.switch(pages_from(walk, g * group) - 1, [
+                functools.partial(update, n + 1) for n in range(group)])
+
+        @pl.when(groups[1][0] != walk)               # the walk's last group
         def _():
-            out_ref[r] = _softmax_finish(acc, l_i).astype(out_ref.dtype)
+            out_ref[i] = _softmax_finish(acc, l_i).astype(out_ref.dtype)
         return (w + 1, *groups[1:])
 
     lax.while_loop(lambda c: c[1][0] < end, attend, (0, *groups))
@@ -235,126 +247,108 @@ DECODE_ROWS_PER_BLOCK = 16
 DECODE_PAGES_PER_GROUP = 7
 DECODE_GROUPS_IN_FLIGHT = 2
 
-
-def _loop_walk(q, pool, block_table, kv_len, layer, *, latent_dim, sm_scale,
-               cost):
-    R, H, W = q.shape
-    _, P_pool, page_size, _ = pool.shape
-    Rb = math.gcd(R, DECODE_ROWS_PER_BLOCK)
-    G = min(DECODE_PAGES_PER_GROUP, block_table.shape[1])
-    ring = DECODE_GROUPS_IN_FLIGHT + 1
-    rows = lambda i, *_: (i, 0, 0)                          # noqa: E731
-    kernel = functools.partial(
-        _mla_loop_kernel, group=G, n_pool=P_pool, page_size=page_size,
-        latent_dim=latent_dim, sm_scale=sm_scale)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(R // Rb,),
-            in_specs=[pl.BlockSpec((Rb, H, W), rows),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((Rb, H, latent_dim), rows),
-            scratch_shapes=[pltpu.VMEM((ring, G * page_size, W), pool.dtype),
-                            pltpu.SemaphoreType.DMA((ring, G)),
-                            pltpu.VMEM((H, latent_dim), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((R, H, latent_dim), q.dtype),
-        cost_estimate=cost,
-        name="mla_decode_paged",
-        interpret=default_interpret(),
-    )(kv_len, block_table, layer, q, pool)
-
-
-def _grid_walk(q, pool, block_table, kv_len, layer, *, latent_dim, sm_scale,
-               rows_per_block, pages_per_step, cost):
-    R, H, W = q.shape
-    _, P_pool, page_size, _ = pool.shape
-    Rb, N = rows_per_block, pages_per_step
-    assert R % Rb == 0, f"{R} rows in blocks of {Rb}"
-    n_blk, M = R // Rb, Rb * H
-    n_steps = -(-block_table.shape[1] // N)
-    kl_blk = kv_len.reshape(n_blk, Rb).max(axis=1)
-    bt_blk = block_table[::Rb]
-    kl_rows = jnp.repeat(kv_len, H)[:, None]                # [R * H, 1]
-
-    def page_index(j):
-        def index(b, s, kl, bt, ly):
-            last = jnp.maximum((kl[b] + page_size - 1) // page_size - 1, 0)
-            page = bt[b, jnp.minimum(s * N + j, last)]
-            return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0)
-        return index
-
-    rows = lambda b, s, kl, bt, ly: (b, 0)                  # noqa: E731
-    kernel = functools.partial(_mla_kernel, n_pages=N, page_size=page_size,
-                               latent_dim=latent_dim, sm_scale=sm_scale)
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(n_blk, n_steps),
-            in_specs=[pl.BlockSpec((M, W), rows),
-                      pl.BlockSpec((M, 1), rows)]
-            + [pl.BlockSpec((None, None, page_size, W), page_index(j))
-               for j in range(N)],
-            out_specs=pl.BlockSpec((M, latent_dim), rows),
-            scratch_shapes=[pltpu.VMEM((M, latent_dim), jnp.float32),
-                            pltpu.VMEM((M, 1), jnp.float32),
-                            pltpu.VMEM((M, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((R * H, latent_dim), q.dtype),
-        cost_estimate=cost,
-        name="mla_decode_paged",
-        interpret=default_interpret(),
-    )(kl_blk, bt_blk, layer, q.reshape(R * H, W), kl_rows, *([pool] * N))
-    return out.reshape(R, H, latent_dim)
+# A prefill chunk's walk, chosen on the v5e (PERF.md section 6, PR 34,
+# scripts/mla_probe.py: the kernel alone at Kimi-K2 widths, a 512-row chunk of
+# 64 heads x 640 over a 70-page table, 16 rows a block unless said, us a layer
+# call at 0 / 1,536 / 3,584 / 8,448 tokens cached before the chunk; [us a
+# live (row block, page) at 3,584: the MXU alone needs 1.53]):
+#   the (32, 10) grid, 7 pages a step      529  1,702  3,220   6,755  [3.30]
+#   the loop, 1 page a group               359  1,642  3,352   7,415  [3.43] (the grid's bits)
+#   whole groups (a short one masked), 2   293  1,086  2,153   4,685
+#             4                            332  1,028  1,964   4,185
+#             7                            487  1,088  2,060   4,024
+#   a short last group at its own size, 3  279  1,031  2,033   4,443
+#             4                            270    992  1,949   4,223  [2.00] <- shipped
+#             5                            267    969  1,915   4,150
+#             6                            270    975  1,892   4,086  [1.94]
+#             7                            310  2,486  4,960  10,828  [5.08]
+#             10                           478  3,044  5,946  13,113
+#   4, two groups in flight                271    985  1,945   4,226
+#   4, 8 rows a block                      314  1,108  2,152   4,632
+#   4, 32 rows a block (96 MiB of VMEM)    257    947  1,874   4,075
+# An update's unrolled code grows with rows x keys x branches: past ~21 units
+# of [1024 rows x one page] (sizes 1..7 at 16 rows: 28; 1..4 at 32 rows: 20;
+# 1..6 at 16 rows: 21) a call reads 2.5 x slower at every context: the
+# kernel's code no longer fits where the core keeps it. 4 pages a group (10
+# units) stands clear of that edge, inside Mosaic's default 16 MB of scoped
+# VMEM (12.1 MB by the compiler's count), within 3 % of the best row.
+# Consecutive live pages of a row block that share ONE online-softmax update
+# (a [4 x 128, 640] operand against [1024, 640] queries: [1024, 512] float32
+# scores), and groups in flight behind the one attended (the walk is bound by
+# the MXU, not by the 0.8 us a group's DMA takes: one suffices).
+CHUNK_PAGES_PER_GROUP = 4
+CHUNK_GROUPS_IN_FLIGHT = 1
 
 
 def mla_decode_paged(q: jax.Array, pool: jax.Array, block_table: jax.Array,
                      kv_len: jax.Array, *, layer, latent_dim: int,
-                     sm_scale: float, rows_per_block: int = 1,
-                     pages_per_step: int | None = None) -> jax.Array:
+                     sm_scale: float, rows_per_block: int = 1) -> jax.Array:
     """q [R, H, W]: per row and head ``[q' | q_rope | 0]`` in the pool's
     stored width W; pool [L, P, page_size, W] (the stacked latent pool,
     read in place at ``layer``); block_table [R, pages_per_seq] int32;
     kv_len [R] int32. Returns [R, H, latent_dim]: the softmax-weighted mean
     of the cached ``c`` rows, per head, still to be up-projected by ``W_uv``.
 
-    Decode rows, each with a block table of its own (the default:
-    ``pages_per_step`` None, ``rows_per_block`` 1), walk the batch's live
-    pages in one in-kernel loop, a group of pages an update: a row with
-    ``kv_len`` 0 costs nothing and returns zeros, and entries past a row's
-    live pages may be arbitrary, nothing reads them.
+    Either way the rows walk the live pages in one in-kernel loop, a group of
+    pages an online-softmax update, and a table entry past the live pages may
+    be arbitrary: nothing reads it.
 
-    A prefill chunk's rows give ``pages_per_step`` (pages read a grid step)
-    and take the (row block, page step) grid: rows ``[i * rows_per_block,
-    (i + 1) * rows_per_block)`` must share one block-table row (that of the
-    first); a row with ``kv_len`` 0 returns zeros if its whole block is
-    empty, else a finite value nobody reads; the index maps never dereference
-    an entry past a row block's live pages. One update a page, in page
-    order: at one row a block it is the decode loop's arithmetic with a
-    group of one page, to the bit."""
+    Decode rows, each with a block table of its own (``rows_per_block`` 1,
+    the default): a row with ``kv_len`` 0 costs nothing and returns zeros.
+
+    A prefill chunk's rows (``rows_per_block`` > 1, which must divide R):
+    rows ``[i * rows_per_block, (i + 1) * rows_per_block)`` PROMISE to share
+    one block-table row (the first's is the one read) and walk its pages
+    together, as far as the largest ``kv_len`` among them. A block whose rows
+    all have ``kv_len`` 0 costs nothing and returns zeros; a row with
+    ``kv_len`` 0 inside a live block (a chunk's padding tail) returns a finite
+    value nobody reads. At one page a group the arithmetic is the decode
+    loop's at one page a group, to the bit; a larger group changes the order
+    of the float32 sums inside it."""
     R, H, W = q.shape
     L, P_pool, page_size, W_pool = pool.shape
     assert W == W_pool and latent_dim <= W, (q.shape, pool.shape)
     assert latent_dim % 128 == 0 and W % 128 == 0, (
         "the value slice and the stored row are lane-aligned")
-    S = block_table.shape[1]
+    Rb, S = rows_per_block, block_table.shape[1]
+    assert R % Rb == 0, f"{R} rows in blocks of {Rb}"
     live = R * S * page_size
     cost = pl.CostEstimate(
         flops=2 * live * H * (W + latent_dim),
-        bytes_accessed=(q.size + R // rows_per_block * S * page_size * W)
+        bytes_accessed=(q.size + R // Rb * S * page_size * W)
         * q.dtype.itemsize,
         transcendentals=live * H)
-    walk = dict(latent_dim=latent_dim, sm_scale=sm_scale, cost=cost)
-    kv_len = kv_len.astype(jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    if pages_per_step is None:
-        assert rows_per_block == 1, "rows that share a table take the grid"
-        return _loop_walk(q, pool, block_table, kv_len, layer, **walk)
-    return _grid_walk(q, pool, block_table, kv_len, layer, **walk,
-                      rows_per_block=rows_per_block,
-                      pages_per_step=pages_per_step)
+    if Rb == 1:                       # decode rows: a table each
+        n, G, F = (math.gcd(R, DECODE_ROWS_PER_BLOCK), DECODE_PAGES_PER_GROUP,
+                   DECODE_GROUPS_IN_FLIGHT)
+    else:                             # a chunk's row blocks: one walk a step
+        n, G, F = 1, CHUNK_PAGES_PER_GROUP, CHUNK_GROUPS_IN_FLIGHT
+        q, block_table = q.reshape(R // Rb, Rb * H, W), block_table[::Rb]
+    G, ring, M = min(G, S), F + 1, Rb * H
+    walks = lambda i, *_: (i, 0, 0)                         # noqa: E731
+    kernel = functools.partial(
+        _mla_loop_kernel, rows=Rb, group=G, n_pool=P_pool,
+        page_size=page_size, latent_dim=latent_dim, sm_scale=sm_scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(R // Rb // n,),
+            in_specs=[pl.BlockSpec((n, M, W), walks),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((n, M, latent_dim), walks),
+            scratch_shapes=[pltpu.VMEM((ring, G * page_size, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((ring, G)),
+                            pltpu.VMEM((M, latent_dim), jnp.float32),
+                            pltpu.VMEM((M, 1), jnp.float32),
+                            pltpu.VMEM((M, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((R // Rb, M, latent_dim), q.dtype),
+        cost_estimate=cost,
+        name="mla_decode_paged",
+        interpret=default_interpret(),
+    )(kv_len.astype(jnp.int32), block_table,
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+    return out.reshape(R, H, latent_dim)
 
 
 __all__ = ["mla_decode_paged"]
