@@ -660,3 +660,164 @@ def test_decode_stall_bounded_by_chunk(tiny_model):
 
     assert run(C) <= C                         # stall bounded by the chunk
     assert C < run(2 * C) <= 2 * C
+
+
+# ---------------------------------------------------------------------------
+# the step's phases (ISSUE 35): spans and exact totals from one primitive
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = {"admit", "chunk_prep", "chunk_wait", "grow", "sync",
+               "dispatch", "decode_wait", "reconcile", "post"}
+
+
+class SpanRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps every span
+    opened as (name, ids, the names of the spans open around it)."""
+
+    def __init__(self):
+        self.opened = []
+        self._stack = []
+
+    def __call__(self, name, **ids):
+        rec = self
+
+        class Span:
+            def __enter__(self):
+                rec.opened.append((name, ids, tuple(rec._stack)))
+                rec._stack.append(name)
+
+            def __exit__(self, *exc):
+                assert rec._stack.pop() == name
+
+        return Span()
+
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["k1", "k4"])
+def phased_run(request, tiny_model, reference_tokens):
+    """The reference prompts through an engine whose span factory is a
+    recorder, polled once while idle before and after."""
+    cfg, params = tiny_model
+    prompts, want = reference_tokens
+    eng = ServingEngine(params, cfg, num_slots=2, page_size=8, num_pages=16,
+                        pages_per_seq=4, prefill_chunk=8,
+                        decode_horizon=request.param)
+    rec = eng.metrics.span = SpanRecorder()
+    assert eng.step() is False                 # idle: nothing to do
+    idle_spans, rec.opened = rec.opened, []
+    idle_counts = {k: h.count for k, h in eng.metrics.hist.items()}
+    rids = [eng.submit(p, REF_NEW_TOKENS) for p in prompts]
+    res = eng.run(max_steps=2000)
+    return {"eng": eng, "spans": rec.opened, "idle_spans": idle_spans,
+            "idle_counts": idle_counts, "rids": rids,
+            "tokens": [res[r] for r in rids], "want": want}
+
+
+def test_idle_step_observes_nothing(phased_run):
+    """A poll that finds the engine idle opens its two spans and leaves
+    every histogram as it was: the rag cell's empty steps must not dilute
+    a mean."""
+    assert [s[0] for s in phased_run["idle_spans"]] == ["engine.step",
+                                                        "engine.admit"]
+    assert not any(phased_run["idle_counts"].values())
+
+
+def test_step_opens_exactly_the_named_phases(phased_run):
+    """Every span is ``engine.submit`` (with its rid), ``engine.step`` or
+    one of the nine phases; a phase opens directly inside ``engine.step``
+    and carries its step number; the chunk's two carry the rid being
+    prefilled and its cursor; and the tokens served under the recorder are
+    the contiguous reference's."""
+    spans = phased_run["spans"]
+    names = {name for name, _, _ in spans}
+    assert names == {"engine.submit", "engine.step"} | {
+        "engine." + p for p in STEP_PHASES}
+    submits = [ids for name, ids, _ in spans if name == "engine.submit"]
+    assert [ids["rid"] for ids in submits] == phased_run["rids"]
+    step_no = None
+    for name, ids, around in spans:
+        if name == "engine.submit":
+            assert around == ()
+        elif name == "engine.step":
+            assert around == ()
+            step_no = ids["step"]
+        else:
+            assert around == ("engine.step",), (name, around)
+            assert ids["step"] == step_no
+    chunks = [(name, ids) for name, ids, _ in spans
+              if name in ("engine.chunk_prep", "engine.chunk_wait")]
+    assert chunks and len(chunks) % 2 == 0
+    for (n0, prep), (n1, wait) in zip(chunks[::2], chunks[1::2]):
+        assert (n0, n1) == ("engine.chunk_prep", "engine.chunk_wait")
+        assert prep == wait and prep["rid"] in phased_run["rids"]
+        assert prep["cursor"] % 8 == 0         # whole chunks of 8 so far
+    # a prompt's chunks are its cursor advancing from 0
+    by_rid = {}
+    for _, ids in chunks[::2]:
+        by_rid.setdefault(ids["rid"], []).append(ids["cursor"])
+    for rid, n in zip(phased_run["rids"], REF_PROMPT_LENS):
+        assert by_rid[rid] == list(range(0, n, 8))
+    assert phased_run["tokens"] == phased_run["want"]
+
+
+def test_phase_totals_tile_the_step(phased_run):
+    """Host work and waits add up to ``step_s.total`` within 2 %: what a
+    step does outside every phase is a handful of statements."""
+    hist = phased_run["eng"].metrics.hist
+    parts = sum(hist[f"phase_{p}_s"].total for p in STEP_PHASES)
+    whole = hist["step_s"].total
+    assert 0.98 * whole <= parts <= whole
+    counters = phased_run["eng"].metrics.counters
+    assert hist["phase_dispatch_s"].count == counters["dispatches"]
+    assert hist["phase_decode_wait_s"].count == counters["dispatches"]
+    assert hist["phase_reconcile_s"].count == counters["dispatches"]
+    assert hist["phase_chunk_prep_s"].count == counters["prefill_chunks"]
+    assert hist["phase_chunk_wait_s"].count == counters["prefill_chunks"]
+    assert hist["phase_sync_s"].count == counters["host_syncs"]
+    assert hist["phase_admit_s"].count == hist["step_s"].count
+
+
+def test_older_timers_are_differences_of_the_phase_stamps(phased_run):
+    """``step_device_s`` is dispatch + decode wait, ``prefill_stall_s`` the
+    chunk's prep + wait, ``decode_stall_s`` the step up to the chunk's
+    token, ``step_host_s`` the rest of a dispatching step before ``post``:
+    the names and counts of before, from the same stamps."""
+    m = phased_run["eng"].metrics
+    h = m.hist
+    total = lambda *names: sum(h[n].total for n in names)   # noqa: E731
+    near = lambda a, b: abs(a - b) <= 0.02 * max(a, b)      # noqa: E731
+    assert h["step_device_s"].count == h["step_host_s"].count \
+        == m.counters["dispatches"]
+    assert near(h["step_device_s"].total,
+                total("phase_dispatch_s", "phase_decode_wait_s"))
+    assert h["prefill_stall_s"].count == m.counters["prefill_chunks"]
+    assert near(h["prefill_stall_s"].total,
+                total("phase_chunk_prep_s", "phase_chunk_wait_s"))
+    assert h["decode_stall_s"].count == h["step_s"].count
+    assert near(h["decode_stall_s"].total,
+                total("phase_admit_s", "phase_chunk_prep_s",
+                      "phase_chunk_wait_s"))
+    # host + device is a dispatching step without its closing phase (at
+    # k4 one step of this run is a chunk alone, and is in neither)
+    both = total("step_host_s", "step_device_s")
+    rest = h["step_s"].total - h["phase_post_s"].total
+    assert both <= rest
+    if h["step_s"].count == m.counters["dispatches"]:
+        assert near(both, rest)
+
+
+def test_phase_without_a_histogram_is_a_span_alone():
+    from triton_dist_tpu.serving.metrics import ServingMetrics
+    m = ServingMetrics()
+    rec = m.span = SpanRecorder()
+    before = {k: h.count for k, h in m.hist.items()}
+    with m.phase("submit", rid=3) as ph:
+        pass
+    assert rec.opened == [("engine.submit", {"rid": 3}, ())]
+    assert ph.t1 >= ph.t0
+    assert {k: h.count for k, h in m.hist.items()} == before
+    with m.phase("grow", step=0) as ph:
+        ph.drop()
+    assert m.hist["phase_grow_s"].count == 0
+    with m.phase("grow", step=1):
+        pass
+    assert m.hist["phase_grow_s"].count == 1

@@ -364,7 +364,6 @@ class SimEngine:
             if req.done:
                 self._finish(slot)
         self.metrics.observe("queue_depth", self.sched.queue_depth)
-        self.metrics.observe("pool_occupancy", self.alloc.occupancy())
         self._steps += 1
         return True
 
@@ -989,9 +988,6 @@ class Cluster:
                 stepped += 1
         self.metrics.inc("replica_steps", stepped)
         self._cluster_steps += 1
-        self.metrics.observe("fleet_size", sum(
-            1 for r in self.replicas if r.lifecycle in
-            (ReplicaState.ACTIVE, ReplicaState.WARMING)))
         self._harvest()
         # drain pass: a DRAINING replica sheds its queue every step (the
         # journal-cursor requeue — normally once at drain_begin, again
@@ -1047,7 +1043,6 @@ class Cluster:
         if fin_step is not None and len(req.generated) > 1:
             itl = ((fin_step - req.first_token_step)
                    / (len(req.generated) - 1))
-            self.metrics.observe("itl_steps", itl)
             self.metrics.observe_class("itl_steps", cls, itl)
         self._latency_feed.append((cls, ttft, itl))
 
@@ -1070,7 +1065,6 @@ class Cluster:
         rep.warm_remaining = warm_steps
         self.replicas.append(rep)
         self.metrics.inc("scale_ups")
-        self.metrics.observe("scale_up_build_s", rep.build_s)
         self._scale_event("scale_up", rep.index)
         return rep
 
@@ -1090,7 +1084,6 @@ class Cluster:
         assert any(r.admitting and r.index != index for r in self.replicas), \
             "cannot drain the last admitting replica"
         rep.lifecycle = ReplicaState.DRAINING
-        self.metrics.inc("drains_begun")
         self._scale_event("drain_begin", index)
         return self._requeue_queued(rep)
 

@@ -334,7 +334,6 @@ class PageMigrationChannel:
             got *= 2                   # duplicated increment — over-signal
         if self.ledger.landed(rid, chunk_idx, got, generation=attempt):
             self.metrics.inc("pages_migrated", min(got, n))
-            self.metrics.observe("migrate_pages_per_chunk", min(got, n))
         return pool_k, pool_v
 
     def tick(self, now: int) -> list[tuple[int, Exception]]:
@@ -360,7 +359,6 @@ class PageMigrationChannel:
                 continue
             if fresh:
                 self.metrics.inc("pages_migrated", got)
-                self.metrics.observe("migrate_pages_per_chunk", got)
             else:
                 self.metrics.inc("stale_signals")
         return poisoned
@@ -1393,8 +1391,6 @@ class DisaggServingEngine:
         self.metrics_decode.inc("dispatches")
         self.metrics_decode.inc("decode_steps", int(limits.max()))
         self.metrics_decode.observe("queue_depth", len(self._handoff))
-        self.metrics_decode.observe("pool_occupancy",
-                                    self.alloc_d.occupancy())
         self.metrics_decode.observe("active_slots", len(active))
 
         n_tokens = 0

@@ -480,6 +480,13 @@ class ServingEngine:
 
     def submit(self, prompt, max_new_tokens: int, rid: int | None = None,
                tenant: str | None = None, cls: str | None = None) -> int:
+        if rid is None:
+            rid = self._next_rid
+        with self.metrics.phase("submit", rid=rid):
+            return self._submit(prompt, max_new_tokens, rid, tenant, cls)
+
+    def _submit(self, prompt, max_new_tokens: int, rid: int,
+                tenant: str | None, cls: str | None) -> int:
         prompt = tuple(int(t) for t in np.asarray(prompt).reshape(-1))
         assert prompt and max_new_tokens >= 1
         total = len(prompt) + max_new_tokens - 1   # KV the request may hold
@@ -490,8 +497,6 @@ class ServingEngine:
         assert need <= self.alloc.num_pages - self.alloc.reserved, (
             f"request needs {need} pages > pool size — it could never run "
             "even alone")
-        if rid is None:
-            rid = self._next_rid
         self._next_rid = max(self._next_rid, rid + 1)
         req = Request(rid=rid, prompt=prompt, max_new_tokens=max_new_tokens,
                       eos_token=self.eos_id,
@@ -743,12 +748,21 @@ class ServingEngine:
                     else min(budget, spec.stall_budget)
         return budget
 
-    def _dispatch_prefill_chunk(self) -> int:
-        """Run AT MOST ONE prefill chunk: the oldest (lowest admission
-        ticket) PREFILLING slot advances its cursor by one chunk. The
-        final chunk fuses the first-token argmax on device and flips the
-        slot to ACTIVE (mirrors set, ready for this step's decode
-        dispatch). Returns prompt tokens processed (0 = no prefill work).
+    def _oldest_prefilling(self):
+        """The slot whose prompt advances this step: the oldest (lowest
+        admission ticket) PREFILLING one. (None, None) = no prefill work."""
+        slot, req = None, None
+        for i, r in enumerate(self.sched.slots):
+            if (r is not None and r.state is RequestState.PREFILLING
+                    and (req is None or r.admitted_seq < req.admitted_seq)):
+                slot, req = i, r
+        return slot, req
+
+    def _launch_chunk(self, slot: int, req: Request):
+        """Launch ONE prefill chunk of ``req`` (a step runs at most one):
+        its cursor advances by one chunk once ``_commit_chunk`` has the
+        chunk's token. Returns (the token on device, the new cursor, the
+        slot's table row, prompt tokens processed).
 
         Deadline-aware sizing (ISSUE 14): when a stall-budgeted class is
         decoding, the EFFECTIVE chunk shrinks to its budget — same
@@ -758,13 +772,6 @@ class ServingEngine:
         is bit-identical and ``compile_stats`` stays flat (the scalar is
         a runtime argument, not a shape).
         """
-        slot, req = None, None
-        for i, r in enumerate(self.sched.slots):
-            if (r is not None and r.state is RequestState.PREFILLING
-                    and (req is None or r.admitted_seq < req.admitted_seq)):
-                slot, req = i, r
-        if slot is None:
-            return 0
         C = self.prefill_chunk
         budget = self._step_prefill_budget()
         # the prefilling request's OWN class chunk budget (ISSUE 19):
@@ -798,22 +805,24 @@ class ServingEngine:
                            (end - 1) // self.page_size + 1):
                 self._cow_writable(req, i)
         row = self._device_bt_row(req.rid, slot)
-        t0 = time.perf_counter()
         tok_dev, self.pool = self._chunk_step(
             self.params, jnp.asarray(toks),
             jnp.asarray(start, jnp.int32), jnp.asarray(n_eff, jnp.int32),
             self.pool, jnp.asarray(row))
-        # one int32 scalar download — it fences the chunk for honest
-        # stall timing and, on the final chunk, IS the first token (the
-        # argmax ran on device; the host never sees logits)
-        tok0 = int(tok_dev)
-        dt = time.perf_counter() - t0
+        return tok_dev, n_eff, row, len(part)
+
+    def _commit_chunk(self, slot: int, req: Request, tok0: int, n_eff: int,
+                      row) -> None:
+        """The chunk's token is on the host: advance the cursor; a prompt's
+        final chunk fused the first-token argmax on device, so ``tok0`` IS
+        the first token and the slot flips to ACTIVE (mirrors set, ready
+        for this step's decode dispatch)."""
+        sp = len(req.prompt)
         req.prefill_cursor = n_eff
         self.metrics.inc("prefill_chunks")
-        self.metrics.observe("prefill_stall_s", dt)
         self._jlog("chunk", rid=req.rid, cursor=req.prefill_cursor)
         if req.prefill_cursor < sp:
-            return len(part)
+            return
         # last chunk → the slot starts decoding this very step
         req.state = RequestState.ACTIVE
         req.generated.append(tok0)
@@ -842,7 +851,6 @@ class ServingEngine:
         self._dirty = True
         if req.done:            # max_new_tokens == 1 or tok0 == eos_id
             self._finish(slot)
-        return len(part)
 
     # -- slot teardown ----------------------------------------------------
     def _finish(self, slot: int) -> None:
@@ -980,19 +988,12 @@ class ServingEngine:
         ``decode_horizon`` tokens per slot). Returns False when there is
         nothing to do (engine idle).
 
-        Thin wrapper around ``_step_impl``: the quota buckets refill and
-        the TTL expiry sweep runs before the iteration (an expired
-        request must not be admitted), ``_post_step`` after a productive
-        one (checkpoint cadence here; the sharded engine chains its
-        digest cross-check in front)."""
-        self.sched.tick(self._steps)
-        self._expire_queued()
-        progressed = self._step_impl()
-        self.metrics.counters["quota_throttled"] = \
-            self.sched.quota_throttled
-        if progressed:
-            self._post_step()
-        return progressed
+        The step is the span ``engine.step`` / the histogram ``step_s``,
+        and ``_step_impl`` cuts it into the phases of
+        ``serving.metrics.PHASES``, each a child span and a histogram of
+        its own (``ServingMetrics`` has the table)."""
+        with self.metrics.phase("step", step=self._steps) as whole:
+            return self._step_impl(whole)
 
     def _expire_queued(self) -> None:
         for req in self.sched.expire(self._steps):
@@ -1008,12 +1009,12 @@ class ServingEngine:
                        tenant=req.tenant, cls=req.cls)
 
     def _post_step(self) -> None:
+        """After a productive step (checkpoint cadence here; the sharded
+        engine chains its digest cross-check in front)."""
         self._maybe_checkpoint()
 
-    def _step_impl(self) -> bool:
-        t_begin = time.perf_counter()
-        if self.sched.idle:
-            return False
+    def _step_impl(self, whole) -> bool:
+        m, n = self.metrics, self._steps
 
         def can_hold(req: Request) -> bool:
             # a mid-prefill preemptee kept its filled pages
@@ -1027,24 +1028,116 @@ class ServingEngine:
                 avail += self.prefix_cache.evictable
             return avail >= need
 
-        while (adm := self.sched.admissible(can_hold)) is not None:
-            self._admit(*adm)
+        # the quota buckets refill and the TTL expiry sweep runs before
+        # the admissions (an expired request must not be admitted)
+        with m.phase("admit", step=n) as admit:
+            self.sched.tick(n)
+            self._expire_queued()
+            if self.sched.idle:
+                # a poll between arrivals observes nothing: its microseconds
+                # must not dilute the mean of the steps that did work
+                whole.drop()
+                admit.drop()
+                return False
+            while (adm := self.sched.admissible(can_hold)) is not None:
+                self._admit(*adm)
+            m.counters["quota_throttled"] = self.sched.quota_throttled
+            pslot, preq = self._oldest_prefilling()
 
         # ≤1 prefill chunk co-scheduled with the decode dispatch
         # (Sarathi-style): the decode stall this step is bounded by
         # prefill_chunk tokens, not a whole prompt
-        prefilled_tokens = self._dispatch_prefill_chunk()
-        self.metrics.observe("decode_stall_s",
-                             time.perf_counter() - t_begin)
-        self.metrics.observe("step_prefill_tokens", prefilled_tokens)
+        prefilled_tokens, stalled = 0, admit.t1
+        if preq is not None:
+            ids = {"step": n, "rid": preq.rid, "cursor": preq.prefill_cursor}
+            with m.phase("chunk_prep", **ids) as prep:
+                tok_dev, cursor, prow, prefilled_tokens = \
+                    self._launch_chunk(pslot, preq)
+            with m.phase("chunk_wait", **ids) as chunk_wait:
+                # one int32 scalar download — it fences the chunk for
+                # honest stall timing and, on the final chunk, IS the first
+                # token (the argmax ran on device; the host never sees
+                # logits)
+                tok0 = int(tok_dev)
+            stalled = chunk_wait.t1
+            m.observe("prefill_stall_s", stalled - prep.t0)
+        m.observe("decode_stall_s", stalled - whole.t0)
+        m.observe("step_prefill_tokens", prefilled_tokens)
 
-        # allocate-on-decode growth, preempting (youngest first) when dry.
-        # Slot order is index order — deterministic. The FIRST step is
-        # guaranteed (preempt until a page frees); the rest of the horizon
-        # is opportunistic: extend capacity page by page WITHOUT
-        # preempting, and clamp the slot's limit where growth stops — the
-        # auto-clamp that keeps a slot inside its pre-ensured pages
-        # mid-scan.
+        with m.phase("grow", step=n):
+            if preq is not None:
+                self._commit_chunk(pslot, preq, tok0, cursor, prow)
+            limits, active = self._grow()
+
+        if not active:
+            if prefilled_tokens or not self.sched.idle:
+                # the step did real work (a prefill chunk) even with no
+                # decodable row — count it and keep the loop hot. Or nothing
+                # was dispatched but work is still queued (quota-throttled
+                # or capacity-blocked): the logical clock MUST advance
+                # anyway — sched.tick(self._steps) refills the token buckets
+                # off it, so a frozen clock would turn a bounded deficit
+                # wait into permanent starvation (and a spurious
+                # stall-watchdog trip)
+                self._steps += 1
+                with m.phase("post", step=n):
+                    self._post_step()
+                return True
+            return False
+
+        if self._dirty:
+            with m.phase("sync", step=n):
+                self._sync_mirrors()
+            self._dirty = False
+            m.inc("host_syncs")
+
+        with m.phase("dispatch", step=n) as dispatch:
+            if self.spec_k:
+                (toks, acc, self._token_dev, self._pos_dev, self._hist_dev,
+                 self._hlen_dev, self.pool) = self._step(
+                    self.params, self._token_dev, self._pos_dev, self.pool,
+                    self._bt_dev, jnp.asarray(limits), self._hist_dev,
+                    self._hlen_dev)
+            else:
+                toks, self._token_dev, self._pos_dev, self.pool = self._step(
+                    self.params, self._token_dev, self._pos_dev, self.pool,
+                    self._bt_dev, jnp.asarray(limits))
+        with m.phase("decode_wait", step=n) as decode_wait:
+            # [B] committed-count vector under speculation
+            accepted = np.asarray(acc) if self.spec_k else None
+            slab = np.asarray(toks)        # [horizon, B] — blocks on device
+        with m.phase("reconcile", step=n) as reconcile:
+            n_tokens, emitted_by_slot = self._reconcile(
+                limits, active, slab, accepted)
+
+        dev_dt = decode_wait.t1 - dispatch.t0
+        host_dt = (reconcile.t1 - whole.t0) - dev_dt
+        with m.phase("post", step=n):
+            m.observe("step_device_s", dev_dt)
+            m.observe("step_host_s", host_dt)
+            per_tok = (dev_dt + host_dt) / max(n_tokens, 1)
+            for _ in range(n_tokens):
+                m.observe("tok_latency_s", per_tok)
+            # per-class ITL (ISSUE 14): the same per-token estimate, labeled
+            # by the emitting request's class — the isolation panel's number
+            for slot, req in active:
+                label = class_label(req)
+                if label is not None:
+                    for _ in range(emitted_by_slot.get(slot, 0)):
+                        m.observe_class("itl_s", label, per_tok)
+            self._post_step()
+        return True
+
+    def _grow(self):
+        """Allocate-on-decode growth, preempting (youngest first) when dry.
+        Returns (the per-slot ``limits``, the decoding (slot, request)s).
+
+        Slot order is index order — deterministic. The FIRST step is
+        guaranteed (preempt until a page frees); the rest of the horizon
+        is opportunistic: extend capacity page by page WITHOUT
+        preempting, and clamp the slot's limit where growth stops — the
+        auto-clamp that keeps a slot inside its pre-ensured pages
+        mid-scan."""
         limits = np.zeros(self.num_slots, np.int32)
         for slot in range(self.num_slots):
             req = self.sched.slots[slot]
@@ -1077,45 +1170,12 @@ class ServingEngine:
             r = self.sched.slots[slot]
             if r is None or r.state is not RequestState.ACTIVE:
                 limits[slot] = 0
+        return limits, [(s, r) for s, r in self.sched.active
+                        if r.state is RequestState.ACTIVE]
 
-        active = [(s, r) for s, r in self.sched.active
-                  if r.state is RequestState.ACTIVE]
-        if not active:
-            if prefilled_tokens:
-                # the step did real work (a prefill chunk) even with no
-                # decodable row — count it and keep the loop hot
-                self._steps += 1
-                return True
-            if self.sched.idle:
-                return False
-            # nothing dispatched but work is still queued (quota-throttled
-            # or capacity-blocked): the logical clock MUST advance anyway —
-            # sched.tick(self._steps) refills the token buckets off it, so
-            # a frozen clock would turn a bounded deficit wait into
-            # permanent starvation (and a spurious stall-watchdog trip)
-            self._steps += 1
-            return True
-
-        if self._dirty:
-            self._sync_mirrors()
-            self._dirty = False
-            self.metrics.inc("host_syncs")
-
-        t_disp = time.perf_counter()
-        if self.spec_k:
-            (toks, acc, self._token_dev, self._pos_dev, self._hist_dev,
-             self._hlen_dev, self.pool) = self._step(
-                self.params, self._token_dev, self._pos_dev, self.pool,
-                self._bt_dev, jnp.asarray(limits), self._hist_dev,
-                self._hlen_dev)
-            accepted = np.asarray(acc)     # [B] committed-count vector
-        else:
-            toks, self._token_dev, self._pos_dev, self.pool = self._step(
-                self.params, self._token_dev, self._pos_dev, self.pool,
-                self._bt_dev, jnp.asarray(limits))
-            accepted = None
-        slab = np.asarray(toks)            # [horizon, B] — blocks on device
-        t_done = time.perf_counter()
+    def _reconcile(self, limits, active, slab, accepted):
+        """Commit a dispatch's token slab to the scheduler's state. Returns
+        (tokens emitted, {slot: tokens emitted})."""
         # a family's own counters ride the same slab, one row each after
         # the token rows (decode_multistep_paged): no further download
         for j, name in enumerate(self._family.counters):
@@ -1131,7 +1191,6 @@ class ServingEngine:
         else:
             self.metrics.inc("decode_steps", int(limits.max()))
         self.metrics.observe("queue_depth", self.sched.queue_depth)
-        self.metrics.observe("pool_occupancy", self.alloc.occupancy())
         self.metrics.observe("active_slots", len(active))
         if self._ring:
             # pages held by kind: a seated sequence's ledger pages (a full
@@ -1173,22 +1232,7 @@ class ServingEngine:
                 self._finish(slot)
             elif self.spec_k and emitted < int(limits[slot]):
                 self._spec_rewind(slot, req)
-
-        dev_dt = t_done - t_disp
-        host_dt = (t_disp - t_begin) + (time.perf_counter() - t_done)
-        self.metrics.observe("step_device_s", dev_dt)
-        self.metrics.observe("step_host_s", host_dt)
-        per_tok = (dev_dt + host_dt) / max(n_tokens, 1)
-        for _ in range(n_tokens):
-            self.metrics.observe("tok_latency_s", per_tok)
-        # per-class ITL (ISSUE 14): the same per-token estimate, labeled
-        # by the emitting request's class — the isolation panel's number
-        for slot, req in active:
-            label = class_label(req)
-            if label is not None:
-                for _ in range(emitted_by_slot.get(slot, 0)):
-                    self.metrics.observe_class("itl_s", label, per_tok)
-        return True
+        return n_tokens, emitted_by_slot
 
     def run(self, max_steps: int | None = None,
             arrivals=None, recover=None) -> dict[int, list[int]]:

@@ -14,6 +14,16 @@ import json
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
+# The phases of one ``ServingEngine.step()``, in the order a step runs them.
+# Each is a span ``engine.<name>`` in the profiler's trace and an exact
+# histogram ``phase_<name>_s``, children of ``engine.step`` / ``step_s``.
+# The two ``*_wait`` block on a device program; the rest is the host's own work.
+PHASES = ("admit", "chunk_prep", "chunk_wait", "grow", "sync", "dispatch",
+          "decode_wait", "reconcile", "post")
+_PHASE_HIST = {"step": "step_s", **{p: f"phase_{p}_s" for p in PHASES}}
+
 
 class Histogram:
     """Streaming histogram: exact count/sum/min/max + a bounded sample
@@ -67,6 +77,34 @@ class Histogram:
         }
 
 
+class Phase:
+    """One timed span: a profiler annotation around a ``perf_counter()``
+    pair. ``t0`` / ``t1`` are the stamps the engine derives its older
+    timers from; ``drop()`` keeps the span and forgets the observation (a
+    step that found nothing to do must not dilute a mean)."""
+
+    __slots__ = ("_span", "_hist", "t0", "t1")
+
+    def __init__(self, span, hist):
+        self._span = span
+        self._hist = hist
+
+    def __enter__(self):
+        self._span.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        self._span.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(self.t1 - self.t0)
+        return False
+
+    def drop(self) -> None:
+        self._hist = None
+
+
 class AttainmentWindow:
     """Windowed SLO attainment over keyed latency series (ISSUE 18).
 
@@ -117,9 +155,48 @@ class ServingMetrics:
     control-plane change — admission, finish, preemption, growth; a quiet
     dispatch reuses the device-resident carry and uploads nothing);
     histograms — TTFT (s), per-token latency (s), queue depth (sampled
-    per step), pool occupancy (fraction, sampled per step), batch
-    occupancy (active slots per step), per-dispatch device time and host
-    overhead (s) — the device/host split bench.py reports.
+    per step), batch occupancy (active slots per step), per-dispatch
+    device time and host overhead (s) — the device/host split bench.py
+    reports.
+
+    The phases of a step (``phase(name, **ids)``; always on). Each is a
+    span ``engine.<name>`` on the ``/host:CPU`` plane of the profiler's
+    trace (the clock the device's ``XLA Ops`` line uses; it carries the
+    step number, and ``rid`` / ``cursor`` where it works for one request)
+    and an exact histogram; all are children of ``engine.step`` /
+    ``step_s``, and they tile it: the host-work and wait totals add up to
+    ``step_s.total``. ``snapshot()`` prints each one's mean, p50, p99 and
+    max: a stalled step shows as ``phase_decode_wait_s.max``.
+
+    ====================  ===========================================  =====
+    histogram             what the step does there                     kind
+    ====================  ===========================================  =====
+    phase_admit_s         quota refill, TTL expiry, admissions         host
+                          (prefix adoption inside), choice of the
+                          slot to prefill
+    phase_chunk_prep_s    a chunk's budget, token buffer, COW guard,   host
+                          table row, uploads and launch (rid, cursor)
+    phase_chunk_wait_s    blocked on the chunk program (rid, cursor)   wait
+    phase_grow_s          the chunk's commit, page growth and          host
+                          preemption, limits, every decoding slot's
+                          table row
+    phase_sync_s          slot mirrors uploaded after a control-plane  host
+                          change
+    phase_dispatch_s      the limits' upload and the decode launch     host
+    phase_decode_wait_s   blocked on the decode program's token slab   wait
+    phase_reconcile_s     family counters, gauges, the commit loop,    host
+                          finishes, speculation rewinds
+    phase_post_s          the closing per-token observations, then     host
+                          checkpoint cadence (sharded: digest check)
+    ====================  ===========================================  =====
+
+    ``engine.submit`` (rid) is a span alone. A ``step()`` that finds the
+    engine idle observes nothing. ``decode_stall_s`` (step start to the
+    chunk's token), ``prefill_stall_s`` (chunk prep + wait),
+    ``step_device_s`` (dispatch + decode wait) and ``step_host_s`` (step
+    start to reconcile's end, less ``step_device_s``: it still counts
+    the chunk's BLOCKING call as host time) are differences of the same
+    stamps.
     A model family's own counters (``PagedFamily.counters``: for a share of
     an expert-parallel layer, ``moe_local_rows`` = routed assignments that
     landed on the experts held here and ``moe_experts_touched`` = held
@@ -220,8 +297,8 @@ class ServingMetrics:
             "lend_degradations": 0,
             "rewarmed_prefixes": 0,
             # elastic autoscaling (ISSUE 18): fleet membership changes
-            # (replicas added / drains begun / drains reaching quiescence
-            # / replicas retired), queued requests a draining replica
+            # (replicas added / drains reaching quiescence / replicas
+            # retired), queued requests a draining replica
             # handed back through its journal cursor for re-placement on
             # a peer, total replica-steps actually run (the counterfactual
             # bench row divides this by static-peak provisioning), drain-
@@ -230,7 +307,6 @@ class ServingMetrics:
             # lend-ahead attempts that degraded to a typed no-op because
             # an engine lacked the lend surface (mixed fleets)
             "scale_ups": 0,
-            "drains_begun": 0,
             "drains_done": 0,
             "retires": 0,
             "requeues": 0,
@@ -260,11 +336,12 @@ class ServingMetrics:
             "ttft_prefill_s": Histogram(),
             "tok_latency_s": Histogram(),
             "queue_depth": Histogram(),
-            "pool_occupancy": Histogram(),
             "active_slots": Histogram(),
             "step_device_s": Histogram(),
             "step_host_s": Histogram(),
-            # per-chunk dispatch latency (one prefill chunk per step max)
+            # the whole of a step that found work, and its phases (PHASES)
+            **{name: Histogram() for name in _PHASE_HIST.values()},
+            # a chunk's prep + wait (one prefill chunk per step max)
             "prefill_stall_s": Histogram(),
             # per-step decode stall: time the step spent on admission +
             # prefill work before the decode dispatch could launch —
@@ -275,11 +352,10 @@ class ServingMetrics:
             # bound the simulator regression test asserts: max ≤ chunk)
             "step_prefill_tokens": Histogram(),
             # disaggregated serving (ISSUE 6): per-chunk migration launch
-            # latency (s), pages per migrated chunk, and how many decode-
-            # worker steps a completed prefill waited for its covering
-            # signals (0 = admitted the very step the last chunk landed)
+            # latency (s), and how many decode-worker steps a completed
+            # prefill waited for its covering signals (0 = admitted the
+            # very step the last chunk landed)
             "migrate_s": Histogram(),
-            "migrate_pages_per_chunk": Histogram(),
             "migrate_wait_steps": Histogram(),
             # robustness ladder (ISSUE 7): TTFT of requests that needed
             # at least one retry but still handed off (recovered), TTFT
@@ -341,16 +417,11 @@ class ServingMetrics:
             # lend wall time per page (µs) — the bench row
             "lend_us_per_page": Histogram(),
             # elastic autoscaling (ISSUE 18): deterministic step-space
-            # TTFT/ITL (the series the per-class SLO attainment windows
-            # sample — wall clock would make scale decisions replay-
-            # unstable), fleet size sampled once per cluster step, and
-            # the wall seconds each scale-up spent building its engine
-            # (artifact load dominates when an AOT artifact is threaded —
-            # the scale-up-to-first-token split cluster_sim reports)
+            # TTFT (wall clock would make scale decisions replay-unstable;
+            # the per-class ``ttft_steps`` / ``itl_steps`` series the SLO
+            # attainment windows sample are labeled twins, made on first
+            # touch)
             "ttft_steps": Histogram(),
-            "itl_steps": Histogram(),
-            "fleet_size": Histogram(),
-            "scale_up_build_s": Histogram(),
             # speculative decoding (ISSUE 20): tokens COMMITTED per slot
             # per verify dispatch (1 = speculation earned nothing over
             # greedy that dispatch; mean > 1 is the whole win — the bench
@@ -358,6 +429,18 @@ class ServingMetrics:
             "accepted_per_dispatch": Histogram(),
         }
         self._t0 = time.perf_counter()
+
+    # what opens a span: the profiler's annotation (a flag test while no
+    # profiler session runs). A test replaces it with a recorder.
+    span = TraceAnnotation
+
+    def phase(self, name: str, **ids) -> Phase:
+        """``with metrics.phase("grow", step=n):`` is the span
+        ``engine.grow`` carrying ``ids`` in the profiler's trace, and its
+        seconds observed by ``phase_grow_s`` (``step`` by ``step_s``; a
+        name with no histogram is a span alone)."""
+        return Phase(self.span("engine." + name, **ids),
+                     self.hist.get(_PHASE_HIST.get(name)))
 
     def inc(self, name: str, by: int = 1) -> None:
         self.counters[name] += by
@@ -443,4 +526,5 @@ class ServingMetrics:
         print(self.json_line(), file=file)
 
 
-__all__ = ["AttainmentWindow", "Histogram", "ServingMetrics"]
+__all__ = ["AttainmentWindow", "Histogram", "PHASES", "Phase",
+           "ServingMetrics"]
