@@ -108,6 +108,38 @@ def assert_replay_identical(tokens, gold, n):
     assert not bad, f"token streams diverged from the golden: rids {bad}"
 
 
+def serve_noting_victims(eng, reqs, fence=False):
+    """Submit ``reqs`` [(prompt, max_new_tokens)] and step ``eng`` until idle,
+    every chunk fenced on the host or (as the engine does) only a prompt's
+    last. Returns (tokens a request, the control plane's digest after every
+    step, whether ``_grow`` preempted a slot mid-prefill in the very step
+    whose chunk was launched and not waited for, the counters)."""
+    import jax
+    if fence:
+        chunk_step = eng._chunk_step
+        eng._chunk_step = lambda *a: jax.block_until_ready(chunk_step(*a))
+    preempt, victims = eng._preempt, []
+    not_awaited = lambda: eng.metrics.counters["chunks_not_awaited"]  # noqa: E731
+
+    def spy(slot):
+        req = eng.sched.slots[slot]
+        victims.append((req.state.value, req.prefill_cursor, not_awaited()))
+        preempt(slot)
+
+    eng._preempt = spy
+    rids = [eng.submit(prompt, n) for prompt, n in reqs]
+    digests, hit = [], False
+    while True:
+        before, seen = not_awaited(), len(victims)
+        if not eng.step():
+            break
+        digests.append(eng.control_digest())
+        hit |= any(state == "prefilling" and cursor > 0 and count == before + 1
+                   for state, cursor, count in victims[seen:])
+    done = {r.rid: list(r.generated) for r in eng._finished}
+    return [done[r] for r in rids], digests, hit, eng.metrics.counters
+
+
 def seeded_trace(n, staggered=False):
     """[(arrival step, prompt, max_new_tokens)]: prompts of 3..16 tokens (one
     to two pages of 8), 2..5 new tokens. Bursty (two arrivals a step, so a

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import TEST_WORLD  # noqa: F401
+from conftest import TEST_WORLD, serve_noting_victims  # noqa: F401
 from triton_dist_tpu.models.llama import (LlamaConfig, decode_step, generate,
                                           init_kv_cache, init_page_pool,
                                           init_params, prefill,
@@ -745,17 +745,23 @@ def test_step_opens_exactly_the_named_phases(phased_run):
             assert ids["step"] == step_no
     chunks = [(name, ids) for name, ids, _ in spans
               if name in ("engine.chunk_prep", "engine.chunk_wait")]
-    assert chunks and len(chunks) % 2 == 0
-    for (n0, prep), (n1, wait) in zip(chunks[::2], chunks[1::2]):
-        assert (n0, n1) == ("engine.chunk_prep", "engine.chunk_wait")
-        assert prep == wait and prep["rid"] in phased_run["rids"]
-        assert prep["cursor"] % 8 == 0         # whole chunks of 8 so far
-    # a prompt's chunks are its cursor advancing from 0
-    by_rid = {}
-    for _, ids in chunks[::2]:
+    length = dict(zip(phased_run["rids"], REF_PROMPT_LENS))
+    by_rid, awaited = {}, []
+    for i, (name, ids) in enumerate(chunks):
+        if name == "engine.chunk_wait":
+            # a wait follows the prep of the same chunk, and only a last one
+            assert i and chunks[i - 1] == ("engine.chunk_prep", ids)
+            assert ids["cursor"] + 8 >= length[ids["rid"]]
+            awaited.append(ids["rid"])
+            continue
+        assert ids["cursor"] % 8 == 0          # whole chunks of 8 so far
         by_rid.setdefault(ids["rid"], []).append(ids["cursor"])
-    for rid, n in zip(phased_run["rids"], REF_PROMPT_LENS):
+        if ids["cursor"] + 8 >= length[ids["rid"]]:     # the prompt's last
+            assert chunks[i + 1] == ("engine.chunk_wait", ids)
+    # a prompt's chunks are its cursor advancing from 0; one wait a prompt
+    for rid, n in length.items():
         assert by_rid[rid] == list(range(0, n, 8))
+    assert sorted(awaited) == sorted(phased_run["rids"])
     assert phased_run["tokens"] == phased_run["want"]
 
 
@@ -771,16 +777,22 @@ def test_phase_totals_tile_the_step(phased_run):
     assert hist["phase_decode_wait_s"].count == counters["dispatches"]
     assert hist["phase_reconcile_s"].count == counters["dispatches"]
     assert hist["phase_chunk_prep_s"].count == counters["prefill_chunks"]
-    assert hist["phase_chunk_wait_s"].count == counters["prefill_chunks"]
+    # only a prompt's last chunk is waited for: 1 + 1 + 2 + 3 chunks, 4 waits
+    assert counters["chunks_not_awaited"] == 3
+    assert hist["phase_chunk_wait_s"].count == len(phased_run["rids"]) \
+        == counters["prefill_chunks"] - counters["chunks_not_awaited"]
+    snap = phased_run["eng"].metrics.snapshot()
+    assert snap["chunks_not_awaited"] == 3
     assert hist["phase_sync_s"].count == counters["host_syncs"]
     assert hist["phase_admit_s"].count == hist["step_s"].count
 
 
 def test_older_timers_are_differences_of_the_phase_stamps(phased_run):
     """``step_device_s`` is dispatch + decode wait, ``prefill_stall_s`` the
-    chunk's prep + wait, ``decode_stall_s`` the step up to the chunk's
-    token, ``step_host_s`` the rest of a dispatching step before ``post``:
-    the names and counts of before, from the same stamps."""
+    chunk's prep (+ its wait where it is a prompt's last), ``decode_stall_s``
+    the step up to the chunk's launch or token, ``step_host_s`` the rest of
+    a dispatching step before ``post``: the names and counts of before, from
+    the same stamps."""
     m = phased_run["eng"].metrics
     h = m.hist
     total = lambda *names: sum(h[n].total for n in names)   # noqa: E731
@@ -821,3 +833,167 @@ def test_phase_without_a_histogram_is_a_span_alone():
     with m.phase("grow", step=1):
         pass
     assert m.hist["phase_grow_s"].count == 1
+
+
+# ---------------------------------------------------------------------------
+# a chunk whose token nobody reads is not waited for (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+class Poison:
+    """Stands in for the token of a chunk that is not its prompt's last:
+    any way of bringing it to the host raises."""
+
+    def _read(self, *a, **kw):
+        raise AssertionError("a non-final chunk's token was read on the host")
+
+    __int__ = __index__ = __array__ = _read
+
+
+class Watched:
+    """An engine whose two programs' results are watched: ``events`` keeps
+    every launch and every host read, in order. A non-final chunk's token is
+    ``Poison``; a final chunk's and the decode slab come through proxies
+    that note the read and hand over the real value."""
+
+    def __init__(self, eng):
+        self.eng, self.events = eng, []
+        chunk_step, step = eng._chunk_step, eng._step
+        events = self.events
+
+        class Read:
+            def __init__(self, what, value):
+                self.what, self.value = what, value
+
+            def __int__(self):
+                events.append("read " + self.what)
+                return int(self.value)
+
+            def __array__(self, dtype=None, copy=None):
+                events.append("read " + self.what)
+                return np.asarray(self.value, dtype)
+
+        def watched_chunk(params, toks, start, n_eff, pool, row):
+            req = eng._oldest_prefilling()[1]
+            tok, pool = chunk_step(params, toks, start, n_eff, pool, row)
+            last = int(n_eff) >= len(req.prompt)
+            events.append("final chunk" if last else "chunk")
+            return (Read("token", tok) if last else Poison()), pool
+
+        def watched_step(*args):
+            toks, *rest = step(*args)
+            events.append("decode")
+            return Read("slab", toks), *rest
+
+        eng._chunk_step, eng._step = watched_chunk, watched_step
+
+    def step_events(self):
+        """One ``step()``: the events it added."""
+        n = len(self.events)
+        assert self.eng.step()
+        return self.events[n:]
+
+
+def _chunk4_engine(tiny_model, horizon, **kw):
+    cfg, params = tiny_model
+    kw = {"num_slots": 2, "num_pages": 16, "pages_per_seq": 4, **kw}
+    return ServingEngine(params, cfg, page_size=8, prefill_chunk=4,
+                         decode_horizon=horizon, **kw)
+
+
+HORIZONS = pytest.mark.parametrize("horizon", [1, 4], ids=["k1", "k4"])
+
+
+@HORIZONS
+def test_a_non_final_chunks_token_is_never_read(tiny_model, reference_tokens,
+                                                horizon):
+    """With every non-final chunk's token poisoned the engine serves the
+    contiguous reference's tokens: it reads one token a prompt, the last
+    chunk's, through the same wrapper."""
+    prompts, want = reference_tokens
+    w = Watched(_chunk4_engine(tiny_model, horizon))
+    rids = [w.eng.submit(p, REF_NEW_TOKENS) for p in prompts]
+    res = w.eng.run(max_steps=2000)
+    assert [res[r] for r in rids] == want
+    chunks = sum(-(-n // 4) for n in REF_PROMPT_LENS)
+    assert w.events.count("chunk") == chunks - len(prompts) \
+        == w.eng.metrics.counters["chunks_not_awaited"]
+    assert w.events.count("final chunk") == w.events.count("read token") \
+        == len(prompts)
+    # a final chunk's token is read at once, before anything else is launched
+    for i, e in enumerate(w.events):
+        if e == "final chunk":
+            assert w.events[i + 1] == "read token"
+
+
+@HORIZONS
+def test_decode_is_launched_behind_a_running_chunk(tiny_model,
+                                                   reference_tokens, horizon):
+    """A row decoding while a five-chunk prompt prefills: in a step with a
+    non-final chunk the decode program is launched before the step's first
+    (and only) host read, the slab's."""
+    prompts, want = reference_tokens
+    w = Watched(_chunk4_engine(tiny_model, horizon))
+    short = w.eng.submit(prompts[0], REF_NEW_TOKENS)          # one chunk
+    assert w.step_events()[:2] == ["final chunk", "read token"]
+    long = w.eng.submit(prompts[3], REF_NEW_TOKENS)           # 19: five
+    assert w.step_events() == ["chunk", "decode", "read slab"]
+    res = w.eng.run(max_steps=2000)
+    assert [res[short], res[long]] == [want[0], want[3]]
+    assert "read token" not in w.events[2:w.events.index("final chunk", 2)]
+
+
+@HORIZONS
+def test_a_chunk_alone_leaves_its_step_in_flight(tiny_model, reference_tokens,
+                                                 horizon):
+    """A four-chunk prompt into an empty engine: three steps that are a
+    chunk alone, each returning with nothing read, then the last chunk's
+    token and the first dispatch."""
+    prompts, want = reference_tokens
+    w = Watched(_chunk4_engine(tiny_model, horizon))
+    rid = w.eng.submit(prompts[2], REF_NEW_TOKENS)            # 13 tokens
+    for cursor in (4, 8, 12):
+        assert w.step_events() == ["chunk"]
+        assert w.eng.sched.slots[0].prefill_cursor == cursor
+    assert w.eng._steps == 3                   # the clock ran all the same
+    assert w.step_events() == ["final chunk", "read token", "decode",
+                               "read slab"]
+    assert w.eng.run(max_steps=2000)[rid] == want[2]
+    h = w.eng.metrics.hist
+    assert (h["phase_chunk_prep_s"].count, h["phase_chunk_wait_s"].count) \
+        == (4, 1)
+
+
+@pytest.fixture(scope="module")
+def victim_case(tiny_model):
+    """A 10-token prompt decoding 21 tokens beside a 40-token prompt in a
+    pool of 6 pages, and the contiguous reference's tokens for both."""
+    cfg, params = tiny_model
+    rng = np.random.RandomState(11)
+    reqs = [([int(t) for t in rng.randint(1, cfg.vocab_size, size=n)], m)
+            for n, m in ((10, 21), (40, 2))]
+    want = [[int(t) for t in jax.jit(
+        lambda p, t, m=m: generate(p, t, cfg, m, max_seq=48))(
+            params, jnp.asarray([prompt], jnp.int32))[0]]
+        for prompt, m in reqs]
+    return reqs, want
+
+
+@HORIZONS
+def test_a_victim_whose_chunk_was_not_awaited(tiny_model, victim_case,
+                                              horizon):
+    """``_grow`` preempts the prefilling slot in the very step whose chunk
+    was launched and not waited for: the victim keeps its filled pages and
+    its cursor, the tokens are the reference's, and the control plane's
+    digest after every step is that of a run which fences every chunk."""
+    reqs, want = victim_case
+
+    def serve(fence):
+        eng = _chunk4_engine(tiny_model, horizon, num_pages=7,
+                             pages_per_seq=6)
+        return serve_noting_victims(eng, reqs, fence)
+
+    tokens, digests, hit, counters = serve(fence=False)
+    assert hit and counters["preemptions"] >= 1
+    assert counters["prefill_chunks"] == 13    # ceil(10/4) + ceil(40/4)
+    assert tokens == want
+    assert serve(fence=True)[:2] == (tokens, digests)

@@ -466,6 +466,49 @@ def test_a_preempted_sequence_replays_its_tokens(replay):
     assert len({tuple(t) for t in golden.values()}) == 3
 
 
+@pytest.fixture(scope="module")
+def ring_victim(model):
+    """``serve(horizon, spare, fence)``: a 15-token prompt decoding 5 tokens
+    beside a 24-token prompt through a two-slot engine whose pool has
+    ``spare`` pages free (None: all 29), every chunk fenced or not, as
+    ``conftest.serve_noting_victims`` returns it. One trace of the programs
+    a horizon serves every engine; the roomy pool's tokens at K=1 are the
+    golden."""
+    fc, pc, w = model
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(1, 256, 15), 5), (rng.integers(1, 256, 24), 2)]
+    programs = {}
+
+    def serve(horizon, spare, fence=False):
+        eng = ServingEngine(
+            w, dataclasses.replace(pc, ring_slots=0, ring_chunk=0),
+            num_slots=2, page_size=PAGE, num_pages=30, pages_per_seq=PPS,
+            prefill_chunk=CHUNK, decode_horizon=horizon)
+        eng._step, eng._chunk_step = programs.setdefault(
+            horizon, (eng._step, eng._chunk_step))
+        if spare is not None:
+            assert eng.alloc.alloc("ballast", eng.alloc.free_pages - spare)
+        return conftest.serve_noting_victims(eng, reqs, fence)
+
+    golden, _, _, roomy = serve(1, spare=None)
+    assert roomy["preemptions"] == 0
+    return serve, golden
+
+
+@pytest.mark.parametrize("horizon", [1, 4], ids=["k1", "k4"])
+def test_a_ring_victim_whose_chunk_was_not_awaited(ring_victim, horizon):
+    """With 5 pages to spare ``_grow`` preempts the prefilling slot while its
+    chunk may still be running (ISSUE 36). The victim restarts (its ring
+    stays with the slot); the tokens are the roomy pool's, and the digests
+    those of a run which fences every chunk."""
+    serve, golden = ring_victim
+    tokens, digests, hit, counters = serve(horizon, spare=5)
+    assert hit and counters["preemptions"] == 1
+    assert counters["prefill_chunks"] == 1 + 1 + 2      # the victim's again
+    assert tokens == golden
+    assert serve(horizon, spare=5, fence=True)[:2] == (tokens, digests)
+
+
 def test_the_engine_sizes_the_rings_and_counts_pages_by_kind(replay):
     eng = replay[0]
     ring = eng.cfg.ring_pages(PAGE)

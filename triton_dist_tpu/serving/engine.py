@@ -760,9 +760,10 @@ class ServingEngine:
 
     def _launch_chunk(self, slot: int, req: Request):
         """Launch ONE prefill chunk of ``req`` (a step runs at most one):
-        its cursor advances by one chunk once ``_commit_chunk`` has the
-        chunk's token. Returns (the token on device, the new cursor, the
-        slot's table row, prompt tokens processed).
+        ``_commit_chunk`` advances its cursor by one chunk. Returns (the
+        token on device, the new cursor, the slot's table row, prompt
+        tokens processed). Only a prompt's LAST chunk (new cursor ==
+        ``len(req.prompt)``) has a token anybody reads.
 
         Deadline-aware sizing (ISSUE 14): when a stall-budgeted class is
         decoding, the EFFECTIVE chunk shrinks to its budget — same
@@ -811,17 +812,20 @@ class ServingEngine:
             self.pool, jnp.asarray(row))
         return tok_dev, n_eff, row, len(part)
 
-    def _commit_chunk(self, slot: int, req: Request, tok0: int, n_eff: int,
-                      row) -> None:
-        """The chunk's token is on the host: advance the cursor; a prompt's
-        final chunk fused the first-token argmax on device, so ``tok0`` IS
-        the first token and the slot flips to ACTIVE (mirrors set, ready
-        for this step's decode dispatch)."""
+    def _commit_chunk(self, slot: int, req: Request, tok0: int | None,
+                      n_eff: int, row) -> None:
+        """Advance the cursor past a launched chunk. A chunk that is not the
+        prompt's last has no token (``tok0`` is None) and may still be
+        running: the programs that follow queue behind it on the pool it
+        writes. A prompt's final chunk fused the first-token argmax on
+        device, so ``tok0`` IS the first token and the slot flips to ACTIVE
+        (mirrors set, ready for this step's decode dispatch)."""
         sp = len(req.prompt)
         req.prefill_cursor = n_eff
         self.metrics.inc("prefill_chunks")
         self._jlog("chunk", rid=req.rid, cursor=req.prefill_cursor)
-        if req.prefill_cursor < sp:
+        if tok0 is None:
+            self.metrics.inc("chunks_not_awaited")
             return
         # last chunk → the slot starts decoding this very step
         req.state = RequestState.ACTIVE
@@ -1047,19 +1051,22 @@ class ServingEngine:
         # ≤1 prefill chunk co-scheduled with the decode dispatch
         # (Sarathi-style): the decode stall this step is bounded by
         # prefill_chunk tokens, not a whole prompt
-        prefilled_tokens, stalled = 0, admit.t1
+        prefilled_tokens, stalled, tok0 = 0, admit.t1, None
         if preq is not None:
             ids = {"step": n, "rid": preq.rid, "cursor": preq.prefill_cursor}
             with m.phase("chunk_prep", **ids) as prep:
                 tok_dev, cursor, prow, prefilled_tokens = \
                     self._launch_chunk(pslot, preq)
-            with m.phase("chunk_wait", **ids) as chunk_wait:
-                # one int32 scalar download — it fences the chunk for
-                # honest stall timing and, on the final chunk, IS the first
-                # token (the argmax ran on device; the host never sees
-                # logits)
-                tok0 = int(tok_dev)
-            stalled = chunk_wait.t1
+            stalled = prep.t1
+            if cursor >= len(preq.prompt):
+                with m.phase("chunk_wait", **ids) as chunk_wait:
+                    # one int32 scalar download: the prompt's first token
+                    # (the argmax ran on device; the host never sees logits)
+                    tok0 = int(tok_dev)
+                stalled = chunk_wait.t1
+            # (any other chunk's token is nobody's and is never read: the
+            # host goes on while the chunk runs, and what this step or the
+            # next launches queues behind it on the pool it writes)
             m.observe("prefill_stall_s", stalled - prep.t0)
         m.observe("decode_stall_s", stalled - whole.t0)
         m.observe("step_prefill_tokens", prefilled_tokens)

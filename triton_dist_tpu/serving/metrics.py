@@ -176,7 +176,9 @@ class ServingMetrics:
                           slot to prefill
     phase_chunk_prep_s    a chunk's budget, token buffer, COW guard,   host
                           table row, uploads and launch (rid, cursor)
-    phase_chunk_wait_s    blocked on the chunk program (rid, cursor)   wait
+    phase_chunk_wait_s    a prompt's LAST chunk alone: blocked on the  wait
+                          chunk program for the first token (rid,
+                          cursor); no other chunk is waited for
     phase_grow_s          the chunk's commit, page growth and          host
                           preemption, limits, every decoding slot's
                           table row
@@ -191,12 +193,16 @@ class ServingMetrics:
     ====================  ===========================================  =====
 
     ``engine.submit`` (rid) is a span alone. A ``step()`` that finds the
-    engine idle observes nothing. ``decode_stall_s`` (step start to the
-    chunk's token), ``prefill_stall_s`` (chunk prep + wait),
-    ``step_device_s`` (dispatch + decode wait) and ``step_host_s`` (step
-    start to reconcile's end, less ``step_device_s``: it still counts
-    the chunk's BLOCKING call as host time) are differences of the same
-    stamps.
+    engine idle observes nothing. A chunk that is not its prompt's last has
+    no token anybody reads (counter ``chunks_not_awaited``): the step goes
+    on while it runs, the decode program queues behind it, and a step that
+    is such a chunk alone returns with the chunk in flight.
+    ``decode_stall_s`` (step start to the chunk's launch, or to its token
+    where it is the last), ``prefill_stall_s`` (chunk prep, + wait where
+    there is one), ``step_device_s`` (dispatch + decode wait: it holds the
+    tail of a chunk that was not waited for) and ``step_host_s`` (step
+    start to reconcile's end, less ``step_device_s``: it counts a LAST
+    chunk's blocking call as host time) are differences of the same stamps.
     A model family's own counters (``PagedFamily.counters``: for a share of
     an expert-parallel layer, ``moe_local_rows`` = routed assignments that
     landed on the experts held here and ``moe_experts_touched`` = held
@@ -229,6 +235,11 @@ class ServingMetrics:
             # the contiguous-cache converters and the host argmax never
             # run (tests assert this via prefill_chunks > 0)
             "prefill_chunks": 0,
+            # of those, the chunks whose token the step never read (every
+            # chunk but a prompt's last): the host went on while they ran,
+            # so prefill_chunks - chunks_not_awaited ==
+            # phase_chunk_wait_s.count
+            "chunks_not_awaited": 0,
             # sharded serving (ISSUE 8): replicated-decision digest
             # cross-checks run (each one all-gathered the control-plane
             # digest over the mesh and compared every rank to rank 0)
