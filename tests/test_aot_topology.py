@@ -839,3 +839,111 @@ def test_hybrid_state_and_pages_stay_in_place(hybrid_programs, program):
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
     # the state leaf is 1.64 GB: no copy of it fits under this
     assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+
+
+# -- the sink-window family at MiMo-V2-Flash widths (ISSUE 37) ---------------------
+
+SINK_SLOTS, SINK_P, SINK_PPS = 24, 2593, 108
+
+
+def _sink_window_cfg(periods=1):
+    """MiMo-V2-Flash as published (every width; window 128; 8 / 4 KV heads;
+    keys of 192 in 256 lanes, values of 128), the leading dense layer and
+    ``periods`` periods, 32 of 256 experts, 1/8 of the vocabulary."""
+    from triton_dist_tpu.models import window_moe as wm
+    return wm.bind(wm.WindowMoEConfig(
+        vocab_size=19072, d_model=4096, n_layers=1 + 6 * periods, n_heads=64,
+        n_kv_heads=8, full_kv_heads=4, head_dim=192, v_head_dim=128,
+        k_pool_width=256, window=128,
+        layer_kinds=("window",) * 4 + ("full", "window"), rope_dims=64,
+        rope_theta=1e4, full_rope_theta=5e6, sinks=True, value_scale=0.707,
+        n_dense_layers=1, d_ff=16384, moe_d_ff=2048, n_routed_experts=256,
+        n_experts_held=32, topk=8, n_shared_experts=0, selection_bias=True,
+        sequential=True, max_seq_len=SINK_PPS * 128), SINK_SLOTS, 512)
+
+
+def _sink_window_lowered(topo, cfg):
+    from jax.sharding import SingleDeviceSharding
+    from triton_dist_tpu.models import window_moe as wm
+    from triton_dist_tpu.models.llama import (decode_multistep_paged,
+                                              prefill_chunk_paged)
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
+    params = on(jax.eval_shape(lambda k: wm.init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pool = on(jax.eval_shape(lambda: cfg.paged.init_pool(cfg, SINK_P, 128)))
+    B, K, C, W = SINK_SLOTS, 4, 512, SINK_PPS + 1
+    return {
+        "decode": jax.jit(
+            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
+                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
+            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
+                                       i32(B, W), i32(B)),
+        "chunk": jax.jit(
+            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
+                p, t, s, n, cfg, pages, bt),
+            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
+                                       i32(W))}
+
+
+@pytest.fixture(scope="module")
+def sink_window_programs(topo):
+    """The engine's two programs at the benchmark cell's sizes (24 slots, K =
+    4, chunk 512, 108 pages a sequence and the ring's column), lowered for
+    one described v5e, pool donated. Name -> (optimised HLO text, memory
+    analysis, configuration)."""
+    cfg = _sink_window_cfg()
+    out = {}
+    for name, low in _sink_window_lowered(topo, cfg).items():
+        exe = low.compile()
+        out[name] = (exe.as_text(), exe.memory_analysis(), cfg)
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_sink_window_pools_stay_in_place(sink_window_programs, program):
+    """Mosaic takes the paged GQA kernels at keys of 256 lanes beside values
+    of 128, groups of 8 and 16, a sink and a one-page window; the trace will
+    find each variant by its own name; and nothing shaped like a pool leaf or
+    a layer of one comes out of a ``copy`` or a slice (a 192-wide minor dim
+    invites a re-layout: the pool's keys are held in 256 lanes). The decode
+    program still re-lays out ``wq`` / ``wk`` / ``wv`` once a dispatch, as in
+    every family (ROADMAP A10): 0.8 GB of its temporaries here."""
+    import re
+    text, mem, cfg = sink_window_programs[program]
+    kernels = {"decode": ("gqa_decode_paged_window_sink", "gqa_decode_paged"),
+               "chunk": ("gqa_prefill_paged_window_sink",
+                         "gqa_prefill_paged")}[program]
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), kernel
+    ring = 1 + SINK_SLOTS * cfg.ring_pages(128)
+    leaves = [f"{SINK_P},4,128,256]", f"{SINK_P},4,128,128]",
+              f"{ring},8,128,256]", f"{ring},8,128,128]"]
+    big = [f"[{n},{s}" for s in leaves for n in (1, 2, 5)] \
+        + [f"[{s}" for s in leaves]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        kind = name if opcode == "fusion" else opcode
+        if any(s in result for s in big) and re.search(
+                r"copy|slice", kind) and not re.search(r"update.slice", kind):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    pool_bytes = 2 * (2 * SINK_P * 4 + 5 * ring * 8) * 128 * (256 + 128)
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
+
+
+def test_sink_window_segments_are_one_scanned_body_each(topo):
+    """A leading dense layer and TWO periods of six layers whose kinds differ
+    in shape lower to one ``while`` a segment (13 layers, not 13 bodies): the
+    chunk program has two, the decode program two inside its horizon's one."""
+    low = _sink_window_lowered(topo, _sink_window_cfg(periods=2))
+    whiles = {name: lo.as_text().count("stablehlo.while")
+              for name, lo in low.items()}
+    assert whiles == {"chunk": 2, "decode": 3}, whiles

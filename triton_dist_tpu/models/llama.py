@@ -441,10 +441,12 @@ class PagedFamily:
       arrays ``[layer, page, ...]`` (the engine's page copy, export and
       import map over its leaves; the ledger never sees its shape).
     - ``segments(cfg, params)``: the runs of layers that share a body, in
-      order: ``[(blocks, first_layer, n_layers, ffn)]`` with ``blocks`` the
-      run's stacked per-layer params and ``ffn(cfg, p, h, layer, active) ->
-      (out [R, D], counts)``; ``counts`` maps names of ``counters`` to int32
-      scalars (``{}``: none).
+      order: ``[(blocks, first_layer, n_layers, ffn[, period])]`` with
+      ``blocks`` the run's stacked per-layer params and ``ffn(cfg, p, h,
+      layer, active) -> (out [R, D], counts)``; ``counts`` maps names of
+      ``counters`` to int32 scalars (``{}``: none). A run may bring its OWN
+      ``period`` (a leading dense run of one kind of layer before a periodic
+      one); without one it has the family's.
     - ``attention(cfg, p, h, layer, pool, block_table, pos, kv_len, active,
       shared_table, lin, attn_io) -> (out [R, D], pool[, counts])``:
       projections, the pool write, the paged walk and the output projection
@@ -454,7 +456,12 @@ class PagedFamily:
       ``attention`` above; say three window layers and a full one). The
       period is what the layer loop scans: its body is ``len(period)``
       layers, so 32 layers of period 4 are 8 trips of one compiled body.
-      None: every layer runs ``attention`` (a period of one).
+      None: every layer runs ``attention`` (a period of one). Where the
+      kinds of a period differ in SHAPE (other KV heads, a sink), an entry
+      is ``(kind, attention)`` and the run's ``blocks`` is ``{kind: stack}``,
+      each kind's layers stacked on their own: the body takes a period's
+      slice of every stack, and layer j of the period is the next of its
+      kind's.
     - ``norm(x, w, eps)``: the layers' and the head's norm (``rmsnorm``).
     - ``parallel``: the block form. False: ``x + attn(norm(x))`` then ``x +
       ffn(norm(x))``, two norms a layer. True: ``x + attn(u) + ffn(u)`` with
@@ -609,10 +616,11 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     summed over the layers, in ``counters``' order).
 
     The body that is scanned is ONE PERIOD of the family's layer pattern
-    (``PagedFamily.period``; one layer where every layer is of one kind):
-    its params are the run's stacked ``[n, ...]`` arrays seen as
-    ``[n / period, period, ...]`` (a free view), its layer index the period's
-    first.
+    (``PagedFamily.period``, or the run's own; one layer where every layer
+    is of one kind): its params are the run's stacked ``[n, ...]`` arrays
+    seen as ``[n / period, period, ...]`` (a free view), or, where the kinds
+    differ in shape, each kind's stack seen as ``[n / period, that kind's
+    layers a period, ...]``; its layer index is the period's first.
 
     The pool is the loop's CARRY, never its per-layer input or output:
     ``lax.scan`` cannot alias an ``xs`` to a ``ys``, so scanning over the
@@ -627,8 +635,7 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     ``V[i]`` and its result is put back at the static index."""
     fam = cfg.paged
     norm = fam.norm or rmsnorm
-    period = fam.period(cfg) if fam.period else (fam.attention,)
-    P = len(period)
+    family_period = fam.period(cfg) if fam.period else (fam.attention,)
     lin = linear or (lambda h, w, name: h @ w)
     hooked = not (ffn is None and attn_io is None and linear is None)
 
@@ -658,27 +665,43 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
         return x, pool, counts
 
     carry = (x, pages, {name: jnp.int32(0) for name in fam.counters})
-    for blocks, first, n, seg_ffn in fam.segments(cfg, params):
+    for blocks, first, n, seg_ffn, *own in fam.segments(cfg, params):
+        period = own[0] if own else family_period
+        P = len(period)
         assert n % P == 0, f"{n} layers are no whole number of periods of {P}"
+        # (attention, its stack, its rank among the period's layers of that
+        # stack) of every layer of the period, and each stack's layers a
+        # period: ONE stack (``None``) of P where the layers share a shape
+        plan, each = [], {}
+        for entry in period:
+            kind, attention = entry if isinstance(entry, tuple) \
+                else (None, entry)
+            plan.append((attention, kind, each.get(kind, 0)))
+            each[kind] = each.get(kind, 0) + 1
+        stacks = {None: blocks} if None in each else blocks
 
-        def body(carry, xs, seg_ffn=seg_ffn):
-            p, i = xs               # a period's params [P, ...], its first
-            for j, attention in enumerate(period):
-                carry = layer(carry, LayerParams(p, j) if P > 1 else p,
+        def body(carry, xs, seg_ffn=seg_ffn, plan=plan, whole=P == 1):
+            p, i = xs       # a period's params {stack: [c, ...]}, its first
+            for j, (attention, kind, rank) in enumerate(plan):
+                carry = layer(carry,
+                              p[kind] if whole else LayerParams(p[kind], rank),
                               i + j if j else i, attention, seg_ffn)
             return carry, None
 
         if hooked:
             for j in range(n):
-                carry = layer(carry, LayerParams(blocks, j), first + j,
-                              period[j % P], seg_ffn)
+                attention, kind, rank = plan[j % P]
+                carry = layer(carry, LayerParams(
+                    stacks[kind], (j // P) * each[kind] + rank), first + j,
+                    attention, seg_ffn)
         else:
             firsts = jnp.arange(first, first + n, dtype=jnp.int32)
             if P > 1:
-                blocks = jax.tree.map(
-                    lambda a: a.reshape((n // P, P) + a.shape[1:]), blocks)
+                stacks = {kind: jax.tree.map(
+                    lambda a, c=c: a.reshape((n // P, c) + a.shape[1:]),
+                    stacks[kind]) for kind, c in each.items()}
                 firsts = firsts[::P]
-            carry, _ = lax.scan(body, carry, (blocks, firsts))
+            carry, _ = lax.scan(body, carry, (stacks, firsts))
     x, pages, counts = carry
     return x, pages, tuple(counts[name] for name in fam.counters)
 

@@ -41,17 +41,27 @@ from triton_dist_tpu.utils import default_interpret
 NEG_INF = -1e30
 
 
-def _softmax_init(acc, m_i, l_i):
+def _softmax_init(acc, m_i, l_i, sink=None):
+    """An empty running softmax. With ``sink`` (a learned logit a query head,
+    shaped as ``m_i``, whose value is zero) the sink is its first term: it
+    starts the running maximum and holds exp(sink - m) = 1 of the sum, and
+    every later update rescales it with the rest. Nothing is added to
+    ``acc``: rows then sum to less than one."""
     acc[...] = jnp.zeros_like(acc)
-    m_i[...] = jnp.full_like(m_i, NEG_INF)
-    l_i[...] = jnp.zeros_like(l_i)
+    if sink is None:
+        m_i[...] = jnp.full_like(m_i, NEG_INF)
+        l_i[...] = jnp.zeros_like(l_i)
+    else:
+        m_i[...] = sink
+        l_i[...] = jnp.ones_like(l_i)
 
 
 def _softmax_update(start, kv_len, q, k, v, acc, m_i, l_i, *, block_s: int,
                     sm_scale: float, n_kv_heads: int, lo=None):
-    """One online-softmax update: the KV block ``k`` / ``v`` [Hkv, block_s,
-    D], whose first key sits at position ``start``, against all Hq query
-    heads ``q`` [Hq, D] of one row, as a [Hkv, G, ·] batched
+    """One online-softmax update: the KV block ``k`` [Hkv, block_s, Dk] /
+    ``v`` [Hkv, block_s, Dv], whose first key sits at position ``start``,
+    against all Hq query heads ``q`` [Hq, Dk] of one row (``acc`` [Hq, Dv];
+    keys and values may differ in width), as a [Hkv, G, ·] batched
     contraction (Mosaic needs the last-two block dims full/aligned, so heads
     are not split). Keys at ``kv_len`` and beyond are masked, and with ``lo``
     (a sliding window's bound) those below it. Analog of
@@ -63,7 +73,7 @@ def _softmax_update(start, kv_len, q, k, v, acc, m_i, l_i, *, block_s: int,
     # operands stay in the input dtype (f32 accumulate): upcasting
     # bf16 first would run the MXU at its slower f32 rate (see the
     # ring-attention pipeline note)
-    q = q.reshape(n_kv_heads, G, D)
+    q = q.reshape(n_kv_heads, G, q.shape[-1])
     scores = jax.lax.dot_general(
         q, k, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * sm_scale  # [Hkv, G, bs]
@@ -112,10 +122,10 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
         lambda: _softmax_finish(out_ref, lse_ref, acc, m_i, l_i))
 
 
-def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                         out_ref, lse_ref, k_buf, v_buf, sem, acc, m_i, l_i,
-                         *, n_pool: int, page_size: int, sm_scale: float,
-                         n_kv_heads: int, window: int | None = None):
+def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, *refs,
+                         n_pool: int, page_size: int, sm_scale: float,
+                         n_kv_heads: int, window: int | None = None,
+                         sinks: bool = False):
     """Grid (row blocks,) over a paged KV pool left in HBM: ONE loop over the
     block's LIVE pages alone, rows in order and a row's pages in order, each
     fetched by hand (``bt_ref[row, idx]`` of layer ``layer_ref[0]``, straight
@@ -130,7 +140,14 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
     With ``window`` a row attends its last ``window`` keys alone: its walk
     starts at the page of key ``kv_len - window`` (no page before it is a
     step or a DMA), that first page is masked below the bound, and the block
-    table is a RING: logical page ``idx`` lives in column ``idx % columns``."""
+    table is a RING: logical page ``idx`` lives in column ``idx % columns``.
+
+    With ``sinks`` one more operand follows ``q_ref``: ``sink_ref`` [Hq, 1]
+    float32, the learned logit a query head that every live row's softmax
+    starts from (``_softmax_init``)."""
+    sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
+    k_hbm, v_hbm, out_ref, lse_ref, k_buf, v_buf, sem, acc, m_i, l_i = refs
+    sink = None if sink_ref is None else sink_ref[...]
     rows, pages_per_seq = q_ref.shape[0], bt_ref.shape[1]
     row0 = pl.program_id(0) * rows
     end = row0 + rows
@@ -195,7 +212,7 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, k_hbm, v_hbm,
         start(*pages[-1], (w + depth - 1) % depth)
         (row, idx), buf = pages[0], w % depth
         pl.when(idx == first_page(row)[1])(
-            lambda: _softmax_init(acc, m_i, l_i))
+            lambda: _softmax_init(acc, m_i, l_i, sink))
         for copy in fetch(row, idx, buf):
             copy.wait()
         r = row - row0
@@ -279,11 +296,19 @@ def _window_pages(window: int, page_size: int, rows: int) -> int:
     return -(-(window + rows - 1) // page_size) + 1
 
 
-def _check_ring(window, ring: int, page_size: int, rows: int) -> None:
+def _check_ring(window, ring: int, page_size: int, rows: int,
+                widths: tuple) -> None:
     assert window is None or (window >= 1 and ring >= _window_pages(
         window, page_size, rows)), (
-        f"a ring of {ring} pages of {page_size} cannot hold a window of "
-        f"{window} keys for {rows} consecutive rows")
+        f"a ring of {ring} pages of {page_size} (keys and values "
+        f"{' and '.join(str(w) for w in widths)} wide) cannot hold a window "
+        f"of {window} keys for {rows} consecutive rows")
+
+
+def _kernel_name(base: str, window, sinks) -> str:
+    """A paged GQA kernel's name in a trace: one a variant."""
+    return base + ("_window" if window else "") + (
+        "" if sinks is None else "_sink")
 
 
 def _as_stack(k_pages, v_pages, layer):
@@ -332,7 +357,8 @@ DECODE_PAGES_IN_FLIGHT = 2
 def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      block_table: jax.Array, kv_len: jax.Array,
                      sm_scale: float | None = None, layer=None,
-                     window: int | None = None):
+                     window: int | None = None,
+                     sinks: jax.Array | None = None):
     """Paged-attention decode over a shared KV page pool (the serving-side
     cache layout; parity with the reference's block_table path and its
     ``ref_paged_attn`` golden, test_sp_decode_attn.py:81-134).
@@ -382,23 +408,40 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     and touches no page before it: a row costs its window, not its context.
     ``ring`` pages must span the window plus one page. The kernel's name in
     a trace is then ``gqa_decode_paged_window``.
+
+    Keys and values may differ in WIDTH: q [B, Hq, Dk] against k_pages
+    [..., Dk] and v_pages [..., Dv] gives out [B, Hq, Dv] (``sm_scale``
+    defaults to ``Dk ** -0.5``: a pool whose keys are zero-padded to a lane
+    multiple passes the head's own). ``sinks`` [Hq] float32 (None = all of
+    the above, the same program to the bit) is a learned logit a query head
+    that joins every row's softmax with a value of zero: ``p_j = exp(a_j - m)
+    / (sum_j exp(a_j - m) + exp(s - m))``, ``m = max(max_j a_j, s)``; rows
+    then sum to less than one, and ``lse`` counts the sink. The kernel's name
+    gains ``_sink``.
     """
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
-    B, Hq, D = q.shape
+    B, Hq, Dk = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
+    Dv = v_pages.shape[-1]
+    assert k_pages.shape[-1] == Dk and v_pages.shape[:-1] == k_pages.shape[:-1]
     assert Hq % Hkv == 0
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     pages_per_seq = block_table.shape[1]
-    _check_ring(window, pages_per_seq, page_size, rows=1)
+    _check_ring(window, pages_per_seq, page_size, 1, (Dk, Dv))
     Rb = math.gcd(B, DECODE_ROWS_PER_BLOCK)
-    sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(Dk)
     rows = lambda i, *_: (i, 0, 0)                          # noqa: E731
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    page_buf = pltpu.VMEM((DECODE_PAGES_IN_FLIGHT + 1, Hkv, page_size, D),
-                          k_pages.dtype)
+    page_buf = lambda D: pltpu.VMEM(                        # noqa: E731
+        (DECODE_PAGES_IN_FLIGHT + 1, Hkv, page_size, D), k_pages.dtype)
     kernel = functools.partial(_decode_paged_kernel, n_pool=P_pool,
                                page_size=page_size, sm_scale=sm_scale,
-                               n_kv_heads=Hkv, window=window)
+                               n_kv_heads=Hkv, window=window,
+                               sinks=sinks is not None)
+    extra, extra_specs = (), []
+    if sinks is not None:
+        extra = (sinks.astype(jnp.float32).reshape(Hq, 1),)
+        extra_specs = [pl.BlockSpec((Hq, 1), lambda i, *_: (0, 0))]
     # what a row can read at most: its window's pages, or the whole table
     live = min(pages_per_seq, _window_pages(window, page_size, 1)) \
         if window else pages_per_seq
@@ -407,31 +450,33 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B // Rb,),
-            in_specs=[pl.BlockSpec((Rb, Hq, D), rows), in_hbm, in_hbm],
+            in_specs=[pl.BlockSpec((Rb, Hq, Dk), rows), *extra_specs,
+                      in_hbm, in_hbm],
             out_specs=[
-                pl.BlockSpec((Rb, Hq, D), rows),
+                pl.BlockSpec((Rb, Hq, Dv), rows),
                 pl.BlockSpec((Rb, Hq, 128), rows),
             ],
             scratch_shapes=[
-                page_buf, page_buf,
+                page_buf(Dk), page_buf(Dv),
                 pltpu.SemaphoreType.DMA((2, DECODE_PAGES_IN_FLIGHT + 1)),
-                pltpu.VMEM((Hq, D), jnp.float32),
+                pltpu.VMEM((Hq, Dv), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
             ],
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, 128), jnp.float32),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * Hq * live * page_size * D,
+            flops=2 * B * Hq * live * page_size * (Dk + Dv),
             bytes_accessed=(q.size
-                            + B * live * Hkv * page_size * D * 2),
+                            + B * live * Hkv * page_size * (Dk + Dv)),
             transcendentals=B * Hq * live * page_size),
-        name="gqa_decode_paged_window" if window else "gqa_decode_paged",
+        name=_kernel_name("gqa_decode_paged", window, sinks),
         interpret=default_interpret(),
-    )(kv_len.astype(jnp.int32), block_table, layer, q, k_pages, v_pages)
+    )(kv_len.astype(jnp.int32), block_table, layer, q, *extra, k_pages,
+      v_pages)
 
 
 # Rows of a prefill chunk that share one walk of the sequence's pages: with
@@ -443,7 +488,7 @@ PREFILL_ROWS_PER_BLOCK = 64
 
 
 def _prefill_paged_kernel(*refs, page_size: int, sm_scale: float,
-                          window: int | None = None):
+                          window: int | None = None, sinks: bool = False):
     """Grid (row blocks, pages). ``q_ref`` [Hkv, M, D] is a block of
     Rb rows x G heads a KV head (M = Rb * G), ``klr_ref`` [M, 1] their
     ``kv_len``, ``kl_ref[i]`` the largest of block i; ``k_ref`` / ``v_ref``
@@ -452,17 +497,19 @@ def _prefill_paged_kernel(*refs, page_size: int, sm_scale: float,
     where decode has G, and the ``kv_len`` mask per row. With ``window``
     grid step s is logical page ``first_ref[i] + s`` (the page of the
     block's lowest bound) and a row's keys below ``kv_len - window`` are
-    masked too."""
-    if window:
-        (kl_ref, _, _, first_ref, q_ref, klr_ref, k_ref, v_ref, out_ref,
-         acc, m_i, l_i) = refs
-    else:
-        (kl_ref, _, _, q_ref, klr_ref, k_ref, v_ref, out_ref,
-         acc, m_i, l_i) = refs
+    masked too. With ``sinks`` one more operand follows ``klr_ref``:
+    ``sink_ref`` [Hkv, M, 1] float32, every (head, row)'s learned logit, which
+    the softmax starts from (``_softmax_init``)."""
+    kl_ref, _, _, *refs = refs
+    first_ref, refs = (refs[0], refs[1:]) if window else (None, refs)
+    q_ref, klr_ref, *refs = refs
+    sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
+    k_ref, v_ref, out_ref, acc, m_i, l_i = refs
     i, s = pl.program_id(0), pl.program_id(1)
     page = first_ref[i] + s if window else s
 
-    pl.when(s == 0)(lambda: _softmax_init(acc, m_i, l_i))
+    pl.when(s == 0)(lambda: _softmax_init(
+        acc, m_i, l_i, None if sink_ref is None else sink_ref[...]))
 
     # a page that no row of the block can see: no compute (and no DMA, the
     # index map revisits the block's last live page)
@@ -480,7 +527,8 @@ def _prefill_paged_kernel(*refs, page_size: int, sm_scale: float,
         # adds exp(NEG_INF - m) = 0; rows that see nothing are zeroed below.
         # (Under a window a later row of the block may see nothing of the
         # block's first pages: it adds exp(0) there, and its first real key
-        # scales all of that by exp(NEG_INF - m) = 0.)
+        # scales all of that by exp(NEG_INF - m) = 0. With a sink the
+        # running max is real from the start and a masked page adds 0.)
         seen = pos < klr_ref[...]
         if window:
             seen = jnp.logical_and(seen, pos >= klr_ref[...] - window)
@@ -507,7 +555,8 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       sm_scale: float | None = None, layer=None,
                       rows_per_block: int = PREFILL_ROWS_PER_BLOCK,
                       window: int | None = None,
-                      vmem_limit_bytes: int | None = None) -> jax.Array:
+                      vmem_limit_bytes: int | None = None,
+                      sinks: jax.Array | None = None) -> jax.Array:
     """``gqa_decode_paged`` for rows that all belong to ONE sequence (a
     prefill chunk's queries): the same online-softmax walk of the block
     table, shared by ``rows_per_block`` rows at a time, so that a page of the
@@ -537,23 +586,33 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     touch, from the page of the lowest bound, never over the pages before.
     The kernel's name in a trace is then ``gqa_prefill_paged_window``.
     ``vmem_limit_bytes`` raises Mosaic's scoped-VMEM limit for a block that
-    needs more than its 16 MB default."""
+    needs more than its 16 MB default. Keys and values of different widths
+    (q [C, Hq, Dk], out [C, Hq, Dv]) and ``sinks`` [Hq] float32 are
+    ``gqa_decode_paged``'s; the name gains ``_sink``."""
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
-    C, Hq, D = q.shape
+    C, Hq, Dk = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
+    Dv = v_pages.shape[-1]
+    assert k_pages.shape[-1] == Dk and v_pages.shape[:-1] == k_pages.shape[:-1]
     assert Hq % Hkv == 0 and block_table.ndim == 1, (q.shape, block_table.shape)
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     G = Hq // Hkv
     Rb = math.gcd(C, rows_per_block)
     n_blk, M = C // Rb, Rb * G
     pages_per_seq = block_table.shape[0]
-    _check_ring(window, pages_per_seq, page_size, rows=C)
-    sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
+    _check_ring(window, pages_per_seq, page_size, C, (Dk, Dv))
+    sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(Dk)
     kv_len = kv_len.astype(jnp.int32)
     kl_blk = kv_len.reshape(n_blk, Rb).max(axis=1)
     kl_rows = jnp.repeat(kv_len, G)[:, None]                # [C * G, 1]
     # head-major rows: a KV head's operand is its G query heads of every row
-    q_hm = q.reshape(C, Hkv, G, D).swapaxes(0, 1).reshape(Hkv, C * G, D)
+    q_hm = q.reshape(C, Hkv, G, Dk).swapaxes(0, 1).reshape(Hkv, C * G, Dk)
+    extra, extra_specs = (), []
+    if sinks is not None:
+        # the sink of (head, block row r, group head g) is its query head's
+        extra = (jnp.tile(sinks.astype(jnp.float32).reshape(Hkv, 1, G),
+                          (1, Rb, 1)).reshape(Hkv, M, 1),)
+        extra_specs = [pl.BlockSpec((Hkv, M, 1), lambda i, s, *_: (0, 0, 0))]
 
     def page_index(i, s, kl, bt, ly, *first):
         last = jnp.maximum((kl[i] + page_size - 1) // page_size - 1, 0)
@@ -564,7 +623,8 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
 
     rows = lambda i, s, *_: (0, i, 0)                       # noqa: E731
-    page_block = pl.BlockSpec((None, 1, Hkv, page_size, D), page_index)
+    page_block = lambda D: pl.BlockSpec(                    # noqa: E731
+        (None, 1, Hkv, page_size, D), page_index)
     scalars = (kl_blk, block_table, layer)
     n_pages = pages_per_seq
     if window:
@@ -580,34 +640,36 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             vmem_limit_bytes=vmem_limit_bytes)}
     out = pl.pallas_call(
         functools.partial(_prefill_paged_kernel, page_size=page_size,
-                          sm_scale=sm_scale, window=window),
+                          sm_scale=sm_scale, window=window,
+                          sinks=sinks is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(n_blk, n_pages),
             in_specs=[
-                pl.BlockSpec((Hkv, M, D), rows),
+                pl.BlockSpec((Hkv, M, Dk), rows),
                 pl.BlockSpec((M, 1), lambda i, s, *_: (i, 0)),
-                page_block,
-                page_block,
+                *extra_specs,
+                page_block(Dk),
+                page_block(Dv),
             ],
-            out_specs=pl.BlockSpec((Hkv, M, D), rows),
+            out_specs=pl.BlockSpec((Hkv, M, Dv), rows),
             scratch_shapes=[
-                pltpu.VMEM((Hkv, M, D), jnp.float32),
+                pltpu.VMEM((Hkv, M, Dv), jnp.float32),
                 pltpu.VMEM((Hkv, M, 1), jnp.float32),
                 pltpu.VMEM((Hkv, M, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((Hkv, C * G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hkv, C * G, Dv), q.dtype),
         cost_estimate=pl.CostEstimate(
-            flops=4 * live * Hq * D,
-            bytes_accessed=(2 * q.size + n_blk * n_pages * Hkv
-                            * page_size * D * 2) * q.dtype.itemsize,
+            flops=2 * live * Hq * (Dk + Dv),
+            bytes_accessed=(q.size + C * Hq * Dv + n_blk * n_pages * Hkv
+                            * page_size * (Dk + Dv)) * q.dtype.itemsize,
             transcendentals=live * Hq),
-        name="gqa_prefill_paged_window" if window else "gqa_prefill_paged",
+        name=_kernel_name("gqa_prefill_paged", window, sinks),
         interpret=default_interpret(),
         **params,
-    )(*scalars, q_hm, kl_rows, k_pages, v_pages)
-    return out.reshape(Hkv, C, G, D).swapaxes(0, 1).reshape(C, Hq, D)
+    )(*scalars, q_hm, kl_rows, *extra, k_pages, v_pages)
+    return out.reshape(Hkv, C, G, Dv).swapaxes(0, 1).reshape(C, Hq, Dv)
 
 
 def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
@@ -651,6 +713,10 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     """
     assert (layer is not None) == (k_pages.ndim == 5), (
         "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
+    # keys and values may differ in width (k_new [B, Hkv, Dk], v_new [B,
+    # Hkv, Dv]): a row's index in the two 2-D views is the same
+    assert k_pages.shape[:-1] == v_pages.shape[:-1], (k_pages.shape,
+                                                      v_pages.shape)
     idx = _page_row_index(k_pages.shape, block_table, pos, active, layer)
     return _write_rows(k_pages, k_new, idx), _write_rows(v_pages, v_new, idx)
 
