@@ -536,6 +536,52 @@ def _kernel_grids(jaxpr, found):
     return found
 
 
+def _engine_programs(topo, cfg, init_params, pages, B, C, W, K=4):
+    """The engine's two model functions for ``cfg`` and their abstract
+    arguments on one described v5e, as the engine hands them over:
+    ``(step, chunk, params, step_rest, chunk_rest)``. ``pages`` pages of 128
+    rows, ``B`` slots, a chunk of ``C`` rows, a table ``W`` wide, K token-
+    steps a dispatch."""
+    from jax.sharding import SingleDeviceSharding
+    from triton_dist_tpu.models.llama import (decode_multistep_paged,
+                                              prefill_chunk_paged)
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
+    params = on(jax.eval_shape(lambda k: init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pool = on(jax.eval_shape(lambda: cfg.paged.init_pool(cfg, pages, 128)))
+
+    def step(p, t, pos, pg, bt, lim):
+        return decode_multistep_paged(p, t, pos, cfg, pg, bt, lim,
+                                      horizon=K, eos_id=None)
+
+    def chunk(p, t, s, n, pg, bt):
+        return prefill_chunk_paged(p, t, s, n, cfg, pg, bt)
+
+    return (step, chunk, params, (i32(B), i32(B), pool, i32(B, W), i32(B)),
+            (i32(C), i32(), i32(), pool, i32(W)))
+
+
+def _plain_jits(step, chunk, params, step_rest, chunk_rest):
+    """The two programs as plain ``jax.jit``s (pool donated, every parameter
+    in the layout it comes in), bound to their arguments: name ->
+    (jitted, arguments)."""
+    return {"decode": (jax.jit(step, donate_argnums=(3,)),
+                       (params, *step_rest)),
+            "chunk": (jax.jit(chunk, donate_argnums=(4,)),
+                      (params, *chunk_rest))}
+
+
+def _mistral_cfg(n_layers=POOL_L):
+    import dataclasses
+    from triton_dist_tpu.models.llama import LlamaConfig
+    cfg = dataclasses.replace(LlamaConfig.mistral_7b(), n_layers=n_layers)
+    assert (cfg.n_kv_heads, cfg.head_dim) == (POOL_HKV, POOL_D)
+    return cfg
+
+
 @pytest.fixture(scope="module")
 def paged_programs(topo):
     """The engine's two programs at Mistral-7B widths, 2 layers, at the
@@ -543,35 +589,10 @@ def paged_programs(topo):
     lowered for one described v5e the way ``benchmark/tools/fit.py`` lowers
     them (pool donated). Name -> (optimised HLO text, memory analysis, the
     grid of each Pallas kernel of the traced program by its ``name=``)."""
-    import dataclasses
-    from jax.sharding import SingleDeviceSharding
-    from triton_dist_tpu.models.llama import (LlamaConfig,
-                                              decode_multistep_paged,
-                                              init_page_pool, init_params,
-                                              prefill_chunk_paged)
-    cfg = dataclasses.replace(LlamaConfig.mistral_7b(), n_layers=POOL_L)
-    assert (cfg.n_kv_heads, cfg.head_dim) == (POOL_HKV, POOL_D)
-    chip = SingleDeviceSharding(topo.devices[0])
-    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
-    params = on(jax.eval_shape(lambda k: init_params(k, cfg),
-                               jax.random.PRNGKey(0)))
-    pool = on(jax.eval_shape(
-        lambda: init_page_pool(cfg, POOL_P, POOL_PAGE)))
-    B, K, C = 16, 4, 256
-    traced = {
-        "decode": jax.jit(
-            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
-                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
-            donate_argnums=(3,)).trace(params, i32(B), i32(B), pool,
-                                       i32(B, POOL_PPS), i32(B)),
-        "chunk": jax.jit(
-            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
-                p, t, s, n, cfg, pages, bt),
-            donate_argnums=(4,)).trace(params, i32(C), i32(), i32(), pool,
-                                       i32(POOL_PPS))}
-
+    from triton_dist_tpu.models.llama import init_params
+    traced = {name: fn.trace(*args) for name, (fn, args) in _plain_jits(
+        *_engine_programs(topo, _mistral_cfg(), init_params, POOL_P, 16, 256,
+                          POOL_PPS)).items()}
     out = {}
     for name, tr in traced.items():
         exe = tr.lower().compile()
@@ -654,6 +675,13 @@ def test_decode_rows_walk_live_pages_only(paged_programs):
 LAT_P, LAT_HELD = 1121, 12
 
 
+def _latent_cfg():
+    import dataclasses
+    from triton_dist_tpu.models import mla
+    return dataclasses.replace(mla.LatentMoEConfig(), n_layers=3,
+                               vocab_size=20480, n_experts_held=LAT_HELD)
+
+
 @pytest.fixture(scope="module")
 def latent_programs(topo):
     """The engine's two programs for ``models.mla`` at Kimi-K2 widths (3
@@ -662,33 +690,11 @@ def latent_programs(topo):
     sequence), lowered for one described v5e, pool donated. Name -> (optimised
     HLO text, memory analysis, configuration, the grid of each Pallas kernel
     by its ``name=``)."""
-    import dataclasses
-    from jax.sharding import SingleDeviceSharding
+    cfg = _latent_cfg()
     from triton_dist_tpu.models import mla
-    from triton_dist_tpu.models.llama import (decode_multistep_paged,
-                                              prefill_chunk_paged)
-    cfg = dataclasses.replace(mla.LatentMoEConfig(), n_layers=3,
-                              vocab_size=20480, n_experts_held=LAT_HELD)
-    fam = cfg.paged
-    chip = SingleDeviceSharding(topo.devices[0])
-    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
-    params = on(jax.eval_shape(lambda k: mla.init_params(k, cfg),
-                               jax.random.PRNGKey(0)))
-    pool = on(jax.eval_shape(lambda: fam.init_pool(cfg, LAT_P, 128)))
-    B, K, C, PPS = 32, 4, 512, 70
-    traced = {
-        "decode": jax.jit(
-            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
-                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
-            donate_argnums=(3,)).trace(params, i32(B), i32(B), pool,
-                                       i32(B, PPS), i32(B)),
-        "chunk": jax.jit(
-            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
-                p, t, s, n, cfg, pages, bt),
-            donate_argnums=(4,)).trace(params, i32(C), i32(), i32(), pool,
-                                       i32(PPS))}
+    traced = {name: fn.trace(*args) for name, (fn, args) in _plain_jits(
+        *_engine_programs(topo, cfg, mla.init_params, LAT_P, 32, 512,
+                          70)).items()}
     out = {}
     for name, tr in traced.items():
         exe = tr.lower().compile()
@@ -754,6 +760,13 @@ def test_latent_walks_are_loops_over_live_pages(latent_programs):
 HYB_P, HYB_SLOTS = 1282, 64
 
 
+def _hybrid_cfg(n_layers):
+    import dataclasses
+    from triton_dist_tpu.models import hybrid_ssm as hm
+    return hm.bind(dataclasses.replace(hm.HybridSSMConfig(),
+                                       n_layers=n_layers), HYB_SLOTS, 512)
+
+
 @pytest.fixture(scope="module")
 def hybrid_programs(topo):
     """The engine's two programs for ``models.hybrid_ssm`` at Falcon-H1-34B
@@ -761,33 +774,11 @@ def hybrid_programs(topo):
     published), at the benchmark cell's sizes (64 slots, K = 4, chunk 512, 20 pages a
     sequence and the slot's column), lowered for one described v5e, pool
     donated. Name -> (optimised HLO text, memory analysis, configuration)."""
-    import dataclasses
-    from jax.sharding import SingleDeviceSharding
     from triton_dist_tpu.models import hybrid_ssm as hm
-    from triton_dist_tpu.models.llama import (decode_multistep_paged,
-                                              prefill_chunk_paged)
-    cfg = hm.bind(dataclasses.replace(hm.HybridSSMConfig(), n_layers=6),
-                  HYB_SLOTS, 512)
-    fam = cfg.paged
-    chip = SingleDeviceSharding(topo.devices[0])
-    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
-    params = on(jax.eval_shape(lambda k: hm.init_params(k, cfg),
-                               jax.random.PRNGKey(0)))
-    pool = on(jax.eval_shape(lambda: fam.init_pool(cfg, HYB_P, 128)))
-    B, K, C, W = HYB_SLOTS, 4, 512, 21
-    progs = {
-        "decode": jax.jit(
-            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
-                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
-            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
-                                       i32(B, W), i32(B)),
-        "chunk": jax.jit(
-            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
-                p, t, s, n, cfg, pages, bt),
-            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
-                                       i32(W))}
+    cfg = _hybrid_cfg(6)
+    progs = {name: fn.lower(*args) for name, (fn, args) in _plain_jits(
+        *_engine_programs(topo, cfg, hm.init_params, HYB_P, HYB_SLOTS, 512,
+                          21)).items()}
     out = {}
     for name, low in progs.items():
         exe = low.compile()
@@ -863,29 +854,10 @@ def _sink_window_cfg(periods=1):
 
 
 def _sink_window_lowered(topo, cfg):
-    from jax.sharding import SingleDeviceSharding
     from triton_dist_tpu.models import window_moe as wm
-    from triton_dist_tpu.models.llama import (decode_multistep_paged,
-                                              prefill_chunk_paged)
-    chip = SingleDeviceSharding(topo.devices[0])
-    on = lambda t: jax.tree_util.tree_map(            # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), t)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)  # noqa: E731
-    params = on(jax.eval_shape(lambda k: wm.init_params(k, cfg),
-                               jax.random.PRNGKey(0)))
-    pool = on(jax.eval_shape(lambda: cfg.paged.init_pool(cfg, SINK_P, 128)))
-    B, K, C, W = SINK_SLOTS, 4, 512, SINK_PPS + 1
-    return {
-        "decode": jax.jit(
-            lambda p, t, pos, pages, bt, lim: decode_multistep_paged(
-                p, t, pos, cfg, pages, bt, lim, horizon=K, eos_id=None),
-            donate_argnums=(3,)).lower(params, i32(B), i32(B), pool,
-                                       i32(B, W), i32(B)),
-        "chunk": jax.jit(
-            lambda p, t, s, n, pages, bt: prefill_chunk_paged(
-                p, t, s, n, cfg, pages, bt),
-            donate_argnums=(4,)).lower(params, i32(C), i32(), i32(), pool,
-                                       i32(W))}
+    return {name: fn.lower(*args) for name, (fn, args) in _plain_jits(
+        *_engine_programs(topo, cfg, wm.init_params, SINK_P, SINK_SLOTS, 512,
+                          SINK_PPS + 1)).items()}
 
 
 @pytest.fixture(scope="module")
@@ -908,9 +880,10 @@ def test_sink_window_pools_stay_in_place(sink_window_programs, program):
     of 128, groups of 8 and 16, a sink and a one-page window; the trace will
     find each variant by its own name; and nothing shaped like a pool leaf or
     a layer of one comes out of a ``copy`` or a slice (a 192-wide minor dim
-    invites a re-layout: the pool's keys are held in 256 lanes). The decode
-    program still re-lays out ``wq`` / ``wk`` / ``wv`` once a dispatch, as in
-    every family (ROADMAP A10): 0.8 GB of its temporaries here."""
+    invites a re-layout: the pool's keys are held in 256 lanes). As the plain
+    ``jax.jit`` it is HERE, the decode program re-lays out ``wq`` / ``wk`` /
+    ``wv`` once a dispatch (0.8 GB of its temporaries); the engine's does not
+    (ISSUE 38: the guard at the end of this file)."""
     import re
     text, mem, cfg = sink_window_programs[program]
     kernels = {"decode": ("gqa_decode_paged_window_sink", "gqa_decode_paged"),
@@ -947,3 +920,107 @@ def test_sink_window_segments_are_one_scanned_body_each(topo):
     whiles = {name: lo.as_text().count("stablehlo.while")
               for name, lo in low.items()}
     assert whiles == {"chunk": 2, "decode": 3}, whiles
+
+
+# -- the parameters are held in the layouts the decode program reads (ISSUE 38) -
+
+def _window_cfg():
+    """command-a-plus as published (every width; window 4,096; three window
+    layers and a full one: one period), 16 of 128 experts, 1/8 of the
+    vocabulary, bound to the cell's 24 slots and 2,048-row chunk."""
+    from triton_dist_tpu.models import window_moe as wm
+    return wm.bind(wm.WindowMoEConfig(
+        vocab_size=32768, d_model=4096, n_layers=4, n_heads=128, n_kv_heads=8,
+        head_dim=128, window=4096,
+        layer_kinds=("window",) * 3 + ("full",), moe_d_ff=4096,
+        n_routed_experts=128, n_experts_held=16, topk=8, n_shared_experts=4,
+        rope_theta=5e4, norm_eps=1e-5, max_seq_len=200 * 128), 24, 2048)
+
+
+def _family_programs(topo, family):
+    """One of the five one-chip families at PUBLISHED widths and its cell's
+    slots, chunk and table width, depth cut to one period (Mistral: the
+    cell's 20 layers, which a scanned layer loop compiles as fast as two, so
+    that neither ``wq``'s stack nor ``wk``'s fits the chip's 128 MiB of
+    on-chip memory, where a copy is no temporary), pools cut as the guards
+    above cut them."""
+    from triton_dist_tpu.models import hybrid_ssm as hm
+    from triton_dist_tpu.models import llama, mla
+    from triton_dist_tpu.models import window_moe as wm
+    cfg, init, pages, B, C, W = {
+        "dense": lambda: (_mistral_cfg(20), llama.init_params, POOL_P, 16,
+                          256, POOL_PPS),
+        "latent": lambda: (_latent_cfg(), mla.init_params, LAT_P, 32, 512,
+                           70),
+        "window": lambda: (_window_cfg(), wm.init_params, 1201, 24, 2048,
+                           201),
+        "hybrid": lambda: (_hybrid_cfg(2), hm.init_params, HYB_P, HYB_SLOTS,
+                           512, 21),
+        "sink_window": lambda: (_sink_window_cfg(), wm.init_params, SINK_P,
+                                SINK_SLOTS, 512, SINK_PPS + 1)}[family]()
+    return _engine_programs(topo, cfg, init, pages, B, C, W)
+
+
+@pytest.mark.parametrize("family", ["dense", "latent", "window", "hybrid",
+                                    "sink_window"])
+def test_no_program_relays_out_a_weight_at_its_entry(topo, family):
+    """Compiled THROUGH ``serving.layouts.held_layout_programs`` (what the
+    engine calls off the CPU), neither the decode nor the chunk program
+    copies, transposes or re-tiles a parameter of a weight leaf's shape in
+    its ENTRY computation: the weights are committed once to what the decode
+    program reads, and the chunk program is compiled against that. No copy of
+    the leaves held re-laid would fit the decode program's temporaries."""
+    from triton_dist_tpu.serving import layouts
+    step, chunk, params, step_rest, chunk_rest = _family_programs(topo,
+                                                                  family)
+    decode, chunk_jit, formats = layouts.held_layout_programs(
+        step, chunk, params, step_rest)
+    held = layouts.relaid(params, formats)
+    assert held, "the compiler asked for every leaf as it comes"
+    assert layouts.entry_copies(decode.as_text(), params) == []
+    chunk_exe = chunk_jit.lower(params, *chunk_rest).compile()
+    assert layouts.entry_copies(chunk_exe.as_text(), params) == []
+    assert decode.memory_analysis().temp_size_in_bytes < sum(
+        h["bytes"] for h in held)
+
+
+def test_the_plain_decode_program_copies_what_the_held_one_does_not(topo):
+    """The guard above can fail: the same decode program as a plain
+    ``jax.jit`` (Mistral-7B widths, 20 layers) re-lays ``wq`` and ``wk`` out
+    at its entry, every dispatch (ROADMAP A10, as it stood), and the held
+    program's temporaries are smaller by the bytes that one copied."""
+    from triton_dist_tpu.serving import layouts
+    step, chunk, params, step_rest, _ = _family_programs(topo, "dense")
+    decode, _, formats = layouts.held_layout_programs(step, chunk, params,
+                                                      step_rest)
+    plain = jax.jit(step, donate_argnums=(3,)).lower(
+        params, *step_rest).compile()
+    copies = layouts.entry_copies(plain.as_text(), params)
+    assert len(copies) == 2 and all(" copy(" in c for c in copies), copies
+    freed = (plain.memory_analysis().temp_size_in_bytes
+             - decode.memory_analysis().temp_size_in_bytes)
+    copied = sum(h["bytes"] for h in layouts.relaid(params, formats))
+    assert 0.99 * copied <= freed <= 1.01 * copied, (freed, copied)
+
+
+def test_relaid_bytes_are_the_leaves_not_held_as_they_come(topo):
+    """What the engine reports as ``params_relaid_bytes`` / ``_leaves``
+    (``layouts.relaid`` of the formats it committed to): at Mistral-7B's
+    widths the decode program asks for ``wq`` and ``wk`` with the contracted
+    dim minor, every other leaf as the device holds it by default."""
+    from triton_dist_tpu.serving import layouts
+    step, chunk, params, step_rest, _ = _family_programs(topo, "dense")
+    _, _, formats = layouts.held_layout_programs(step, chunk, params,
+                                                 step_rest)
+    held = {h["leaf"]: h for h in layouts.relaid(params, formats)}
+    assert sorted(held) == ["['blocks']['wk']", "['blocks']['wq']"], held
+    assert {(h["from"], h["to"]) for h in held.values()} == {
+        ("{2,1,0}", "{1,2,0}")}
+    blocks = params["blocks"]
+    assert sum(h["bytes"] for h in held.values()) == 2 * (
+        blocks["wq"].size + blocks["wk"].size) > 0
+    differ = [jax.tree_util.keystr(path) for (path, leaf), fmt in zip(
+        jax.tree_util.tree_leaves_with_path(params),
+        jax.tree_util.tree_leaves(formats))
+        if fmt.layout != layouts.default_layout(fmt, leaf)]
+    assert differ == sorted(held)

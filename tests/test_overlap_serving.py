@@ -46,7 +46,8 @@ MAX_STEPS = 100_000
 
 # exactly one compiled program per path, regardless of overlap mode —
 # overlap must not fork the program cache
-ONE_OF_EACH = {"decode_compiles": 1, "prefill_chunk_compiles": 1}
+ONE_OF_EACH = {"decode_compiles": 1, "prefill_chunk_compiles": 1,
+               "params_relaid_bytes": 0, "params_relaid_leaves": []}
 
 
 def _serve(moe_model, tp, sp, ep, **kw):

@@ -348,3 +348,115 @@ def test_hooked_path_equals_scanned(params, pool, hook):
     cw, cg = prefill_chunk_paged(*args), prefill_chunk_paged(*args, **hooks)
     assert int(cg[0]) == int(cw[0])
     same_rows_close_values(cg[1], cw[1], pool)
+
+
+# -- (e) the engine, its parameters as they come or held as asked (ISSUE 38) ---
+
+PROMPTS = [[int(t) for t in np.arange(n) * 31 % CFG.vocab_size + 1]
+           for n in (21, 16, 5, 33)]
+NEW_TOKENS = 6
+
+
+def parent_tokens(params, prompt):
+    """What the parent's functions serve one request alone: the prompt
+    through 16-token chunks, then one token a decode step."""
+    live = jnp.asarray([1, 0, 0, 0], jnp.int32)
+    chunk_fn = jax.jit(ref_chunk)
+    step_fn = jax.jit(lambda p, t, pos, pg, bt: ref_multistep(
+        p, t, pos, pg, bt, live, 1, None))
+    pool = init_page_pool(CFG, N_PAGES, PAGE)
+    row, n = BT[0], len(prompt)
+    for start in range(0, n, 16):
+        chunk = np.zeros(16, np.int32)
+        part = prompt[start:start + 16]
+        chunk[:len(part)] = part
+        tok, pool = chunk_fn(params, jnp.asarray(chunk), jnp.int32(start),
+                             jnp.int32(n), pool, row)
+    out = [int(tok)]
+    bt = jnp.zeros((B, PPS), jnp.int32).at[0].set(row)
+    for i in range(NEW_TOKENS - 1):
+        toks, _, _, pool = step_fn(
+            params, jnp.zeros(B, jnp.int32).at[0].set(out[-1]),
+            jnp.zeros(B, jnp.int32).at[0].set(n + i), pool, bt)
+        out.append(int(toks[0, 0]))
+    return out
+
+
+def build_engine(params, monkeypatch, held):
+    """``held``: the engine takes the branch it takes on an accelerator (the
+    decode program compiled with each parameter leaf's layout left to the
+    compiler, the weights committed to what it chose), here on the CPU."""
+    from triton_dist_tpu.serving import engine as engine_mod
+    if held:
+        monkeypatch.setattr(engine_mod.jax, "default_backend", lambda: "tpu")
+    eng = engine_mod.ServingEngine(
+        params, CFG, num_slots=B, page_size=PAGE, num_pages=N_PAGES - 1,
+        pages_per_seq=PPS, decode_horizon=4, prefill_chunk=16)
+    monkeypatch.undo()
+    return eng
+
+
+@pytest.fixture(scope="module")
+def parents_tokens(params):
+    return {i: parent_tokens(params, p) for i, p in enumerate(PROMPTS)}
+
+
+@pytest.mark.parametrize("held", [False, True],
+                         ids=["as-they-come", "held-as-asked"])
+def test_engine_serves_the_parents_tokens(params, parents_tokens,
+                                          monkeypatch, held):
+    """On the CPU the engine leaves its parameters as they come and reports
+    that it re-laid out nothing; made to take the accelerator's branch, it
+    compiles the decode program ahead, holds the weights in the formats that
+    program asked for (on this backend: the ones they have), and serves the
+    same tokens from ONE decode and ONE chunk program."""
+    from jax.stages import Compiled
+    eng = build_engine(params, monkeypatch, held)
+    assert isinstance(eng._step, Compiled) == held
+    assert (eng._formats is not None) == held
+    out = eng.run(max_steps=400, arrivals=[
+        (i, p, NEW_TOKENS) for i, p in enumerate(PROMPTS)])
+    assert out == parents_tokens
+    assert eng.compile_stats == {
+        "decode_compiles": 1, "prefill_chunk_compiles": 1,
+        "params_relaid_bytes": 0, "params_relaid_leaves": []}
+    assert eng.metrics.snapshot()["params_relaid_bytes"] == 0
+
+
+def test_setting_params_recommits_them(params, monkeypatch):
+    """``eng.params = w`` hands the programs ``w`` in the formats they were
+    compiled for: the engine then serves what an engine built on ``w``
+    serves, and compiles nothing."""
+    other = jax.tree_util.tree_map(lambda a: a * 1.5, params)
+    want = build_engine(other, monkeypatch, held=True).run(
+        max_steps=400, arrivals=[(0, PROMPTS[0], NEW_TOKENS)])
+    eng = build_engine(params, monkeypatch, held=True)
+    first = eng.run(max_steps=400, arrivals=[(0, PROMPTS[0], NEW_TOKENS)])
+    eng.params = other
+    for got, fmt in zip(jax.tree_util.tree_leaves(eng.params),
+                        jax.tree_util.tree_leaves(eng._formats)):
+        assert got.format.sharding == fmt.sharding
+    again = eng.run(max_steps=400, arrivals=[(1, PROMPTS[0], NEW_TOKENS)])
+    assert again[1] == want[0] != first[0]
+    assert eng.compile_stats["prefill_chunk_compiles"] == 1
+
+
+def test_commit_copies_only_what_is_not_held_as_asked():
+    """``layouts.commit`` on formats that DO ask for something (the CPU takes
+    a concrete layout too): the leaf asked for column-major is copied into
+    it, value for value, and counted by ``relaid``; a leaf whose format asks
+    for nothing is the caller's buffer; a tree already committed comes back
+    as it is."""
+    from jax.experimental.layout import Format, Layout
+    from triton_dist_tpu.serving import layouts
+    w = jnp.arange(24, dtype=jnp.float32).reshape(4, 6)
+    b = jnp.ones((3,))
+    asked = {"w": Format(Layout((1, 0), ()), w.sharding),
+             "b": Format(None, b.sharding)}
+    got = layouts.commit({"w": w, "b": b}, asked)
+    assert got["w"].format == asked["w"] and got["b"] is b
+    assert np.array_equal(got["w"], w)
+    assert layouts.relaid({"w": w, "b": b}, asked) == [{
+        "leaf": "['w']", "shape": [4, 6], "from": "{1,0}", "to": "{0,1}",
+        "bytes": 96}]
+    assert layouts.commit(got, asked)["w"] is got["w"]

@@ -526,7 +526,9 @@ def test_compile_count_guard(tiny_model, monkeypatch, horizon):
     res = eng.run(max_steps=5000, arrivals=arrivals)
     assert len(res) == 10
     assert eng.compile_stats == {"decode_compiles": 1,
-                                 "prefill_chunk_compiles": 1}
+                                 "prefill_chunk_compiles": 1,
+                                 "params_relaid_bytes": 0,
+                                 "params_relaid_leaves": []}
     # the jit-entry hook agrees (pallas interpret mode jits its own internal
     # wrappers — not ours)
     ours = [f for f in made

@@ -402,11 +402,13 @@ def test_unequal_shapes_and_a_leading_segment_scan_one_body_each():
 # -- (e) through the engine -----------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def replay(model):
-    """Three requests (contexts to 85 tokens: past the 64 a ring holds)
-    through ONE engine of two slots, twice: undisturbed, and with the oldest
-    request preempted in the middle of its prefill and a decoding one
-    preempted later."""
+def replay_engine(model):
+    """Three requests (contexts to 85 tokens: past the 64 a ring holds) and
+    ONE engine of two slots to put them through, undisturbed (``replay_golden``)
+    and then with the oldest request preempted in the middle of its prefill
+    and a decoding one preempted later (``replay``): a fixture each, because
+    a run is a minute of interpreter and the suite's watchdog counts a
+    fixture's wall."""
     fc, pc, w = model
     rng = np.random.default_rng(7)
     reqs = [(rng.integers(1, 256, n), m) for n, m in
@@ -438,7 +440,18 @@ def replay(model):
         done = {r.rid: list(r.generated) for r in eng._finished}
         return {i: done[rid] for i, rid in enumerate(rids)}
 
-    return eng, reqs, run(False), run(True), seen
+    return eng, reqs, run, seen
+
+
+@pytest.fixture(scope="module")
+def replay_golden(replay_engine):
+    return replay_engine[2](False)
+
+
+@pytest.fixture(scope="module")
+def replay(replay_engine, replay_golden):
+    eng, reqs, run, seen = replay_engine
+    return eng, reqs, replay_golden, run(True), seen
 
 
 def test_a_preempted_sequence_replays_its_tokens(replay):

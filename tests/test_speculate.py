@@ -52,7 +52,8 @@ EOS = 5
 # exactly one compiled program per path, regardless of K or spec on/off —
 # speculation must not fork the program cache (the verify program IS the
 # decode program; the drafter traces into it)
-ONE_OF_EACH = {"decode_compiles": 1, "prefill_chunk_compiles": 1}
+ONE_OF_EACH = {"decode_compiles": 1, "prefill_chunk_compiles": 1,
+               "params_relaid_bytes": 0, "params_relaid_leaves": []}
 
 
 @pytest.fixture(scope="module")

@@ -4,6 +4,10 @@ EP/SP overlap ops (see docs/serving.md).
 - kv_pool    — paged KV page allocator + cache<->pages converters
 - scheduler  — FIFO admission / preemption policy over fixed batch slots
 - engine     — the jitted one-step-per-token decode engine
+- layouts    — the layouts the engine holds its weights in (ISSUE 38): the
+               decode program compiled with each parameter leaf's layout
+               left to the compiler, the weights committed once to what it
+               chose, the chunk program compiled against that
 - sharded    — the engine on a TP/SP/EP mesh (SP-sharded page pool, TP
                projections, EP MoE FFN through the overlap kernels, with
                the replicated-decision digest guard)

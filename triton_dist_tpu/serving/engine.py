@@ -58,6 +58,7 @@ import numpy as np
 from triton_dist_tpu.models.llama import (decode_multistep_paged,
                                           prefill_chunk_paged)
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
+from triton_dist_tpu.serving import layouts
 from triton_dist_tpu.serving.deadline import Deadline, EngineStallError
 from triton_dist_tpu.serving.journal import ControlJournal
 from triton_dist_tpu.serving.kv_pool import KVPagePool, _fnv1a
@@ -197,6 +198,10 @@ class ServingEngine:
                 raise NotImplementedError(
                     f"the {fam.name!r} model family ({type(cfg).__name__}) "
                     f"does not support {option}")
+        # the formats the programs take their parameters in, leaf by leaf
+        # (None: as they come). Chosen below, once the programs exist;
+        # ``params``'s setter commits whatever it is given to them.
+        self._formats = None
         self.params = params
         self.cfg = cfg
         self.page_size = page_size
@@ -354,11 +359,6 @@ class ServingEngine:
         step_kw = {} if ps is None else {"out_shardings": (
             (None, None, rep, rep, rep, rep, {"k": ps, "v": ps})
             if self.spec_k else (None, rep, rep, {"k": ps, "v": ps}))}
-        if jax.default_backend() == "cpu":
-            self._step = jax.jit(step, **step_kw)  # CPU: no donation
-        else:
-            self._step = jax.jit(step, donate_argnums=(3,), **step_kw)
-
         self.prefill_chunk = prefill_chunk
 
         # ONE program for every prompt length/position: chunk size is the
@@ -370,11 +370,46 @@ class ServingEngine:
                 attn_io=attn_io, linear=linear)
         chunk_kw = {} if ps is None else {
             "out_shardings": (None, {"k": ps, "v": ps})}
-        if jax.default_backend() == "cpu":
+        abstract = lambda tree: jax.tree_util.tree_map(     # noqa: E731
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        held = []
+        if jax.default_backend() == "cpu":      # no donation
+            self._step = jax.jit(step, **step_kw)
             self._chunk_step = jax.jit(chunk, **chunk_kw)
-        else:
+        elif ps is not None or artifact is not None:
+            # on a mesh, or seeded from an artifact (whose programs were
+            # exported for parameters as they come): today's layouts
+            self._step = jax.jit(step, donate_argnums=(3,), **step_kw)
             self._chunk_step = jax.jit(chunk, donate_argnums=(4,),
                                        **chunk_kw)
+        else:
+            # the decode program is compiled HERE, each parameter leaf in
+            # the layout the compiler chooses for it, and the weights are
+            # committed to those once (serving/layouts.py): no dispatch
+            # re-lays a projection out again. The chunk program is compiled
+            # at its first call, as ever, against the same formats.
+            decode_rest = (i32(num_slots), i32(num_slots),
+                           abstract(self.pool), i32(*self._bt.shape),
+                           i32(num_slots))
+            if self.spec_k:
+                decode_rest += (i32(num_slots, self.spec_hist),
+                                i32(num_slots))
+            self._step, self._chunk_step, self._formats = \
+                layouts.held_layout_programs(step, chunk, self.params,
+                                             decode_rest)
+            held = layouts.relaid(self.params, self._formats)
+            # a format names its device, so these programs' outputs are
+            # COMMITTED to it: so is the pool from the start, or the chunk
+            # program would be compiled for each of the two
+            self.pool = jax.device_put(self.pool, jax.tree_util.tree_leaves(
+                self._formats)[0].sharding)
+            self.params = params            # committed by the setter
+        # what the engine holds in another layout than the device's default
+        # (0 bytes: the mechanism did nothing, as on the CPU)
+        self._relaid_leaves = [h["leaf"] for h in held]
+        self.metrics.counters["params_relaid_bytes"] = sum(
+            h["bytes"] for h in held)
 
         # TDT_SIGCHECK=1: lint the engine's compiled programs against the
         # trace-determinism contract at BUILD time (sigcheck rung 0 — see
@@ -383,9 +418,6 @@ class ServingEngine:
         # before any request is admitted.
         if os.environ.get("TDT_SIGCHECK") == "1":
             from triton_dist_tpu.analysis.lint import lint_engine_programs
-            abstract = lambda tree: jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-            i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
             # lint with the shapes the programs actually run on: the
             # sharded subclass pads the pool's page dim up to a multiple
             # of |sp| right after this ctor returns (unified pool
@@ -421,6 +453,18 @@ class ServingEngine:
         self._aot_artifact = artifact
         if artifact is not None:
             self._seed_from_artifact(artifact, artifact_key)
+
+    @property
+    def params(self):
+        """The weights, as the programs take them. Setting them commits each
+        leaf to the format the decode program was compiled for (a copy of a
+        leaf that is held in another; the caller's tree is left alone)."""
+        return self._params
+
+    @params.setter
+    def params(self, tree) -> None:
+        self._params = tree if self._formats is None else layouts.commit(
+            tree, self._formats)
 
     # -- AOT artifact (ISSUE 15) ------------------------------------------
     def _default_artifact_key(self) -> str:
@@ -1515,6 +1559,11 @@ class ServingEngine:
             "prefill_chunk_compiles": n(
                 self._chunk_step,
                 1 if self.metrics.counters["prefill_chunks"] else 0),
+            # bytes and paths of the parameter leaves held in another layout
+            # than the device's default (serving/layouts.py; 0: none)
+            "params_relaid_bytes": self.metrics.counters[
+                "params_relaid_bytes"],
+            "params_relaid_leaves": list(self._relaid_leaves),
         }
         if self._aot_artifact is not None:
             from triton_dist_tpu.aot.artifact import LoadedProgram
