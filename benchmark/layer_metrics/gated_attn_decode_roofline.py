@@ -1,0 +1,18 @@
+"""Kernels: the gated full-attention layers' decode walk as a share of its
+roofline, at groups of 8 query heads over 2 KV heads of 256: the keys it had
+to attend (the program's counter ``attn_full_keys``: context summed over LIVE
+rows, inner steps and full layers) at 2,048 B a key
+(``benchmark/costs_linear_attn_moe.py``; memory bounds it), over the device
+time of ``%gqa_decode_paged`` in the decode program."""
+from benchmark import costs_linear_attn_moe as C
+from benchmark.layer_metrics.gated_attn_ms import mine
+from benchmark.layer_metrics.gqa_attn_ms import KERNEL
+from benchmark.layer_metrics.mla_attn_ms import kernel_s
+
+
+def read(run):
+    keys = (run.get("counters_trace") or {}).get("attn_full_keys")
+    secs, n = kernel_s(run, KERNEL)
+    if not mine(run) or not keys or not n or run.get("peaks") is None:
+        return None
+    return 100.0 * C.walk_least_s(run["cfg"], keys, run["peaks"]) / secs

@@ -24,7 +24,8 @@ Run it once in each of two checkouts (the process imports ``--root``'s
 package), then ``--same`` says which files differ; ``--kernels-same`` compares
 the Mosaic kernels' MLIR alone (a change of the programs around them that must
 leave every kernel as it was). PR 37 held the four configurations it shares
-code with to the parent this way: every program and kernel identical."""
+code with to the parent this way: every program and kernel identical; PR 39
+(whose layer loop indexes the layers of SEVERAL periods in place) all five."""
 import argparse
 import base64
 import filecmp
@@ -36,7 +37,8 @@ import re
 import sys
 
 CONFIGS = ("mistral-7b-v5e1", "kimi-k2-ep32-v5e1", "command-a-plus-ep8-v5e1",
-           "falcon-h1-34b-v5e1", "mimo-v2-flash-ep8-v5e1")
+           "falcon-h1-34b-v5e1", "mimo-v2-flash-ep8-v5e1",
+           "qwen3-next-80b-ep16-v5e1")
 TABLES = re.compile(r"^\d+ |^(FileNames|FunctionNames|FileLocations|"
                     r"StackFrames)")
 BODY = re.compile(r'"body":"([^"]*)"')

@@ -922,6 +922,98 @@ def test_sink_window_segments_are_one_scanned_body_each(topo):
     assert whiles == {"chunk": 2, "decode": 3}, whiles
 
 
+# -- the linear-attention family at Qwen3-Next widths (ISSUE 39) --------------------
+
+LIN_SLOTS, LIN_P, LIN_PPS = 128, 1282, 40
+
+
+def _linear_attn_cfg(periods=1):
+    """Qwen3-Next-80B-A3B as published (every width: 16 / 32 linear heads of
+    128, conv 4; 16 / 2 heads of 256, rotary 64; router 512, top-10, experts
+    of 512), ``periods`` periods of (linear x 3, full), 32 of 512 experts, 1/8
+    of the vocabulary, bound to the cell's 128 slots and 2,048-row chunk."""
+    from triton_dist_tpu.models import linear_attn_moe as lm
+    return lm.bind(lm.LinearAttnMoEConfig(
+        vocab_size=19072, n_layers=4 * periods, n_experts_held=32,
+        max_seq_len=LIN_PPS * 128), LIN_SLOTS, 2048)
+
+
+def _linear_attn_lowered(topo, cfg):
+    from triton_dist_tpu.models import linear_attn_moe as lm
+    return {name: fn.lower(*args) for name, (fn, args) in _plain_jits(
+        *_engine_programs(topo, cfg, lm.init_params, LIN_P, LIN_SLOTS, 2048,
+                          LIN_PPS + 1)).items()}
+
+
+@pytest.fixture(scope="module")
+def linear_attn_programs(topo):
+    """The engine's two programs at the benchmark cell's sizes (128 slots, K
+    = 4, chunk 2,048, 40 pages a sequence and the slot's column; the K/V pool
+    cut to 1,282 pages), one period, lowered for one described v5e, pool
+    donated. Name -> (optimised HLO text, memory analysis, configuration)."""
+    cfg = _linear_attn_cfg()
+    out = {}
+    for name, low in _linear_attn_lowered(topo, cfg).items():
+        exe = low.compile()
+        out[name] = (exe.as_text(), exe.memory_analysis(), cfg)
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_linear_attn_states_and_pages_stay_in_place(linear_attn_programs,
+                                                    program):
+    """Mosaic takes ``gdn_decode_update`` (its in-kernel transposes, its
+    hand-made DMAs out of and back into the aliased state leaf) and the paged
+    GQA kernels at heads of 256 in groups of 8 at the published widths; the
+    trace will find them by name; and nothing shaped like the state leaf, a
+    layer of it, the conv leaf or the K/V pool comes out of a ``copy`` or a
+    slice: each kind of layer's leaves (3 layers of states, 1 of pages) are
+    carried, written and read where they lie."""
+    import re
+    text, mem, cfg = linear_attn_programs[program]
+    kernels = {"decode": ("gdn_decode_update", "gqa_decode_paged",
+                          "grouped_gemm_gated"),
+               "chunk": ("gqa_prefill_paged", "grouped_gemm_gated")}[program]
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), kernel
+    assert (program == "chunk") == (
+        re.search(r"%gdn_decode_update[.\d]* = ", text) is None)
+    S = LIN_SLOTS + 1
+    state = f"{S},32,128,128]"
+    kv = f"{LIN_P},2,128,256]"
+    big = [f"[3,{state}", f"[1,{state}", f"[{state}", f"[1,{kv}", f"[{kv}",
+           f"[{3 * S},24576]"]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        kind = name if opcode == "fusion" else opcode
+        if any(s in result for s in big) and re.search(
+                r"copy|slice", kind) and not re.search(r"update.slice", kind):
+            moved.append(line.strip()[:160])
+    assert not moved, "\n".join(moved)
+    pool_bytes = 3 * S * (32 * 128 * 128 * 4 + 3 * 8192 * 2) \
+        + 2 * LIN_P * 2 * 128 * 256 * 2
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+    # the state leaf is 0.81 GB a period: no copy of it fits under this
+    assert mem.temp_size_in_bytes < 0.7e9, mem.temp_size_in_bytes
+
+
+def test_linear_attn_periods_are_one_scanned_body(topo):
+    """Periods of four layers of two kinds lower to ONE ``while`` over the
+    periods (8 or 12 layers, not 8 or 12 bodies): a third period adds no
+    loop to either program (the body's own: a scan over blocks of 64 tokens
+    a linear layer of the chunk program, the compacted share's loop a layer,
+    the horizon's in the decode program)."""
+    whiles = [{name: lo.as_text().count("stablehlo.while") for name, lo in
+               _linear_attn_lowered(topo, _linear_attn_cfg(periods)).items()}
+              for periods in (2, 3)]
+    assert whiles[0] == whiles[1], whiles
+    assert whiles[0]["chunk"] == 1 + 3 + 4 and whiles[0]["decode"] == 2 + 4
+
+
 # -- the parameters are held in the layouts the decode program reads (ISSUE 38) -
 
 def _window_cfg():
@@ -938,13 +1030,14 @@ def _window_cfg():
 
 
 def _family_programs(topo, family):
-    """One of the five one-chip families at PUBLISHED widths and its cell's
+    """One of the six one-chip families at PUBLISHED widths and its cell's
     slots, chunk and table width, depth cut to one period (Mistral: the
     cell's 20 layers, which a scanned layer loop compiles as fast as two, so
     that neither ``wq``'s stack nor ``wk``'s fits the chip's 128 MiB of
     on-chip memory, where a copy is no temporary), pools cut as the guards
     above cut them."""
     from triton_dist_tpu.models import hybrid_ssm as hm
+    from triton_dist_tpu.models import linear_attn_moe as lm
     from triton_dist_tpu.models import llama, mla
     from triton_dist_tpu.models import window_moe as wm
     cfg, init, pages, B, C, W = {
@@ -957,12 +1050,14 @@ def _family_programs(topo, family):
         "hybrid": lambda: (_hybrid_cfg(2), hm.init_params, HYB_P, HYB_SLOTS,
                            512, 21),
         "sink_window": lambda: (_sink_window_cfg(), wm.init_params, SINK_P,
-                                SINK_SLOTS, 512, SINK_PPS + 1)}[family]()
+                                SINK_SLOTS, 512, SINK_PPS + 1),
+        "linear_attn": lambda: (_linear_attn_cfg(), lm.init_params, LIN_P,
+                                LIN_SLOTS, 2048, LIN_PPS + 1)}[family]()
     return _engine_programs(topo, cfg, init, pages, B, C, W)
 
 
 @pytest.mark.parametrize("family", ["dense", "latent", "window", "hybrid",
-                                    "sink_window"])
+                                    "sink_window", "linear_attn"])
 def test_no_program_relays_out_a_weight_at_its_entry(topo, family):
     """Compiled THROUGH ``serving.layouts.held_layout_programs`` (what the
     engine calls off the CPU), neither the decode nor the chunk program

@@ -335,6 +335,20 @@ def _fd_ssm_decode_update():
                       jnp.zeros((3, 2, 8), f32), jnp.zeros((3, 2, 8), f32))
 
 
+def _fd_gdn_decode_update():
+    from ..ops import gdn_decode_update
+    # two live rows around an idle one, two head blocks a row (32 value heads
+    # in blocks of 16): every block fetched out of the aliased state leaf is
+    # waited and written back (4 fetches, 4 stores), and the idle row starts
+    # none
+    gdn_decode_update(jnp.zeros((2, 4, 32, 8, 128), f32), 1,
+                      jnp.array([1, 2, 3], i32),
+                      jnp.array([True, False, True]),
+                      jnp.zeros((3, 16, 8), f32), jnp.zeros((3, 16, 8), f32),
+                      jnp.zeros((3, 32, 128), f32), jnp.zeros((3, 32), f32),
+                      jnp.zeros((3, 32), f32))
+
+
 def _fd_decode_combine():
     from ..ops import decode_combine
     decode_combine(jnp.zeros((2, 1, 4, 128), f32),
@@ -541,6 +555,11 @@ _ENTRIES = [
     RegistryEntry("ssm_decode_update", _local(_fd_ssm_decode_update),
                   meshes=MESH_LOCAL),
     RegistryEntry("ssd_chunk_scan",
+                  skip="plain jnp scan in blocks; " + _SKIP_PURE),
+    # the matrix state of the linear-attention layers (ISSUE 39)
+    RegistryEntry("gdn_decode_update", _local(_fd_gdn_decode_update),
+                  meshes=MESH_LOCAL),
+    RegistryEntry("gdn_chunk_scan",
                   skip="plain jnp scan in blocks; " + _SKIP_PURE),
     RegistryEntry("ll_ag_merge", _run_ll_ag_merge),
     RegistryEntry("sp_gqa_flash_decode", _run_sp_gqa_flash_decode),

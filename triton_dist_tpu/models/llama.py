@@ -620,7 +620,10 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     is of one kind): its params are the run's stacked ``[n, ...]`` arrays
     seen as ``[n / period, period, ...]`` (a free view), or, where the kinds
     differ in shape, each kind's stack seen as ``[n / period, that kind's
-    layers a period, ...]``; its layer index is the period's first.
+    layers a period, ...]``; its layer index is the period's first. Where a
+    run is SEVERAL periods of several layers the body takes the period's
+    first index alone and indexes each layer in the stacks where they lie
+    (``body_in_place``).
 
     The pool is the loop's CARRY, never its per-layer input or output:
     ``lax.scan`` cannot alias an ``xs`` to a ``ys``, so scanning over the
@@ -688,6 +691,19 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
                               i + j if j else i, attention, seg_ffn)
             return carry, None
 
+        def body_in_place(carry, i, seg_ffn=seg_ffn, plan=plan,
+                          stacks=stacks, each=each, first=first, P=P):
+            # SEVERAL periods of several layers: each layer's params are
+            # indexed where the stacks lie. As the scan's input a period's
+            # slice of every stack was copied whole every trip (100 MB of
+            # ``w_qkv`` [3, 2048, 8192] a period and token-step on the v5e);
+            # one layer's slice is fetched as a period-of-one's is.
+            for j, (attention, kind, rank) in enumerate(plan):
+                at = (i - first) // P * each[kind] + rank
+                carry = layer(carry, LayerParams(stacks[kind], at),
+                              i + j if j else i, attention, seg_ffn)
+            return carry, None
+
         if hooked:
             for j in range(n):
                 attention, kind, rank = plan[j % P]
@@ -696,6 +712,9 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
                     attention, seg_ffn)
         else:
             firsts = jnp.arange(first, first + n, dtype=jnp.int32)
+            if P > 1 and n > P:
+                carry, _ = lax.scan(body_in_place, carry, firsts[::P])
+                continue
             if P > 1:
                 stacks = {kind: jax.tree.map(
                     lambda a, c=c: a.reshape((n // P, c) + a.shape[1:]),

@@ -39,6 +39,7 @@ from triton_dist_tpu.ops.flash_decode import (  # noqa: F401
     pool_ag_start_local, flash_decode_dist)
 from triton_dist_tpu.ops.mla_decode import mla_decode_paged  # noqa: F401
 from triton_dist_tpu.ops.ssm import ssm_decode_update, ssd_chunk_scan  # noqa: F401
+from triton_dist_tpu.ops.gdn import gdn_decode_update, gdn_chunk_scan  # noqa: F401
 from triton_dist_tpu.ops.group_gemm import (  # noqa: F401
     PackedGatedWeights, align_tokens_by_expert, used_block_count,
     emit_grouped_gemm, grouped_gemm, pack_gated_weights, grouped_gemm_gated,
