@@ -113,31 +113,36 @@ def serve_noting_victims(eng, reqs, fence=False):
     every chunk fenced on the host or (as the engine does) only a prompt's
     last. Returns (tokens a request, the control plane's digest after every
     step, whether ``_grow`` preempted a slot mid-prefill in the very step
-    whose chunk was launched and not waited for, the counters)."""
+    that committed a chunk nobody waited for, the counters, whether one such
+    victim's chunk had been launched ahead, in the step before)."""
     import jax
     if fence:
         chunk_step = eng._chunk_step
         eng._chunk_step = lambda *a: jax.block_until_ready(chunk_step(*a))
     preempt, victims = eng._preempt, []
-    not_awaited = lambda: eng.metrics.counters["chunks_not_awaited"]  # noqa: E731
+    counters = eng.metrics.counters
+    counts = lambda: (counters["chunks_not_awaited"],       # noqa: E731
+                      counters["chunks_prelaunched"])
 
     def spy(slot):
         req = eng.sched.slots[slot]
-        victims.append((req.state.value, req.prefill_cursor, not_awaited()))
+        victims.append((req.state.value, req.prefill_cursor, *counts()))
         preempt(slot)
 
     eng._preempt = spy
     rids = [eng.submit(prompt, n) for prompt, n in reqs]
-    digests, hit = [], False
+    digests, hit, hit_ahead = [], False, False
     while True:
-        before, seen = not_awaited(), len(victims)
+        (before, ahead), seen = counts(), len(victims)
         if not eng.step():
             break
         digests.append(eng.control_digest())
-        hit |= any(state == "prefilling" and cursor > 0 and count == before + 1
-                   for state, cursor, count in victims[seen:])
+        mid = [v for v in victims[seen:] if v[0] == "prefilling" and v[1] > 0
+               and v[2] == before + 1]
+        hit |= bool(mid)
+        hit_ahead |= any(v[3] == ahead + 1 for v in mid)
     done = {r.rid: list(r.generated) for r in eng._finished}
-    return [done[r] for r in rids], digests, hit, eng.metrics.counters
+    return [done[r] for r in rids], digests, hit, counters, hit_ahead
 
 
 def seeded_trace(n, staggered=False):

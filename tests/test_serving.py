@@ -749,17 +749,24 @@ def test_step_opens_exactly_the_named_phases(phased_run):
               if name in ("engine.chunk_prep", "engine.chunk_wait")]
     length = dict(zip(phased_run["rids"], REF_PROMPT_LENS))
     by_rid, awaited = {}, []
+    chunk_of = lambda ids: (ids["rid"], ids["cursor"])      # noqa: E731
     for i, (name, ids) in enumerate(chunks):
         if name == "engine.chunk_wait":
-            # a wait follows the prep of the same chunk, and only a last one
-            assert i and chunks[i - 1] == ("engine.chunk_prep", ids)
+            # a wait follows the prep of the same chunk, and only a last one:
+            # in the same step, or in the one before where the chunk was
+            # launched ahead, behind that step's decode dispatch
+            prep_name, prep_ids = chunks[i - 1]
+            assert i and prep_name == "engine.chunk_prep"
+            assert chunk_of(prep_ids) == chunk_of(ids)
+            assert ids["step"] - prep_ids["step"] in (0, 1)
             assert ids["cursor"] + 8 >= length[ids["rid"]]
             awaited.append(ids["rid"])
             continue
         assert ids["cursor"] % 8 == 0          # whole chunks of 8 so far
         by_rid.setdefault(ids["rid"], []).append(ids["cursor"])
         if ids["cursor"] + 8 >= length[ids["rid"]]:     # the prompt's last
-            assert chunks[i + 1] == ("engine.chunk_wait", ids)
+            assert chunks[i + 1][0] == "engine.chunk_wait"
+            assert chunk_of(chunks[i + 1][1]) == chunk_of(ids)
     # a prompt's chunks are its cursor advancing from 0; one wait a prompt
     for rid, n in length.items():
         assert by_rid[rid] == list(range(0, n, 8))
@@ -790,26 +797,34 @@ def test_phase_totals_tile_the_step(phased_run):
 
 
 def test_older_timers_are_differences_of_the_phase_stamps(phased_run):
-    """``step_device_s`` is dispatch + decode wait, ``prefill_stall_s`` the
-    chunk's prep (+ its wait where it is a prompt's last), ``decode_stall_s``
-    the step up to the chunk's launch or token, ``step_host_s`` the rest of
-    a dispatching step before ``post``: the names and counts of before, from
-    the same stamps."""
+    """``step_device_s`` is dispatch + decode wait and the prep of a chunk
+    launched ahead between them, ``prefill_stall_s`` a chunk's prep where the
+    step itself launched it (+ its wait where it is a prompt's last),
+    ``decode_stall_s`` the step up to the chunk's launch or token,
+    ``step_host_s`` the rest of a dispatching step before ``post``: the names
+    and counts of before, from the same stamps. Every prep lies in exactly
+    one of ``step_device_s`` and ``prefill_stall_s``."""
     m = phased_run["eng"].metrics
     h = m.hist
     total = lambda *names: sum(h[n].total for n in names)   # noqa: E731
     near = lambda a, b: abs(a - b) <= 0.02 * max(a, b)      # noqa: E731
     assert h["step_device_s"].count == h["step_host_s"].count \
         == m.counters["dispatches"]
-    assert near(h["step_device_s"].total,
-                total("phase_dispatch_s", "phase_decode_wait_s"))
+    assert 0 < m.counters["chunks_prelaunched"] < m.counters["prefill_chunks"]
+    device = total("phase_dispatch_s", "phase_decode_wait_s")
+    assert device <= h["step_device_s"].total \
+        <= 1.02 * (device + h["phase_chunk_prep_s"].total)
     assert h["prefill_stall_s"].count == m.counters["prefill_chunks"]
-    assert near(h["prefill_stall_s"].total,
-                total("phase_chunk_prep_s", "phase_chunk_wait_s"))
+    assert h["phase_chunk_wait_s"].total <= h["prefill_stall_s"].total \
+        <= 1.02 * total("phase_chunk_prep_s", "phase_chunk_wait_s")
+    assert near(total("step_device_s", "prefill_stall_s"),
+                total("phase_dispatch_s", "phase_decode_wait_s",
+                      "phase_chunk_prep_s", "phase_chunk_wait_s"))
     assert h["decode_stall_s"].count == h["step_s"].count
-    assert near(h["decode_stall_s"].total,
+    assert near(total("decode_stall_s", "step_device_s"),
                 total("phase_admit_s", "phase_chunk_prep_s",
-                      "phase_chunk_wait_s"))
+                      "phase_chunk_wait_s", "phase_dispatch_s",
+                      "phase_decode_wait_s"))
     # host + device is a dispatching step without its closing phase (at
     # k4 one step of this run is a chunk alone, and is in neither)
     both = total("step_host_s", "step_device_s")
@@ -895,11 +910,21 @@ class Watched:
         return self.events[n:]
 
 
+_CHUNK4_PROGRAMS = {}
+
+
 def _chunk4_engine(tiny_model, horizon, **kw):
+    """Engines of one shape share the first one's two jitted programs (as
+    ``test_window_moe.ring_victim`` does): a trace and a compile a shape,
+    not an engine."""
     cfg, params = tiny_model
     kw = {"num_slots": 2, "num_pages": 16, "pages_per_seq": 4, **kw}
-    return ServingEngine(params, cfg, page_size=8, prefill_chunk=4,
-                         decode_horizon=horizon, **kw)
+    eng = ServingEngine(params, cfg, page_size=8, prefill_chunk=4,
+                        decode_horizon=horizon, **kw)
+    shape = (horizon, kw["num_slots"], kw["num_pages"], kw["pages_per_seq"])
+    eng._step, eng._chunk_step = _CHUNK4_PROGRAMS.setdefault(
+        shape, (eng._step, eng._chunk_step))
+    return eng
 
 
 HORIZONS = pytest.mark.parametrize("horizon", [1, 4], ids=["k1", "k4"])
@@ -921,10 +946,13 @@ def test_a_non_final_chunks_token_is_never_read(tiny_model, reference_tokens,
         == w.eng.metrics.counters["chunks_not_awaited"]
     assert w.events.count("final chunk") == w.events.count("read token") \
         == len(prompts)
-    # a final chunk's token is read at once, before anything else is launched
+    # a final chunk's token is read before the next DECODE program is
+    # launched (at once where the step launched the chunk itself; after the
+    # slab where it was launched ahead, behind the step before's dispatch)
     for i, e in enumerate(w.events):
         if e == "final chunk":
-            assert w.events[i + 1] == "read token"
+            rest = w.events[i + 1:]
+            assert "read token" in rest[:rest.index("decode")]
 
 
 @HORIZONS
@@ -938,7 +966,8 @@ def test_decode_is_launched_behind_a_running_chunk(tiny_model,
     short = w.eng.submit(prompts[0], REF_NEW_TOKENS)          # one chunk
     assert w.step_events()[:2] == ["final chunk", "read token"]
     long = w.eng.submit(prompts[3], REF_NEW_TOKENS)           # 19: five
-    assert w.step_events() == ["chunk", "decode", "read slab"]
+    # (the chunk behind the decode program is the NEXT step's, launched ahead)
+    assert w.step_events() == ["chunk", "decode", "chunk", "read slab"]
     res = w.eng.run(max_steps=2000)
     assert [res[short], res[long]] == [want[0], want[3]]
     assert "read token" not in w.events[2:w.events.index("final chunk", 2)]
@@ -983,10 +1012,11 @@ def victim_case(tiny_model):
 @HORIZONS
 def test_a_victim_whose_chunk_was_not_awaited(tiny_model, victim_case,
                                               horizon):
-    """``_grow`` preempts the prefilling slot in the very step whose chunk
-    was launched and not waited for: the victim keeps its filled pages and
-    its cursor, the tokens are the reference's, and the control plane's
-    digest after every step is that of a run which fences every chunk."""
+    """``_grow`` preempts the prefilling slot in the very step that commits
+    a chunk nobody waited for, launched in the step BEFORE behind its decode
+    dispatch (ISSUE 40): the victim keeps its filled pages and its cursor,
+    the tokens are the reference's, and the control plane's digest after
+    every step is that of a run which fences every chunk."""
     reqs, want = victim_case
 
     def serve(fence):
@@ -994,8 +1024,118 @@ def test_a_victim_whose_chunk_was_not_awaited(tiny_model, victim_case,
                              pages_per_seq=6)
         return serve_noting_victims(eng, reqs, fence)
 
-    tokens, digests, hit, counters = serve(fence=False)
-    assert hit and counters["preemptions"] >= 1
+    tokens, digests, hit, counters, hit_ahead = serve(fence=False)
+    assert hit and hit_ahead and counters["preemptions"] >= 1
     assert counters["prefill_chunks"] == 13    # ceil(10/4) + ceil(40/4)
+    assert 0 < counters["chunks_prelaunched"] <= 13
     assert tokens == want
-    assert serve(fence=True)[:2] == (tokens, digests)
+    fenced = serve(fence=True)
+    assert fenced[:2] == (tokens, digests) and fenced[4]
+
+
+# ---------------------------------------------------------------------------
+# the next step's chunk is launched behind this step's decode dispatch,
+# before the host waits for the slab (ISSUE 40)
+# ---------------------------------------------------------------------------
+
+@HORIZONS
+def test_the_next_chunk_is_launched_before_the_slab_is_read(
+        tiny_model, victim_case, horizon):
+    """With a request mid-prefill at dispatch time a step launches the decode
+    program, then the chunk the NEXT step would launch, and only then reads
+    the slab; the next step launches no chunk of its own. Nothing of the
+    launched chunk is committed until that step: the cursor, the counters and
+    the digest are a step's of the parent's order. Tokens are the
+    reference's, and ``chunks_prelaunched <= prefill_chunks`` throughout."""
+    reqs, want = victim_case
+    w = Watched(_chunk4_engine(tiny_model, horizon, pages_per_seq=6))
+    eng, c = w.eng, w.eng.metrics.counters
+    a = eng.submit(*reqs[0])       # 10 tokens; decodes 21: 5 dispatches at K=4
+    while "read token" not in w.events:
+        w.step_events()
+    assert eng._ahead is None                  # nobody else was prefilling
+    b = eng.submit(*reqs[1])                   # 40 tokens: ten chunks of 4
+    # its first chunk at the usual place (nothing was in flight), its second
+    # behind the decode program
+    assert w.step_events() == ["chunk", "decode", "chunk", "read slab"]
+    assert eng.sched.slots[1].prefill_cursor == 4 and eng._ahead.start == 4
+    assert (c["prefill_chunks"], c["chunks_prelaunched"]) == (4, 0)
+    for cursor in (8, 12):
+        assert w.step_events() == ["decode", "chunk", "read slab"]
+        assert eng.sched.slots[1].prefill_cursor == cursor
+        assert c["chunks_prelaunched"] <= c["prefill_chunks"]
+    assert c["chunks_prelaunched"] == 2
+    h = eng.metrics.hist                       # one prep a chunk, as before
+    assert h["phase_chunk_prep_s"].count == c["prefill_chunks"] + 1
+    res = eng.run(max_steps=2000)
+    assert [res[a], res[b]] == want
+    snap = eng.metrics.snapshot()
+    assert 0 < snap["chunks_prelaunched"] <= snap["prefill_chunks"] == 13
+    assert h["phase_chunk_prep_s"].count == 13 and eng._ahead is None
+    # the 40-token prompt's last chunk was launched at the usual place or
+    # ahead: either way its token was read before the next decode launch
+    assert w.events.count("final chunk") == w.events.count("read token") == 2
+
+
+@HORIZONS
+def test_a_checkpoint_with_a_final_chunk_unread(tiny_model, reference_tokens,
+                                                horizon):
+    """A prompt's LAST chunk launched ahead holds a token the next step
+    reads. ``checkpoint()`` taken between the two steps is host-only and sees
+    an uncommitted launch: restored in place, the engine drops it, re-prefills
+    and serves the reference's tokens; ``_preempt`` of the owner drops it too."""
+    from triton_dist_tpu.serving import ControlJournal
+    from triton_dist_tpu.serving import checkpoint as ckpt_mod
+    prompts, want = reference_tokens
+    journal = ControlJournal()
+    w = Watched(_chunk4_engine(tiny_model, horizon, journal=journal))
+    eng = w.eng
+    short = eng.submit(prompts[0], REF_NEW_TOKENS)            # one chunk
+    assert w.step_events()[:2] == ["final chunk", "read token"]
+    two = eng.submit(prompts[1], REF_NEW_TOKENS)              # 8: two chunks
+    assert w.step_events() == ["chunk", "decode", "final chunk", "read slab"]
+    assert eng._ahead is not None and "read token" not in w.events[2:]
+    ck = eng.checkpoint()
+    chunks = eng.metrics.counters["prefill_chunks"]
+    ckpt_mod.restore(eng, ck, journal)
+    assert eng._ahead is None
+    res = eng.run(max_steps=2000)
+    assert [res[short], res[two]] == want[:2]
+    # the dropped launch was never committed: both prompts prefilled again
+    # (at K=4 the one-chunk prompt had finished before the checkpoint)
+    assert eng.metrics.counters["prefill_chunks"] \
+        == chunks + 2 + (horizon == 1)
+    # a victim with a launch in flight: the launch goes with the seat
+    third = eng.submit(prompts[0], REF_NEW_TOKENS)
+    w.step_events()
+    long = eng.submit(prompts[3], REF_NEW_TOKENS)             # 19: five
+    assert w.step_events() == ["chunk", "decode", "chunk", "read slab"]
+    slot = next(i for i, r in enumerate(eng.sched.slots)
+                if r is not None and r.rid == long)
+    eng._preempt(slot)
+    assert eng._ahead is None
+    res = eng.run(max_steps=2000)
+    assert [res[third], res[long]] == [want[0], want[3]]
+
+
+@HORIZONS
+def test_nothing_is_launched_ahead_under_a_stall_budget(tiny_model,
+                                                        reference_tokens,
+                                                        horizon):
+    """While a class with a ``stall_budget`` decodes, the next chunk's size
+    depends on who still decodes after the slab: it is sized and launched at
+    the usual place, and nothing is launched ahead."""
+    from triton_dist_tpu.serving import SLOPolicy
+    prompts, want = reference_tokens
+    w = Watched(_chunk4_engine(
+        tiny_model, horizon, slo=SLOPolicy.chat_batch(chat_stall_budget=2)))
+    eng, c = w.eng, w.eng.metrics.counters
+    chat = eng.submit(prompts[0], REF_NEW_TOKENS, tenant="c0", cls="chat")
+    assert w.step_events()[:2] == ["final chunk", "read token"]
+    batch = eng.submit(prompts[3], REF_NEW_TOKENS, tenant="b0", cls="batch")
+    while any(r is not None and r.rid == chat for r in eng.sched.slots):
+        assert w.step_events() == ["chunk", "decode", "read slab"]
+        assert eng._ahead is None
+    assert c["chunks_prelaunched"] == 0 and c["chunk_shrinks"] > 0
+    res = eng.run(max_steps=2000)
+    assert [res[chat], res[batch]] == [want[0], want[3]]

@@ -490,7 +490,7 @@ def ring_victim(model):
             assert eng.alloc.alloc("ballast", eng.alloc.free_pages - spare)
         return conftest.serve_noting_victims(eng, reqs, fence)
 
-    golden, _, _, roomy = serve(1, spare=None)
+    golden, _, _, roomy, _ = serve(1, spare=None)
     assert roomy["preemptions"] == 0
     return serve, golden
 
@@ -502,7 +502,7 @@ def test_a_ring_victim_whose_chunk_was_not_awaited(ring_victim, horizon):
     stays with the slot); the tokens are the roomy pool's, and the digests
     those of a run which fences every chunk."""
     serve, golden = ring_victim
-    tokens, digests, hit, counters = serve(horizon, spare=5)
+    tokens, digests, hit, counters, _ = serve(horizon, spare=5)
     assert hit and counters["preemptions"] == 1
     assert counters["prefill_chunks"] == 1 + 1 + 2      # the victim's again
     assert tokens == golden

@@ -50,6 +50,7 @@ import json
 import os
 import time
 from collections import deque
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -125,6 +126,15 @@ def check_prefill_chunk(prefill_chunk) -> int:
             f"program every prompt is prefilled through); got "
             f"{prefill_chunk!r}")
     return int(prefill_chunk)
+
+
+class _Ahead(NamedTuple):
+    """A chunk launched for the step that follows: whose it is, the cursor
+    it started at, and what ``_launch_chunk`` returned."""
+    slot: int
+    req: Request
+    start: int
+    chunk: tuple
 
 
 class ServingEngine:
@@ -325,6 +335,9 @@ class ServingEngine:
         self._hist_len = np.zeros(num_slots, np.int32)
         self._sync_mirrors()
         self._dirty = False                 # mirrors diverged from device
+        # the NEXT step's chunk, launched behind this step's decode dispatch
+        # and not committed yet (``_launch_ahead``), or None
+        self._ahead: _Ahead | None = None
 
         # the hooks: attn_io/linear are the sharded engine's SP attention
         # and TP projections; ffn_chunk is a chunk-row-count FFN distinct
@@ -856,6 +869,39 @@ class ServingEngine:
             self.pool, jnp.asarray(row))
         return tok_dev, n_eff, row, len(part)
 
+    def _launch_ahead(self) -> None:
+        """Launch the chunk the NEXT step will run, now: behind this step's
+        decode dispatch and before the host blocks on its slab, so that the
+        device goes from the decode program straight into the chunk and the
+        slab's readback, ``reconcile``, ``post``, the caller's loop and the
+        next ``admit`` run under it. Only the host's order of launches
+        moves: the pool orders the chunk behind the decode program and the
+        next decode program behind the chunk, as the launches at the usual
+        place did.
+
+        Done only where the next step's choice is fixed already: a request
+        is PREFILLING (the coming ``admit`` hands out younger tickets alone,
+        so the oldest stays the oldest, and a slot decoding now cannot go
+        back to prefilling but through ``_preempt``, which drops the launch)
+        and no stall-budgeted class is decoding (that budget is a function
+        of who still decodes after the slab: such a chunk is sized and
+        launched at the usual place; a class's own ``chunk_budget`` is the
+        request's). NOTHING is committed here: cursor, counters, journal and
+        digest move in the next step's ``grow``, where they always did, so
+        between the two steps the engine is the parent's plus this record.
+        ``_preempt`` of its owner and ``_restore_state`` drop it (the chunk
+        is redone from the cursor it never moved); ``checkpoint()`` is
+        host-only and does not see it; a final chunk's token is read by
+        the next step's ``chunk_wait``."""
+        slot, req = self._oldest_prefilling()
+        if req is None or self._step_prefill_budget() is not None:
+            return
+        start = req.prefill_cursor
+        with self.metrics.phase("chunk_prep", step=self._steps, rid=req.rid,
+                                cursor=start):
+            self._ahead = _Ahead(slot, req, start,
+                                 self._launch_chunk(slot, req))
+
     def _commit_chunk(self, slot: int, req: Request, tok0: int | None,
                       n_eff: int, row) -> None:
         """Advance the cursor past a launched chunk. A chunk that is not the
@@ -919,6 +965,11 @@ class ServingEngine:
 
     def _preempt(self, slot: int) -> None:
         req = self.sched.slots[slot]
+        if self._ahead is not None and self._ahead.req is req:
+            # a chunk launched ahead and not committed: the victim's cursor
+            # never moved past it, so it is redone after re-admission (what
+            # it wrote lies in pages freed here, or in a slot that restarts)
+            self._ahead = None
         # composition hook (ISSUE 12): a wrapping engine (compose.py) may
         # own this slot's request — MIGRATING seats hold pages in a pool
         # this engine cannot see — and takes over the eviction when so
@@ -1094,14 +1145,24 @@ class ServingEngine:
 
         # ≤1 prefill chunk co-scheduled with the decode dispatch
         # (Sarathi-style): the decode stall this step is bounded by
-        # prefill_chunk tokens, not a whole prompt
+        # prefill_chunk tokens, not a whole prompt. The previous step may
+        # have launched it already, behind its decode dispatch
+        ahead, self._ahead = self._ahead, None
+        if ahead is not None:
+            pslot, preq, start, chunk = ahead
+            assert self.sched.slots[pslot] is preq \
+                and preq.state is RequestState.PREFILLING \
+                and preq.prefill_cursor == start, \
+                "a chunk launched ahead outlived its request's seat"
         prefilled_tokens, stalled, tok0 = 0, admit.t1, None
         if preq is not None:
             ids = {"step": n, "rid": preq.rid, "cursor": preq.prefill_cursor}
-            with m.phase("chunk_prep", **ids) as prep:
-                tok_dev, cursor, prow, prefilled_tokens = \
-                    self._launch_chunk(pslot, preq)
-            stalled = prep.t1
+            began = stalled
+            if ahead is None:
+                with m.phase("chunk_prep", **ids) as prep:
+                    chunk = self._launch_chunk(pslot, preq)
+                began, stalled = prep.t0, prep.t1
+            tok_dev, cursor, prow, prefilled_tokens = chunk
             if cursor >= len(preq.prompt):
                 with m.phase("chunk_wait", **ids) as chunk_wait:
                     # one int32 scalar download: the prompt's first token
@@ -1111,13 +1172,15 @@ class ServingEngine:
             # (any other chunk's token is nobody's and is never read: the
             # host goes on while the chunk runs, and what this step or the
             # next launches queues behind it on the pool it writes)
-            m.observe("prefill_stall_s", stalled - prep.t0)
+            m.observe("prefill_stall_s", stalled - began)
         m.observe("decode_stall_s", stalled - whole.t0)
         m.observe("step_prefill_tokens", prefilled_tokens)
 
         with m.phase("grow", step=n):
             if preq is not None:
                 self._commit_chunk(pslot, preq, tok0, cursor, prow)
+                if ahead is not None:
+                    m.inc("chunks_prelaunched")
             limits, active = self._grow()
 
         if not active:
@@ -1153,6 +1216,7 @@ class ServingEngine:
                 toks, self._token_dev, self._pos_dev, self.pool = self._step(
                     self.params, self._token_dev, self._pos_dev, self.pool,
                     self._bt_dev, jnp.asarray(limits))
+        self._launch_ahead()
         with m.phase("decode_wait", step=n) as decode_wait:
             # [B] committed-count vector under speculation
             accepted = np.asarray(acc) if self.spec_k else None
@@ -1459,6 +1523,7 @@ class ServingEngine:
             self._park(slot)
         self._sync_mirrors()
         self._dirty = False
+        self._ahead = None          # every live request restarts at cursor 0
         if state is None:
             return
         # integrity audit: the snapshot's ledger must digest to the value

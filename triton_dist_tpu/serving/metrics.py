@@ -16,7 +16,9 @@ from collections import deque
 
 from jax.profiler import TraceAnnotation
 
-# The phases of one ``ServingEngine.step()``, in the order a step runs them.
+# The phases of one ``ServingEngine.step()``, in the order a step runs them
+# (``chunk_prep`` a second time between ``dispatch`` and ``decode_wait``, for
+# the NEXT step's chunk where it is launched ahead: that step then opens none).
 # Each is a span ``engine.<name>`` in the profiler's trace and an exact
 # histogram ``phase_<name>_s``, children of ``engine.step`` / ``step_s``.
 # The two ``*_wait`` block on a device program; the rest is the host's own work.
@@ -175,7 +177,10 @@ class ServingMetrics:
                           (prefix adoption inside), choice of the
                           slot to prefill
     phase_chunk_prep_s    a chunk's budget, token buffer, COW guard,   host
-                          table row, uploads and launch (rid, cursor)
+                          table row, uploads and launch (rid, cursor):
+                          after admit for the step's own chunk, or
+                          between dispatch and decode_wait for the
+                          NEXT step's, launched ahead (one a chunk)
     phase_chunk_wait_s    a prompt's LAST chunk alone: blocked on the  wait
                           chunk program for the first token (rid,
                           cursor); no other chunk is waited for
@@ -196,13 +201,24 @@ class ServingMetrics:
     engine idle observes nothing. A chunk that is not its prompt's last has
     no token anybody reads (counter ``chunks_not_awaited``): the step goes
     on while it runs, the decode program queues behind it, and a step that
-    is such a chunk alone returns with the chunk in flight.
+    is such a chunk alone returns with the chunk in flight. Where a request
+    is PREFILLING at dispatch time and no stall-budgeted class decodes, the
+    chunk the NEXT step would launch is launched right behind the decode
+    program, before the host blocks on the slab (counter
+    ``chunks_prelaunched``, counted at the chunk's commit): nothing of it
+    is committed until that step's ``grow``, a step returns with it in
+    flight, and its token, where it is the prompt's last, is read by that
+    step's ``chunk_wait`` (``docs/serving.md`` has who drops it).
     ``decode_stall_s`` (step start to the chunk's launch, or to its token
-    where it is the last), ``prefill_stall_s`` (chunk prep, + wait where
-    there is one), ``step_device_s`` (dispatch + decode wait: it holds the
-    tail of a chunk that was not waited for) and ``step_host_s`` (step
-    start to reconcile's end, less ``step_device_s``: it counts a LAST
-    chunk's blocking call as host time) are differences of the same stamps.
+    where it is the last; to admission's end where a non-final chunk was
+    launched ahead), ``prefill_stall_s`` (one a chunk: its prep where the
+    step launched it itself, + wait where there is one), ``step_device_s``
+    (dispatch's start to decode wait's end: it holds the tail of a chunk
+    that was not waited for and the prep of the chunk launched ahead, so
+    every prep lies in exactly one of it and ``prefill_stall_s``) and
+    ``step_host_s`` (step start to reconcile's end, less
+    ``step_device_s``: it counts a LAST chunk's blocking call as host time)
+    are differences of the same stamps.
     A model family's own counters (``PagedFamily.counters``: for a share of
     an expert-parallel layer, ``moe_local_rows`` = routed assignments that
     landed on the experts held here and ``moe_experts_touched`` = held
@@ -240,6 +256,11 @@ class ServingMetrics:
             # so prefill_chunks - chunks_not_awaited ==
             # phase_chunk_wait_s.count
             "chunks_not_awaited": 0,
+            # and the chunks launched AHEAD: behind the previous step's
+            # decode dispatch, before the host blocked on its slab, so the
+            # step that ran them had no chunk_prep of its own (counted at
+            # the chunk's commit: chunks_prelaunched <= prefill_chunks)
+            "chunks_prelaunched": 0,
             # sharded serving (ISSUE 8): replicated-decision digest
             # cross-checks run (each one all-gathered the control-plane
             # digest over the mesh and compared every rank to rank 0)
