@@ -33,6 +33,19 @@ a KV head (128 x 128 under 8 KV heads), ``--chunk 2048`` the chunk's rows,
 ``window=`` over a RING of ``--pps`` pages (the context's pages wrap into it),
 ``--vmem-mb`` the chunk kernel's scoped-VMEM limit, ``--no-baseline`` leaves
 out the rows-of-decode variant (``gap`` is then against the first variant).
+
+The chunk walk as one in-kernel loop (ISSUE 41): ``--pages-per-group 1,2,4``
+tries several ``PREFILL_PAGES_PER_GROUP`` beside every ``--rows`` (a tree
+without the constant, the (row block, page) grid before ISSUE 41, runs as it
+ships whatever the flag says, so the same command in a checkout of another
+commit is the comparison). A line's ``pages_live`` / ``pages_edge`` are the
+pages the call's row blocks walk and those of them that take the masked
+update (``ops.flash_decode.chunk_walk_counts``; None in a tree without it),
+``grid_steps_before`` the (row block, page) steps of the grid that was,
+``sha1`` the result's. ``--longdoc`` is the long-document cell's chunk
+(command-a-plus: 16 heads a KV head, 2,048 rows, 6k and 20k tokens before the
+chunk) under window 4,096 over a ring of 49 pages and under none over a table
+of 200.
 """
 
 from __future__ import annotations
@@ -40,6 +53,7 @@ from __future__ import annotations
 import argparse
 import hashlib
 import json
+import math
 import os
 import sys
 import time
@@ -136,6 +150,72 @@ def decode_probe(a, dev, kp, vp, trace_dir):
     return lines
 
 
+def walk_pages(fd, start, real, rb, window):
+    """(pages the call's row blocks walk, edge pages among them, steps of the
+    (row block, page) grid before ISSUE 41) of one chunk call."""
+    from triton_dist_tpu.ops.flash_decode import _window_pages
+    rb = math.gcd(C, rb)
+    # (by the function both trees have)
+    grid = C // rb * (min(PPS, _window_pages(window, PAGE, rb)) if window
+                      else PPS)
+    if not hasattr(fd, "chunk_walk_counts"):
+        return None, None, grid
+    return (*fd.chunk_walk_counts(start, real, C, rb, PAGE, window, PPS), grid)
+
+
+def chunk_probe(a, dev, kq, kp, vp, window, trace_dir):
+    from triton_dist_tpu.ops import flash_decode as fd
+    q = jax.random.normal(kq, (C, HQ, D), jnp.bfloat16)
+    bt = jnp.asarray(np.random.default_rng(0).permutation(P - 1)[:PPS] + 1,
+                     jnp.int32)
+    win = {"window": window} if window else {}
+    variants = {} if a.no_baseline else {"decode_rows": (None, None, layers(
+        lambda q, kp, vp, bt, kv, ly: gqa_decode_paged(
+            q, kp, vp, jnp.broadcast_to(bt, (C, PPS)), kv, layer=ly,
+            **win)[0]))}
+    if a.vmem_mb:
+        win = dict(win, vmem_limit_bytes=a.vmem_mb << 20)
+    # pages a group: the tree's own, or several (a tree without the constant
+    # has one walk, a page a grid step)
+    own = getattr(fd, "PREFILL_PAGES_PER_GROUP", None)
+    groups = [int(g) for g in a.pages_per_group.split(",")] if (
+        a.pages_per_group and own) else [own]
+    for rb in (int(r) for r in a.rows.split(",")):
+        for g in groups:
+            variants[f"prefill_rb{rb}" + (f"_g{g}" if own else "")] = (
+                rb, g, layers(
+                    lambda q, kp, vp, bt, kv, ly, rb=rb: gqa_prefill_paged(
+                        q, kp, vp, bt, kv, layer=ly, rows_per_block=rb,
+                        **win)))
+    lines = []
+    for start, real in CONTEXTS:
+        idx = start + np.arange(C)
+        kv = jnp.asarray(np.where(idx < start + real, idx + 1, 0), jnp.int32)
+        want = None
+        for name, (rb, g, fn) in variants.items():
+            if g and a.pages_per_group:
+                # a trace-time constant: each variant is traced at its first
+                # call, and the score budget must not cut the group asked for
+                fd.PREFILL_PAGES_PER_GROUP = g
+                fd.PREFILL_GROUP_SCORE_BYTES = 1 << 30
+            got, ms, kern = measure(fn, (q, kp, vp, bt, kv), a.reps,
+                                    trace_dir)
+            want = got if want is None else want
+            live, edge, grid = walk_pages(fd, start, real, rb, window) \
+                if rb else (None, None, None)
+            lines.append({
+                "context": start, "real": real, "variant": name,
+                "group": a.group, "window": window, "chunk": C,
+                "pages_per_group": g, "ms_layer": ms, "kernel_us": kern,
+                "pages_live": live, "pages_edge": edge,
+                "grid_steps_before": grid,
+                "gap": float(np.abs(got - want).max()),
+                "sha1": hashlib.sha1(got.tobytes()).hexdigest(),
+                "device": dev.device_kind})
+            print(json.dumps(lines[-1]), flush=True)
+    return lines
+
+
 def main():
     global L, P, HQ, PPS, C, SLOTS, CONTEXTS
     ap = argparse.ArgumentParser()
@@ -158,8 +238,17 @@ def main():
     ap.add_argument("--contexts", default=None,
                     help="tokens before the chunk : real tokens in it, ...")
     ap.add_argument("--vmem-mb", type=int, default=None)
+    ap.add_argument("--pages-per-group", default=None,
+                    help="PREFILL_PAGES_PER_GROUP values to try, e.g. 1,2,4")
+    ap.add_argument("--longdoc", action="store_true",
+                    help="the long-document cell's chunk: --group 16 --chunk "
+                    "2048, 6k and 20k of context, window 4096 and none")
     ap.add_argument("--no-baseline", action="store_true")
     a = ap.parse_args()
+    if a.longdoc:
+        a.group, a.chunk, a.no_baseline = 16, 2048, True
+        a.contexts = a.contexts or "6144:2048,20480:2048"
+        a.vmem_mb = a.vmem_mb or 48
     L, P, HQ, PPS, C, SLOTS = (a.layers, a.pages, HKV * a.group, a.pps,
                                a.chunk, a.slots)
     if a.contexts:
@@ -180,36 +269,13 @@ def main():
         with open(os.path.join(out_dir, "decode_attn_probe.jsonl"), "a") as f:
             f.writelines(json.dumps(ln) + "\n" for ln in lines)
         return
-    q = jax.random.normal(kq, (C, HQ, D), jnp.bfloat16)
-    bt = jnp.asarray(np.random.default_rng(0).permutation(P - 1)[:PPS] + 1,
-                     jnp.int32)
-    win = {"window": a.window} if a.window else {}
-    variants = {} if a.no_baseline else {"decode_rows": layers(
-        lambda q, kp, vp, bt, kv, ly: gqa_decode_paged(
-            q, kp, vp, jnp.broadcast_to(bt, (C, PPS)), kv, layer=ly,
-            **win)[0])}
-    if a.vmem_mb:
-        win = dict(win, vmem_limit_bytes=a.vmem_mb << 20)
-    for rb in (int(r) for r in a.rows.split(",")):
-        variants[f"prefill_rb{rb}"] = layers(
-            lambda q, kp, vp, bt, kv, ly, rb=rb: gqa_prefill_paged(
-                q, kp, vp, bt, kv, layer=ly, rows_per_block=rb, **win))
     lines = []
-    for start, real in CONTEXTS:
-        idx = start + np.arange(C)
-        kv = jnp.asarray(np.where(idx < start + real, idx + 1, 0), jnp.int32)
-        want = None
-        for name, fn in variants.items():
-            got, ms, kern = measure(fn, (q, kp, vp, bt, kv), a.reps,
-                                    trace_dir)
-            want = got if want is None else want
-            lines.append({
-                "context": start, "real": real, "variant": name,
-                "group": a.group, "window": a.window, "chunk": C,
-                "ms_layer": ms, "kernel_us": kern,
-                "gap": float(np.abs(got - want).max()),
-                "device": dev.device_kind})
-            print(json.dumps(lines[-1]), flush=True)
+    # (window, pages a sequence): what was asked for, or the long-document
+    # cell's two kinds of layer
+    for window, pps in ([(4096, 49), (None, 200)] if a.longdoc
+                        else [(a.window, PPS)]):
+        PPS = pps
+        lines += chunk_probe(a, dev, kq, kp, vp, window, trace_dir)
     with open(os.path.join(out_dir, "prefill_attn_probe.jsonl"), "a") as f:
         f.writelines(json.dumps(ln) + "\n" for ln in lines)
 

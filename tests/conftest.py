@@ -10,9 +10,11 @@ default platform is.
 How to run (the one place that says it; README.md points here):
 
 - tier 1, the gate:  ``pytest tests/ -q -m 'not slow' -n 6 --dist load``
-  (ends by itself in well under 900 s on 8 cores; the driver allows 1470 s;
-  ``--dist loadfile``, which the driver ran before, does as well: no test
-  leans on another of its file having run in the same process)
+  (the driver allows 1470 s, and since PR 41 this sandbox's 8 cores need
+  nearly all of them: 8,200 worker-seconds; the driver's machine took 968 s
+  at PR 40. ``--dist loadfile``, which the driver ran before, does as well:
+  no test leans on another of its file having run in the same process.
+  ``FIXTURE_HEAVY_FIRST`` below says which files go first, and why)
 - the slow tier:     ``pytest tests/ -q -m slow`` (the full bit-identity
   matrices and dense crash sweeps; hours on the interpreter)
 - one file:          ``pytest tests/test_chaos.py -q``
@@ -20,8 +22,11 @@ How to run (the one place that says it; README.md points here):
 Every test, and every module- or session-scoped fixture's set-up, runs
 under ONE wall-clock watchdog: ``WATCHDOG_S`` = 120 s (``_wall_limit``
 below). There is no switch to lift or change it; only a test marked
-``slow`` runs without it. A tier-1 test needs less
-than half of it, so only a real hang reaches the limit; one that Python
+``slow`` runs without it. A tier-1 test or fixture needs less than half of
+it alone on the machine and up to 1.7 x that beside five busy workers (a
+fixture that is a minute of interpreter is split: its programs' compile, its
+golden run and its disturbed run a fixture each), so only a real hang
+reaches the limit; one that Python
 cannot get out of (every thread in a futex) ends its worker ``HARD_S`` = 30 s
 later: that test is lost, the run goes on.
 """
@@ -309,6 +314,27 @@ def _limited(item, what):
         return contextlib.nullcontext()
     return _wall_limit(what, item.config.stash.get(
         fault_handler_stderr_fd_key, sys.__stderr__.fileno()))
+
+
+# ---------------------------------------------------------------- order
+# ``--dist load`` deals CONSECUTIVE tests to a worker, a sixth of a quarter
+# of the suite at first (39 of 956) and ever fewer as the queue runs down:
+# two at a time at the end, so a late file's tests land on all six workers
+# and each of them builds the file's module fixtures again (the driver's
+# run of PR 41: tests/test_window_moe.py 880 worker-seconds and
+# test_sink_window_moe.py 809, where one process takes 388 and 638). The
+# files whose module fixtures are a minute of interpreter and more (an
+# engine and a golden run a family) therefore go FIRST, where a file is one
+# worker's chunk or two's. The order is the same in every worker (xdist
+# refuses a run whose workers collected differently) and no test leans on it.
+FIXTURE_HEAVY_FIRST = ("test_sink_window_moe.py", "test_window_moe.py",
+                       "test_linear_attn_moe.py", "test_latent_moe.py",
+                       "test_hybrid_ssm.py")
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(FIXTURE_HEAVY_FIRST)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
 
 
 @pytest.fixture(autouse=True)

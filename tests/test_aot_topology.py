@@ -755,6 +755,50 @@ def test_latent_walks_are_loops_over_live_pages(latent_programs):
         assert asked and not any(asked), (name, asked)
 
 
+def test_kv_chunk_walks_are_loops_over_live_pages(topo):
+    """The K/V chunk walk is the in-kernel loop over a row block's live pages
+    (ISSUE 41): whatever the call (dense, a window over a ring, a sink beside
+    keys wider than values), ``gqa_prefill_paged``'s grid is the row blocks
+    ALONE, where the (row block, page) grid made 4 x 13, 64 x 34 and 64 x
+    200, 16 x 3 and 16 x 108 steps a layer call. The dense call (4 heads a KV
+    head, 64 rows a block) stays inside Mosaic's default 16 MB of scoped VMEM
+    and asks for no limit; both window families (16, and 8 / 16, heads a KV
+    head, 32 rows a block) ask for ``CHUNK_VMEM_LIMIT`` in both kinds of
+    layer. (That Mosaic takes each call at its published widths is what the
+    programs' compiles in the fixtures around this test show.)"""
+    from triton_dist_tpu.models import llama
+    from triton_dist_tpu.models import window_moe as wm
+
+    def walks(jaxpr, found):
+        """name -> (grid, scoped-VMEM limit asked for) of the chunk walks."""
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" and eqn.params[
+                    "name"].startswith("gqa_prefill_paged"):
+                asked = eqn.params["compiler_params"].get("mosaic_tpu")
+                found[eqn.params["name"]] = (
+                    tuple(eqn.params["grid_mapping"].grid),
+                    getattr(asked, "vmem_limit_bytes", None))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walks(sub, found)
+        return found
+
+    def chunk_walks(cfg, init, pages, B, C, W):
+        _, chunk, params, _, rest = _engine_programs(topo, cfg, init, pages,
+                                                     B, C, W)
+        return walks(jax.jit(chunk).trace(params, *rest).jaxpr.jaxpr, {})
+
+    limit = wm.CHUNK_VMEM_LIMIT
+    assert chunk_walks(_mistral_cfg(), llama.init_params, POOL_P, 16, 256,
+                       POOL_PPS) == {"gqa_prefill_paged": ((4,), None)}
+    assert chunk_walks(_window_cfg(), wm.init_params, 1201, 24, 2048,
+                       201) == {"gqa_prefill_paged_window": ((64,), limit),
+                                "gqa_prefill_paged": ((64,), limit)}
+    assert chunk_walks(_sink_window_cfg(), wm.init_params, SINK_P, SINK_SLOTS,
+                       512, SINK_PPS + 1) == {
+        "gqa_prefill_paged_window_sink": ((16,), limit),
+        "gqa_prefill_paged": ((16,), limit)}
+
+
 # -- the mixer-beside-attention family's programs at published widths (ISSUE 32)
 
 HYB_P, HYB_SLOTS = 1282, 64

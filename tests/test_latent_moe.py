@@ -437,6 +437,9 @@ def test_the_engine_serves_the_family_and_counts(model, ample):
     assert c["moe_experts_touched"] <= per_step * c["decode_steps"]
     assert eng.compile_stats["decode_compiles"] == 1
     assert eng.compile_stats["prefill_chunk_compiles"] == 1
+    # a latent chunk walks no K/V pages: the walk's counters do not exist
+    assert not {"chunk_walk_pages", "chunk_walk_edge_pages"} & set(
+        eng.metrics.snapshot())
 
 
 def test_a_tight_pool_preempts_and_resumes_to_the_same_tokens(model, ample):

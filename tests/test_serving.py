@@ -1038,6 +1038,42 @@ def test_a_victim_whose_chunk_was_not_awaited(tiny_model, victim_case,
 # before the host waits for the slab (ISSUE 40)
 # ---------------------------------------------------------------------------
 
+def test_a_chunks_walked_and_edge_pages_are_counted_at_its_commit(
+        tiny_model, victim_case):
+    """``chunk_walk_pages`` / ``chunk_walk_edge_pages`` move at a chunk's
+    commit alone, by what ``ops.flash_decode.chunk_walk_counts`` (the plan the
+    kernel walks by) says of the chunk's cursor and length, times the layers
+    that walk. Chunks of 4 over pages of 8: the 10-token prompt's three
+    chunks walk 1 + 1 + 2 pages a layer, of which 1 + 1 + 1 are edge (the
+    third chunk's first page is interior: both its rows see all 8 keys)."""
+    from triton_dist_tpu.ops.flash_decode import chunk_walk_counts
+    reqs, want = victim_case
+    eng = _chunk4_engine(tiny_model, 4, pages_per_seq=6)
+    cfg, c = eng.cfg, eng.metrics.counters
+    assert eng._chunk_walks == ((cfg.n_layers, 64, None),)
+    chunks, commit = [], eng._commit_chunk
+
+    def recording(slot, req, tok0, n_eff, row):
+        chunks.append((req.prefill_cursor, n_eff - req.prefill_cursor))
+        before = c["chunk_walk_pages"], c["chunk_walk_edge_pages"]
+        commit(slot, req, tok0, n_eff, row)
+        pages, edge = chunk_walk_counts(*chunks[-1], 4, 64, 8, None, 6)
+        assert (c["chunk_walk_pages"] - before[0],
+                c["chunk_walk_edge_pages"] - before[1]) == (
+                    cfg.n_layers * pages, cfg.n_layers * edge)
+
+    eng._commit_chunk = recording
+    a = eng.submit(*reqs[0])
+    res = eng.run(max_steps=2000)
+    assert chunks == [(0, 4), (4, 4), (8, 2)] and res[a] == want[0]
+    assert (c["chunk_walk_pages"], c["chunk_walk_edge_pages"]) == (
+        4 * cfg.n_layers, 3 * cfg.n_layers)
+    b = eng.submit(*reqs[1])                   # 40 tokens: ten chunks of 4
+    assert eng.run(max_steps=2000)[b] == want[1] and len(chunks) == 13
+    snap = eng.metrics.snapshot()
+    assert 0 < snap["chunk_walk_edge_pages"] < snap["chunk_walk_pages"]
+
+
 @HORIZONS
 def test_the_next_chunk_is_launched_before_the_slab_is_read(
         tiny_model, victim_case, horizon):

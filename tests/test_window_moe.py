@@ -38,7 +38,7 @@ from benchmark.references import window_moe_lm as ref
 from triton_dist_tpu.models import window_moe as wm
 from triton_dist_tpu.models.llama import (decode_step_paged,
                                           prefill_chunk_paged)
-from triton_dist_tpu.ops import mla_decode
+from triton_dist_tpu.ops import flash_decode, mla_decode
 from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
                                               gqa_prefill_paged)
 from triton_dist_tpu.serving import ServingEngine
@@ -90,14 +90,22 @@ def table(pc, slot, first_page=3):
                        + [1 + slot * pc.ring_pages(PAGE)], jnp.int32)
 
 
+_CHUNK_PROGRAMS = {}
+
+
 def prefill(w, pc, pool, bt, toks, n_pre):
-    chunk = jax.jit(lambda t, s, pg: prefill_chunk_paged(
-        w, t, s, jnp.int32(n_pre), pc, pg, bt))
+    # ONE traced chunk program a configuration: the weights, the table and
+    # the prompt's length are its arguments (a program a call re-traced and
+    # re-compiled it three times in one test)
+    chunk = _CHUNK_PROGRAMS.setdefault(pc, jax.jit(
+        lambda w, t, s, n, pg, bt: prefill_chunk_paged(w, t, s, n, pc, pg,
+                                                       bt)))
     for start in range(0, n_pre, CHUNK):
         part = np.zeros(CHUNK, np.int32)
         real = toks[start:min(start + CHUNK, n_pre)]
         part[:len(real)] = real
-        tok, pool = chunk(jnp.asarray(part), jnp.int32(start), pool)
+        tok, pool = chunk(w, jnp.asarray(part), jnp.int32(start),
+                          jnp.int32(n_pre), pool, bt)
     return tok, pool
 
 
@@ -340,12 +348,13 @@ def recomputed():
 
     def get(case):
         if case not in cache:
-            # the pinned tree made one online-softmax update a page: so does
-            # the latent loop, a chunk's or the decode rows', with a group
-            # of one page
+            # the pinned tree made one online-softmax update a page: so do
+            # the latent loop, a chunk's or the decode rows', and the K/V
+            # chunk walk's loop (ISSUE 41) with a group of one page
             with pytest.MonkeyPatch.context() as patch:
                 patch.setattr(mla_decode, "DECODE_PAGES_PER_GROUP", 1)
                 patch.setattr(mla_decode, "CHUNK_PAGES_PER_GROUP", 1)
+                patch.setattr(flash_decode, "PREFILL_PAGES_PER_GROUP", 1)
                 cache[case] = parent_pins.CASES[case]()
         return cache[case]
     return get
@@ -415,11 +424,13 @@ def test_a_ring_another_sequence_filled_is_never_attended(model):
 
 
 @pytest.fixture(scope="module")
-def replay(model):
-    """Three requests (contexts to 75 tokens: past the 56 a ring holds)
-    through ONE engine of two slots, twice: undisturbed, and with the oldest
-    request preempted in the middle of its prefill and a decoding one
-    preempted later."""
+def replay_engine(model):
+    """Three requests (contexts to 75 tokens: past the 56 a ring holds) and
+    ONE engine of two slots to put them through, undisturbed
+    (``replay_golden``) and then with the oldest request preempted in the
+    middle of its prefill and a decoding one preempted later (``replay``): a
+    fixture each, because a run is most of a minute of interpreter and the
+    suite's watchdog counts a fixture's wall."""
     fc, pc, w = model
     rng = np.random.default_rng(7)
     reqs = [(rng.integers(1, 256, n), m) for n, m in
@@ -451,7 +462,18 @@ def replay(model):
         done = {r.rid: list(r.generated) for r in eng._finished}
         return {i: done[rid] for i, rid in enumerate(rids)}
 
-    return eng, serve(False), serve(True), seen
+    return eng, serve, seen
+
+
+@pytest.fixture(scope="module")
+def replay_golden(replay_engine):
+    return replay_engine[1](False)
+
+
+@pytest.fixture(scope="module")
+def replay(replay_engine, replay_golden):
+    eng, serve, seen = replay_engine
+    return eng, replay_golden, serve(True), seen
 
 
 def test_a_preempted_sequence_replays_its_tokens(replay):
@@ -464,6 +486,25 @@ def test_a_preempted_sequence_replays_its_tokens(replay):
     assert eng.metrics.counters["preemptions"] == 2
     conftest.assert_replay_identical(again, golden, 3)
     assert len({tuple(t) for t in golden.values()}) == 3
+
+
+def test_the_engine_sizes_the_rings_and_counts_pages_by_kind(replay):
+    eng = replay[0]
+    ring = eng.cfg.ring_pages(PAGE)
+    assert (eng.cfg.ring_slots, eng.cfg.ring_chunk) == (2, CHUNK)
+    assert eng._bt.shape == (2, PPS + 1)
+    assert eng.pool["wk"].shape[1] == 1 + 2 * ring
+    full = eng.metrics.hist["kv_pages_full"].total
+    held = eng.metrics.hist["kv_pages_window"].total
+    assert 0 < held < full            # a context past the ring was served
+    c = eng.metrics.counters
+    assert c["attn_window_keys"] > 0 and c["attn_full_keys"] > 0
+    assert c["moe_local_rows"] > 0
+    # both kinds' chunk walks are counted, by the kind's layers and window
+    walks = dict((w, n) for n, _, w in eng.cfg.paged.chunk_walks(eng.cfg))
+    assert walks == {eng.cfg.window: eng.cfg.layers_of("window"),
+                     None: eng.cfg.layers_of("full")}
+    assert 0 < c["chunk_walk_edge_pages"] <= c["chunk_walk_pages"]
 
 
 @pytest.fixture(scope="module")
@@ -507,20 +548,6 @@ def test_a_ring_victim_whose_chunk_was_not_awaited(ring_victim, horizon):
     assert counters["prefill_chunks"] == 1 + 1 + 2      # the victim's again
     assert tokens == golden
     assert serve(horizon, spare=5, fence=True)[:2] == (tokens, digests)
-
-
-def test_the_engine_sizes_the_rings_and_counts_pages_by_kind(replay):
-    eng = replay[0]
-    ring = eng.cfg.ring_pages(PAGE)
-    assert (eng.cfg.ring_slots, eng.cfg.ring_chunk) == (2, CHUNK)
-    assert eng._bt.shape == (2, PPS + 1)
-    assert eng.pool["wk"].shape[1] == 1 + 2 * ring
-    full = eng.metrics.hist["kv_pages_full"].total
-    held = eng.metrics.hist["kv_pages_window"].total
-    assert 0 < held < full            # a context past the ring was served
-    c = eng.metrics.counters
-    assert c["attn_window_keys"] > 0 and c["attn_full_keys"] > 0
-    assert c["moe_local_rows"] > 0
 
 
 @pytest.mark.parametrize("option", [{"prefix_cache": True},

@@ -725,10 +725,15 @@ def _fake_collectives(state: CaptureState):
         return true_fun(*operands) if bool(np.asarray(pred)) \
             else false_fun(*operands)
 
+    def switch(index, branches, *operands, **_kw):
+        at = min(max(_as_int(index), 0), len(branches) - 1)
+        return branches[at](*operands)
+
     return dict(all_gather=all_gather, psum=psum, psum_scatter=psum_scatter,
                 ppermute=ppermute, all_to_all=all_to_all,
                 axis_index=axis_index, axis_size=axis_size,
-                fori_loop=fori_loop, while_loop=while_loop, cond=cond)
+                fori_loop=fori_loop, while_loop=while_loop, cond=cond,
+                switch=switch)
 
 
 def _fake_when(condition):

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from triton_dist_tpu.models.llama import PagedFamily, rope
+from triton_dist_tpu.models.llama import (PagedFamily, plain_chunk_walks,
+                                          rope)
 
 # The recurrent state's dtype: a running sum over the whole context. Not a
 # config field: bfloat16 is a different result, not a faster one
@@ -355,7 +356,8 @@ HYBRID_SSM = PagedFamily(
     counters=("ssm_state_rows",),
     # a state is the slot's and cannot be rewound, shared or copied by page
     lacks=("speculate", "prefix_cache", "hooks"),
-    slot_state=slot_state_bytes, bind=bind)
+    slot_state=slot_state_bytes, bind=bind,
+    chunk_walks=lambda cfg: plain_chunk_walks(cfg.n_layers))
 
 
 __all__ = ["HybridSSMConfig", "HYBRID_SSM", "init_params", "init_pools",

@@ -66,7 +66,8 @@ from jax import lax
 
 from triton_dist_tpu.models.expert_share import (COUNTERS, held_ids,
                                                  held_picks, softmax_route)
-from triton_dist_tpu.models.llama import PagedFamily, gated_ffn, rope
+from triton_dist_tpu.models.llama import (PagedFamily, gated_ffn,
+                                          plain_chunk_walks, rope)
 
 # The recurrent state's dtype: a running sum over the whole context. Not a
 # config field: bfloat16 is a different result, not a faster one
@@ -465,7 +466,9 @@ LINEAR_ATTN_MOE = PagedFamily(
     counters=COUNTERS + ("gdn_state_rows", "attn_full_keys"),
     # a state is the slot's and cannot be rewound, shared or copied by page
     lacks=("speculate", "prefix_cache", "hooks"),
-    slot_state=slot_state_bytes, bind=bind)
+    slot_state=slot_state_bytes, bind=bind,
+    # the full layers alone walk pages
+    chunk_walks=lambda cfg: plain_chunk_walks(cfg.layers_of("full")))
 
 
 __all__ = ["LinearAttnMoEConfig", "LINEAR_ATTN_MOE", "init_params",

@@ -504,7 +504,14 @@ class PagedFamily:
       reference or copied by page: the engine restarts a preempted request
       and refuses page copy, export and import by name.
     - ``embed(cfg, params, tokens) -> x``: the rows the layer loop starts
-      from; None: ``params["embed"][tokens]``."""
+      from; None: ``params["embed"][tokens]``.
+    - ``chunk_walks(cfg) -> ((layers, rows_per_block, window), ...)``: the
+      ``gqa_prefill_paged`` calls of ONE chunk program, a kind of attention
+      layer each: how many layers walk, in row blocks of what, under which
+      window (None: the whole context). The engine counts a chunk's walked
+      and edge pages from it at the chunk's commit, on the host
+      (``ops.flash_decode.chunk_walk_counts``: counters ``chunk_walk_pages``
+      / ``chunk_walk_edge_pages``); None: the chunk walks no K/V pages."""
     name: str
     init_pool: Any
     segments: Any
@@ -520,6 +527,7 @@ class PagedFamily:
     bind: Any = None
     slot_state: Any = None
     embed: Any = None
+    chunk_walks: Any = None
 
     # ``benchmark/tools/fit_paged.py`` reads the two shared programs off the
     # record; they are the module's functions, whatever the family.
@@ -1188,12 +1196,22 @@ def forward_tp_overlap(ctx: ShmemContext, params: dict, tokens: jax.Array,
     return (x @ params["lm_head"]).astype(jnp.float32)
 
 
+def plain_chunk_walks(layers: int) -> tuple:
+    """``PagedFamily.chunk_walks`` of a family whose ``layers`` attention
+    layers call ``gqa_prefill_paged`` as ``_gqa_attention`` does: the whole
+    context, at the kernel's default block."""
+    from triton_dist_tpu.ops.flash_decode import PREFILL_ROWS_PER_BLOCK
+    return ((layers, PREFILL_ROWS_PER_BLOCK, None),)
+
+
 GQA_DENSE = PagedFamily(
     name="gqa_dense", init_pool=init_page_pool, segments=_gqa_segments,
-    attention=_gqa_attention, decode_speculate=decode_speculate_paged)
+    attention=_gqa_attention, decode_speculate=decode_speculate_paged,
+    chunk_walks=lambda cfg: plain_chunk_walks(cfg.n_layers))
 
 
 __all__ = ["LlamaConfig", "LayerParams", "PagedFamily", "GQA_DENSE",
+           "plain_chunk_walks",
            "swiglu_ffn", "gated_ffn", "require_config", "init_params",
            "param_specs", "forward",
            "forward_tp_overlap", "mlp_tp_overlap", "rmsnorm", "rope",

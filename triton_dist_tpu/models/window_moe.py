@@ -449,7 +449,10 @@ WINDOW_MOE = PagedFamily(
     counters=COUNTERS + ("attn_window_keys", "attn_full_keys"),
     # ring pages are the slot's: nothing copies, exports or indexes them
     lacks=("speculate", "prefix_cache", "hooks"),
-    slot_ring=lambda cfg, page_size: cfg.ring_pages(page_size), bind=bind)
+    slot_ring=lambda cfg, page_size: cfg.ring_pages(page_size), bind=bind,
+    chunk_walks=lambda cfg: (
+        (cfg.layers_of("window"), CHUNK_ROWS_PER_BLOCK, cfg.window),
+        (cfg.layers_of("full"), CHUNK_ROWS_PER_BLOCK, None)))
 
 # ``sequential`` configs: RMSNorm twice a layer and an untied ``lm_head``
 # (``PagedFamily``'s defaults); everything else is the record above

@@ -27,6 +27,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -296,6 +297,13 @@ def _window_pages(window: int, page_size: int, rows: int) -> int:
     return -(-(window + rows - 1) // page_size) + 1
 
 
+def _pages_at_most(window, table: int, page_size: int, rows: int) -> int:
+    """Pages ``rows`` consecutive rows can walk together at most: their
+    windows' pages, or the whole table."""
+    return min(table, _window_pages(window, page_size, rows)) if window \
+        else table
+
+
 def _check_ring(window, ring: int, page_size: int, rows: int,
                 widths: tuple) -> None:
     assert window is None or (window >= 1 and ring >= _window_pages(
@@ -442,9 +450,7 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if sinks is not None:
         extra = (sinks.astype(jnp.float32).reshape(Hq, 1),)
         extra_specs = [pl.BlockSpec((Hq, 1), lambda i, *_: (0, 0))]
-    # what a row can read at most: its window's pages, or the whole table
-    live = min(pages_per_seq, _window_pages(window, page_size, 1)) \
-        if window else pages_per_seq
+    live = _pages_at_most(window, pages_per_seq, page_size, 1)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -486,68 +492,273 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 # rows read 116 but need 18 MB of scoped VMEM, over Mosaic's 16 MB default.
 PREFILL_ROWS_PER_BLOCK = 64
 
+# The chunk's walk is ONE grid step a row block, a loop over its live pages
+# inside, a GROUP of pages an online-softmax update. Chosen on the v5e
+# (PERF.md section 6, PR 41, scripts/prefill_attn_probe.py --longdoc: the
+# kernel alone at command-a-plus's widths, a 2,048-row chunk of 128 query
+# heads over 8 KV heads, 32 rows a block = [512, 128] queries a head; ms a
+# layer call under window 4,096 (any context) | no window, 6k | 20k of
+# context before the chunk; sha1 = the result's hash):
+#   the (64, 34 | 200) grid, a page a step (PR 40)  10.60 | 19.28 | 51.65
+#   the loop, KV heads looped, pages a group:
+#     1 (the grid's sha1)                           26.75 | 46.30 | 137.7
+#     2                                             14.23 | 23.37 | 68.06
+#     4                                              8.12 | 13.08 | 36.71
+#     8                                              5.46 |  8.45 | 22.33
+#   + the next block's first group behind this block's last, 16 / 64 rows a
+#     block at 4 pages a group: 9.83 / 8.67 | 14.97 / 14.16 | 42.2 / 40.8
+#     4 / 6 pages a group           7.97 / 6.50 | 12.64 / 9.84 | 36.3 / 27.7
+#     8                                              5.29 |  8.00 | 21.89  <-
+#     8, KV heads unrolled side by side as far as 1 / 2 / 4 MB of their
+#     float32 scores go:             5.32 / 5.26 / 12.00 | 7.91 / 7.85 / 20.4
+# An update costs ~12 us of fixed work a [8 x 512]-row block (two cross-lane
+# reductions, the [M, 1] bookkeeping, the accumulator's rescale: 1.5 us a KV
+# head) beside ~0.8 us a page: at one page a group the loop is 2.5 x the
+# grid, whose unrolled heads hid one head's chain under another's products,
+# and every doubling of the group nearly halves the call. The MXU alone needs
+# 3.0 ms a window layer. Mosaic (libtpu 0.0.34) dies on a ``lax.switch`` of
+# more than ten branches: 8 is the largest group of exact sizes. The same
+# command at Mistral's widths (4 heads a group, 64 rows a block, a 256-row
+# chunk; us a layer call at 0 / 512 / 1,280 / 677 tokens before it): the
+# grid 31.8 / 70.8 / 129.4 / 83.1, the loop at 8 pages 36.7 / 61.4 / 100.8 /
+# 67.7 (heads unrolled as far as 2 MB: 32.4 / 56.7 / 88.5 / 60.3); MiMo's
+# (sink_window_probe): a window layer 0.186 -> 0.183 ms, a full layer 1.46 ->
+# 0.64 at 2k, 5.70 -> 2.26 at 12k. Unrolled heads buy 0-12 % of a short walk
+# and nothing of a long one, double the code Mosaic compiles, and with 4 MB
+# unrolled every call read 2.3-3.4 x SLOWER (PR 34's cliff): the heads are a
+# loop.
+# Consecutive pages of a row block's walk that share ONE online-softmax
+# update, at most; and what ONE KV head's float32 scores of such a group may
+# take ([M, pages x page_size] x 4 B): a block of more rows x heads than
+# [512] takes fewer pages a group.
+PREFILL_PAGES_PER_GROUP = 8
+PREFILL_GROUP_SCORE_BYTES = 2 << 20
+# ... and of EDGE pages, which take the masked update: a block of consecutive
+# rows has at most two or three on either side of its interior pages, so
+# larger masked updates would be code nobody runs
+PREFILL_EDGE_PAGES_PER_GROUP = 4
 
-def _prefill_paged_kernel(*refs, page_size: int, sm_scale: float,
+
+def chunk_walk_bounds(kv_len: jax.Array, rows: int):
+    """Of every block of ``rows`` consecutive entries of ``kv_len`` [C]:
+    (the smallest LIVE ``kv_len``, i.e. over rows with ``kv_len`` > 0, and 0
+    for a block of padding alone; the largest). These two numbers a block are
+    all a chunk's walk is planned from (``chunk_walk_pages``)."""
+    kl = kv_len.reshape(-1, rows)
+    kl_max = kl.max(axis=1)
+    kl_min = jnp.where(kl > 0, kl, jnp.iinfo(jnp.int32).max).min(axis=1)
+    return jnp.where(kl_max > 0, kl_min, 0), kl_max
+
+
+def chunk_walk_pages(kl_min, kl_max, page_size: int, window=None,
+                     pages_per_seq=None, xp=jnp):
+    """The pages a row block walks and which of them need the mask, from the
+    block's smallest live ``kv_len`` and its largest (``chunk_walk_bounds``;
+    scalars or arrays, one entry a block): ``(first, lo, hi, end)``, logical
+    pages.
+
+    The block walks ``[first, end)``: from the page of its LOWEST bound
+    (``kl_min - window``; page 0 without a window) to the page of its last
+    key (capped at the table's ``pages_per_seq`` pages where there is no
+    ring). A page is INTERIOR when every live row of the block sees every key
+    of it: it ends at or before the smallest live ``kv_len`` and (under a
+    window) starts at or after the HIGHEST bound, ``kl_max - window``. Those
+    are ``[lo, hi)``; the pages ``[first, lo)`` and ``[hi, end)`` are EDGE
+    pages, where some live row's mask drops a key (``lo == hi == end`` where
+    no page is interior: the walk is one run of edge pages). A padding row
+    (``kv_len`` 0) is not live: it is zeroed at the end whatever it summed, so
+    it makes no page an edge; a sink changes where the softmax starts and no
+    page's kind. A block of padding alone walks nothing (``first == end``).
+    ``xp`` is the array module: ``jnp`` in a program or a kernel, ``numpy``
+    on the host (``chunk_walk_counts``)."""
+    end = (kl_max + page_size - 1) // page_size
+    if window:
+        first = xp.maximum(kl_min - window, 0) // page_size
+        lo = (xp.maximum(kl_max - window, 0) + page_size - 1) // page_size
+    else:
+        end = xp.minimum(end, pages_per_seq)
+        first = lo = 0 * end
+    lo = xp.minimum(xp.maximum(lo, first), end)
+    hi = xp.minimum(xp.maximum(kl_min // page_size, lo), end)
+    # no interior page: the walk is ONE run of edge pages, ``[first, end)``
+    lo = xp.where(hi > lo, lo, end)
+    return first, lo, xp.maximum(hi, lo), end
+
+
+def chunk_walk_counts(start: int, real: int, chunk: int, rows: int,
+                      page_size: int, window=None, pages_per_seq=None):
+    """(pages walked, edge pages among them) of ONE ``gqa_prefill_paged``
+    call on a chunk of ``chunk`` rows in blocks of ``rows`` whose first row
+    sits at position ``start`` and whose first ``real`` rows are live: summed
+    over the row blocks, on the host (numpy), from the same plan the kernel
+    walks by."""
+    rows = math.gcd(chunk, rows)
+    # row r attends start + r + 1 keys: a block's bounds (``chunk_walk_bounds``
+    # of such a chunk) from its first position
+    at = start + np.arange(0, chunk, rows)
+    live = at < start + real
+    first, lo, hi, end = chunk_walk_pages(
+        np.where(live, at + 1, 0),
+        np.where(live, np.minimum(at + rows, start + real), 0), page_size,
+        window, pages_per_seq, np)
+    return int((end - first).sum()), int((end - first - (hi - lo)).sum())
+
+
+def _prefill_paged_kernel(kmin_ref, kmax_ref, bt_ref, layer_ref, q_ref,
+                          klr_ref, *refs, group: int, edge: int,
+                          n_pool: int, page_size: int, sm_scale: float,
                           window: int | None = None, sinks: bool = False):
-    """Grid (row blocks, pages). ``q_ref`` [Hkv, M, D] is a block of
-    Rb rows x G heads a KV head (M = Rb * G), ``klr_ref`` [M, 1] their
-    ``kv_len``, ``kl_ref[i]`` the largest of block i; ``k_ref`` / ``v_ref``
-    [1, Hkv, page_size, D] the page. The online-softmax update is
-    ``_online_softmax_body``'s, with M query rows a head against the page
-    where decode has G, and the ``kv_len`` mask per row. With ``window``
-    grid step s is logical page ``first_ref[i] + s`` (the page of the
-    block's lowest bound) and a row's keys below ``kv_len - window`` are
-    masked too. With ``sinks`` one more operand follows ``klr_ref``:
-    ``sink_ref`` [Hkv, M, 1] float32, every (head, row)'s learned logit, which
-    the softmax starts from (``_softmax_init``)."""
-    kl_ref, _, _, *refs = refs
-    first_ref, refs = (refs[0], refs[1:]) if window else (None, refs)
-    q_ref, klr_ref, *refs = refs
+    """Grid (row blocks,) over a paged KV pool left in HBM: ONE loop over the
+    block's pages ``[first, end)`` of ``chunk_walk_pages(kmin_ref[i],
+    kmax_ref[i])`` and nothing else, a GROUP of up to ``group`` consecutive
+    pages a turn. ``q_ref`` [Hkv, M, Dk] is a block of Rb rows x G heads a KV
+    head (M = Rb * G), ``klr_ref`` [M, 1] their ``kv_len``, ``bt_ref`` the ONE
+    table row they share. With ``sinks`` one more operand follows
+    ``klr_ref``: ``sink_ref`` [Hkv, M, 1] float32, every (head, row)'s learned
+    logit, which the softmax starts from (``_softmax_init``).
+
+    A turn copies the group's pages (``bt_ref[page]``, under a window
+    ``bt_ref[page % ring]``, of layer ``layer_ref[0]``, clamped into the pool)
+    into adjacent [page_size] slices of one of two VMEM operands ``k_buf`` /
+    ``v_buf`` [2, Hkv, group * page_size, D], the next group's copies in
+    flight behind it (behind a block's last group: the NEXT block's first,
+    ``turns`` counting groups across grid steps so that both sides name the
+    same operand), and makes ONE online-softmax update of the block's rows
+    against the group: running max, ``alpha``, the accumulator's rescale and
+    the cast of ``p`` once a group, KV head by KV head (a loop: the update's
+    code is one head's, whatever Hkv). Groups never straddle a change of kind
+    (``[first, lo)``, ``[lo, hi)``, ``[hi, end)`` are cut into groups each on
+    its own, interior runs by ``group`` pages and edge runs by ``edge``), and
+    a run's short last group is attended at its own size (one branch a size:
+    no stale row of the operand is ever read).
+
+    An INTERIOR group takes the update WITHOUT the mask: every live row sees
+    every key, ``where(True, s, NEG_INF) == s``, so the result is the masked
+    update's to the bit (a padding row sums keys it should not see, and is
+    zeroed at the end as ever). An EDGE group masks, a row at a time, keys at
+    ``kv_len`` and beyond and (window) those below ``kv_len - window``. A row
+    sees key 0 of the walk's first page or nothing yet: a page wholly masked
+    for a row finds its running max real and adds exp(NEG_INF - m) = 0, or
+    finds it NEG_INF and adds exp(0), which the row's first real key scales
+    by exp(NEG_INF - m) = 0; with a sink the max is real from the start."""
     sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
-    k_ref, v_ref, out_ref, acc, m_i, l_i = refs
-    i, s = pl.program_id(0), pl.program_id(1)
-    page = first_ref[i] + s if window else s
+    k_hbm, v_hbm, out_ref, k_buf, v_buf, sem, turns, acc, m_i, l_i = refs
+    i, n_blk = pl.program_id(0), pl.num_programs(0)
+    Hkv, M, _ = q_ref.shape
+    pages_per_seq = bt_ref.shape[0]
+    layer = layer_ref[0]
 
-    pl.when(s == 0)(lambda: _softmax_init(
-        acc, m_i, l_i, None if sink_ref is None else sink_ref[...]))
+    def walk(blk):
+        """A block's walk: its four page bounds, the groups of its low edge
+        pages, of its interior ones, and of them all."""
+        first, lo, hi, end = chunk_walk_pages(
+            kmin_ref[blk], kmax_ref[blk], page_size, window, pages_per_seq)
+        n_low = (lo - first + edge - 1) // edge
+        n_in = (hi - lo + group - 1) // group
+        return (first, lo, hi, end, n_low, n_in,
+                n_low + n_in + (end - hi + edge - 1) // edge)
 
-    # a page that no row of the block can see: no compute (and no DMA, the
-    # index map revisits the block's last live page)
-    @pl.when(page * page_size < kl_ref[i])
+    def group_at(of, t):
+        """(first page, pages, masked?) of walk ``of``'s t-th group."""
+        first, lo, hi, end, n_low, n_in, _ = of
+        low, mid = t < n_low, t < n_low + n_in
+        page = jnp.where(low, first + t * edge, jnp.where(
+            mid, lo + (t - n_low) * group, hi + (t - n_low - n_in) * edge))
+        stop = jnp.where(low, lo, jnp.where(mid, hi, end))
+        return (page, jnp.minimum(stop - page, jnp.where(mid & ~low, group,
+                                                         edge)), low | ~mid)
+
+    def copies(page0, n, slot, do):
+        """``do`` both copies of each of the ``n`` pages from ``page0``."""
+        def one(j, _):
+            col = (page0 + j) % pages_per_seq if window else page0 + j
+            # the clamp keeps even a garbage block-table entry inside the pool
+            page = jnp.clip(bt_ref[col], 0, n_pool - 1)
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for kv, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                do(pltpu.make_async_copy(hbm.at[layer, page],
+                                         buf.at[slot, :, at],
+                                         sem.at[kv, slot, j]))
+
+        # a loop, not ``group`` conditionals: the trace and Mosaic's compile
+        # are inside the serving engine's set-up time
+        lax.fori_loop(0, n, one, None)
+
+    # groups attended by the blocks before this one: a group's operand is
+    # ``turns % 2``, so that a block can start the NEXT block's first group
+    # behind its own last one (scratch outlives a grid step)
+    @pl.when(i == 0)
     def _():
-        q, k, v = q_ref[...], k_ref[0], v_ref[0]
-        scores = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * sm_scale  # [Hkv, M, page]
-        M = scores.shape[1]
-        pos = page * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (M, page_size), 1)
-        # a row sees key 0 on page 0 or sees nothing at all, so a page
-        # wholly masked for a row finds its running max already real and
-        # adds exp(NEG_INF - m) = 0; rows that see nothing are zeroed below.
-        # (Under a window a later row of the block may see nothing of the
-        # block's first pages: it adds exp(0) there, and its first real key
-        # scales all of that by exp(NEG_INF - m) = 0. With a sink the
-        # running max is real from the start and a masked page adds 0.)
-        seen = pos < klr_ref[...]
-        if window:
-            seen = jnp.logical_and(seen, pos >= klr_ref[...] - window)
-        scores = jnp.where(seen[None], scores, NEG_INF)
-        m_new = jnp.maximum(m_i[...], jnp.max(scores, axis=2, keepdims=True))
-        alpha = jnp.exp(m_i[...] - m_new)
-        p = jnp.exp(scores - m_new)
-        l_i[...] = l_i[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)             # [Hkv, M, D]
-        acc[...] = acc[...] * alpha + pv
-        m_i[...] = m_new
+        turns[0] = 0
 
-    @pl.when(s == pl.num_programs(1) - 1)
-    def _():
-        l_safe = jnp.where(l_i[...] > 0, l_i[...], 1.0)
-        out = jnp.where((klr_ref[...] > 0)[None], acc[...] / l_safe, 0.0)
-        out_ref[...] = out.astype(out_ref.dtype)
+    base, mine = turns[0], walk(i)
+    n_groups = mine[-1]
+    after = walk(jnp.minimum(i + 1, n_blk - 1))
+    more = (i + 1 < n_blk) & (after[-1] > 0)
+
+    def start_after(t, slot):
+        """Start, into operand ``slot``, the group that follows this block's
+        group t (t = -1: its first): its own next one, or behind its last
+        the next block's first."""
+        own = t + 1 < n_groups
+        page0, n, _ = group_at(
+            tuple(jnp.where(own, a, b) for a, b in zip(mine, after)),
+            jnp.where(own, t + 1, 0))
+        pl.when(own | more)(lambda: copies(page0, n, slot,
+                                           lambda copy: copy.start()))
+
+    def update(n, masked, page0, slot):
+        """One online-softmax update against the ``n`` pages in ``slot``."""
+        T = n * page_size
+
+        def head(h, _):
+            q, k, v = q_ref[h], k_buf[slot, h, :T], v_buf[slot, h, :T]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale   # [M, T]
+            if masked:
+                pos = page0 * page_size + jax.lax.broadcasted_iota(
+                    jnp.int32, (M, T), 1)
+                seen = pos < klr_ref[...]
+                if window:
+                    seen = jnp.logical_and(seen, pos >= klr_ref[...] - window)
+                s = jnp.where(seen, s, NEG_INF)
+            m_old = m_i[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_old - m_new)
+            p = jnp.exp(s - m_new)
+            l_i[h] = l_i[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)              # [M, Dv]
+            acc[h] = acc[h] * alpha + pv
+            m_i[h] = m_new
+
+        # KV head by KV head, a loop: the update's code is one head's,
+        # whatever Hkv (unrolled side by side the heads' code passes what the
+        # core holds: the probe's table above)
+        lax.fori_loop(0, Hkv, head, None)
+
+    _softmax_init(acc, m_i, l_i, None if sink_ref is None else sink_ref[...])
+    # a walk's first group is started by the block before it: the first
+    # block starts its own, and a block that walks nothing hands on
+    pl.when((i == 0) | (n_groups == 0))(lambda: start_after(-1, base % 2))
+
+    def turn(t, _):
+        slot = (base + t) % 2
+        start_after(t, 1 - slot)
+        page0, n, masked = group_at(mine, t)
+        copies(page0, n, slot, lambda copy: copy.wait())
+        lax.cond(masked, *(functools.partial(lax.switch, n - 1, [
+            functools.partial(update, size, mask, page0, slot)
+            for size in range(1, (edge if mask else group) + 1)])
+            for mask in (True, False)))
+
+    lax.fori_loop(0, n_groups, turn, None)
+    turns[0] = base + n_groups
+    l_safe = jnp.where(l_i[...] > 0, l_i[...], 1.0)
+    out = jnp.where((klr_ref[...] > 0)[None], acc[...] / l_safe, 0.0)
+    out_ref[...] = out.astype(out_ref.dtype)
 
 
 def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -572,38 +783,76 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     [C, Hq, D], equal to ``gqa_decode_paged`` on C copies of the row up to
     summation order; no lse (nothing merges a chunk's partials).
 
-    Grid (row blocks, pages): a (block, page) pair past the block's largest
-    ``kv_len`` — the pages above a block's own last position, not only
-    those past the prompt — revisits the block's last live page (no DMA)
-    and skips its compute.
+    Grid (row blocks,), and inside a step ONE loop over the block's LIVE
+    pages, fetched by the kernel's own double-buffered copies out of the pool
+    left in HBM (``_prefill_paged_kernel``; before ISSUE 41 a (row block,
+    page) grid stepped every page of the table, live or not, with one update
+    a page). The walk ends at the block's own last position: the pages above
+    it, not only those past the prompt, are no steps and no bytes, and a block
+    of padding alone costs nothing. A GROUP of consecutive pages shares one
+    online-softmax update (at most ``PREFILL_PAGES_PER_GROUP``, fewer where
+    one head's float32 scores of a group would pass
+    ``PREFILL_GROUP_SCORE_BYTES`` or the walk cannot be that long; a walk
+    shorter than a group is one short group), and the mask runs on EDGE pages
+    alone (``chunk_walk_pages``): the pages where some live row of the block
+    does not see every key, i.e. those past the block's smallest live
+    ``kv_len`` and, under a window, those before its highest bound. On the
+    others, where(True, s, NEG_INF) == s: their result is the masked one's to
+    the bit. At one page a group the result is bitwise that grid's; a larger
+    group changes the order of the float32 sums inside it.
 
-    ``window`` (static; None = the above, the same program to the bit) as in
-    ``gqa_decode_paged``: a row attends its last ``window`` keys, the table
-    is a ring, and the ring must span the window AND the C rows (the chunk
-    writes its rows before it walks). A block's live rows are then
-    CONSECUTIVE positions (a chunk's are): its grid runs over the
-    ``ceil((window + rows_per_block - 1) / page) + 1`` pages their windows can
-    touch, from the page of the lowest bound, never over the pages before.
-    The kernel's name in a trace is then ``gqa_prefill_paged_window``.
+    ``window`` (static; None = the above) as in ``gqa_decode_paged``: a row
+    attends its last ``window`` keys, the table is a ring, and the ring must
+    span the window AND the C rows (the chunk writes its rows before it
+    walks). A block's live rows are then CONSECUTIVE positions (a chunk's
+    are): its walk runs from the page of the lowest bound to the page of its
+    last key, at most ``ceil((window + rows_per_block - 1) / page) + 1``
+    pages, never over the pages before. The kernel's name in a trace is then
+    ``gqa_prefill_paged_window``.
     ``vmem_limit_bytes`` raises Mosaic's scoped-VMEM limit for a block that
     needs more than its 16 MB default. Keys and values of different widths
     (q [C, Hq, Dk], out [C, Hq, Dv]) and ``sinks`` [Hq] float32 are
     ``gqa_decode_paged``'s; the name gains ``_sink``."""
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
+    Rb = math.gcd(q.shape[0], rows_per_block)
+    page_size, pages_per_seq = k_pages.shape[3], block_table.shape[0]
+    M = Rb * (q.shape[1] // k_pages.shape[2])
+    group = max(1, min(PREFILL_PAGES_PER_GROUP,
+                       _pages_at_most(window, pages_per_seq, page_size, Rb),
+                       PREFILL_GROUP_SCORE_BYTES // (M * page_size * 4)))
+    # ONE trace a signature: the layers of a model that call with the same
+    # shapes (a period's window layers; every engine a process builds) share
+    # it. The module's constants and the backend's mode are read here,
+    # outside, so that they are part of what a trace is found by.
+    return jax.jit(_prefill_walk, static_argnames=(
+        "sm_scale", "rows_per_block", "window", "vmem_limit_bytes", "group",
+        "edge", "interpret"))(
+        q, k_pages, v_pages, block_table, kv_len, layer, sinks,
+        sm_scale=sm_scale, rows_per_block=Rb, window=window,
+        vmem_limit_bytes=vmem_limit_bytes, group=group,
+        edge=min(group, PREFILL_EDGE_PAGES_PER_GROUP),
+        interpret=default_interpret())
+
+
+def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
+                  sm_scale, rows_per_block, window, vmem_limit_bytes, group,
+                  edge, interpret):
+    """``gqa_prefill_paged`` on the stacked pool: the head-major transposes
+    and the kernel's call, in blocks of ``rows_per_block`` rows (a divisor of
+    the chunk's), at most ``group`` / ``edge`` pages an interior / an edge
+    group."""
     C, Hq, Dk = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
     Dv = v_pages.shape[-1]
     assert k_pages.shape[-1] == Dk and v_pages.shape[:-1] == k_pages.shape[:-1]
     assert Hq % Hkv == 0 and block_table.ndim == 1, (q.shape, block_table.shape)
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
-    G = Hq // Hkv
-    Rb = math.gcd(C, rows_per_block)
+    G, Rb = Hq // Hkv, rows_per_block
     n_blk, M = C // Rb, Rb * G
     pages_per_seq = block_table.shape[0]
     _check_ring(window, pages_per_seq, page_size, C, (Dk, Dv))
     sm_scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(Dk)
     kv_len = kv_len.astype(jnp.int32)
-    kl_blk = kv_len.reshape(n_blk, Rb).max(axis=1)
     kl_rows = jnp.repeat(kv_len, G)[:, None]                # [C * G, 1]
     # head-major rows: a KV head's operand is its G query heads of every row
     q_hm = q.reshape(C, Hkv, G, Dk).swapaxes(0, 1).reshape(Hkv, C * G, Dk)
@@ -612,48 +861,35 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # the sink of (head, block row r, group head g) is its query head's
         extra = (jnp.tile(sinks.astype(jnp.float32).reshape(Hkv, 1, G),
                           (1, Rb, 1)).reshape(Hkv, M, 1),)
-        extra_specs = [pl.BlockSpec((Hkv, M, 1), lambda i, s, *_: (0, 0, 0))]
-
-    def page_index(i, s, kl, bt, ly, *first):
-        last = jnp.maximum((kl[i] + page_size - 1) // page_size - 1, 0)
-        if window:
-            page = bt[jnp.minimum(first[0][i] + s, last) % pages_per_seq]
-        else:
-            page = bt[jnp.minimum(s, last)]
-        return (ly[0], jnp.clip(page, 0, P_pool - 1), 0, 0, 0)
-
-    rows = lambda i, s, *_: (0, i, 0)                       # noqa: E731
-    page_block = lambda D: pl.BlockSpec(                    # noqa: E731
-        (None, 1, Hkv, page_size, D), page_index)
-    scalars = (kl_blk, block_table, layer)
-    n_pages = pages_per_seq
-    if window:
-        # the page of the lowest bound among the block's live rows
-        bound = jnp.where(kv_len > 0, jnp.maximum(kv_len - window, 0),
-                          jnp.iinfo(jnp.int32).max)
-        lo = bound.reshape(n_blk, Rb).min(axis=1)
-        scalars += (jnp.where(kl_blk > 0, lo, 0) // page_size,)
-        n_pages = min(pages_per_seq, _window_pages(window, page_size, Rb))
+        extra_specs = [pl.BlockSpec((Hkv, M, 1), lambda i, *_: (0, 0, 0))]
+    n_pages = _pages_at_most(window, pages_per_seq, page_size, Rb)
+    rows = lambda i, *_: (0, i, 0)                          # noqa: E731
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    group_buf = lambda D: pltpu.VMEM(                       # noqa: E731
+        (2, Hkv, group * page_size, D), k_pages.dtype)
     live = C * n_pages * page_size
     params = {} if vmem_limit_bytes is None else {
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes)}
     out = pl.pallas_call(
-        functools.partial(_prefill_paged_kernel, page_size=page_size,
-                          sm_scale=sm_scale, window=window,
-                          sinks=sinks is not None),
+        functools.partial(_prefill_paged_kernel, group=group, edge=edge,
+                          n_pool=P_pool,
+                          page_size=page_size, sm_scale=sm_scale,
+                          window=window, sinks=sinks is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=(n_blk, n_pages),
+            num_scalar_prefetch=4,
+            grid=(n_blk,),
             in_specs=[
                 pl.BlockSpec((Hkv, M, Dk), rows),
-                pl.BlockSpec((M, 1), lambda i, s, *_: (i, 0)),
+                pl.BlockSpec((M, 1), lambda i, *_: (i, 0)),
                 *extra_specs,
-                page_block(Dk),
-                page_block(Dv),
+                in_hbm, in_hbm,
             ],
             out_specs=pl.BlockSpec((Hkv, M, Dv), rows),
             scratch_shapes=[
+                group_buf(Dk), group_buf(Dv),
+                pltpu.SemaphoreType.DMA((2, 2, group)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((Hkv, M, Dv), jnp.float32),
                 pltpu.VMEM((Hkv, M, 1), jnp.float32),
                 pltpu.VMEM((Hkv, M, 1), jnp.float32),
@@ -666,9 +902,10 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                             * page_size * (Dk + Dv)) * q.dtype.itemsize,
             transcendentals=live * Hq),
         name=_kernel_name("gqa_prefill_paged", window, sinks),
-        interpret=default_interpret(),
+        interpret=interpret,
         **params,
-    )(*scalars, q_hm, kl_rows, *extra, k_pages, v_pages)
+    )(*chunk_walk_bounds(kv_len, Rb), block_table, layer, q_hm, kl_rows,
+      *extra, k_pages, v_pages)
     return out.reshape(Hkv, C, G, Dv).swapaxes(0, 1).reshape(C, Hq, Dv)
 
 

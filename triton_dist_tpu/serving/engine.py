@@ -58,6 +58,7 @@ import numpy as np
 
 from triton_dist_tpu.models.llama import (decode_multistep_paged,
                                           prefill_chunk_paged)
+from triton_dist_tpu.ops.flash_decode import chunk_walk_counts
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
 from triton_dist_tpu.serving import layouts
 from triton_dist_tpu.serving.deadline import Deadline, EngineStallError
@@ -220,6 +221,15 @@ class ServingEngine:
         self.metrics = metrics or ServingMetrics()
         for name in fam.counters:
             self.metrics.counters.setdefault(name, 0)
+        # the chunk program's K/V walks, a kind of attention layer each
+        # (none: a latent pool's chunk, or an ``attn_io`` hook, which takes
+        # the chunk as rows of decode): what ``_commit_chunk`` counts a
+        # chunk's walked and edge pages from
+        self._chunk_walks = tuple(fam.chunk_walks(cfg)) if (
+            fam.chunk_walks and attn_io is None) else ()
+        if self._chunk_walks:
+            self.metrics.counters.setdefault("chunk_walk_pages", 0)
+            self.metrics.counters.setdefault("chunk_walk_edge_pages", 0)
         # pages of the ring each slot owns in the family's bounded layers
         # (0: none). The ring is the slot's, not the ledger's: admission
         # needs a slot (which brings its ring) and ledger pages, and the
@@ -911,6 +921,16 @@ class ServingEngine:
         device, so ``tok0`` IS the first token and the slot flips to ACTIVE
         (mirrors set, ready for this step's decode dispatch)."""
         sp = len(req.prompt)
+        for layers, rows, window in self._chunk_walks:
+            # the pages this chunk's row blocks walked in every layer of the
+            # kind, and those of them that took the masked update: the plan
+            # the kernel walks by, from the cursor and the length alone
+            pages, edge = chunk_walk_counts(
+                req.prefill_cursor, n_eff - req.prefill_cursor,
+                self.prefill_chunk, rows, self.page_size, window,
+                self.pages_per_seq)
+            self.metrics.inc("chunk_walk_pages", layers * pages)
+            self.metrics.inc("chunk_walk_edge_pages", layers * edge)
         req.prefill_cursor = n_eff
         self.metrics.inc("prefill_chunks")
         self._jlog("chunk", rid=req.rid, cursor=req.prefill_cursor)

@@ -219,6 +219,14 @@ class ServingMetrics:
     ``step_host_s`` (step start to reconcile's end, less
     ``step_device_s``: it counts a LAST chunk's blocking call as host time)
     are differences of the same stamps.
+    Where the chunk program walks K/V pages (``PagedFamily.chunk_walks``) the
+    engine counts, at every chunk's commit and on the host, from the cursor
+    and the chunk's length alone (``ops.flash_decode.chunk_walk_counts``: the
+    plan the kernel walks by): ``chunk_walk_pages`` (pages a row block of
+    ``gqa_prefill_paged`` walked, summed over row blocks, attention layers
+    and chunks) and ``chunk_walk_edge_pages`` (those of them that took the
+    MASKED online-softmax update: the pages where some live row of the block
+    does not see every key; the others run without the mask).
     A model family's own counters (``PagedFamily.counters``: for a share of
     an expert-parallel layer, ``moe_local_rows`` = routed assignments that
     landed on the experts held here and ``moe_experts_touched`` = held
