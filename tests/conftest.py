@@ -10,10 +10,12 @@ default platform is.
 How to run (the one place that says it; README.md points here):
 
 - tier 1, the gate:  ``pytest tests/ -q -m 'not slow' -n 6 --dist load``
-  (the driver allows 1470 s, and since PR 41 this sandbox's 8 cores need
-  nearly all of them: 8,200 worker-seconds; the driver's machine took 968 s
-  at PR 40. ``--dist loadfile``, which the driver ran before, does as well:
-  no test leans on another of its file having run in the same process.
+  (the driver allows 1470 s; the budget is three quarters of that: since
+  PR 42 this sandbox's 8 cores take 1,080-1,220 s and 6,290-6,760
+  worker-seconds where PR 41's tree took 1,326-1,558 and 7,561-8,902 in the
+  same hours; the driver's machine took 1,374 s on PR 41's. ``--dist
+  loadfile``, which the driver ran before, does as well: no test leans on
+  another of its file having run in the same process.
   ``FIXTURE_HEAVY_FIRST`` below says which files go first, and why)
 - the slow tier:     ``pytest tests/ -q -m slow`` (the full bit-identity
   matrices and dense crash sweeps; hours on the interpreter)
@@ -48,9 +50,10 @@ from triton_dist_tpu.utils.env import force_virtual_cpu_devices  # noqa: E402
 _N_DEVICES = int(os.environ.get("TDT_TEST_DEVICES", "12"))
 force_virtual_cpu_devices(_N_DEVICES)
 
-# Per-run XLA compile cache: many tests build fresh engines/kernels whose
-# programs lower to byte-identical HLO (each engine owns its own jax.jit
-# objects, so the trace-level cache cannot share them). A content-keyed
+# Per-run XLA compile cache: many tests build fresh kernels and programs
+# that lower to byte-identical HLO under jax.jit objects of their own (the
+# ENGINES of one configuration and shape share theirs since PR 42:
+# serving/programs.py). A content-keyed
 # persistent cache dedupes those XLA compiles within one suite run — it
 # does NOT affect the compile-count guards, which count trace-cache
 # entries, not XLA compiles. Fresh temp dir per run: nothing persists
@@ -206,6 +209,15 @@ def micro_model():
     return cfg, init_params(jax.random.key(1), cfg)
 
 
+@pytest.fixture
+def own_programs(monkeypatch):
+    """``own_programs()``: engines built from here on trace their own
+    programs, whatever the process has built (``serving.programs``' memo is
+    replaced by an empty one until the test ends)."""
+    from triton_dist_tpu.serving import programs
+    return lambda: monkeypatch.setattr(programs, "_MEMO", {})
+
+
 # ----------------------------------------------------- crash/recover harness
 RECOVERY_MAX_STEPS = 600  # far above any legitimate run length
 
@@ -318,18 +330,35 @@ def _limited(item, what):
 
 # ---------------------------------------------------------------- order
 # ``--dist load`` deals CONSECUTIVE tests to a worker, a sixth of a quarter
-# of the suite at first (39 of 956) and ever fewer as the queue runs down:
-# two at a time at the end, so a late file's tests land on all six workers
-# and each of them builds the file's module fixtures again (the driver's
-# run of PR 41: tests/test_window_moe.py 880 worker-seconds and
-# test_sink_window_moe.py 809, where one process takes 388 and 638). The
-# files whose module fixtures are a minute of interpreter and more (an
-# engine and a golden run a family) therefore go FIRST, where a file is one
-# worker's chunk or two's. The order is the same in every worker (xdist
-# refuses a run whose workers collected differently) and no test leans on it.
-FIXTURE_HEAVY_FIRST = ("test_sink_window_moe.py", "test_window_moe.py",
-                       "test_linear_attn_moe.py", "test_latent_moe.py",
-                       "test_hybrid_ssm.py")
+# of the suite at first (40 of 964), then runs of 30-60 as the queue runs
+# down and two at a time at the end: a late file's tests land on all six
+# workers, and each of them builds the file's module fixtures and traces its
+# engines' programs again (a worker has its own ``serving.programs`` memo).
+# So the files go in this order, the rest after them by name:
+# 1. the five family files, whose module fixtures are a minute of
+#    interpreter and more (the driver's run of PR 41: test_window_moe.py 880
+#    worker-seconds where one process takes 388);
+# 2. the files that build engines, those of ONE model and shape side by
+#    side (``micro_model``, then ``moe_model`` on a mesh, then the tiny
+#    model), so that a worker meets a shape many times and traces it once
+#    (PR 42: test_serving.py dealt late cost 469 worker-seconds of which a
+#    second trace in every worker was most);
+# 3. the files whose single tests take a minute and more, so that none of
+#    them starts when the queue is empty and five workers stand idle.
+# The order is the same in every worker (xdist refuses a run whose workers
+# collected differently) and no test leans on it.
+FIXTURE_HEAVY_FIRST = (
+    "test_sink_window_moe.py", "test_window_moe.py",
+    "test_linear_attn_moe.py", "test_latent_moe.py", "test_hybrid_ssm.py",
+    "test_recovery.py", "test_chaos.py", "test_slo.py",
+    "test_recovery_mesh.py", "test_sharded_serving.py",
+    "test_overlap_serving.py", "test_speculate.py", "test_cluster.py",
+    "test_long_context.py", "test_prefix_cache.py", "test_serving.py",
+    "test_disagg.py", "test_pool_in_place.py", "test_aot_artifact.py",
+    "test_autoscale.py", "test_lending.py",
+    "test_aot_topology.py", "test_train.py", "test_tools.py",
+    "test_ring_attention.py", "test_hierarchical.py",
+    "test_sync_hardening.py")
 
 
 def pytest_collection_modifyitems(items):

@@ -16,9 +16,9 @@ FILE = os.path.join(HERE, "parent_pins_families.npz")
 FAMILIES = ("latent", "window", "sink_window", "hybrid")
 
 
-def programs(name):
-    """Two requests through a ``ServingEngine`` of two slots, chunks of 16 (the
-    first prompt spans two), K = 3: every request's tokens."""
+def engine(name):
+    """A ``ServingEngine`` of two slots, chunks of 16, K = 3, for the family's
+    tiny preset and seeded weights."""
     import jax
     from triton_dist_tpu.models import hybrid_ssm, mla, window_moe
     from triton_dist_tpu.serving import ServingEngine
@@ -32,8 +32,15 @@ def programs(name):
         "hybrid": (hybrid_ssm.HybridSSMConfig.tiny(), hybrid_ssm.init_params),
     }[name]
     params = init(jax.random.PRNGKey(3), cfg)
-    eng = ServingEngine(params, cfg, num_slots=2, page_size=8, num_pages=24,
-                        pages_per_seq=8, prefill_chunk=16, decode_horizon=3)
+    return ServingEngine(params, cfg, num_slots=2, page_size=8, num_pages=24,
+                         pages_per_seq=8, prefill_chunk=16, decode_horizon=3)
+
+
+def programs(name):
+    """Two requests through that engine (the first prompt spans two chunks):
+    every request's tokens."""
+    eng = engine(name)
+    cfg = eng.cfg
     rng = np.random.default_rng(5)
     rids = [eng.submit(rng.integers(1, cfg.vocab_size, n), 5)
             for n in (21, 9)]

@@ -80,6 +80,7 @@ def main() -> None:
     # os._exit afterwards: a hung interpret thread would otherwise block
     # interpreter shutdown forever.
     import threading
+    import time
 
     def attempt():
         try:
@@ -112,6 +113,21 @@ def main() -> None:
         print("MP_AG_UNSUPPORTED Deadlock: interpret-mode kernel "
               "semaphores are in-process state; a 2-process mesh never "
               "sees the peer's signals", flush=True)
+    # process 0 hosts the coordination service and leaves LAST: a peer whose
+    # service goes away before its own exit is terminated by jax's client
+    # ("Socket closed", exit 1: one tier-1 run in eight here, PR 42)
+    try:
+        from jax._src.distributed import global_state
+        if me:
+            global_state.client.key_value_set(f"mp_worker_bye/{me}", "1")
+        else:
+            for peer in range(1, jax.process_count()):
+                global_state.client.blocking_key_value_get(
+                    f"mp_worker_bye/{peer}", 60_000)
+            time.sleep(0.5)     # the peer's os._exit follows its set
+    except Exception as e:      # a dead peer fails the test by its own code
+        print(f"MP_EXIT_HANDSHAKE {type(e).__name__}: {str(e)[:160]}",
+              flush=True)
     os._exit(0)
 
 

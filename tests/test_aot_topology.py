@@ -994,13 +994,19 @@ def linear_attn_programs(topo):
     """The engine's two programs at the benchmark cell's sizes (128 slots, K
     = 4, chunk 2,048, 40 pages a sequence and the slot's column; the K/V pool
     cut to 1,282 pages), one period, lowered for one described v5e, pool
-    donated. Name -> (optimised HLO text, memory analysis, configuration)."""
+    donated. ``programs(name)`` -> (optimised HLO text, memory analysis,
+    configuration), each compiled at its first reading: a program of this
+    family is a minute of the compiler, and the suite's watchdog counts a
+    fixture's wall beside five busy workers."""
+    import functools
     cfg = _linear_attn_cfg()
-    out = {}
-    for name, low in _linear_attn_lowered(topo, cfg).items():
-        exe = low.compile()
-        out[name] = (exe.as_text(), exe.memory_analysis(), cfg)
-    return out
+    lowered = _linear_attn_lowered(topo, cfg)
+
+    @functools.cache
+    def programs(name):
+        exe = lowered[name].compile()
+        return exe.as_text(), exe.memory_analysis(), cfg
+    return programs
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
@@ -1014,7 +1020,7 @@ def test_linear_attn_states_and_pages_stay_in_place(linear_attn_programs,
     slice: each kind of layer's leaves (3 layers of states, 1 of pages) are
     carried, written and read where they lie."""
     import re
-    text, mem, cfg = linear_attn_programs[program]
+    text, mem, cfg = linear_attn_programs(program)
     kernels = {"decode": ("gdn_decode_update", "gqa_decode_paged",
                           "grouped_gemm_gated"),
                "chunk": ("gqa_prefill_paged", "grouped_gemm_gated")}[program]
@@ -1100,20 +1106,29 @@ def _family_programs(topo, family):
     return _engine_programs(topo, cfg, init, pages, B, C, W)
 
 
-@pytest.mark.parametrize("family", ["dense", "latent", "window", "hybrid",
-                                    "sink_window", "linear_attn"])
-def test_no_program_relays_out_a_weight_at_its_entry(topo, family):
-    """Compiled THROUGH ``serving.layouts.held_layout_programs`` (what the
-    engine calls off the CPU), neither the decode nor the chunk program
-    copies, transposes or re-tiles a parameter of a weight leaf's shape in
-    its ENTRY computation: the weights are committed once to what the decode
-    program reads, and the chunk program is compiled against that. No copy of
-    the leaves held re-laid would fit the decode program's temporaries."""
+@pytest.fixture(scope="module", params=["dense", "latent", "window", "hybrid",
+                                        "sink_window", "linear_attn"])
+def held_family(topo, request):
+    """A family's programs THROUGH ``serving.layouts.held_layout_programs``
+    (what the engine calls off the CPU): ``(params, chunk_rest, decode,
+    chunk_jit, formats)``. The decode program is compiled here and the chunk
+    program in the test: at the linear-attention family's widths each is
+    most of a minute, and the suite's watchdog counts either's wall."""
     from triton_dist_tpu.serving import layouts
-    step, chunk, params, step_rest, chunk_rest = _family_programs(topo,
-                                                                  family)
-    decode, chunk_jit, formats = layouts.held_layout_programs(
-        step, chunk, params, step_rest)
+    step, chunk, params, step_rest, chunk_rest = _family_programs(
+        topo, request.param)
+    return (params, chunk_rest,
+            *layouts.held_layout_programs(step, chunk, params, step_rest))
+
+
+def test_no_program_relays_out_a_weight_at_its_entry(held_family):
+    """Neither the decode nor the chunk program copies, transposes or
+    re-tiles a parameter of a weight leaf's shape in its ENTRY computation:
+    the weights are committed once to what the decode program reads, and the
+    chunk program is compiled against that. No copy of the leaves held
+    re-laid would fit the decode program's temporaries."""
+    from triton_dist_tpu.serving import layouts
+    params, chunk_rest, decode, chunk_jit, formats = held_family
     held = layouts.relaid(params, formats)
     assert held, "the compiler asked for every leaf as it comes"
     assert layouts.entry_copies(decode.as_text(), params) == []

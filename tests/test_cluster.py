@@ -90,12 +90,14 @@ def test_composed_bit_identical_to_sharded_golden(moe_model, golden, mesh):
 
 
 @pytest.mark.mesh
-def test_composed_engine_at_1x2x2_is_one_pool_contract(moe_model):
+def test_composed_engine_at_1x2x2_is_one_pool_contract(moe_model,
+                                                         own_programs):
     """Tier 1's stand-in for the 1x2x2 replay (`slow`), nothing dispatched:
     both fleets' pools share ONE sp-aware shape on the 4-device mesh, no
     program exists before the first dispatch, and neither pool will ship
     a scratch or an SP padding page."""
     from triton_dist_tpu.serving.kv_pool import PageLedgerError
+    own_programs()
     eng = _composed(moe_model, 1, 2, 2)
     assert eng.mesh_desc == "1x2x2" and eng.decode.n_ranks == 4
     assert eng.compile_stats == {"prefill_chunk_compiles": 0,

@@ -502,7 +502,19 @@ def test_the_tiny_preset_serves():
 PINS = np.load(parent_pins_families.FILE)
 
 
-@pytest.mark.parametrize("family", parent_pins_families.FAMILIES)
+@pytest.fixture(scope="module", params=parent_pins_families.FAMILIES)
+def family(request):
+    """A family whose two programs are compiled: an engine of the pinned
+    run's shape has served two chunks and a dispatch, so that the compile
+    (most of a minute for the sink-window preset) and the pinned run each
+    have the suite's watchdog to themselves."""
+    eng = parent_pins_families.engine(request.param)
+    eng.submit(np.arange(1, 18), 2)
+    while eng.step():
+        pass
+    return request.param
+
+
 def test_the_other_families_serve_the_parent_s_tokens(family):
     """The chunk and the decode program of each other family that shares
     code with this one (the layer loop, ``expert_share``, the GQA kernels,

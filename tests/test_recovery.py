@@ -236,7 +236,7 @@ def test_colocated_checkpoint_cadence_sweep(micro_model, journaled_golden):
         "checkpoints"]
 
 
-def test_restore_compiles_nothing(micro_model):
+def test_restore_compiles_nothing(micro_model, own_programs):
     """The compile guard (ISSUE 9 acceptance): restore is host-only —
     the jit trace caches are untouched by restore itself, and the whole
     recovered run still ends at exactly one decode + one chunk program."""
@@ -248,6 +248,7 @@ def test_restore_compiles_nothing(micro_model):
     with pytest.raises(InjectedCrash):
         eng.run(max_steps=MAX_STEPS, arrivals=arrivals)
     done = sum(1 for e in journal.entries if e["kind"] == "submit")
+    own_programs()      # the crashed engine's programs went with its process
     eng2 = mk(journal=journal, checkpoint_every=8)
     assert eng2._step._cache_size() == 0
     assert eng2._chunk_step._cache_size() == 0

@@ -85,11 +85,12 @@ def test_sharded_crash_sweep_dense(moe_model, tp, sp, ep, stride):
 
 
 @pytest.mark.mesh
-def test_sharded_restore_at_n4_is_host_only(moe_model):
+def test_sharded_restore_at_n4_is_host_only(moe_model, own_programs):
     """Tier 1's stand-in for the 2x2x1 crash sweep (`slow`), nothing
     dispatched: a fresh 4-rank engine restored from another's snapshot
     reaches the same control digest on every rank, having traced no
     program."""
+    own_programs()
     eng = sharded_engine(moe_model, 2, 2, 1, journal=ControlJournal())
     for _, prompt, mnt in _trace(3):
         eng.submit(prompt, mnt)

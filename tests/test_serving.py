@@ -17,7 +17,8 @@ from triton_dist_tpu.models.llama import (LlamaConfig, decode_step, generate,
                                           init_params, prefill,
                                           prefill_chunk_paged)
 from triton_dist_tpu.serving import (ContinuousBatchingScheduler, KVPagePool,
-                                     PageLedgerError, Request, ServingEngine)
+                                     PageLedgerError, Request, ServingEngine,
+                                     programs)
 
 pytestmark = pytest.mark.serving
 
@@ -505,7 +506,8 @@ def test_compile_count_guard(tiny_model, monkeypatch, horizon):
     """A trace with 10 DISTINCT prompt lengths builds and compiles exactly
     two programs: ONE decode step (the scanned K=4 program is another
     program than the K=1 one, and as shape-stable) and ONE chunk program
-    (start offset and prompt length are its runtime scalars)."""
+    (start offset and prompt length are its runtime scalars). A second
+    engine of the shape enters ``jax.jit`` for neither."""
     cfg, params = tiny_model
     real_jit = jax.jit
     made = []
@@ -515,9 +517,11 @@ def test_compile_count_guard(tiny_model, monkeypatch, horizon):
         return real_jit(fun, *a, **k)
 
     monkeypatch.setattr(jax, "jit", counting_jit)
-    eng = ServingEngine(params, cfg, num_slots=4, page_size=8, num_pages=32,
-                        pages_per_seq=4, decode_horizon=horizon,
-                        prefill_chunk=8)
+    monkeypatch.setattr(programs, "_MEMO", {})
+    mk = lambda: ServingEngine(                             # noqa: E731
+        params, cfg, num_slots=4, page_size=8, num_pages=32, pages_per_seq=4,
+        decode_horizon=horizon, prefill_chunk=8)
+    eng = mk()
     rng = np.random.RandomState(3)
     arrivals = []
     for i, plen in enumerate(range(3, 23, 2)):  # 10 distinct prompt lengths
@@ -531,9 +535,13 @@ def test_compile_count_guard(tiny_model, monkeypatch, horizon):
                                  "params_relaid_leaves": []}
     # the jit-entry hook agrees (pallas interpret mode jits its own internal
     # wrappers — not ours)
-    ours = [f for f in made
-            if "ServingEngine" in getattr(f, "__qualname__", "")]
-    assert len(ours) == 2
+    ours = lambda: [f for f in made if "engine_programs" in getattr(  # noqa: E731
+        f, "__qualname__", "")]
+    assert len(ours()) == 2
+    del made[:]
+    twin = mk()
+    assert twin._step is eng._step and twin._chunk_step is eng._chunk_step
+    assert not made
 
 
 def test_eos_truncation_multistep(tiny_model):
@@ -910,21 +918,11 @@ class Watched:
         return self.events[n:]
 
 
-_CHUNK4_PROGRAMS = {}
-
-
 def _chunk4_engine(tiny_model, horizon, **kw):
-    """Engines of one shape share the first one's two jitted programs (as
-    ``test_window_moe.ring_victim`` does): a trace and a compile a shape,
-    not an engine."""
     cfg, params = tiny_model
     kw = {"num_slots": 2, "num_pages": 16, "pages_per_seq": 4, **kw}
-    eng = ServingEngine(params, cfg, page_size=8, prefill_chunk=4,
-                        decode_horizon=horizon, **kw)
-    shape = (horizon, kw["num_slots"], kw["num_pages"], kw["pages_per_seq"])
-    eng._step, eng._chunk_step = _CHUNK4_PROGRAMS.setdefault(
-        shape, (eng._step, eng._chunk_step))
-    return eng
+    return ServingEngine(params, cfg, page_size=8, prefill_chunk=4,
+                         decode_horizon=horizon, **kw)
 
 
 HORIZONS = pytest.mark.parametrize("horizon", [1, 4], ids=["k1", "k4"])
@@ -1175,3 +1173,114 @@ def test_nothing_is_launched_ahead_under_a_stall_budget(tiny_model,
     assert c["chunks_prelaunched"] == 0 and c["chunk_shrinks"] > 0
     res = eng.run(max_steps=2000)
     assert [res[chat], res[batch]] == [want[0], want[3]]
+
+
+# ---------------------------------------------------------------------------
+# a program belongs to a configuration and a shape (serving/programs.py)
+# ---------------------------------------------------------------------------
+
+def count_traces(monkeypatch):
+    """``traces``: how often each of the two model functions under the
+    engines' programs has been entered (a trace enters it; a dispatch of a
+    compiled program does not)."""
+    traces = {"decode_multistep_paged": 0, "prefill_chunk_paged": 0}
+
+    def counting(name, fn):
+        def counted(*a, **kw):
+            traces[name] += 1
+            return fn(*a, **kw)
+        return counted
+
+    for name in traces:
+        monkeypatch.setattr(programs, name,
+                            counting(name, getattr(programs, name)))
+    return traces
+
+
+def test_a_second_engine_of_a_shape_traces_nothing(tiny_model,
+                                                   reference_tokens,
+                                                   own_programs, monkeypatch):
+    """Two engines of one configuration and one shape hold the SAME two
+    programs: the second serves its first request without entering either
+    model function again, and serves the first one's tokens."""
+    prompts, want = reference_tokens
+    own_programs()
+    traces = count_traces(monkeypatch)
+    first = _chunk4_engine(tiny_model, 4)
+    rid = first.submit(prompts[1], REF_NEW_TOKENS)
+    assert first.run(max_steps=500)[rid] == want[1]
+    seen = dict(traces)
+    assert all(seen.values())
+    twin = _chunk4_engine(tiny_model, 4)
+    assert twin._step is first._step
+    assert twin._chunk_step is first._chunk_step
+    assert twin.pool is not first.pool
+    rid = twin.submit(prompts[1], REF_NEW_TOKENS)
+    assert twin.run(max_steps=500)[rid] == want[1]
+    assert traces == seen
+    assert twin.compile_stats == first.compile_stats
+    assert twin.compile_stats["decode_compiles"] == 1
+    assert twin.compile_stats["prefill_chunk_compiles"] == 1
+
+
+@pytest.mark.parametrize("differs", [
+    {"num_pages": 17}, {"horizon": 4}, {"eos_id": 5},
+    {"ffn": lambda h, p: jnp.zeros_like(h)}], ids=lambda kw: next(iter(kw)))
+def test_an_engine_of_another_key_has_its_own_programs(tiny_model, differs):
+    """One component of the key differs (a shape, the horizon, ``eos_id``, a
+    hook): the engine gets programs of its own, and each of the two has
+    compiled ONE decode and ONE chunk program after serving."""
+    cfg, _ = tiny_model
+    base = _chunk4_engine(tiny_model, 1)
+    other = _chunk4_engine(tiny_model, **{"horizon": 1, **differs})
+    assert other._step is not base._step
+    assert other._chunk_step is not base._chunk_step
+    for eng in (base, other):
+        eng.submit(list(range(1, 7)), 3)
+        eng.run(max_steps=200)
+        stats = eng.compile_stats
+        assert (stats["decode_compiles"],
+                stats["prefill_chunk_compiles"]) == (1, 1)
+
+
+def test_a_wrapped_program_stays_on_its_engine(tiny_model):
+    """``Watched`` wraps the two attributes of ITS engine: the next engine of
+    the shape gets the programs, not the wrappers."""
+    w = Watched(_chunk4_engine(tiny_model, 4))
+    plain = _chunk4_engine(tiny_model, 4)
+    assert plain._step is not w.eng._step
+    assert plain._chunk_step is not w.eng._chunk_step
+    assert plain._step._cache_size() >= 0       # a ``jax.jit`` object
+    assert _chunk4_engine(tiny_model, 4)._step is plain._step
+
+
+def test_a_second_disagg_engine_of_a_shape_traces_nothing(tiny_model,
+                                                          own_programs,
+                                                          monkeypatch):
+    """The disaggregated engine's three programs belong to the configuration,
+    the mesh and the shapes too."""
+    from triton_dist_tpu.serving.disagg import DisaggServingEngine
+    from triton_dist_tpu.shmem.context import initialize_distributed
+    cfg, params = tiny_model
+    own_programs()
+    traces = count_traces(monkeypatch)
+    # (a context is its mesh: two contexts of one mesh are one key)
+    mk = lambda: DisaggServingEngine(                       # noqa: E731
+        params, cfg, num_slots=2, num_prefill_slots=2, page_size=8,
+        num_pages=32, pages_per_seq=8, prefill_chunk=8,
+        ctx=initialize_distributed(axis_names=("role",), mesh_shape=(2,)))
+    prompt = list(range(1, 12))
+    first = mk()
+    rid = first.submit(prompt, 4)
+    tokens = first.run(max_steps=500)[rid]
+    seen = dict(traces)
+    assert all(seen.values())
+    twin = mk()
+    assert (twin._chunk_step, twin._dec_step, twin._migrate) == (
+        first._chunk_step, first._dec_step, first._migrate)
+    rid = twin.submit(prompt, 4)
+    assert twin.run(max_steps=500)[rid] == tokens
+    assert traces == seen
+    assert twin.compile_stats == first.compile_stats == {
+        "prefill_chunk_compiles": 1, "decode_compiles": 1,
+        "migrate_compiles": 1}

@@ -163,25 +163,45 @@ def test_chunks_then_decode_through_both_pools_match_the_reference(served):
     assert np.abs(want).max() > 0.3          # against logits of this size
 
 
+@pytest.fixture(scope="module")
+def small():
+    """The class at THREE layers (a leading dense full layer, then one period
+    of a window layer and a full one): every mechanism a control alters is
+    there, and a pair of programs compiles in a third of the seven layers'
+    time. (program config, weights, 66 tokens, the reference's logits of
+    them); the sound programs' five decode steps after a 61-token prompt
+    match the reference like the seven layers' do."""
+    fc = file_cfg()
+    fc.update(num_hidden_layers=3, hybrid_layer_pattern=[0, 1, 0],
+              moe_layer_freq=[0, 1, 1])
+    w = jax.jit(lambda k: ref.init_weights(k, fc))(jax.random.PRNGKey(3))
+    pc = Adapter(fc)._program_config()
+    assert (pc.n_dense_layers, pc.layer_kinds) == (1, ("window", "full"))
+    toks = tokens_of(66)
+    want = np.asarray(ref.logits(w, toks, fc))
+    _, got, _, _ = serve(w, pc, toks, 61)
+    assert np.abs(got - want[61:]).max() < 2e-5
+    assert np.abs(want).max() > 0.3
+    return pc, w, toks, want
+
+
 @pytest.mark.parametrize("control", [c for c in CONTROLS if c != "none"])
-def test_the_program_wrong_in_one_thing_moves_the_logits(model, wanted,
-                                                         control):
+def test_the_program_wrong_in_one_thing_moves_the_logits(small, control):
     """Each control of ``benchmark/tools/sink_control.py``: the sink dropped,
     the window a page wider, the value scale dropped, RoPE over the whole
     head, one theta for both kinds, the selection bias ignored, the full
     layers grouped as under 8 KV heads. A comparison that holds the mechanism
     reads a difference hundreds of times its tolerance (61 prompt tokens in
     four chunks: past the window and the page beyond it; then 5 decode
-    steps)."""
-    fc, pc, w = model
+    steps), at three layers already (the least: the ignored bias, 8e-3)."""
+    pc, w, toks, want = small
     alter, reweigh = CONTROLS[control]
     if alter:
         pc = alter(pc, PAGE)
     if reweigh:
         w = reweigh(pc, w)
-    toks, want = wanted
-    _, got, _, _ = serve(w, pc, toks[:66], 61)
-    assert np.abs(got - want[61:66]).max() > 2e-3, control
+    _, got, _, _ = serve(w, pc, toks, 61)
+    assert np.abs(got - want[61:]).max() > 2e-3, control
 
 
 def test_the_walk_counters_count_live_rows_only(model, served):
@@ -447,10 +467,10 @@ def replay_engine(model):
         w, dataclasses.replace(pc, ring_slots=0, ring_chunk=0), num_slots=2,
         page_size=PAGE, num_pages=30, pages_per_seq=PPS, prefill_chunk=CHUNK,
         decode_horizon=2) for _ in range(2))
-    # both programs compile HERE, through a twin that shares them (two chunks
+    # both programs compile HERE, through a twin of the same shape (two chunks
     # and a decode dispatch), so that the golden run's fixture is the
     # interpreter's minute alone under the watchdog
-    twin._step, twin._chunk_step = eng._step, eng._chunk_step
+    assert twin._step is eng._step and twin._chunk_step is eng._chunk_step
     twin.submit(reqs[1][0][:CHUNK + 1], 2)
     while twin.step():
         pass

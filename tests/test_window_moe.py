@@ -513,20 +513,17 @@ def ring_victim(model):
     beside a 24-token prompt through a two-slot engine whose pool has
     ``spare`` pages free (None: all 29), every chunk fenced or not, as
     ``conftest.serve_noting_victims`` returns it. One trace of the programs
-    a horizon serves every engine; the roomy pool's tokens at K=1 are the
-    golden."""
+    a horizon serves every engine (they are of one shape); the roomy pool's
+    tokens at K=1 are the golden."""
     fc, pc, w = model
     rng = np.random.default_rng(11)
     reqs = [(rng.integers(1, 256, 15), 5), (rng.integers(1, 256, 24), 2)]
-    programs = {}
 
     def serve(horizon, spare, fence=False):
         eng = ServingEngine(
             w, dataclasses.replace(pc, ring_slots=0, ring_chunk=0),
             num_slots=2, page_size=PAGE, num_pages=30, pages_per_seq=PPS,
             prefill_chunk=CHUNK, decode_horizon=horizon)
-        eng._step, eng._chunk_step = programs.setdefault(
-            horizon, (eng._step, eng._chunk_step))
         if spare is not None:
             assert eng.alloc.alloc("ballast", eng.alloc.free_pages - spare)
         return conftest.serve_noting_victims(eng, reqs, fence)

@@ -52,7 +52,6 @@ fault-free runs only (same caveat as disagg's degraded rung).
 from __future__ import annotations
 
 import json
-import os
 import time
 from collections import deque
 
@@ -64,6 +63,7 @@ from triton_dist_tpu.models.llama import init_page_pool, require_config
 from triton_dist_tpu.models.moe import MoEConfig
 from triton_dist_tpu.ops.allgather_gemm import GemmConfig
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
+from triton_dist_tpu.serving import programs
 from triton_dist_tpu.serving.deadline import (Backoff, Deadline,
                                               EngineStallError)
 from triton_dist_tpu.serving.disagg import (DECODE_ROLE, ChunkSignalLedger,
@@ -224,27 +224,19 @@ class DisaggShardedEngine:
 
         pshard = self.decode._pool_out_sharding
         kw = {"out_shardings": (pshard, pshard, self.decode._rep_sharding)}
-        if jax.default_backend() == "cpu":
-            self._xmig = jax.jit(xmig, **kw)
-        else:
-            self._xmig = jax.jit(xmig, donate_argnums=(6, 7), **kw)
+        self._xmig = programs.jit(xmig, (6, 7), **kw)
         if artifact is not None:
             # _launch reads self._xmig at call time, so seeding here is
             # enough — no closure rebind needed
             self._xmig = artifact.program(self._aot_key, "xmig")
 
-        # TDT_SIGCHECK=1: the decode engine linted its own two programs in
-        # its constructor; lint the composition's third program here
-        if os.environ.get("TDT_SIGCHECK") == "1":
-            from triton_dist_tpu.analysis.lint import lint_engine_programs
-            abstract = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-            i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-            kp = abstract(self.pool_p["k"])
-            vp = abstract(self.pool_p["v"])
-            lint_engine_programs({"xmig_pages": (xmig, (
-                i32(pmax), i32(pmax), i32(1), i32(1), kp, vp, kp, vp))},
-                type(self).__name__)
+        # the decode engine linted its own two programs in its constructor;
+        # the composition's third program here
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        kp, vp = (jax.ShapeDtypeStruct(a.shape, a.dtype)
+                  for a in (self.pool_p["k"], self.pool_p["v"]))
+        programs.lint_if_asked({"xmig_pages": (xmig, (
+            i32(pmax), i32(pmax), i32(1), i32(1), kp, vp, kp, vp))}, self)
 
         def _launch(src, dst, n, tag, kp, vp):
             dk, dv, landed = self._xmig(src, dst, n, tag, kp, vp,
@@ -1117,17 +1109,11 @@ class DisaggShardedEngine:
         """The composition adds NO programs to the sharded engine's two
         (the prefill fleet reuses its chunk executable — same shapes,
         same committed sharding) beyond the one migration copy program."""
-        def n(fn, fallback):
-            try:
-                return int(fn._cache_size())
-            except Exception:
-                return fallback
-
         base = self.decode.compile_stats
         stats = {
             "prefill_chunk_compiles": base["prefill_chunk_compiles"],
             "decode_compiles": base["decode_compiles"],
-            "migrate_compiles": n(
+            "migrate_compiles": programs.compiles(
                 self._xmig,
                 1 if self.metrics.counters["migrate_chunks"] else 0),
         }

@@ -53,7 +53,6 @@ deployment (see docs/serving.md for the launch recipe and the
 from __future__ import annotations
 
 import json
-import os
 import time
 from collections import deque
 
@@ -62,13 +61,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from triton_dist_tpu.models.llama import (LlamaConfig,
-                                          decode_multistep_paged,
-                                          init_page_pool,
-                                          prefill_chunk_paged,
+from triton_dist_tpu.models.llama import (LlamaConfig, init_page_pool,
                                           require_config)
-from triton_dist_tpu.ops.page_migrate import migrate_pages
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
+from triton_dist_tpu.serving import programs
 from triton_dist_tpu.serving.deadline import (Backoff, Deadline,
                                               EngineStallError)
 from triton_dist_tpu.serving.engine import (class_label, mark_prefill_start,
@@ -543,47 +539,21 @@ class DisaggServingEngine:
         self._bt_dev = self._up(np.stack([self._z_bt, self._bt]))
         self._dirty = False
 
+        # widest possible per-chunk migration: a C-token chunk can
+        # finalize at most C//ps whole pages plus the straddle page it
+        # completes plus the final chunk's partial last page — and a
+        # RETRY may need to re-send a whole prompt's pages in one call
+        pmax = max(prefill_chunk // page_size + 2, pages_per_seq)
+
         # -- the three device programs (each ONE compiled SPMD program
-        # both roles enter; the off-role shard runs on parked inputs) ----
-        pspec = P(axis)
-
-        def chunk_f(p, toks, start, plen, kp, vp, bt):
-            pages = {"k": kp[0], "v": vp[0]}
-            tok, pages = prefill_chunk_paged(
-                p, toks[0], start[0], plen[0], cfg, pages, bt[0], ffn=ffn)
-            return tok[None], pages["k"][None], pages["v"][None]
-
-        chunk_sm = ctx.shard_map(
-            chunk_f, in_specs=(P(),) + (pspec,) * 6,
-            out_specs=(pspec,) * 3)
-
-        K = decode_horizon
-
-        def dec_f(p, tok, pos, kp, vp, bt, lim):
-            pages = {"k": kp[0], "v": vp[0]}
-            toks, tok2, pos2, pages = decode_multistep_paged(
-                p, tok[0], pos[0], cfg, pages, bt[0], lim[0],
-                horizon=K, eos_id=eos_id, ffn=ffn)
-            return (toks[None], tok2[None], pos2[None],
-                    pages["k"][None], pages["v"][None])
-
-        dec_sm = ctx.shard_map(
-            dec_f, in_specs=(P(),) + (pspec,) * 6,
-            out_specs=(pspec,) * 5)
-
-        def mig_f(src, dst, n, tag, kp, vp):
-            return migrate_pages(ctx, kp, vp, src, dst, n, axis=axis,
-                                 producer=PREFILL_ROLE,
-                                 consumer=DECODE_ROLE, tag=tag)
-
-        if jax.default_backend() == "cpu":   # CPU: donation unsupported
-            self._chunk_step = jax.jit(chunk_sm)
-            self._dec_step = jax.jit(dec_sm)
-            self._migrate = jax.jit(mig_f)
-        else:
-            self._chunk_step = jax.jit(chunk_sm, donate_argnums=(4, 5))
-            self._dec_step = jax.jit(dec_sm, donate_argnums=(3, 4))
-            self._migrate = jax.jit(mig_f, donate_argnums=(4, 5))
+        # both roles enter), built once a process for this configuration,
+        # mesh and these shapes (serving/programs.py) ----
+        self._chunk_step, self._dec_step, self._migrate, lint = \
+            programs.disagg_programs(
+                cfg, decode_horizon, eos_id, ffn, ctx, axis,
+                (PREFILL_ROLE, DECODE_ROLE), prefill_chunk, B, pages_per_seq,
+                pmax, programs.signature((self.pool_k, self.pool_v)),
+                programs.signature(params))
 
         # AOT artifact seeding (ISSUE 15): replace all three SPMD programs
         # with the artifact's deserialized executables BEFORE the channel
@@ -596,31 +566,7 @@ class DisaggServingEngine:
             self._dec_step = artifact.program(self._aot_key, "decode")
             self._migrate = artifact.program(self._aot_key, "migrate")
 
-        # widest possible per-chunk migration: a C-token chunk can
-        # finalize at most C//ps whole pages plus the straddle page it
-        # completes plus the final chunk's partial last page — and a
-        # RETRY may need to re-send a whole prompt's pages in one call
-        pmax = max(prefill_chunk // page_size + 2, pages_per_seq)
-
-        # TDT_SIGCHECK=1: build-time determinism lint of the three role-
-        # stacked SPMD programs (sigcheck rung 0 — docs/debugging.md);
-        # trace-only, abstract args, raises before any request is admitted
-        if os.environ.get("TDT_SIGCHECK") == "1":
-            from triton_dist_tpu.analysis.lint import lint_engine_programs
-            abstract = lambda tree: jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-            i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-            kv = (abstract(self.pool_k), abstract(self.pool_v))
-            lint_engine_programs({
-                "prefill_chunk_paged": (chunk_sm, (
-                    abstract(self.params), i32(2, prefill_chunk), i32(2),
-                    i32(2), *kv, i32(2, pages_per_seq))),
-                "decode_multistep_paged": (dec_sm, (
-                    abstract(self.params), i32(2, B), i32(2, B), *kv,
-                    i32(2, B, pages_per_seq), i32(2, B))),
-                "migrate_pages": (mig_f, (
-                    i32(pmax), i32(pmax), i32(1), i32(), *kv)),
-            }, type(self).__name__)
+        programs.lint_if_asked(lint, self)
 
         self.channel = PageMigrationChannel(
             self._migrate, pmax, reserved=1, metrics=self.metrics,
@@ -1746,18 +1692,13 @@ class DisaggServingEngine:
         (prefill worker, every prompt length), one decode program, one
         migration program (every chunk size ≤ pmax) — no per-prompt-length
         recompiles anywhere (test-pinned)."""
-        def n(fn, fallback):
-            try:
-                return int(fn._cache_size())
-            except Exception:
-                return fallback
-
         stats = {
-            "prefill_chunk_compiles": n(
+            "prefill_chunk_compiles": programs.compiles(
                 self._chunk_step,
                 1 if self.metrics.counters["prefill_chunks"] else 0),
-            "decode_compiles": n(self._dec_step, 1 if self._steps else 0),
-            "migrate_compiles": n(
+            "decode_compiles": programs.compiles(
+                self._dec_step, 1 if self._steps else 0),
+            "migrate_compiles": programs.compiles(
                 self._migrate,
                 1 if self.metrics.counters["migrate_chunks"] else 0),
         }

@@ -47,7 +47,6 @@ at every horizon (tests/test_serving.py asserts both for K in {1, 4}).
 from __future__ import annotations
 
 import json
-import os
 import time
 from collections import deque
 from typing import NamedTuple
@@ -56,11 +55,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from triton_dist_tpu.models.llama import (decode_multistep_paged,
-                                          prefill_chunk_paged)
 from triton_dist_tpu.ops.flash_decode import chunk_walk_counts
 from triton_dist_tpu.serving import checkpoint as ckpt_mod
-from triton_dist_tpu.serving import layouts
+from triton_dist_tpu.serving import layouts, programs
 from triton_dist_tpu.serving.deadline import Deadline, EngineStallError
 from triton_dist_tpu.serving.journal import ControlJournal
 from triton_dist_tpu.serving.kv_pool import KVPagePool, _fnv1a
@@ -349,78 +346,32 @@ class ServingEngine:
         # and not committed yet (``_launch_ahead``), or None
         self._ahead: _Ahead | None = None
 
-        # the hooks: attn_io/linear are the sharded engine's SP attention
-        # and TP projections; ffn_chunk is a chunk-row-count FFN distinct
-        # from the decode one, needed when the FFN is shape-specialized
-        # like the EP a2a dispatch
-        K = self.decode_horizon
-        if self.spec_k:
-            def step(p, t, pos, pages, bt, lim, hist, hlen):
-                return fam.decode_speculate(
-                    p, t, pos, cfg, pages, bt, lim, horizon=K, hist=hist,
-                    hist_len=hlen, eos_id=eos_id, ffn=ffn, attn_io=attn_io,
-                    linear=linear)
-        else:
-            def step(p, t, pos, pages, bt, lim):
-                return decode_multistep_paged(
-                    p, t, pos, cfg, pages, bt, lim, horizon=K,
-                    eos_id=eos_id, ffn=ffn, attn_io=attn_io, linear=linear)
-        # pool-output sharding pin (sharded engine sets _pool_out_sharding
-        # BEFORE calling super().__init__): without it, GSPMD may choose a
-        # different output sharding for the pool than the committed SP
-        # input sharding (the a2a's all_to_all regions perturb the
-        # propagation; an internal with_sharding_constraint loses too) and
-        # the SECOND dispatch recompiles against the flipped signature —
-        # breaking the one-program-per-path contract ``compile_stats``
-        # pins. out_shardings at the jit boundary always wins.
-        ps = getattr(self, "_pool_out_sharding", None)
-        # the fed-back token/pos carries are pinned replicated for the
-        # same reason (their initial host uploads are committed to the
-        # matching sharding by the sharded engine)
-        rep = None if ps is None else \
-            jax.sharding.NamedSharding(ps.mesh, jax.sharding.PartitionSpec())
-        step_kw = {} if ps is None else {"out_shardings": (
-            (None, None, rep, rep, rep, rep, {"k": ps, "v": ps})
-            if self.spec_k else (None, rep, rep, {"k": ps, "v": ps}))}
+        # the programs: built once a process for this key, shared by every
+        # engine of it (serving/programs.py). The hooks: attn_io/linear are
+        # the sharded engine's SP attention and TP projections; ffn_chunk is
+        # a chunk-row-count FFN distinct from the decode one, needed when the
+        # FFN is shape-specialized like the EP a2a dispatch.
         self.prefill_chunk = prefill_chunk
-
-        # ONE program for every prompt length/position: chunk size is the
-        # only shape; cursor and prompt length ride as runtime scalars
-        # (same trick as the decode limit argument)
-        def chunk(p, t, s, n, pages, bt):
-            return prefill_chunk_paged(
-                p, t, s, n, cfg, pages, bt, ffn=ffn_chunk or ffn,
-                attn_io=attn_io, linear=linear)
-        chunk_kw = {} if ps is None else {
-            "out_shardings": (None, {"k": ps, "v": ps})}
-        abstract = lambda tree: jax.tree_util.tree_map(     # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        ps = getattr(self, "_pool_out_sharding", None)
+        # the pool as the programs meet it: the sharded subclass (which set
+        # ``_pool_out_sharding`` before this ctor) pads the page dim up to a
+        # multiple of |sp| right after it returns (unified pool contract)
+        sp = getattr(self, "_pool_sp_ranks", 1)
+        pool_met = {
+            k: jax.ShapeDtypeStruct(
+                v.shape[:1] + (v.shape[1] + (-v.shape[1]) % sp,)
+                + v.shape[2:], v.dtype)
+            for k, v in self.pool.items()}
+        self._step, self._chunk_step, self._formats, lint = \
+            programs.engine_programs(
+                cfg, self.decode_horizon, eos_id, self.spec_k,
+                self.spec_hist, prefill_chunk, num_slots, self._bt.shape[1],
+                programs.signature(pool_met),
+                programs.signature(self.params),
+                (ffn, ffn_chunk, attn_io, linear), ps,
+                ps is None and artifact is None)
         held = []
-        if jax.default_backend() == "cpu":      # no donation
-            self._step = jax.jit(step, **step_kw)
-            self._chunk_step = jax.jit(chunk, **chunk_kw)
-        elif ps is not None or artifact is not None:
-            # on a mesh, or seeded from an artifact (whose programs were
-            # exported for parameters as they come): today's layouts
-            self._step = jax.jit(step, donate_argnums=(3,), **step_kw)
-            self._chunk_step = jax.jit(chunk, donate_argnums=(4,),
-                                       **chunk_kw)
-        else:
-            # the decode program is compiled HERE, each parameter leaf in
-            # the layout the compiler chooses for it, and the weights are
-            # committed to those once (serving/layouts.py): no dispatch
-            # re-lays a projection out again. The chunk program is compiled
-            # at its first call, as ever, against the same formats.
-            decode_rest = (i32(num_slots), i32(num_slots),
-                           abstract(self.pool), i32(*self._bt.shape),
-                           i32(num_slots))
-            if self.spec_k:
-                decode_rest += (i32(num_slots, self.spec_hist),
-                                i32(num_slots))
-            self._step, self._chunk_step, self._formats = \
-                layouts.held_layout_programs(step, chunk, self.params,
-                                             decode_rest)
+        if self._formats is not None:
             held = layouts.relaid(self.params, self._formats)
             # a format names its device, so these programs' outputs are
             # COMMITTED to it: so is the pool from the start, or the chunk
@@ -434,38 +385,7 @@ class ServingEngine:
         self.metrics.counters["params_relaid_bytes"] = sum(
             h["bytes"] for h in held)
 
-        # TDT_SIGCHECK=1: lint the engine's compiled programs against the
-        # trace-determinism contract at BUILD time (sigcheck rung 0 — see
-        # docs/debugging.md). Trace-only on abstract args; a rank-count-
-        # dependent reduction or host callback in the hot path raises here,
-        # before any request is admitted.
-        if os.environ.get("TDT_SIGCHECK") == "1":
-            from triton_dist_tpu.analysis.lint import lint_engine_programs
-            # lint with the shapes the programs actually run on: the
-            # sharded subclass pads the pool's page dim up to a multiple
-            # of |sp| right after this ctor returns (unified pool
-            # contract), so fold the same padding into the abstract args
-            sp = getattr(self, "_pool_sp_ranks", 1)
-            pool_abs = {
-                k: jax.ShapeDtypeStruct(
-                    v.shape[:1] + (v.shape[1] + (-v.shape[1]) % sp,)
-                    + v.shape[2:], v.dtype)
-                for k, v in self.pool.items()}
-            if self.spec_k:
-                programs = {"decode_speculate_paged": (step, (
-                    abstract(self.params), i32(num_slots), i32(num_slots),
-                    pool_abs, i32(*self._bt.shape),
-                    i32(num_slots), i32(num_slots, self.spec_hist),
-                    i32(num_slots)))}
-            else:
-                programs = {"decode_multistep_paged": (step, (
-                    abstract(self.params), i32(num_slots), i32(num_slots),
-                    pool_abs, i32(*self._bt.shape),
-                    i32(num_slots)))}
-            programs["prefill_chunk_paged"] = (chunk, (
-                abstract(self.params), i32(prefill_chunk), i32(), i32(),
-                pool_abs, i32(self._bt.shape[1])))
-            lint_engine_programs(programs, type(self).__name__)
+        programs.lint_if_asked(lint, self)
 
         # AOT artifact seeding (ISSUE 15): swap the freshly-built jit
         # objects for the artifact's deserialized programs so a cold start
@@ -1632,16 +1552,11 @@ class ServingEngine:
         chunk program, each exactly 1 however mixed the traffic. Uses the
         jit-internal cache size when available, falling back to whether
         the program has run."""
-        def n(fn, fallback):
-            try:
-                return int(fn._cache_size())
-            except Exception:
-                return fallback
-
         stats = {
-            "decode_compiles": n(self._step, 1 if self._steps else 0),
+            "decode_compiles": programs.compiles(
+                self._step, 1 if self._steps else 0),
             # exactly one program for ALL prompt lengths
-            "prefill_chunk_compiles": n(
+            "prefill_chunk_compiles": programs.compiles(
                 self._chunk_step,
                 1 if self.metrics.counters["prefill_chunks"] else 0),
             # bytes and paths of the parameter leaves held in another layout

@@ -39,6 +39,7 @@ which is also why ``gemm_rs`` is refused here (docs/serving.md).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -132,6 +133,41 @@ def fd_attn_split_us(n_sp: int, n_layers: int, slots: int, steps: int,
     fold = n_layers * (fit["t0_us"]
                        + (n_sp - 1) * slots * steps * slab_row_bytes / bw)
     return local, fold
+
+
+@functools.lru_cache(maxsize=None)
+def _hooks(ctx, a2a_decode, a2a_chunk, block_m, mb, tp_impl, tp_cfg,
+           sp_overlap, long_context):
+    """``(ffn, ffn_chunk, attn_io, linear)``: ONE set of hook functions for
+    everything they close over (all static records), so that sharded engines
+    of a mesh and a shape meet in ``serving.programs``' memo, which keys
+    hooks by identity."""
+    def moe_ffn(a2a):
+        def ffn(h, p):
+            # p is the unrolled loop's LayerParams view: the expert
+            # tables go in STACKED and are indexed in place
+            b = p.blocks
+            return moe_mlp_ep_overlap(ctx, a2a, h, p["w_router"],
+                                      b["we_gate"], b["we_up"],
+                                      b["we_down"], block_m=block_m,
+                                      microbatches=mb, layer=p.layer)
+        return ffn
+
+    if long_context:
+        def attn_io(q, k, v, kp, vp, bt, pos, kv_len, active):
+            return flash_decode_dist(ctx, q, k, v, kp, vp, bt, pos,
+                                     kv_len, axis="sp", active=active)
+    else:
+        def attn_io(q, k, v, kp, vp, bt, pos, kv_len, active):
+            return sp_paged_attend_write(ctx, q, k, v, kp, vp, bt,
+                                         pos, kv_len, axis="sp",
+                                         active=active, overlap=sp_overlap)
+
+    def linear(h, w, name):
+        return tp_column_linear(ctx, h, w, axis="tp", impl=tp_impl,
+                                cfg=tp_cfg)
+
+    return moe_ffn(a2a_decode), moe_ffn(a2a_chunk), attn_io, linear
 
 
 class ShardedServingEngine(ServingEngine):
@@ -295,19 +331,6 @@ class ShardedServingEngine(ServingEngine):
                                   else seg(self.a2a_chunk))
         self.overlap_microbatches = mb
 
-        def moe_ffn(a2a):
-            def ffn(h, p):
-                # p is the unrolled loop's LayerParams view: the expert
-                # tables go in STACKED and are indexed in place
-                b = p.blocks
-                return moe_mlp_ep_overlap(ctx, a2a, h, p["w_router"],
-                                          b["we_gate"], b["we_up"],
-                                          b["we_down"], block_m=moe_block_m,
-                                          microbatches=mb, layer=p.layer)
-            return ffn
-
-        sp_overlap = overlap == "ep+sp"
-
         # long-context mode (ISSUE 19): swap the SP attention leg from
         # the across-REQUESTS pool-allgather walk (every rank attends
         # over the full pool — per-rank cost ∝ full kv_len) to
@@ -322,20 +345,9 @@ class ShardedServingEngine(ServingEngine):
         self.long_context = long_context
         if long_context:
             self._pool_layout = "interleaved"
-
-            def attn_io(q, k, v, kp, vp, bt, pos, kv_len, active):
-                return flash_decode_dist(ctx, q, k, v, kp, vp, bt, pos,
-                                         kv_len, axis="sp", active=active)
-        else:
-            def attn_io(q, k, v, kp, vp, bt, pos, kv_len, active):
-                return sp_paged_attend_write(ctx, q, k, v, kp, vp, bt,
-                                             pos, kv_len, axis="sp",
-                                             active=active,
-                                             overlap=sp_overlap)
-
-        def linear(h, w, name):
-            return tp_column_linear(ctx, h, w, axis="tp", impl=tp_impl,
-                                    cfg=tp_cfg)
+        ffn, ffn_chunk, attn_io, linear = _hooks(
+            ctx, self.a2a_decode, self.a2a_chunk, moe_block_m, mb, tp_impl,
+            tp_cfg, overlap == "ep+sp", long_context)
 
         # modeled per-decode-step wire split (satellite 2): price each EP
         # a2a with the PR 8 wire fit (t = t0 + bytes/BW). With M overlap
@@ -359,9 +371,8 @@ class ShardedServingEngine(ServingEngine):
             if long_context else (0.0, 0.0))
 
         # pool-output sharding pin: must exist BEFORE super().__init__
-        # builds the jitted programs (it becomes their out_shardings for
-        # the pool pytree — see the comment at the jit construction site
-        # in ServingEngine.__init__)
+        # asks for the jitted programs (it becomes their out_shardings for
+        # the pool pytree — see ``programs.engine_programs``)
         self._pool_out_sharding = jax.sharding.NamedSharding(
             ctx.mesh, page_pool_pspec("sp"))
         # replicated sharding for the control-plane mirrors (_sync_mirrors
@@ -377,8 +388,7 @@ class ShardedServingEngine(ServingEngine):
         super().__init__(params, cfg.base, num_slots=num_slots,
                          page_size=page_size, num_pages=num_pages,
                          pages_per_seq=pages_per_seq,
-                         ffn=moe_ffn(self.a2a_decode),
-                         ffn_chunk=moe_ffn(self.a2a_chunk),
+                         ffn=ffn, ffn_chunk=ffn_chunk,
                          attn_io=attn_io, linear=linear,
                          metrics=metrics, decode_horizon=decode_horizon,
                          eos_id=eos_id, prefill_chunk=prefill_chunk,
