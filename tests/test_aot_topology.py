@@ -1051,6 +1051,56 @@ def test_linear_attn_states_and_pages_stay_in_place(linear_attn_programs,
     assert mem.temp_size_in_bytes < 0.7e9, mem.temp_size_in_bytes
 
 
+SC_SLOTS, SC_P, SC_PPS = 96, 1153, 72
+
+
+def test_short_conv_rows_and_side_by_side_pages_stay_in_place(topo):
+    """LFM2-24B-A2B as published (every width: heads of 64, 32 over 8; conv
+    of 3 taps over 2,048; 64 experts of 1,536, top-4; dense 11,776), the two
+    dense layers and ONE period of (full, conv, conv, conv), bound to the
+    cell's 96 slots and 2,048-row chunk, the K/V pool cut to 1,153 pages, both
+    programs lowered for one described v5e, pool donated. Mosaic takes both
+    paged GQA kernels on ONE pool of ``[K | V]`` rows (heads of 64 as one
+    128-lane row, a page one copy); the trace will find them by the dense
+    kernels' names; nothing shaped like the pool, a layer of it or the conv
+    leaf comes out of a ``copy`` or a slice."""
+    import re
+    from triton_dist_tpu.models import short_conv_moe as sc
+    cfg = sc.bind(sc.ShortConvMoEConfig(n_layers=6,
+                                        max_seq_len=SC_PPS * 128),
+                  SC_SLOTS, 2048)
+    S = SC_SLOTS + 1
+    kv = f"{SC_P},8,128,128]"
+    big = [f"[1,{kv}", f"[{kv}", f"[{5 * S},4096]"]
+    pool_bytes = SC_P * 8 * 128 * 128 * 2 + 5 * S * 4096 * 2
+    assert sc.kv_bytes_per_token(cfg) == 2048       # as published
+    for program, (fn, args) in _plain_jits(*_engine_programs(
+            topo, cfg, sc.init_params, SC_P, SC_SLOTS, 2048,
+            SC_PPS + 1)).items():
+        exe = fn.lower(*args).compile()
+        text, mem = exe.as_text(), exe.memory_analysis()
+        walk = {"decode": "gqa_decode_paged", "chunk": "gqa_prefill_paged"}
+        for kernel in (walk[program], "grouped_gemm_gated", "grouped_gemm"):
+            assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), \
+                (program, kernel)
+        moved = []
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+            if not m:
+                continue
+            name, result, opcode = m.groups()
+            kind = name if opcode == "fusion" else opcode
+            if any(s in result for s in big) and re.search(
+                    r"copy|slice", kind) and not re.search(r"update.slice",
+                                                           kind):
+                moved.append(line.strip()[:160])
+        assert not moved, "\n".join(moved)
+        assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+        # a sparse layer's tables are 1.2 GB: no copy of one fits under this
+        assert mem.temp_size_in_bytes < 0.5e9, (program,
+                                                mem.temp_size_in_bytes)
+
+
 def test_linear_attn_periods_are_one_scanned_body(topo):
     """Periods of four layers of two kinds lower to ONE ``while`` over the
     periods (8 or 12 layers, not 8 or 12 bodies): a third period adds no

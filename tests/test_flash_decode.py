@@ -113,10 +113,13 @@ def _paged_golden(q, k_pages, v_pages, block_table, kv_len):
     return out
 
 
-def test_paged_decode_garbage_block_table_entries():
+@pytest.mark.parametrize("rows", ["k-and-v-apart", "k-v-side-by-side"])
+def test_paged_decode_garbage_block_table_entries(rows):
     """Block-table entries past ceil(kv_len/page_size) may be ARBITRARY —
     even out-of-range page ids — without changing the result or faulting
-    (the index map clamps and never dereferences them)."""
+    (the index map clamps and never dereferences them). ``k-v-side-by-side``:
+    ONE pool whose rows are ``[K | V]`` of a head (``v_pages`` None; heads of
+    64 as one 128-lane row) against the same golden on the halves."""
     B, Hq, Hkv, D, ps, pps, pool = 2, 4, 2, 64, 8, 6, 16
     q = jax.random.normal(jax.random.key(0), (B, Hq, D), jnp.float32)
     kp = jax.random.normal(jax.random.key(1), (pool, Hkv, ps, D), jnp.float32)
@@ -124,13 +127,18 @@ def test_paged_decode_garbage_block_table_entries():
     kv_len = jnp.array([2 * ps + 3, ps], jnp.int32)   # 3 and 1 live pages
     bt_clean = np.array([[3, 7, 1, 0, 0, 0],
                          [5, 0, 0, 0, 0, 0]], np.int32)
-    out_c, lse_c = jax.jit(gqa_decode_paged)(q, kp, vp,
+    if rows == "k-v-side-by-side":
+        held = (jnp.concatenate([kp, vp], -1), None)
+    else:
+        held = (kp, vp)
+    out_c, lse_c = jax.jit(gqa_decode_paged)(q, *held,
                                              jnp.asarray(bt_clean), kv_len)
+    assert out_c.shape == q.shape
     # poison every dead entry with garbage incl. ids far outside the pool
     bt_dirty = bt_clean.copy()
     bt_dirty[0, 3:] = [10 ** 6, -5, 2 ** 31 - 1]
     bt_dirty[1, 1:] = [-(2 ** 31), 999999, -1, 888, pool]
-    out_d, lse_d = jax.jit(gqa_decode_paged)(q, kp, vp,
+    out_d, lse_d = jax.jit(gqa_decode_paged)(q, *held,
                                              jnp.asarray(bt_dirty), kv_len)
     np.testing.assert_array_equal(np.asarray(out_c), np.asarray(out_d))
     np.testing.assert_array_equal(np.asarray(lse_c), np.asarray(lse_d))
@@ -318,6 +326,12 @@ PREFILL_CASES = {
     "dk-not-dv": {"at": (24, 40), "dv": 32},
     "window-sink-dk-not-dv": {"at": (30, 46), "window": 20, "sinks": True,
                               "dv": 32},
+    # ONE pool of ``[K | V]`` rows (``v_pages`` None): keys and values of 32
+    "kv-side-by-side": {"at": (24, 40), "fused": True},
+    "kv-side-by-side-straddles": {"at": (12, 28), "fused": True},
+    "kv-side-by-side-lone-rows": {
+        "kv_len": [0, 0, 0, 9, 0, 0, 0, 0] + [0] * 6 + [33, 0],
+        "fused": True},
 }
 POOL_PAGES = 32
 
@@ -353,6 +367,10 @@ def _prefill_inputs(case, C=16):
     kw = {"window": window} if window else {}
     if spec.get("sinks"):
         kw["sinks"] = jnp.asarray([0.5, -1.0, 2.0, 0.0], jnp.float32)
+    if spec.get("fused"):
+        half = Dk // 2
+        q, vp = q[..., :half], None
+        kp = jnp.concatenate([kp[..., :half], kp[..., half:] * 0.5], -1)
     return q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len), kw
 
 
@@ -398,6 +416,13 @@ def test_prefill_paged_equals_decode_rows(case, layer):
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     padded = np.asarray(kv_len) == 0
     np.testing.assert_array_equal(got[padded], 0.0)
+    if vp is None:
+        # the one pool's halves held apart: the same keys and values
+        half = q.shape[-1]
+        apart = _chunk_rows(None, Rb)(q, kp[..., :half], kp[..., half:], bt,
+                                      kv_len, jnp.int32(layer), None)
+        np.testing.assert_allclose(got, np.asarray(apart), rtol=1e-6,
+                                   atol=1e-6)
     if list(PREFILL_CASES).index(case) < 8:
         # the per-layer form reads the same pages of a [P, ...] pool
         np.testing.assert_array_equal(np.asarray(gqa_prefill_paged(

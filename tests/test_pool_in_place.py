@@ -231,6 +231,12 @@ def test_write_in_place_equals_window_scatter(pool, case, layer):
     # the per-layer form: the same rows of a [P, ...] pool
     assert same(paged_kv_write(pool["k"][layer], pool["v"][layer], kn, vn,
                                bt, pos, active=act), (wk, wv))
+    # ONE pool of ``[K | V]`` rows (``v_pages`` None): the same rows, side by
+    # side, in one scatter
+    both, none = paged_kv_write(jnp.concatenate([pool["k"], pool["v"]], -1),
+                                None, kn, vn, bt, pos, active=act,
+                                layer=layer)
+    assert none is None and same(both, jnp.concatenate(want, -1))
 
 
 def test_write_in_place_speculative_rows(pool):

@@ -1,9 +1,11 @@
 """ONE CHIP'S SHARE of a routed expert layer (sigmoid or softmax scores): what
 the families that hold ``n_held`` of a layer's routed experts (``models.mla``,
-``models.window_moe``, ``models.linear_attn_moe``) have in common. The chip
-routes over ALL the experts the router scores and computes the part of the
-routed sum its own experts give; what the absent experts would add is left
-out, with no exchange and nothing that stands in for one.
+``models.window_moe``, ``models.linear_attn_moe``, ``models.short_conv_moe``)
+have in common. The chip routes over ALL the experts the router scores and
+computes the part of the routed sum its own experts give; what the absent
+experts would add is left out, with no exchange and nothing that stands in
+for one. The WHOLE layer is the share of one (``n_held`` = the router's
+width, ``first_held`` 0): every pick is held.
 """
 
 from __future__ import annotations
@@ -21,15 +23,17 @@ COUNTERS = ("moe_local_rows", "moe_experts_touched")
 
 
 def sigmoid_route(h: jax.Array, w_router: jax.Array, topk: int, bias=None,
-                  scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+                  scale: float = 1.0, eps: float = 1e-20
+                  ) -> tuple[jax.Array, jax.Array]:
     """(expert ids [R, k], weights [R, k] float32): sigmoid scores in
     float32 over ALL routed experts, the k largest (of score + ``bias``
     where the router has a selection bias) chosen, weighed by their scores
-    (without the bias) over their sum, times ``scale``."""
+    (without the bias) over their sum + ``eps`` (the published normaliser's,
+    where a model states one), times ``scale``."""
     g = jax.nn.sigmoid(h.astype(jnp.float32) @ w_router)
     _, ids = lax.top_k(g if bias is None else g + bias, topk)
     w = jnp.take_along_axis(g, ids, axis=-1)
-    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
     return ids, w if scale == 1.0 else w * scale
 
 
@@ -46,7 +50,9 @@ def softmax_route(h: jax.Array, w_router: jax.Array, topk: int
 
 def held_ids(ids: jax.Array, n_held: int, first_held: int, active=None):
     """(local ids [R, k] int32 with -1 for an expert another chip holds or a
-    row masked off by ``active``, the layer's ``COUNTERS``)."""
+    row masked off by ``active``, the layer's ``COUNTERS``). With every
+    routed expert held (``first_held`` 0, ``n_held`` the router's width) no
+    expert is absent, and only ``active`` drops a pick."""
     lid = ids - first_held
     held = jnp.logical_and(lid >= 0, lid < n_held)
     if active is not None:

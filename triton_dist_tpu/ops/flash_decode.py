@@ -126,7 +126,7 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
 def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, *refs,
                          n_pool: int, page_size: int, sm_scale: float,
                          n_kv_heads: int, window: int | None = None,
-                         sinks: bool = False):
+                         sinks: bool = False, fused: bool = False):
     """Grid (row blocks,) over a paged KV pool left in HBM: ONE loop over the
     block's LIVE pages alone, rows in order and a row's pages in order, each
     fetched by hand (``bt_ref[row, idx]`` of layer ``layer_ref[0]``, straight
@@ -145,9 +145,18 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, *refs,
 
     With ``sinks`` one more operand follows ``q_ref``: ``sink_ref`` [Hq, 1]
     float32, the learned logit a query head that every live row's softmax
-    starts from (``_softmax_init``)."""
+    starts from (``_softmax_init``).
+
+    ``fused``: ONE pool whose rows are ``[K | V]`` (``gqa_decode_paged``): no
+    ``v_hbm`` / ``v_buf`` operand, one copy a page, and the page's buffer is
+    both operands of the update (the queries' value lanes are zeros, the
+    output's key lanes are dropped by the caller)."""
     sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
-    k_hbm, v_hbm, out_ref, lse_ref, k_buf, v_buf, sem, acc, m_i, l_i = refs
+    if fused:
+        k_hbm, out_ref, lse_ref, k_buf, sem, acc, m_i, l_i = refs
+        v_buf = k_buf
+    else:
+        k_hbm, v_hbm, out_ref, lse_ref, k_buf, v_buf, sem, acc, m_i, l_i = refs
     sink = None if sink_ref is None else sink_ref[...]
     rows, pages_per_seq = q_ref.shape[0], bt_ref.shape[1]
     row0 = pl.program_id(0) * rows
@@ -178,8 +187,11 @@ def _decode_paged_kernel(kv_len_ref, bt_ref, layer_ref, q_ref, *refs,
         # the clamp keeps even a garbage block-table entry inside the pool
         col = idx % pages_per_seq if window else idx
         page = jnp.clip(bt_ref[row, col], 0, n_pool - 1)
-        return (pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[buf],
-                                      sem.at[0, buf]),
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[buf],
+                                       sem.at[0, buf])
+        if fused:
+            return (k_copy,)
+        return (k_copy,
                 pltpu.make_async_copy(v_hbm.at[layer, page], v_buf.at[buf],
                                       sem.at[1, buf]))
 
@@ -327,8 +339,20 @@ def _as_stack(k_pages, v_pages, layer):
     if layer is None:
         # a per-layer pool is the L = 1 stack: adding a leading 1 is a
         # bitcast, never a copy
-        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        k_pages, layer = k_pages[None], 0
+        v_pages = None if v_pages is None else v_pages[None]
     return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _fused_queries(q, kv_pages, sm_scale):
+    """Queries for a pool whose rows are ``[K | V]`` (``v_pages`` None): q
+    [.., Hq, Dk] padded with zeros over the value lanes, so that a row's
+    score against the whole pool row is its score against the keys; the
+    head's own scale; and Dk, where the values start in an output row."""
+    Dk, W = q.shape[-1], kv_pages.shape[-1]
+    assert Dk < W, f"a fused pool row of {W} holds keys of {Dk} and values"
+    q = jnp.pad(q, ((0, 0),) * (q.ndim - 1) + ((0, W - Dk),))
+    return q, sm_scale if sm_scale is not None else Dk ** -0.5, Dk
 
 
 # The decode rows' walk is ONE grid step a block of rows, a loop over its live
@@ -426,8 +450,20 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     / (sum_j exp(a_j - m) + exp(s - m))``, ``m = max(max_j a_j, s)``; rows
     then sum to less than one, and ``lse`` counts the sink. The kernel's name
     gains ``_sink``.
+
+    ``v_pages`` None: ``k_pages`` [..., Dk + Dv] is ONE pool whose rows are
+    ``[K | V]`` of a KV head side by side (heads of 64 as one 128-lane row:
+    held apart, each would be padded to 128 lanes on the chip, twice the
+    bytes held and read). A page is then ONE copy, and its buffer is both
+    operands of the update: the queries are zero over the value lanes, so the
+    scores are the keys' own, and ``p @ [K | V]`` carries ``p @ V`` in its
+    last Dv lanes, which are returned. Same kernel, one operand fewer.
     """
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
+    fused, values_at = v_pages is None, 0
+    if fused:
+        q, sm_scale, values_at = _fused_queries(q, k_pages, sm_scale)
+        v_pages = k_pages
     B, Hq, Dk = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
     Dv = v_pages.shape[-1]
@@ -445,26 +481,29 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     kernel = functools.partial(_decode_paged_kernel, n_pool=P_pool,
                                page_size=page_size, sm_scale=sm_scale,
                                n_kv_heads=Hkv, window=window,
-                               sinks=sinks is not None)
+                               sinks=sinks is not None, fused=fused)
+    pools = (k_pages,) if fused else (k_pages, v_pages)
     extra, extra_specs = (), []
     if sinks is not None:
         extra = (sinks.astype(jnp.float32).reshape(Hq, 1),)
         extra_specs = [pl.BlockSpec((Hq, 1), lambda i, *_: (0, 0))]
     live = _pages_at_most(window, pages_per_seq, page_size, 1)
-    return pl.pallas_call(
+    width = Dk if fused else Dk + Dv       # elements a key moves and meets
+    out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B // Rb,),
             in_specs=[pl.BlockSpec((Rb, Hq, Dk), rows), *extra_specs,
-                      in_hbm, in_hbm],
+                      *(in_hbm for _ in pools)],
             out_specs=[
                 pl.BlockSpec((Rb, Hq, Dv), rows),
                 pl.BlockSpec((Rb, Hq, 128), rows),
             ],
             scratch_shapes=[
-                page_buf(Dk), page_buf(Dv),
-                pltpu.SemaphoreType.DMA((2, DECODE_PAGES_IN_FLIGHT + 1)),
+                *(page_buf(p.shape[-1]) for p in pools),
+                pltpu.SemaphoreType.DMA((len(pools),
+                                         DECODE_PAGES_IN_FLIGHT + 1)),
                 pltpu.VMEM((Hq, Dv), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
                 pltpu.VMEM((Hq, 1), jnp.float32),
@@ -475,14 +514,14 @@ def gqa_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             jax.ShapeDtypeStruct((B, Hq, 128), jnp.float32),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=2 * B * Hq * live * page_size * (Dk + Dv),
+            flops=2 * B * Hq * live * page_size * width,
             bytes_accessed=(q.size
-                            + B * live * Hkv * page_size * (Dk + Dv)),
+                            + B * live * Hkv * page_size * width),
             transcendentals=B * Hq * live * page_size),
         name=_kernel_name("gqa_decode_paged", window, sinks),
         interpret=default_interpret(),
-    )(kv_len.astype(jnp.int32), block_table, layer, q, *extra, k_pages,
-      v_pages)
+    )(kv_len.astype(jnp.int32), block_table, layer, q, *extra, *pools)
+    return (out[..., values_at:] if fused else out), lse
 
 
 # Rows of a prefill chunk that share one walk of the sequence's pages: with
@@ -607,7 +646,8 @@ def chunk_walk_counts(start: int, real: int, chunk: int, rows: int,
 def _prefill_paged_kernel(kmin_ref, kmax_ref, bt_ref, layer_ref, q_ref,
                           klr_ref, *refs, group: int, edge: int,
                           n_pool: int, page_size: int, sm_scale: float,
-                          window: int | None = None, sinks: bool = False):
+                          window: int | None = None, sinks: bool = False,
+                          fused: bool = False):
     """Grid (row blocks,) over a paged KV pool left in HBM: ONE loop over the
     block's pages ``[first, end)`` of ``chunk_walk_pages(kmin_ref[i],
     kmax_ref[i])`` and nothing else, a GROUP of up to ``group`` consecutive
@@ -642,7 +682,13 @@ def _prefill_paged_kernel(kmin_ref, kmax_ref, bt_ref, layer_ref, q_ref,
     finds it NEG_INF and adds exp(0), which the row's first real key scales
     by exp(NEG_INF - m) = 0; with a sink the max is real from the start."""
     sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
-    k_hbm, v_hbm, out_ref, k_buf, v_buf, sem, turns, acc, m_i, l_i = refs
+    if fused:
+        # rows of ``[K | V]`` (``gqa_decode_paged``): one pool, one operand
+        k_hbm, out_ref, k_buf, sem, turns, acc, m_i, l_i = refs
+        pools, v_buf = ((k_hbm, k_buf),), k_buf
+    else:
+        k_hbm, v_hbm, out_ref, k_buf, v_buf, sem, turns, acc, m_i, l_i = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     i, n_blk = pl.program_id(0), pl.num_programs(0)
     Hkv, M, _ = q_ref.shape
     pages_per_seq = bt_ref.shape[0]
@@ -675,7 +721,7 @@ def _prefill_paged_kernel(kmin_ref, kmax_ref, bt_ref, layer_ref, q_ref,
             # the clamp keeps even a garbage block-table entry inside the pool
             page = jnp.clip(bt_ref[col], 0, n_pool - 1)
             at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
-            for kv, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            for kv, (hbm, buf) in enumerate(pools):
                 do(pltpu.make_async_copy(hbm.at[layer, page],
                                          buf.at[slot, :, at],
                                          sem.at[kv, slot, j]))
@@ -811,9 +857,13 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     ``gqa_prefill_paged_window``.
     ``vmem_limit_bytes`` raises Mosaic's scoped-VMEM limit for a block that
     needs more than its 16 MB default. Keys and values of different widths
-    (q [C, Hq, Dk], out [C, Hq, Dv]) and ``sinks`` [Hq] float32 are
-    ``gqa_decode_paged``'s; the name gains ``_sink``."""
+    (q [C, Hq, Dk], out [C, Hq, Dv]), ``sinks`` [Hq] float32 (the name gains
+    ``_sink``) and ``v_pages`` None (ONE pool of ``[K | V]`` rows) are
+    ``gqa_decode_paged``'s."""
     k_pages, v_pages, layer = _as_stack(k_pages, v_pages, layer)
+    values_at = 0
+    if v_pages is None:
+        q, sm_scale, values_at = _fused_queries(q, k_pages, sm_scale)
     Rb = math.gcd(q.shape[0], rows_per_block)
     page_size, pages_per_seq = k_pages.shape[3], block_table.shape[0]
     M = Rb * (q.shape[1] // k_pages.shape[2])
@@ -824,7 +874,7 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # shapes (a period's window layers; every engine a process builds) share
     # it. The module's constants and the backend's mode are read here,
     # outside, so that they are part of what a trace is found by.
-    return jax.jit(_prefill_walk, static_argnames=(
+    out = jax.jit(_prefill_walk, static_argnames=(
         "sm_scale", "rows_per_block", "window", "vmem_limit_bytes", "group",
         "edge", "interpret"))(
         q, k_pages, v_pages, block_table, kv_len, layer, sinks,
@@ -832,6 +882,7 @@ def gqa_prefill_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         vmem_limit_bytes=vmem_limit_bytes, group=group,
         edge=min(group, PREFILL_EDGE_PAGES_PER_GROUP),
         interpret=default_interpret())
+    return out[..., values_at:] if values_at else out
 
 
 def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
@@ -840,11 +891,14 @@ def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
     """``gqa_prefill_paged`` on the stacked pool: the head-major transposes
     and the kernel's call, in blocks of ``rows_per_block`` rows (a divisor of
     the chunk's), at most ``group`` / ``edge`` pages an interior / an edge
-    group."""
+    group. ``v_pages`` None: ``k_pages`` holds ``[K | V]`` rows and ``q`` is
+    already as wide (``_fused_queries``)."""
     C, Hq, Dk = q.shape
     _, P_pool, Hkv, page_size, _ = k_pages.shape
-    Dv = v_pages.shape[-1]
-    assert k_pages.shape[-1] == Dk and v_pages.shape[:-1] == k_pages.shape[:-1]
+    fused = v_pages is None
+    pools = (k_pages,) if fused else (k_pages, v_pages)
+    Dv = pools[-1].shape[-1]
+    assert k_pages.shape[-1] == Dk and pools[-1].shape[:-1] == k_pages.shape[:-1]
     assert Hq % Hkv == 0 and block_table.ndim == 1, (q.shape, block_table.shape)
     assert page_size % 8 == 0, f"page_size {page_size} must be 8-aligned"
     G, Rb = Hq // Hkv, rows_per_block
@@ -868,6 +922,7 @@ def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
     group_buf = lambda D: pltpu.VMEM(                       # noqa: E731
         (2, Hkv, group * page_size, D), k_pages.dtype)
     live = C * n_pages * page_size
+    width = Dk if fused else Dk + Dv       # elements a key moves and meets
     params = {} if vmem_limit_bytes is None else {
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes)}
@@ -875,7 +930,8 @@ def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
         functools.partial(_prefill_paged_kernel, group=group, edge=edge,
                           n_pool=P_pool,
                           page_size=page_size, sm_scale=sm_scale,
-                          window=window, sinks=sinks is not None),
+                          window=window, sinks=sinks is not None,
+                          fused=fused),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_blk,),
@@ -883,12 +939,12 @@ def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
                 pl.BlockSpec((Hkv, M, Dk), rows),
                 pl.BlockSpec((M, 1), lambda i, *_: (i, 0)),
                 *extra_specs,
-                in_hbm, in_hbm,
+                *(in_hbm for _ in pools),
             ],
             out_specs=pl.BlockSpec((Hkv, M, Dv), rows),
             scratch_shapes=[
-                group_buf(Dk), group_buf(Dv),
-                pltpu.SemaphoreType.DMA((2, 2, group)),
+                *(group_buf(p.shape[-1]) for p in pools),
+                pltpu.SemaphoreType.DMA((len(pools), 2, group)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((Hkv, M, Dv), jnp.float32),
                 pltpu.VMEM((Hkv, M, 1), jnp.float32),
@@ -897,15 +953,15 @@ def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
         ),
         out_shape=jax.ShapeDtypeStruct((Hkv, C * G, Dv), q.dtype),
         cost_estimate=pl.CostEstimate(
-            flops=2 * live * Hq * (Dk + Dv),
+            flops=2 * live * Hq * width,
             bytes_accessed=(q.size + C * Hq * Dv + n_blk * n_pages * Hkv
-                            * page_size * (Dk + Dv)) * q.dtype.itemsize,
+                            * page_size * width) * q.dtype.itemsize,
             transcendentals=live * Hq),
         name=_kernel_name("gqa_prefill_paged", window, sinks),
         interpret=interpret,
         **params,
     )(*chunk_walk_bounds(kv_len, Rb), block_table, layer, q_hm, kl_rows,
-      *extra, k_pages, v_pages)
+      *extra, *pools)
     return out.reshape(Hkv, C, G, Dv).swapaxes(0, 1).reshape(C, Hq, Dv)
 
 
@@ -946,15 +1002,20 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     until PR 25 (``tests/test_aot_topology.py`` holds the compiled programs
     to "no pool-shaped copy"). It keeps that form's rule for stray page
     ids: one outside the pool drops the write, a negative one counts from
-    the end.
+    the end. ``v_pages`` None: ``k_pages`` holds ``[K | V]`` rows; the row
+    written is ``[k_new | v_new]`` and the result ``(pool, None)``.
     """
     assert (layer is not None) == (k_pages.ndim == 5), (
         "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
+    idx = _page_row_index(k_pages.shape, block_table, pos, active, layer)
+    if v_pages is None:
+        # ONE pool of ``[K | V]`` rows (``gqa_decode_paged``): one scatter
+        return _write_rows(k_pages, jnp.concatenate([k_new, v_new], -1),
+                           idx), None
     # keys and values may differ in width (k_new [B, Hkv, Dk], v_new [B,
     # Hkv, Dv]): a row's index in the two 2-D views is the same
     assert k_pages.shape[:-1] == v_pages.shape[:-1], (k_pages.shape,
                                                       v_pages.shape)
-    idx = _page_row_index(k_pages.shape, block_table, pos, active, layer)
     return _write_rows(k_pages, k_new, idx), _write_rows(v_pages, v_new, idx)
 
 
