@@ -16,6 +16,20 @@ roofline in docs/benchmarks.md is built from numbers, not guesses:
 
 Run on the real chip (one process per chip):
   python scripts/moe_probe.py [--quick]
+  python scripts/moe_probe.py --chunks [--root PARENT_CHECKOUT]
+
+``--chunks`` (PR 44): the grouped GEMMs ALONE at the shapes the backlog cells'
+chunk programs call them at (lfm2: 2,048 rows x 4 picks over 64 whole experts
+of 2,048 x 1,536; command-a-plus: 2,048 rows x 8 picks of 128, 16 held, 4,096 x
+4,096; row block 128), each at an even load and at a skewed one, and at lfm2's
+DECODE shape (96 rows x 4 picks, row block 16) as the control: ms a call, the
+bytes the walk moves over that time against 819 GB/s, how often a touched
+expert's tables cross HBM -> VMEM (a walk by row blocks: once a block; by an
+expert's runs: once a run), and a sha256 of the live rows' output.
+``--root`` imports the package from another checkout (the parent's, unpacked
+by ``git archive``), same process shape; ``--shapes a,b`` keeps those rows;
+``--cut N`` reads the walk at another run cut (``_RUN_BLOCKS``, the probe's
+own patch: the package has no such option).
 
 One JSON line per stage. Timing = the bench differenced scan-chain
 (bench.py:_per_iter) — see bench.py's module docstring for why.
@@ -23,15 +37,20 @@ One JSON line per stage. Timing = the bench differenced scan-chain
 
 from __future__ import annotations
 
+import hashlib
 import json
+import math
 import os
 import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
-    __file__))))
+_HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if "--root" in sys.argv:        # the package of another checkout, this probe
+    sys.path.insert(0, os.path.abspath(sys.argv[sys.argv.index("--root") + 1]))
+sys.path.insert(1, _HERE)
 
 from bench import _per_iter, make_chain_timer  # noqa: E402
 
@@ -218,5 +237,107 @@ def main():
                     dequant_edge="expert")))
 
 
+# (name, rows, picks, routed experts, held, hidden, expert width, row block)
+CHUNK_SHAPES = (
+    ("lfm2-chunk", 2048, 4, 64, 64, 2048, 1536, 128),
+    ("command-a-plus-chunk", 2048, 8, 128, 16, 4096, 4096, 128),
+    ("lfm2-decode", 96, 4, 64, 64, 2048, 1536, 16),
+    # decode controls of the shapes lfm2's does not cover: Kimi's (H 7,168:
+    # two strips, runs of one) and Qwen3-Next's (32 held of 512, 2 MB tables)
+    ("kimi-decode", 32, 8, 384, 12, 7168, 2048, 128),
+    ("qwen3-next-decode", 128, 10, 512, 32, 2048, 512, 16),
+)
+with open(os.path.join(_HERE, "benchmark", "peaks.json")) as _f:
+    HBM_GBS = json.load(_f)["TPU v5 lite"]["hbm_bytes_per_s"] / 1e9
+
+
+def draw_picks(rows, picks, routed, held, skew, seed):
+    """Local expert ids [rows * picks] (-1: an expert another chip holds):
+    the ``picks`` largest of a score a (row, expert), iid noise of std 0.1
+    (even) plus, at ``skew``, an expert's own bias of std 0.05: the selection
+    bias of the two configurations' routers at half the scores' spread."""
+    rng = np.random.default_rng(seed)
+    score = rng.normal(0, 0.1, (rows, routed))
+    if skew:
+        score = score + rng.normal(0, 0.05, (1, routed))
+    ids = np.argsort(-score, axis=1)[:, :picks].reshape(-1)
+    return np.where(ids < held, ids, -1).astype(np.int32)
+
+
+def chunks_main():
+    from triton_dist_tpu.ops import group_gemm as gg
+
+    i1, i2 = (5, 15) if "--quick" in sys.argv else (5, 45)
+    dev = jax.devices()[0]
+    print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
+                      "package": os.path.dirname(gg.__file__)}), flush=True)
+    only = (sys.argv[sys.argv.index("--shapes") + 1].split(",")
+            if "--shapes" in sys.argv else None)
+    if "--cut" in sys.argv:     # a probe's reading of another run cut
+        gg._RUN_BLOCKS = int(sys.argv[sys.argv.index("--cut") + 1])
+    for name, rows, picks, routed, held, D, F, bm in CHUNK_SHAPES:
+        if only and name not in only:
+            continue
+        keys = jax.random.split(jax.random.key(0), 4)
+        wg, wu = ((jax.random.normal(k, (held, D, F)) * 0.05
+                   ).astype(jnp.bfloat16) for k in keys[:2])
+        wd = (jax.random.normal(keys[2], (held, F, D)) * 0.05
+              ).astype(jnp.bfloat16)
+        bn, dbn = math.gcd(128, F), math.gcd(512, D)
+        for skew in (False, True):
+            ids = draw_picks(rows, picks, routed, held, skew, 1)
+            gi, rv, be, nb = gg.align_tokens_by_expert(
+                jnp.asarray(ids), held, bm, with_used_count=True)
+            tokens = jax.random.normal(keys[3], (rows * picks, D)
+                                       ).astype(jnp.bfloat16)
+            x = jnp.where(rv[:, None], tokens[gi], 0).astype(jnp.bfloat16)
+            h = jnp.resize(x, (x.shape[0], F))
+            used = np.asarray(be)[:int(nb)]
+            touched = len(set(used.tolist()))
+            kernels = {
+                "gated": (D, F, 2, bn, x, (wg, wu), lambda xx, w: (
+                    gg.grouped_gemm_gated(
+                        xx, *w, be, block_m=bm, block_n=bn, n_blocks_used=nb,
+                        masked=False, block_k=gg.fit_block_k(
+                            D, bm, bn, 2, n_weights=2)))),
+                "down": (F, D, 1, dbn, h, (wd,), lambda hh, w: (
+                    gg.grouped_gemm(
+                        hh, *w, be, block_m=bm, block_n=dbn,
+                        n_blocks_used=nb, masked=False,
+                        block_k=gg.fit_block_k(F, bm, dbn, 2)))),
+            }
+            for kind, (K, N, n_w, tile, a, w, call) in kernels.items():
+                # tables fetched: once a row block, or once a run of an
+                # expert's blocks as THIS package's walk cuts them
+                fetches, walk = len(used), "row blocks"
+                if hasattr(gg, "fit_run_strips"):
+                    _, cut = gg.fit_run_strips(K, bm, tile, 2, 2, n_w)
+                    fetches, walk = sum(
+                        -(-int(n) // cut)
+                        for n in np.bincount(used, minlength=held)), "runs"
+
+                def step(c, w, call=call):
+                    y = call(c, w)
+                    return c + (jnp.sum(y[:bm].astype(jnp.float32)) * 1e-20
+                                ).astype(c.dtype)
+
+                # the live rows' bytes: parent and change print one value
+                live = np.asarray(jax.jit(call)(a, w))[:len(used) * bm]
+                sec = _per_iter(make_chain_timer(step, a, w), i1, i2, 4)
+                moved = 2 * (fetches * n_w * K * N + len(used) * bm * (K + N))
+                print(json.dumps({
+                    "shape": name, "load": "skewed" if skew else "even",
+                    "kernel": kind, "walk": walk, "ms": round(sec * 1e3, 4),
+                    "live_rows": int(np.sum(ids >= 0)),
+                    "blocks_used": len(used), "experts_touched": touched,
+                    "tables_an_expert": round(fetches / touched, 3),
+                    "moved_GB": round(moved / 1e9, 4),
+                    "GB_s": round(moved / 1e9 / sec, 1),
+                    "of_819_pct": round(100 * moved / 1e9 / sec / HBM_GBS,
+                                        1),
+                    "sha256": hashlib.sha256(live.tobytes()).hexdigest()[:16],
+                }), flush=True)
+
+
 if __name__ == "__main__":
-    main()
+    chunks_main() if "--chunks" in sys.argv else main()

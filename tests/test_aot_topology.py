@@ -378,6 +378,32 @@ def test_moe_reduce_rs_lowers_8dev(ctx1d):
                t, i, tw, w)
 
 
+@pytest.mark.parametrize("op", ["ag_moe_group_gemm", "moe_reduce_rs"])
+def test_fused_moe_lowers_with_a_runs_strips_8dev(ctx1d, op):
+    """The fused overlap kernels at a serving-size contraction (4,096 deep:
+    1 MB an x strip), where the run walk inside ``emit_grouped_gemm`` takes
+    all it may (``fit_run_strips``: 8 strips beside the two weight tiles):
+    Mosaic's scoped VMEM holds it next to what the kernels themselves keep."""
+    from triton_dist_tpu.ops import moe
+    from triton_dist_tpu.ops.group_gemm import fit_run_strips
+    E, H, T, bf = 8, 4096, N8 * 256, jnp.bfloat16
+    if op == "ag_moe_group_gemm":
+        bn = moe._default_bn(H, 512, bf)
+        compile_ok(lambda tt, ii, ww: moe.ag_moe_group_gemm(ctx1d, tt, ii, ww),
+                   sds(ctx1d, (T, H), P("x"), bf),
+                   sds(ctx1d, (T,), P("x"), jnp.int32),
+                   sds(ctx1d, (E, H, N8 * 512), P(None, None, "x"), bf))
+    else:
+        bn = moe._default_bn(H, 2048, bf)
+        compile_ok(lambda tt, ii, tw, ww: moe.moe_reduce_rs(ctx1d, tt, ii, tw,
+                                                            ww),
+                   sds(ctx1d, (T * 4, N8 * H), P(None, "x"), bf),
+                   sds(ctx1d, (T * 4,), P(), jnp.int32),
+                   sds(ctx1d, (T, 4), P()),
+                   sds(ctx1d, (E, N8 * H, 2048), P(None, "x", None), bf))
+    assert fit_run_strips(H, 128, bn, 2, 2) == (8, 4)
+
+
 # -- ring attention (training CP) --------------------------------------------
 
 def _qkv_sds(ctx, n, B=1, Hq=2, Hkv=2, s_loc=128, D=128):

@@ -408,3 +408,187 @@ def test_moe_ep_overlap_expert_major(ctx):
             ctx, l, v, router_w, wg, wu, wd, axis="x", block_m=16))(xs),
             np.float32)
         assert_allclose(o, outs[True], atol=6e-2, rtol=6e-2)
+
+
+# -- the bounded grouped GEMMs walk an expert's RUN of row blocks (PR 44) --------
+
+def _parent_walk(tokens, ws, be, *, block_m, block_n, n_blocks_used,
+                 masked=True, block_k=None, row_scale=None, gated=False):
+    """The walk the bounded grouped GEMMs made before they walked runs, kept
+    here as the oracle: ``emit_pipeline`` over (row block, column tile[, k]),
+    the weight tile of ``be[i]`` fetched at every step. Same products, same
+    epilogue (``_gemm_block`` / ``_gated_math``), same cast."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from triton_dist_tpu.ops.group_gemm import _gated_math
+    from triton_dist_tpu.utils import default_interpret
+
+    P, H = tokens.shape
+    N, n_w = ws[0].shape[2], len(ws)
+    nk = 1 if block_k is None else H // block_k
+    n_sc = 0 if row_scale is None else 1
+    dt = tokens.dtype
+
+    def finish(accs, sc_row):
+        if gated:
+            return _gated_math(*accs, sc_row, dt, jax.nn.silu)
+        return (accs[0] if sc_row is None
+                else accs[0] * sc_row[:, None]).astype(dt)
+
+    def kernel(be_ref, nb_ref, t_ref, *refs):
+        w_refs, sc_refs = refs[:n_w], refs[n_w:n_w + n_sc]
+        o_ref, accs = refs[n_w + n_sc], refs[n_w + n_sc + 1:]
+
+        def body(t_blk, *rest):
+            o_blk = rest[-1]
+            sc_row = rest[n_w][0] if n_sc else None
+            parts = [jnp.dot(t_blk[...], w[0],
+                             preferred_element_type=jnp.float32)
+                     for w in rest[:n_w]]
+            if nk == 1:
+                o_blk[...] = finish(parts, sc_row)
+                return
+            k = pl.program_id(2)
+
+            @pl.when(k == 0)
+            def _():
+                for a, p in zip(accs, parts):
+                    a[...] = p
+
+            @pl.when(k > 0)
+            def _():
+                for a, p in zip(accs, parts):
+                    a[...] = a[...] + p
+
+            @pl.when(k == nk - 1)
+            def _():
+                o_blk[...] = finish([a[...] for a in accs], sc_row)
+
+        pltpu.emit_pipeline(
+            body, grid=(jnp.minimum(nb_ref[0], P // block_m), N // block_n, nk),
+            in_specs=[pl.BlockSpec((block_m, H // nk), lambda i, j, k: (i, k))]
+            + [pl.BlockSpec((1, H // nk, block_n),
+                            lambda i, j, k: (be_ref[i], k, j))] * n_w
+            + [pl.BlockSpec((1, block_m), lambda i, j, k: (i, 0))] * n_sc,
+            out_specs=[pl.BlockSpec((block_m, block_n),
+                                    lambda i, j, k: (i, j))],
+        )(t_ref, *w_refs, *sc_refs, o_ref)
+
+    nb = jnp.asarray(n_blocks_used, jnp.int32).reshape(1)
+    out = pl.pallas_call(
+        kernel,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (1 + n_w + n_sc),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=([pltpu.VMEM((block_m, block_n), jnp.float32)] * n_w
+                        if nk > 1 else []),
+        out_shape=jax.ShapeDtypeStruct((P, N), dt),
+        interpret=default_interpret(),
+    )(be, nb, tokens, *ws,
+      *([row_scale.reshape(P // block_m, block_m)] if n_sc else []))
+    if not masked:
+        return out
+    live = jnp.arange(P, dtype=jnp.int32) // block_m < nb[0]
+    return jnp.where(live[:, None], out, jnp.zeros((), dt))
+
+
+def _parent_gemm(tokens, weights, be, **kw):
+    return _parent_walk(tokens, [weights], be, **kw)
+
+
+def _parent_gated(tokens, w_gate, w_up, be, **kw):
+    return _parent_walk(tokens, [w_gate, w_up], be, gated=True, **kw)
+
+
+# rows an expert (a 16-row block): runs of 1, 2, 3 and 5 blocks (5 crosses the
+# cut at 4), an expert with no rows between two with many
+_RUN_LOADS = {
+    "runs-of-1": (16, 16, 16, 16, 16, 16),
+    "runs-of-2": (32, 32, 32),
+    "runs-of-3": (48, 41),
+    "runs-of-5": (80, 16),
+    "mixed": (16, 32, 48, 80, 5),
+    "none-between-many": (40, 0, 70, 3),
+}
+# (load, blocks the bound leaves off, masked, row_scale, block_k, x strips
+# the VMEM budget leaves: None = the 8 these small shapes get)
+_RUN_CASES = {
+    **{name: (name, 0, True, False, None, None) for name in _RUN_LOADS},
+    "bound-below-zeroed": ("none-between-many", 2, True, False, None, None),
+    "bound-below-untouched": ("mixed", 3, False, False, None, None),
+    "row-scale": ("mixed", 0, True, True, None, None),
+    "k-split": ("mixed", 0, True, False, 64, None),
+    "k-split-row-scale": ("none-between-many", 1, True, True, 64, None),
+    # runs cut at 2, and a next run's second strip fetched behind this one's
+    "three-strips": ("mixed", 0, True, True, None, 3),
+    "two-strips": ("none-between-many", 0, True, False, None, 2),
+}
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint16)
+
+
+@pytest.mark.parametrize("kernel, case", [
+    (k, c) for c in _RUN_CASES for k in ("grouped_gemm", "grouped_gemm_gated")
+] + [("held_experts", "skewed-128-rows")])
+def test_run_walk_is_the_parents_walk_bit_for_bit(kernel, case, monkeypatch):
+    """A weight tile stays in VMEM over the consecutive row blocks of one
+    expert; every output block is the product the walk by row blocks made."""
+    from triton_dist_tpu.ops import group_gemm as gg
+    if kernel == "held_experts":
+        from triton_dist_tpu.models.expert_share import held_experts
+        R, k, D, Fe, held = 128, 4, 128, 128, 4
+        keys = jax.random.split(jax.random.key(7), 5)
+        # 128 rows an expert on average, skewed: 3 + 1 + 2 + 1 blocks of 128
+        lid = jnp.asarray(np.random.default_rng(0).permutation(
+            np.repeat(np.arange(held), (300, 10, 150, 52))).reshape(R, k),
+            jnp.int32)
+        h = jax.random.normal(keys[0], (R, D)).astype(jnp.bfloat16)
+        w = jax.random.uniform(keys[1], (R, k), jnp.float32)
+        tables = tuple((jax.random.normal(kk, (2, held) + s) * 0.1
+                        ).astype(jnp.bfloat16) for kk, s in zip(
+            keys[2:], ((D, Fe), (D, Fe), (Fe, D))))
+        run = jax.jit(lambda: held_experts(h, lid, w, tables, held, held))
+        got = np.asarray(run())
+        monkeypatch.setattr(gg, "grouped_gemm", _parent_gemm)
+        monkeypatch.setattr(gg, "grouped_gemm_gated", _parent_gated)
+        want = np.asarray(jax.jit(lambda: held_experts(
+            h, lid, w, tables, held, held))())
+        assert np.isfinite(got).all()
+        assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+        return
+    load, off, masked, scaled, block_k, strips = _RUN_CASES[case]
+    counts = _RUN_LOADS[load]
+    E, bm, H, N = len(counts), 16, 128, 256
+    if strips:
+        n_w = 1 if kernel == "grouped_gemm" else 2
+        monkeypatch.setattr(gg, "_VMEM_TILE_BUDGET",
+                            2 * (2 * n_w * H * 128 + strips * bm * H))
+        assert gg.fit_run_strips(H, bm, 128, 2, 2, n_w)[0] == strips
+    ids = np.random.default_rng(1).permutation(
+        np.repeat(np.arange(E), counts)).astype(np.int32)
+    keys = jax.random.split(jax.random.key(3), 4)
+    tokens = jax.random.normal(keys[0], (len(ids), H)).astype(jnp.bfloat16)
+    ws = [(jax.random.normal(kk, (E, H, N)) * 0.1).astype(jnp.bfloat16)
+          for kk in keys[1:3]]
+    gi, rv, be, nb = align_tokens_by_expert(jnp.asarray(ids), E, bm,
+                                            with_used_count=True)
+    x = jnp.where(rv[:, None], tokens[gi], 0).astype(jnp.bfloat16)
+    kw = dict(block_m=bm, block_n=128, n_blocks_used=nb - off, masked=masked,
+              block_k=block_k,
+              row_scale=jax.random.uniform(keys[3], (x.shape[0],),
+                                           jnp.float32, 0.5, 1.5)
+              if scaled else None)
+    new, old, w = ((grouped_gemm, _parent_gemm, ws[:1])
+                   if kernel == "grouped_gemm"
+                   else (grouped_gemm_gated, _parent_gated, ws))
+    got = jax.jit(lambda: new(x, *w, be, **kw))()
+    want = jax.jit(lambda: old(x, *w, be, **kw))()
+    live = (int(nb) - off) * bm
+    assert np.isfinite(np.asarray(got[:live], np.float32)).all()
+    assert np.array_equal(_bits(got[:live]), _bits(want[:live]))
+    # past the bound: zeros where masked, else whatever the buffer held (the
+    # interpreter fills it with NaN): the walk wrote nothing there
+    tail = np.asarray(got[live:], np.float32)
+    assert (not tail.any()) if masked else np.isnan(tail).all()

@@ -307,6 +307,7 @@ class RankTracer:
         self.events: List[Event] = []
         self.seq = 0
         self.call_index = 0
+        self.scope_index = 0
         self.rdma_index = 0
         self.call_stack: List[_CallCtx] = []
         self.barrier_sems: Dict[str, FakeSem] = {}
@@ -509,6 +510,21 @@ def _is_sem_scratch(s) -> bool:
     return dt is not None and "sem" in str(dt)
 
 
+def _scratch_objs(key: str, scratch) -> list:
+    """Fake refs and semaphores for a kernel's ``scratch_shapes`` (or a
+    ``run_scoped`` body's allocations)."""
+    objs = []
+    for j, s in enumerate(scratch):
+        shp = tuple(getattr(s, "shape", ()) or ())
+        if _is_sem_scratch(s):
+            objs.append(FakeSem(f"{key}/sem{j}", shp, _sem_kind(s)))
+        else:
+            objs.append(FakeRef(BufferInfo(
+                f"{key}/scratch{j}",
+                np.zeros(shp, getattr(s, "dtype", np.float32)))))
+    return objs
+
+
 def _sem_kind(s) -> str:
     from jax.experimental.pallas import tpu as pltpu
     if isinstance(s, pltpu.SemaphoreType):
@@ -611,18 +627,7 @@ def _fake_pallas_call(state: CaptureState):
                             f"{key}/out{j}",
                             np.zeros(leaf.shape, leaf.dtype)))
 
-                scratch_objs = []
-                for j, s in enumerate(scratch):
-                    if _is_sem_scratch(s):
-                        shp = tuple(getattr(s, "shape", ()) or ())
-                        scratch_objs.append(
-                            FakeSem(f"{key}/sem{j}", shp, _sem_kind(s)))
-                    else:
-                        shp = tuple(getattr(s, "shape", ()) or ())
-                        dt = getattr(s, "dtype", np.float32)
-                        scratch_objs.append(
-                            FakeRef(BufferInfo(f"{key}/scratch{j}",
-                                               np.zeros(shp, dt))))
+                scratch_objs = _scratch_objs(key, scratch)
 
                 def invoke(grid_idx):
                     call.grid_pos = tuple(int(i) for i in grid_idx)
@@ -776,6 +781,16 @@ def patched(state: CaptureState):
           lambda axis: int(tracer().call_stack[-1].grid_dims[axis]))
     if hasattr(pl_mod, "semaphore_read"):
         patch(pl_mod, "semaphore_read", lambda sem: tracer().signal_read(sem))
+
+    def run_scoped(f, *types, **_kw):
+        # a kernel's own scratch (``ops/group_gemm.py::_emit_run_walk``):
+        # fresh buffers and semaphores for the body's life
+        t = tracer()
+        t.scope_index += 1
+        site = t.call_stack[-1].key if t.call_stack else "<host>"
+        return f(*_scratch_objs(f"{site}/scope{t.scope_index}", types))
+
+    patch(pl_mod, "run_scoped", run_scoped)
 
     # pallas tpu
     patch(pltpu_mod, "make_async_copy",

@@ -17,6 +17,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -204,6 +205,162 @@ def _gated_block(t_blk, wg_blk, wu_blk, sc_row, out_dtype, activation):
     return _gated_math(g, u, sc_row, out_dtype, activation)
 
 
+# An expert's run: consecutive row blocks of ONE expert (the aligned layout
+# puts them side by side). Cut at this many blocks: past it the run goes on as
+# a new run, whose tiles are fetched again.
+_RUN_BLOCKS = 4
+
+
+def fit_run_strips(K: int, block_m: int, block_n: int, x_itemsize: int,
+                   w_itemsize: int, n_weights: int = 1) -> tuple[int, int]:
+    """(x strips ``_emit_run_walk`` holds in VMEM at once at this shape, the
+    blocks it cuts a run at). The strips are what ``_VMEM_TILE_BUDGET`` leaves
+    beside the ``n_weights`` double-buffered full-K weight tiles, from 2 (the
+    old walk's two strips: wherever ``fit_block_k`` says full K fits, these
+    do) to ``2 * _RUN_BLOCKS`` (a whole run resident AND the next one's strips
+    under way); a run leaves one strip free for the next run's first. Like
+    ``fit_block_k`` this reckons the OPERAND tiles against the budget: the two
+    output tiles and the scale rows (a few hundred KB) ride in the 4 MB the
+    budget leaves of the scoped stack, and a caller's kernel holds nothing
+    else in VMEM (the fused overlap kernels' workspaces are HBM)."""
+    left = _VMEM_TILE_BUDGET - 2 * n_weights * K * block_n * w_itemsize
+    strips = max(2, min(2 * _RUN_BLOCKS,
+                        left // (block_m * K * x_itemsize)))
+    return strips, min(_RUN_BLOCKS, strips - 1)
+
+
+def _emit_run_walk(t_ref, w_refs, sc_ref, o_ref, be_ref, base_blk, m_steps,
+                   block_m: int, block_n: int, tile):
+    """THE full-K walk of the bounded grouped GEMMs, over HBM refs: for the
+    row blocks ``i < m_steps``, ``o[i] = tile(x strip i, the (1, K, block_n)
+    tiles of expert be_ref[base_blk + i] in each of w_refs, scale row i)``,
+    column tile by column tile.
+
+    The unit is an expert's RUN: up to ``_RUN_BLOCKS`` consecutive row blocks
+    that name one expert. A run's x strips are resident in VMEM, each of the
+    expert's weight tiles crosses HBM -> VMEM ONCE and meets every strip of
+    the run before the next tile replaces it, so a table is read once an
+    expert (and cut), not once a row block; where every expert has one block
+    this is the walk ``emit_pipeline`` made over ``(row block, column tile)``,
+    step for step. Each output block is the same ``jnp.dot`` of the same
+    tiles as there: the result is that walk's bit for bit.
+
+    One loop over the runs, all copies the kernel's own: the weight tiles of
+    step ``s`` (a run's column tile) ride slot ``s % 2`` and step ``s + 1``'s
+    are started before ``s`` computes, across runs too; strip ``i`` rides slot
+    ``i % strips`` of a ring, the next run's strips are started as this run's
+    are waited for (those whose slots this run holds: behind its last tile);
+    an output tile leaves through a ring of two."""
+    P, K = t_ref.shape
+    N = w_refs[0].shape[2]
+    n_w, nj, n_blk = len(w_refs), N // block_n, P // block_m
+    strips, cut = fit_run_strips(K, block_m, block_n,
+                                 jnp.dtype(t_ref.dtype).itemsize,
+                                 jnp.dtype(w_refs[0].dtype).itemsize, n_w)
+    m_steps = jnp.minimum(jnp.asarray(m_steps, jnp.int32), n_blk)
+
+    def expert(i):
+        return be_ref[base_blk + jnp.minimum(i, n_blk - 1)]
+
+    def run_len(i):
+        """Blocks of the run that starts at block ``i`` (0 past the walk)."""
+        same, n = i < m_steps, jnp.int32(0)
+        for d in range(cut):
+            same = same & (i + d < m_steps) & (expert(i + d) == expert(i))
+            n = n + same.astype(jnp.int32)
+        return n
+
+    def walk(x_buf, w_buf, o_buf, sc_buf, x_sem, w_sem, o_sem, sc_sem):
+        def strips_of(first, lo, hi, do):
+            """``do`` the copies of strips ``lo .. hi - 1`` of the run that
+            starts at block ``first``."""
+            def one(l, _):
+                blk = first + l
+                slot = blk % strips
+                do(pltpu.make_async_copy(
+                    t_ref.at[pl.ds(pl.multiple_of(blk * block_m, block_m),
+                                   block_m)],
+                    x_buf.at[slot], x_sem.at[slot]))
+                if sc_ref is not None:
+                    do(pltpu.make_async_copy(sc_ref.at[pl.ds(blk, 1)],
+                                             sc_buf.at[slot],
+                                             sc_sem.at[slot]))
+
+            lax.fori_loop(lo, hi, one, None)
+
+        def tiles_of(e, j, slot, do):
+            at = pl.ds(pl.multiple_of(j * block_n, block_n), block_n)
+            for k, w_ref in enumerate(w_refs):
+                do(pltpu.make_async_copy(w_ref.at[pl.ds(e, 1), :, at],
+                                         w_buf.at[slot, k],
+                                         w_sem.at[slot, k]))
+
+        def out_of(slot, blk, j):
+            return pltpu.make_async_copy(
+                o_buf.at[slot],
+                o_ref.at[pl.ds(pl.multiple_of(blk * block_m, block_m),
+                               block_m),
+                         pl.ds(pl.multiple_of(j * block_n, block_n),
+                               block_n)],
+                o_sem.at[slot])
+
+        def run(carry):
+            i, n, s, c = carry
+            e, nxt = expert(i), i + n
+            n2, e2 = run_len(nxt), expert(nxt)
+            strips_of(i, 0, n, lambda copy: copy.wait())
+            early = jnp.minimum(n2, strips - n)
+            strips_of(nxt, 0, early, lambda copy: copy.start())
+
+            def column(j, carry):
+                s, c = carry
+                slot, last = s % 2, j == nj - 1
+                pl.when(jnp.logical_not(last) | (n2 > 0))(
+                    lambda: tiles_of(jnp.where(last, e2, e),
+                                     jnp.where(last, 0, j + 1), 1 - slot,
+                                     lambda copy: copy.start()))
+                tiles_of(e, j, slot, lambda copy: copy.wait())
+
+                def block(l, c):
+                    xs, os = (i + l) % strips, c % 2
+                    pl.when(c >= 2)(lambda: out_of(os, 0, 0).wait())
+                    o_buf[os] = tile(
+                        x_buf.at[xs], [w_buf.at[slot, k] for k in range(n_w)],
+                        None if sc_ref is None else sc_buf[xs][0])
+                    out_of(os, i + l, j).start()
+                    return c + 1
+
+                return s + 1, lax.fori_loop(0, n, block, c)
+
+            s, c = lax.fori_loop(0, nj, column, (s, c))
+            strips_of(nxt, early, n2, lambda copy: copy.start())
+            return nxt, n2, s, c
+
+        n0 = run_len(jnp.int32(0))
+
+        @pl.when(n0 > 0)
+        def _():
+            strips_of(0, 0, n0, lambda copy: copy.start())
+            tiles_of(expert(0), 0, 0, lambda copy: copy.start())
+
+        c = lax.while_loop(lambda carry: carry[1] > 0, run,
+                           (jnp.int32(0), n0, jnp.int32(0), jnp.int32(0)))[3]
+        for back in (1, 2):
+            pl.when(c >= back)(
+                lambda back=back: out_of((c - back) % 2, 0, 0).wait())
+
+    pl.run_scoped(
+        walk,
+        pltpu.VMEM((strips, block_m, K), t_ref.dtype),
+        pltpu.VMEM((2, n_w, 1, K, block_n), w_refs[0].dtype),
+        pltpu.VMEM((2, block_m, block_n), o_ref.dtype),
+        pltpu.VMEM((strips, 1, block_m), jnp.float32),
+        pltpu.SemaphoreType.DMA((strips,)),
+        pltpu.SemaphoreType.DMA((2, n_w)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((strips,)))
+
+
 def emit_grouped_gemm(t_ref, w_ref, o_ref, be_ref, base_blk,
                       block_m: int, block_n: int, out_dtype=None,
                       n_blocks_used=None, sc_ref=None,
@@ -213,10 +370,10 @@ def emit_grouped_gemm(t_ref, w_ref, o_ref, be_ref, base_blk,
 
     ``be_ref`` is an SMEM int32 ref of per-block expert ids (flattened over
     segments; ``base_blk`` offsets into it, may be a traced value). The
-    dynamic index_map streams each block's expert weight tile HBM→VMEM
-    double-buffered — the in-kernel form of ``grouped_gemm`` that the fused
-    MoE overlap kernels call per *arrived segment*, the TPU analog of the
-    reference's per-token-block ``dl.wait`` + grouped ``tl.dot``
+    walk (``_emit_run_walk`` at full K) streams each run's expert weight
+    tiles HBM→VMEM double-buffered — the in-kernel form of ``grouped_gemm``
+    that the fused MoE overlap kernels call per *arrived segment*, the TPU
+    analog of the reference's per-token-block ``dl.wait`` + grouped ``tl.dot``
     (kernel_consumer_m_parallel_scatter_group_gemm,
     allgather_group_gemm.py:229-316).
 
@@ -248,67 +405,53 @@ def emit_grouped_gemm(t_ref, w_ref, o_ref, be_ref, base_blk,
     out_dtype = out_dtype or o_ref.dtype
     m_steps = (P // block_m if n_blocks_used is None
                else jnp.minimum(n_blocks_used, P // block_m))
-    sc_specs3 = ([pl.BlockSpec((1, block_m), lambda i, j, k: (i, 0))]
-                 if sc_ref is not None else [])
-    sc_args = (sc_ref,) if sc_ref is not None else ()
-
-    if block_k is not None and block_k < H:
-        assert H % block_k == 0, (H, block_k)
-        assert acc_ref is not None, "block_k needs an f32 VMEM acc_ref"
-        nk = H // block_k
-
-        def body_acc(t_blk, w_blk, *rest):
-            o_blk = rest[-1]
-            sc_row = rest[0][0] if sc_ref is not None else None
-            k = pl.program_id(2)
-            part = jnp.dot(t_blk[...], w_blk[0],
-                           preferred_element_type=jnp.float32)
-
-            @pl.when(k == 0)
-            def _():
-                acc_ref[...] = part
-
-            @pl.when(k > 0)
-            def _():
-                acc_ref[...] = acc_ref[...] + part
-
-            @pl.when(k == nk - 1)
-            def _():
-                acc = acc_ref[...]
-                if sc_row is not None:
-                    acc = acc * sc_row[:, None]
-                o_blk[...] = acc.astype(out_dtype)
-
-        pltpu.emit_pipeline(
-            body_acc,
-            grid=(m_steps, N // block_n, nk),
-            in_specs=[
-                pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
-                pl.BlockSpec((1, block_k, block_n),
-                             lambda i, j, k: (be_ref[base_blk + i], k, j)),
-            ] + sc_specs3,
-            out_specs=[pl.BlockSpec((block_m, block_n),
-                                    lambda i, j, k: (i, j))],
-        )(t_ref, w_ref, *sc_args, o_ref)
+    if block_k is None or block_k >= H:
+        _emit_run_walk(
+            t_ref, [w_ref], sc_ref, o_ref, be_ref, base_blk, m_steps,
+            block_m, block_n, lambda t_blk, w_blks, sc_row: _gemm_block(
+                t_blk, w_blks[0], sc_row, out_dtype))
         return
 
-    def body(t_blk, w_blk, *rest):
+    # the K-split form keeps the (row block, column tile, k) pipeline: a
+    # shape that needs it has no room for a run's strips (``fit_block_k``)
+    assert H % block_k == 0, (H, block_k)
+    assert acc_ref is not None, "block_k needs an f32 VMEM acc_ref"
+    nk = H // block_k
+
+    def body_acc(t_blk, w_blk, *rest):
         o_blk = rest[-1]
         sc_row = rest[0][0] if sc_ref is not None else None
-        o_blk[...] = _gemm_block(t_blk, w_blk, sc_row, out_dtype)
+        k = pl.program_id(2)
+        part = jnp.dot(t_blk[...], w_blk[0],
+                       preferred_element_type=jnp.float32)
 
-    sc_specs = ([pl.BlockSpec((1, block_m), lambda i, j: (i, 0))]
-                if sc_ref is not None else [])
+        @pl.when(k == 0)
+        def _():
+            acc_ref[...] = part
+
+        @pl.when(k > 0)
+        def _():
+            acc_ref[...] = acc_ref[...] + part
+
+        @pl.when(k == nk - 1)
+        def _():
+            acc = acc_ref[...]
+            if sc_row is not None:
+                acc = acc * sc_row[:, None]
+            o_blk[...] = acc.astype(out_dtype)
+
     pltpu.emit_pipeline(
-        body,
-        grid=(m_steps, N // block_n),
+        body_acc,
+        grid=(m_steps, N // block_n, nk),
         in_specs=[
-            pl.BlockSpec((block_m, H), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, H, block_n),
-                         lambda i, j: (be_ref[base_blk + i], 0, j)),
-        ] + sc_specs,
-        out_specs=[pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))],
-    )(t_ref, w_ref, *sc_args, o_ref)
+            pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
+            pl.BlockSpec((1, block_k, block_n),
+                         lambda i, j, k: (be_ref[base_blk + i], k, j)),
+        ] + ([pl.BlockSpec((1, block_m), lambda i, j, k: (i, 0))]
+             if sc_ref is not None else []),
+        out_specs=[pl.BlockSpec((block_m, block_n),
+                                lambda i, j, k: (i, j))],
+    )(t_ref, w_ref, *((sc_ref,) if sc_ref is not None else ()), o_ref)
 
 
 def grouped_gemm(tokens: jax.Array, weights: jax.Array,
@@ -328,7 +471,13 @@ def grouped_gemm(tokens: jax.Array, weights: jax.Array,
 
     ``n_blocks_used`` (traced int32 scalar from ``used_block_count``)
     truncates the row-block walk at runtime, skipping the up-to-``E`` blocks
-    of pure per-expert padding in the aligned layout — rows past the bound
+    of pure per-expert padding in the aligned layout. That bounded walk goes
+    by an expert's RUNS of row blocks at full K (``_emit_run_walk``: a weight
+    tile in VMEM meets every block of the run, so a table crosses HBM once an
+    expert whatever its rows) and keeps the (row block, column tile, k)
+    pipeline where ``block_k`` splits K: the one rule, here and in
+    ``grouped_gemm_gated`` (whose ``packed`` and convert-once forms keep the
+    pipeline too). Rows past the bound
     are returned ZEROED (callers mask by row validity anyway; zero keeps the
     op total-function for reuse in autodiff contexts). ``masked=False``
     skips that zeroing pass (a full read+write of the output) and leaves
@@ -755,6 +904,13 @@ def grouped_gemm_gated(tokens: jax.Array, w_gate: jax.Array,
                 out_specs=[pl.BlockSpec((block_m, block_n),
                                         lambda i, j, k: (i, j))],
             )(t_ref, *(() if deep else tuple(w_refs)), *sc_args, o_ref)
+            return
+
+        if not (packed or convert_once):
+            _emit_run_walk(
+                t_ref, w_refs, sc_ref, o_ref, be_ref, 0, m_steps, block_m,
+                block_n, lambda t_blk, w_blks, sc_row: _gated_block(
+                    t_blk, *w_blks, sc_row, out_dtype, activation))
             return
 
         n_wp = 0 if deep else n_w
