@@ -227,6 +227,34 @@ def test_device_row_range_and_layout_validation():
         KVPagePool(9, 8, layout="diagonal")
 
 
+@pytest.mark.parametrize("layout,sp_ranks", [
+    ("blocked", 1), ("blocked", 4), ("interleaved", 1), ("interleaved", 2),
+    ("interleaved", 4)])
+def test_device_rows_is_device_row_of_every_page(layout, sp_ranks):
+    """The one array operation a table row and a gather's index go through
+    is the scalar map, id for id, in any order and with repeats (a row's
+    fill is the scratch page over and over)."""
+    pool = KVPagePool(9, 8, sp_ranks=sp_ranks, layout=layout)
+    ids = list(range(pool.device_pages)) + [0, 0, 5, 3, 0]
+    rows = pool.device_rows(ids)
+    assert rows.dtype == np.int32 and rows.shape == (len(ids),)
+    assert rows.tolist() == [pool.device_row(p) for p in ids]
+    assert pool.device_rows(np.asarray(ids[::-1], np.int32)).tolist() \
+        == [pool.device_row(p) for p in ids[::-1]]
+    assert pool.device_rows([]).shape == (0,)
+
+
+@pytest.mark.parametrize("layout", ["blocked", "interleaved"])
+@pytest.mark.parametrize("bad", [-1, 12, 2 ** 31])
+def test_device_rows_refuses_what_device_row_refuses(layout, bad):
+    pool = KVPagePool(9, 8, sp_ranks=4, layout=layout)
+    with pytest.raises(PageLedgerError) as scalar:
+        pool.device_row(bad)
+    with pytest.raises(PageLedgerError) as array:
+        pool.device_rows([1, 2, bad, 3])
+    assert str(array.value) == str(scalar.value)
+
+
 # -------------------------------------------------- workload long class
 def test_workload_long_population():
     spec = parse_workload("n=40,seed=3,chat=0.5,long=0.3,plen=3:10,"

@@ -342,6 +342,15 @@ class ServingEngine:
         self._hist_len = np.zeros(num_slots, np.int32)
         self._sync_mirrors()
         self._dirty = False                 # mirrors diverged from device
+        # the table mirror ALONE diverged (``_grow`` wrote a row the ledger
+        # grew): token and position still equal the device's carry
+        self._bt_dirty = False
+        # (rid, the ledger's stamp of its pages) as ``_grow`` last mirrored
+        # them into the slot's table row, or None where another hand wrote
+        # the row since (seating, parking; a composing engine that seats a
+        # request itself seats a rid no record names): ``_grow`` looks at a
+        # row again only when this no longer names the ledger's state
+        self._bt_seen: list[tuple | None] = [None] * num_slots
         # the NEXT step's chunk, launched behind this step's decode dispatch
         # and not committed yet (``_launch_ahead``), or None
         self._ahead: _Ahead | None = None
@@ -418,19 +427,27 @@ class ServingEngine:
         self._step = artifact.program(key, "decode")
         self._chunk_step = artifact.program(key, "chunk")
 
-    def _sync_mirrors(self) -> None:
-        """Upload the host slot mirrors to the device copies. The sharded
-        engine overrides this to COMMIT the uploads to the mesh (matching
-        the jit out_shardings pin) — pjit's executable cache keys on input
-        sharding/committed-ness, so a flip between an uncommitted first
-        upload and the committed fed-back outputs would cost one spurious
-        recompile per program."""
-        self._token_dev = jnp.asarray(self._token)
-        self._pos_dev = jnp.asarray(self._pos)
-        self._bt_dev = jnp.asarray(self._bt)
+    def _upload(self, mirror: np.ndarray):
+        """One host mirror as the programs take it. The sharded engine
+        COMMITS the upload to the mesh (matching the jit out_shardings pin):
+        pjit's executable cache keys on input sharding/committed-ness, so a
+        flip between an uncommitted first upload and the committed fed-back
+        outputs would cost one spurious recompile per program."""
+        return jnp.asarray(mirror)
+
+    def _sync_mirrors(self, table_only: bool = False) -> None:
+        """Upload the host slot mirrors to the device copies: all of them,
+        or the table alone where nothing but page growth touched a mirror
+        since the last upload (token and position then equal the device's
+        carry by construction, see ``_reconcile``)."""
+        self._bt_dev = self._upload(self._bt)
+        if table_only:
+            return
+        self._token_dev = self._upload(self._token)
+        self._pos_dev = self._upload(self._pos)
         if self.spec_k:
-            self._hist_dev = jnp.asarray(self._hist)
-            self._hlen_dev = jnp.asarray(self._hist_len)
+            self._hist_dev = self._upload(self._hist)
+            self._hlen_dev = self._upload(self._hist_len)
 
     # -- ledger id → device row (ISSUE 19) --------------------------------
     # The ledger allocates in ID space; the device arrays are indexed in
@@ -438,19 +455,16 @@ class ServingEngine:
     # blocked layout, the round-robin bijection under the long-context
     # interleaved layout). EVERY id that crosses the host→device boundary
     # — block-table uploads and host-side pool gathers/scatters — goes
-    # through these two helpers; journal/digest/snapshot payloads stay in
-    # id space, so the control-plane trace is layout-independent.
-
-    def _device_rows(self, ids) -> np.ndarray:
-        return np.asarray([self.alloc.device_row(int(p)) for p in ids],
-                          np.int32)
+    # through ``alloc.device_rows`` (one array operation) or ``device_row``;
+    # journal/digest/snapshot payloads stay in id space, so the
+    # control-plane trace is layout-independent.
 
     def _device_bt_row(self, rid, slot: int) -> np.ndarray:
         """The table row the programs get for ``rid`` in ``slot``: its
         ledger pages, then (a family with per-slot rings) the first page of
         the slot's ring, ``1 + slot * ring`` (page 0 is scratch there too),
         or (a family with per-slot state) the slot's state row, ``1 + slot``."""
-        row = self._device_rows(
+        row = self.alloc.device_rows(
             self.alloc.block_table_row(rid, self.pages_per_seq))
         if self._slot_owned:
             row = np.append(row, np.int32(1 + slot * (self._ring or 1)))
@@ -615,14 +629,14 @@ class ServingEngine:
         """The bytes of ``page_ids`` (ledger ids): the pool's pytree with
         the page dim gathered, [layer, len(page_ids), ...] a leaf."""
         self._pages_alone("page export")
-        rows = self._device_rows(page_ids)
+        rows = self.alloc.device_rows(page_ids)
         return jax.tree.map(lambda a: a[:, rows], self.pool)
 
     def _import_pages(self, page_ids, payload) -> None:
         """Land ``payload`` (as ``_export_pages`` gives it) on
         ``page_ids``."""
         self._pages_alone("page import")
-        rows = self._device_rows(page_ids)
+        rows = self.alloc.device_rows(page_ids)
         self.pool = jax.tree.map(lambda a, b: a.at[:, rows].set(b),
                                  self.pool, payload)
 
@@ -881,6 +895,7 @@ class ServingEngine:
         self._token[slot] = tok0
         self._pos[slot] = sp
         self._bt[slot] = row
+        self._bt_seen[slot] = None
         self._seed_hist(slot, req)
         self._dirty = True
         if req.done:            # max_new_tokens == 1 or tok0 == eos_id
@@ -963,6 +978,7 @@ class ServingEngine:
         self._token[slot] = 0
         self._pos[slot] = 0
         self._bt[slot] = 0
+        self._bt_seen[slot] = None
         self._hist[slot] = 0
         self._hist_len[slot] = 0
         self._dirty = True
@@ -1139,10 +1155,10 @@ class ServingEngine:
                 return True
             return False
 
-        if self._dirty:
+        if self._dirty or self._bt_dirty:
             with m.phase("sync", step=n):
-                self._sync_mirrors()
-            self._dirty = False
+                self._sync_mirrors(table_only=not self._dirty)
+            self._dirty = self._bt_dirty = False
             m.inc("host_syncs")
 
         with m.phase("dispatch", step=n) as dispatch:
@@ -1171,15 +1187,12 @@ class ServingEngine:
             m.observe("step_device_s", dev_dt)
             m.observe("step_host_s", host_dt)
             per_tok = (dev_dt + host_dt) / max(n_tokens, 1)
-            for _ in range(n_tokens):
-                m.observe("tok_latency_s", per_tok)
+            m.observe("tok_latency_s", per_tok, n_tokens)
             # per-class ITL (ISSUE 14): the same per-token estimate, labeled
             # by the emitting request's class — the isolation panel's number
             for slot, req in active:
-                label = class_label(req)
-                if label is not None:
-                    for _ in range(emitted_by_slot.get(slot, 0)):
-                        m.observe_class("itl_s", label, per_tok)
+                m.observe_class("itl_s", class_label(req), per_tok,
+                                emitted_by_slot.get(slot, 0))
             self._post_step()
         return True
 
@@ -1194,39 +1207,59 @@ class ServingEngine:
         auto-clamp that keeps a slot inside its pre-ensured pages
         mid-scan."""
         limits = np.zeros(self.num_slots, np.int32)
+        alloc, slots, page = self.alloc, self.sched.slots, self.page_size
+        pos_of = self._pos.tolist()
+        active, rebuilt, preempted = [], 0, False
         for slot in range(self.num_slots):
-            req = self.sched.slots[slot]
+            req = slots[slot]
             if req is None or req.state is not RequestState.ACTIVE:
                 continue            # mid-prefill slots do not decode
-            pos = int(self._pos[slot])
-            while not self._ensure_pages(req.rid, pos + 1):
-                victim = self.sched.pick_victim(exclude_slot=slot)
-                if victim is None:
-                    raise RuntimeError(
-                        f"KV pool too small: request {req.rid} needs a page "
-                        "with no preemptible peer left")
-                self._preempt(victim)
+            rid, pos = req.rid, pos_of[slot]
+            # tokens the pages it owns still have room for: a page is taken
+            # (and the pool asked) only where the dispatch outgrows them
+            room = alloc.n_pages_of(rid) * page - pos
+            if room < 1:
+                while not self._ensure_pages(rid, pos + 1):
+                    victim = self.sched.pick_victim(exclude_slot=slot)
+                    if victim is None:
+                        raise RuntimeError(
+                            f"KV pool too small: request {rid} needs a page "
+                            "with no preemptible peer left")
+                    self._preempt(victim)
+                    preempted = True
+                room = alloc.n_pages_of(rid) * page - pos
             want = min(self.decode_horizon, req.remaining)
-            lim = 1
-            while lim < want and self._ensure_pages(req.rid, pos + lim + 1):
+            lim = max(1, min(want, room))
+            while lim < want and self._ensure_pages(rid, pos + lim + 1):
                 lim += 1
             limits[slot] = lim
-            # refresh AFTER growth — the kernel writes this scan's (k, v)
-            # into pages ensure() may just have allocated
-            row = self._device_bt_row(req.rid, slot)
-            if not np.array_equal(row, self._bt[slot]):
-                self._bt[slot] = row
-                self._dirty = True
-                self._jlog("grow", rid=req.rid,
-                           pages=len(self.alloc.pages_of(req.rid)))
-        # a slot preempted while a LATER slot grew already has its limit
-        # computed — zero it (its mirrors are parked; writes go to scratch)
-        for slot in range(self.num_slots):
-            r = self.sched.slots[slot]
-            if r is None or r.state is not RequestState.ACTIVE:
-                limits[slot] = 0
-        return limits, [(s, r) for s, r in self.sched.active
-                        if r.state is RequestState.ACTIVE]
+            active.append((slot, req))
+            # the row AFTER growth — the kernel writes this scan's (k, v)
+            # into pages ensure() may just have allocated — and only where
+            # the ledger says the sequence's pages moved since the row was
+            # mirrored (the ring / state column is the slot's and stands)
+            seen = (rid, alloc.stamp(rid))
+            if self._bt_seen[slot] != seen:
+                self._bt_seen[slot] = seen
+                rebuilt += 1
+                row = self._device_bt_row(rid, slot)
+                if not np.array_equal(row, self._bt[slot]):
+                    self._bt[slot] = row
+                    self._bt_dirty = True
+                    self._jlog("grow", rid=rid, pages=alloc.n_pages_of(rid))
+        self.metrics.inc("table_rows_checked", len(active))
+        self.metrics.inc("table_rows_rebuilt", rebuilt)
+        if preempted:
+            # a slot preempted while a LATER slot grew already has its limit
+            # computed — zero it (its mirrors are parked; writes go to
+            # scratch)
+            for slot in range(self.num_slots):
+                r = slots[slot]
+                if r is None or r.state is not RequestState.ACTIVE:
+                    limits[slot] = 0
+            active = [(s, r) for s, r in self.sched.active
+                      if r.state is RequestState.ACTIVE]
+        return limits, active
 
     def _reconcile(self, limits, active, slab, accepted):
         """Commit a dispatch's token slab to the scheduler's state. Returns
@@ -1250,7 +1283,7 @@ class ServingEngine:
         if self._ring:
             # pages held by kind: a seated sequence's ledger pages (a full
             # layer's), and what a ring layer holds of them at most
-            held = [len(self.alloc.pages_of(r.rid))
+            held = [self.alloc.n_pages_of(r.rid)
                     for r in self.sched.slots if r is not None]
             self.metrics.observe("kv_pages_full", sum(held))
             self.metrics.observe("kv_pages_window",
@@ -1263,16 +1296,20 @@ class ServingEngine:
 
         n_tokens = 0
         emitted_by_slot = {}
+        # the slab and the counts as Python ints once a dispatch: a slot's
+        # column each
+        commits = (limits if accepted is None else accepted).tolist()
+        columns = slab[:self.decode_horizon].T.tolist()
         for slot, req in active:
-            n_commit = int(limits[slot]) if accepted is None \
-                else int(accepted[slot])
-            emitted = 0
-            for i in range(n_commit):
-                req.generated.append(int(slab[i, slot]))
-                emitted += 1
-                self.metrics.inc("tokens_generated")
-                if req.done:               # budget exhausted or EOS
-                    break
+            # up to the first token that ends the request (``Request.done``:
+            # budget exhausted, then EOS)
+            col = columns[slot][:commits[slot]]
+            del col[max(1, req.max_new_tokens - len(req.generated)):]
+            if req.eos_token in col:
+                del col[col.index(req.eos_token) + 1:]
+            req.generated.extend(col)
+            emitted = len(col)
+            self.metrics.inc("tokens_generated", emitted)
             # the device froze this row after the same ``emitted`` steps
             # (limit clamp / EOS mask / accept prefix), so the mirrors
             # stay equal to the device carry — a continuing slot costs no
@@ -1462,7 +1499,7 @@ class ServingEngine:
         for slot in range(self.num_slots):
             self._park(slot)
         self._sync_mirrors()
-        self._dirty = False
+        self._dirty = self._bt_dirty = False
         self._ahead = None          # every live request restarts at cursor 0
         if state is None:
             return

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 
@@ -144,6 +145,24 @@ class KVPagePool:
         self._refs: dict[int, int] = {}
         self._cached: list[int] = []
         self._cacheable: set[int] = set()
+        # per-sequence mutation stamps: ``_stamps[seq]`` is a fresh value of
+        # one pool-wide clock after every change to ``_owned[seq]``, so a
+        # stamp never names two page lists, not even across a sequence's
+        # free and re-allocation. What mirrors a sequence's pages (the
+        # engine's table rows) keeps the stamp it mirrored and looks again
+        # only when it moved. No allocation decision: ``digest()`` and
+        # ``snapshot()`` leave it out.
+        self._stamps: dict[object, int] = {}
+        self._clock = 0
+
+    def _touch(self, seq_id) -> None:
+        """``_owned[seq_id]`` changed: every method that changes it ends
+        here."""
+        self._clock += 1
+        if seq_id in self._owned:
+            self._stamps[seq_id] = self._clock
+        else:
+            self._stamps.pop(seq_id, None)
 
     # -- introspection ----------------------------------------------------
     @property
@@ -163,6 +182,15 @@ class KVPagePool:
 
     def holds(self, seq_id) -> bool:
         return seq_id in self._owned
+
+    def n_pages_of(self, seq_id) -> int:
+        return len(self._owned.get(seq_id, ()))
+
+    def stamp(self, seq_id) -> int:
+        """The mutation stamp of ``seq_id``'s page list: it differs from
+        every stamp this pool gave before whenever the list may differ (0:
+        the sequence holds nothing)."""
+        return self._stamps.get(seq_id, 0)
 
     def refcount(self, page_id: int) -> int:
         """How many sequences hold ``page_id`` right now (0 = free or
@@ -198,6 +226,21 @@ class KVPagePool:
         return ((page_id % self.sp_ranks)
                 * (self.device_pages // self.sp_ranks)
                 + page_id // self.sp_ranks)
+
+    def device_rows(self, page_ids):
+        """``device_row`` of every id of ``page_ids`` as ONE int32 array
+        operation (a table row, a gather's or a scatter's index): the same
+        map, the same refusal of an id outside the device range."""
+        ids = np.asarray(page_ids, np.int64)
+        bad = (ids < 0) | (ids >= self.device_pages)
+        if bad.any():
+            raise PageLedgerError(
+                f"page {int(ids[bad][0])} outside the device range "
+                f"[0, {self.device_pages})")
+        if self.layout == "blocked":
+            return ids.astype(np.int32)
+        return ((ids % self.sp_ranks) * (self.device_pages // self.sp_ranks)
+                + ids // self.sp_ranks).astype(np.int32)
 
     def page_shard(self, page_id: int) -> int:
         """Which SP rank's device shard holds ``page_id`` under the
@@ -260,6 +303,8 @@ class KVPagePool:
         pool._free = [int(p) for p in snap["free"]]
         pool._owned = {sid: [int(p) for p in pages]
                        for sid, pages in snap["owned"]}
+        for sid in pool._owned:
+            pool._touch(sid)
         # restored VERBATIM (not re-derived from ownership multiplicity):
         # the checkpoint integrity audit digests the rebuilt pool against
         # the capture-time value, so a tampered refcount/cache field must
@@ -284,6 +329,7 @@ class KVPagePool:
         for p in got:
             self._refs[p] = 1
         self._owned.setdefault(seq_id, []).extend(got)
+        self._touch(seq_id)
         return got
 
     def acquire(self, seq_id, page_ids) -> None:
@@ -313,6 +359,7 @@ class KVPagePool:
                 self._cached.remove(p)
             self._refs[p] = self._refs.get(p, 0) + 1
             self._owned.setdefault(seq_id, []).append(p)
+        self._touch(seq_id)
 
     def _release_page(self, seq_id, p: int) -> bool:
         """Drop one reference to ``p``. On the LAST reference the page
@@ -365,6 +412,7 @@ class KVPagePool:
             self._owned[seq_id] = pages[:keep]
         else:
             self._owned.pop(seq_id, None)
+        self._touch(seq_id)
         return len(tail)
 
     def free_seq(self, seq_id) -> int:
@@ -373,6 +421,7 @@ class KVPagePool:
         pages = self._owned.pop(seq_id, [])
         for p in pages:
             self._release_page(seq_id, p)
+        self._touch(seq_id)
         return len(pages)
 
     # -- prefix-cache retention + copy-on-write (ISSUE 13) ----------------
@@ -428,6 +477,7 @@ class KVPagePool:
         self._refs[new] = 1
         pages[index] = new
         self._refs[old] -= 1
+        self._touch(seq_id)
         return old, new
 
     # -- migration support (disaggregated serving, ISSUE 6) ---------------

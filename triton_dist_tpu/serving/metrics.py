@@ -56,6 +56,31 @@ class Histogram:
                 self._samples = self._samples[::2]
                 self._stride *= 2
 
+    def observe_n(self, value: float, n: int) -> None:
+        """``n`` observations of one value: what ``n`` calls of ``observe``
+        leave behind (count, total, min, max, the retained samples and the
+        stride), visiting the retained observations alone."""
+        if n <= 0:
+            return
+        value = float(value)
+        total = self.total
+        for _ in range(n):              # the calls' own rounding, add by add
+            total += value
+        self.total = total
+        self.min = value if self.min is None else min(self.min, value)
+        self.max = value if self.max is None else max(self.max, value)
+        at, end = self.count, self.count + n
+        while True:
+            at = -(-at // self._stride) * self._stride  # next one retained
+            if at >= end:
+                break
+            self._samples.append(value)
+            if len(self._samples) >= self._max_samples:
+                self._samples = self._samples[::2]
+                self._stride *= 2
+            at += 1
+        self.count = end
+
     def percentile(self, q: float) -> float | None:
         if not self._samples:
             return None
@@ -154,8 +179,9 @@ class ServingMetrics:
     dispatch), dispatches (host→device decode launches — at horizon K one
     dispatch covers up to K steps, so dispatches ≲ decode_steps / K),
     host_syncs (dispatches that had to re-upload host slot state after a
-    control-plane change — admission, finish, preemption, growth; a quiet
-    dispatch reuses the device-resident carry and uploads nothing);
+    control-plane change — admission, finish, preemption: every mirror;
+    growth: the table alone; a quiet dispatch reuses the device-resident
+    carry and uploads nothing);
     histograms — TTFT (s), per-token latency (s), queue depth (sampled
     per step), batch occupancy (active slots per step), per-dispatch
     device time and host overhead (s) — the device/host split bench.py
@@ -185,10 +211,10 @@ class ServingMetrics:
                           chunk program for the first token (rid,
                           cursor); no other chunk is waited for
     phase_grow_s          the chunk's commit, page growth and          host
-                          preemption, limits, every decoding slot's
-                          table row
+                          preemption, limits, the table row of a slot
+                          whose pages the ledger stamped anew
     phase_sync_s          slot mirrors uploaded after a control-plane  host
-                          change
+                          change (the table alone after growth)
     phase_dispatch_s      the limits' upload and the decode launch     host
     phase_decode_wait_s   blocked on the decode program's token slab   wait
     phase_reconcile_s     family counters, gauges, the commit loop,    host
@@ -366,6 +392,13 @@ class ServingMetrics:
             "draft_tokens": 0,
             "draft_accepted": 0,
             "spec_rewinds": 0,
+            # the table mirror (``ServingEngine._grow``): decoding slots a
+            # dispatch looked at, and those of them whose table row was
+            # built again from the ledger because the stamp of the
+            # sequence's pages had moved since the row was mirrored (a page
+            # taken, a rewind, a fresh seat); the others cost one compare
+            "table_rows_checked": 0,
+            "table_rows_rebuilt": 0,
         }
         self.hist = {
             "ttft_s": Histogram(),
@@ -485,8 +518,9 @@ class ServingMetrics:
     def inc(self, name: str, by: int = 1) -> None:
         self.counters[name] += by
 
-    def observe(self, name: str, value: float) -> None:
-        self.hist[name].observe(value)
+    def observe(self, name: str, value: float, n: int = 1) -> None:
+        """``n`` observations of ``value`` (one, unless said)."""
+        self.hist[name].observe_n(value, n)
 
     # -- per-class labels (ISSUE 14) --------------------------------------
     # Labeled series live in the SAME flat dicts under Prometheus-style
@@ -504,13 +538,13 @@ class ServingMetrics:
         self.counters[key] = self.counters.get(key, 0) + by
 
     def observe_class(self, name: str, cls: str | None,
-                      value: float) -> None:
-        if cls is None:
+                      value: float, n: int = 1) -> None:
+        if cls is None or n <= 0:
             return
         key = self.class_key(name, cls)
         if key not in self.hist:
             self.hist[key] = Histogram()
-        self.hist[key].observe(value)
+        self.hist[key].observe_n(value, n)
 
     def classes(self) -> list[str]:
         """Class labels seen so far (sorted — deterministic panels)."""

@@ -375,7 +375,7 @@ class ShardedServingEngine(ServingEngine):
         # the pool pytree — see ``programs.engine_programs``)
         self._pool_out_sharding = jax.sharding.NamedSharding(
             ctx.mesh, page_pool_pspec("sp"))
-        # replicated sharding for the control-plane mirrors (_sync_mirrors
+        # replicated sharding for the control-plane mirrors (_upload
         # commits every upload so pjit's executable cache sees ONE input
         # signature across all dispatches)
         self._rep_sharding = jax.sharding.NamedSharding(ctx.mesh, P())
@@ -467,18 +467,8 @@ class ShardedServingEngine(ServingEngine):
     def _default_artifact_key(self) -> str:
         return f"sharded:{self.mesh_desc}"
 
-    def _sync_mirrors(self) -> None:
-        self._token_dev = jax.device_put(jnp.asarray(self._token),
-                                         self._rep_sharding)
-        self._pos_dev = jax.device_put(jnp.asarray(self._pos),
-                                       self._rep_sharding)
-        self._bt_dev = jax.device_put(jnp.asarray(self._bt),
-                                      self._rep_sharding)
-        if self.spec_k:
-            self._hist_dev = jax.device_put(jnp.asarray(self._hist),
-                                            self._rep_sharding)
-            self._hlen_dev = jax.device_put(jnp.asarray(self._hist_len),
-                                            self._rep_sharding)
+    def _upload(self, mirror):
+        return jax.device_put(jnp.asarray(mirror), self._rep_sharding)
 
     # -- replicated-decision guard ----------------------------------------
     # ``control_digest`` lives on the base engine now (ISSUE 9: journal
