@@ -38,7 +38,8 @@ import sys
 
 CONFIGS = ("mistral-7b-v5e1", "kimi-k2-ep32-v5e1", "command-a-plus-ep8-v5e1",
            "falcon-h1-34b-v5e1", "mimo-v2-flash-ep8-v5e1",
-           "qwen3-next-80b-ep16-v5e1", "lfm2-24b-a2b-pp4-v5e1")
+           "qwen3-next-80b-ep16-v5e1", "lfm2-24b-a2b-pp4-v5e1",
+           "ouro-2.6b-v5e1")
 TABLES = re.compile(r"^\d+ |^(FileNames|FunctionNames|FileLocations|"
                     r"StackFrames)")
 BODY = re.compile(r'"body":"([^"]*)"')

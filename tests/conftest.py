@@ -349,7 +349,8 @@ def _limited(item, what):
 # collected differently) and no test leans on it.
 FIXTURE_HEAVY_FIRST = (
     "test_sink_window_moe.py", "test_window_moe.py",
-    "test_linear_attn_moe.py", "test_short_conv_moe.py", "test_latent_moe.py",
+    "test_linear_attn_moe.py", "test_short_conv_moe.py", "test_looped_lm.py",
+    "test_latent_moe.py",
     "test_hybrid_ssm.py",
     "test_recovery.py", "test_chaos.py", "test_slo.py",
     "test_recovery_mesh.py", "test_sharded_serving.py",

@@ -562,6 +562,25 @@ def _kernel_grids(jaxpr, found):
     return found
 
 
+def _moved_like(text, big):
+    """The instructions of an optimised program whose result is shaped like
+    one of ``big`` (a pool leaf, a layer of it) and that are a ``copy`` or a
+    slice (``dynamic-update-slice`` apart: a carried leaf written in place
+    has the leaf's shape by definition)."""
+    import re
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        kind = name if opcode == "fusion" else opcode
+        if any(s in result for s in big) and re.search(
+                r"copy|slice", kind) and not re.search(r"update.slice", kind):
+            moved.append(line.strip()[:160])
+    return moved
+
+
 def _engine_programs(topo, cfg, init_params, pages, B, C, W, K=4):
     """The engine's two model functions for ``cfg`` and their abstract
     arguments on one described v5e, as the engine hands them over:
@@ -879,19 +898,10 @@ def test_hybrid_state_and_pages_stay_in_place(hybrid_programs, program):
     kv = f"{HYB_P},{cfg.n_kv_heads},128,{cfg.head_dim}]"
     big = [f"[{cfg.n_layers},{s}" for s in (state, kv)] \
         + [f"[1,{state}", f"[{state}", f"[1,{kv}", f"[{kv}"]
-    moved = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
-        if not m:
-            continue
-        name, result, opcode = m.groups()
-        kind = name if opcode == "fusion" else opcode
-        # (a dynamic-update-slice of a carried leaf is written in place and
-        # has the leaf's shape by definition: the chunk's one-slot write;
-        # a copy of the state would show in the temporaries below)
-        if any(s in result for s in big) and re.search(
-                r"copy|slice", kind) and not re.search(r"update.slice", kind):
-            moved.append(line.strip()[:160])
+    # (a dynamic-update-slice of a carried leaf is written in place and
+    # has the leaf's shape by definition: the chunk's one-slot write;
+    # a copy of the state would show in the temporaries below)
+    moved = _moved_like(text, big)
     assert not moved, "\n".join(moved)
     pool_bytes = cfg.n_layers * (
         S * (cfg.ssm_heads * cfg.ssm_state * cfg.ssm_head_dim * 4
@@ -966,16 +976,7 @@ def test_sink_window_pools_stay_in_place(sink_window_programs, program):
               f"{ring},8,128,256]", f"{ring},8,128,128]"]
     big = [f"[{n},{s}" for s in leaves for n in (1, 2, 5)] \
         + [f"[{s}" for s in leaves]
-    moved = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
-        if not m:
-            continue
-        name, result, opcode = m.groups()
-        kind = name if opcode == "fusion" else opcode
-        if any(s in result for s in big) and re.search(
-                r"copy|slice", kind) and not re.search(r"update.slice", kind):
-            moved.append(line.strip()[:160])
+    moved = _moved_like(text, big)
     assert not moved, "\n".join(moved)
     pool_bytes = 2 * (2 * SINK_P * 4 + 5 * ring * 8) * 128 * (256 + 128)
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
@@ -1059,16 +1060,7 @@ def test_linear_attn_states_and_pages_stay_in_place(linear_attn_programs,
     kv = f"{LIN_P},2,128,256]"
     big = [f"[3,{state}", f"[1,{state}", f"[{state}", f"[1,{kv}", f"[{kv}",
            f"[{3 * S},24576]"]
-    moved = []
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
-        if not m:
-            continue
-        name, result, opcode = m.groups()
-        kind = name if opcode == "fusion" else opcode
-        if any(s in result for s in big) and re.search(
-                r"copy|slice", kind) and not re.search(r"update.slice", kind):
-            moved.append(line.strip()[:160])
+    moved = _moved_like(text, big)
     assert not moved, "\n".join(moved)
     pool_bytes = 3 * S * (32 * 128 * 128 * 4 + 3 * 8192 * 2) \
         + 2 * LIN_P * 2 * 128 * 256 * 2
@@ -1109,21 +1101,50 @@ def test_short_conv_rows_and_side_by_side_pages_stay_in_place(topo):
         for kernel in (walk[program], "grouped_gemm_gated", "grouped_gemm"):
             assert re.search(rf"%{kernel}[.\d]* = [^\n]*custom-call", text), \
                 (program, kernel)
-        moved = []
-        for line in text.splitlines():
-            m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
-            if not m:
-                continue
-            name, result, opcode = m.groups()
-            kind = name if opcode == "fusion" else opcode
-            if any(s in result for s in big) and re.search(
-                    r"copy|slice", kind) and not re.search(r"update.slice",
-                                                           kind):
-                moved.append(line.strip()[:160])
+        moved = _moved_like(text, big)
         assert not moved, "\n".join(moved)
         assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
         # a sparse layer's tables are 1.2 GB: no copy of one fits under this
         assert mem.temp_size_in_bytes < 0.5e9, (program,
+                                                mem.temp_size_in_bytes)
+
+
+def test_looped_walks_are_one_body_over_planes_that_stay_in_place(topo):
+    """Ouro-2.6B as published (every width: 16 heads for 16 KV heads of 128,
+    FFN 5,632, vocabulary 49,152, untied; all 4 walks), depth cut to 4 layers
+    for the compile alone, at the cell's 8 slots, 256-row chunk, 42 pages of
+    128 and a table 5 wide, both programs lowered for one described v5e, pool
+    donated. Mosaic takes both paged GQA kernels at a GROUP OF ONE (a
+    [16, 1, 128] query operand a row); the trace will find them by the dense
+    kernels' names; each is ONE call in the traced program whatever the
+    planes (the scan over layers inside ONE loop over the walks: 3 nested
+    loops with the horizon's, 2 in the chunk program); nothing shaped like
+    the 16-plane pool or a plane of it comes out of a ``copy`` or a slice."""
+    import re
+    from triton_dist_tpu.models import looped as lp
+    cfg = lp.LoopedConfig(n_layers=4, max_seq_len=5 * 128)
+    assert lp.kv_bytes_per_token(cfg) == 16 * 8192
+    pages = 42
+    kv = f"{pages},16,128,128]"
+    big = [f"[16,{kv}", f"[1,{kv}", f"[{kv}"]
+    for program, (fn, args) in _plain_jits(*_engine_programs(
+            topo, cfg, lp.init_params, pages, 8, 256, 5)).items():
+        traced = fn.trace(*args)
+        walk = {"decode": "gqa_decode_paged", "chunk": "gqa_prefill_paged"}
+        assert set(_kernel_grids(traced.jaxpr.jaxpr, {})) == {walk[program]}
+        assert str(traced.jaxpr).count("pallas_call") == 1
+        lowered = traced.lower()
+        assert lowered.as_text().count("stablehlo.while") == {
+            "decode": 3, "chunk": 2}[program]
+        exe = lowered.compile()
+        text, mem = exe.as_text(), exe.memory_analysis()
+        assert re.search(rf"%{walk[program]}[.\d]* = [^\n]*custom-call", text)
+        moved = _moved_like(text, big)
+        assert not moved, "\n".join(moved)
+        pool_bytes = 2 * 16 * pages * 16 * 128 * 128 * 2
+        assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not donated"
+        # a walk's four layers are 0.41 GB: no copy of a stack fits under this
+        assert mem.temp_size_in_bytes < 0.3e9, (program,
                                                 mem.temp_size_in_bytes)
 
 

@@ -14,7 +14,9 @@ from conftest import TEST_WORLD  # noqa: F401
 from triton_dist_tpu.models.llama import (LlamaConfig, decode_step, forward,
                                           generate, init_kv_cache,
                                           init_params, prefill)
-from triton_dist_tpu.ops.flash_decode import gqa_decode_paged
+from triton_dist_tpu.ops.flash_decode import (gqa_decode_paged,
+                                              gqa_prefill_paged,
+                                              paged_kv_write)
 
 
 def _ref_paged_attn(q, k_pages, v_pages, block_table, kv_len):
@@ -40,11 +42,17 @@ def _ref_paged_attn(q, k_pages, v_pages, block_table, kv_len):
     return np.stack(outs)
 
 
-def test_paged_decode_matches_dense():
-    B, Hq, Hkv, D, ps, pages_per_seq = 2, 4, 2, 64, 16, 4
+# the paged walk against the dense golden, by path and by query group: the
+# decode rows' kernel, a chunk's rows through ``gqa_prefill_paged``, and rows
+# written by ``paged_kv_write`` then walked; 2 query heads a KV head, and a
+# group of ONE (as many KV heads as query heads: ISSUE 46's looped decoder)
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4)],
+                         ids=["group-of-2", "group-of-1"])
+@pytest.mark.parametrize("path", ["decode", "chunk", "written"])
+def test_paged_decode_matches_dense(path, heads):
+    B, (Hq, Hkv), D, ps, pages_per_seq = 2, heads, 64, 16, 4
     pool = B * pages_per_seq
     key = jax.random.key(0)
-    q = jax.random.normal(key, (B, Hq, D), jnp.float32)
     k_pages = jax.random.normal(jax.random.key(1), (pool, Hkv, ps, D),
                                 jnp.float32)
     v_pages = jax.random.normal(jax.random.key(2), (pool, Hkv, ps, D),
@@ -52,7 +60,33 @@ def test_paged_decode_matches_dense():
     # non-trivial page assignment + ragged lengths
     bt = jnp.asarray(np.random.default_rng(0).permutation(pool)
                      .reshape(B, pages_per_seq).astype(np.int32))
+    if path == "chunk":
+        # 16 rows of ONE sequence at positions 21 .. 36 (three pages), the
+        # last three padding: rows that share a table share the walk
+        C = 16
+        q = jax.random.normal(key, (C, Hq, D), jnp.float32)
+        kv_len = jnp.where(jnp.arange(C) < 13, 22 + jnp.arange(C), 0)
+        out = jax.jit(lambda *a: gqa_prefill_paged(*a, rows_per_block=8))(
+            q, k_pages, v_pages, bt[1], kv_len)
+        rows = jnp.broadcast_to(bt[1], (C, pages_per_seq))
+        ref = _ref_paged_attn(q[:13], k_pages, v_pages, rows[:13],
+                              kv_len[:13])
+        np.testing.assert_allclose(np.asarray(out[:13]), ref, atol=2e-5,
+                                   rtol=2e-5)
+        np.testing.assert_array_equal(np.asarray(out[13:]), 0.0)
+        return
+    q = jax.random.normal(key, (B, Hq, D), jnp.float32)
     kv_len = jnp.asarray([3 * ps + 5, 2 * ps], jnp.int32)
+    if path == "written":
+        # the rows' own keys and values land at kv_len - 1 through the table
+        k_new = jax.random.normal(jax.random.key(3), (B, Hkv, D), jnp.float32)
+        v_new = jax.random.normal(jax.random.key(4), (B, Hkv, D), jnp.float32)
+        k_pages, v_pages = jax.jit(paged_kv_write)(
+            k_pages, v_pages, k_new, v_new, bt, kv_len - 1)
+        for b in range(B):
+            page, row = bt[b, (kv_len[b] - 1) // ps], (kv_len[b] - 1) % ps
+            np.testing.assert_array_equal(k_pages[page, :, row], k_new[b])
+            np.testing.assert_array_equal(v_pages[page, :, row], v_new[b])
     out, lse = jax.jit(gqa_decode_paged)(q, k_pages, v_pages, bt, kv_len)
     ref = _ref_paged_attn(q, k_pages, v_pages, bt, kv_len)
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)

@@ -66,7 +66,7 @@ from jax import lax
 
 from triton_dist_tpu.models.expert_share import (COUNTERS, held_ids,
                                                  held_picks, softmax_route)
-from triton_dist_tpu.models.llama import (PagedFamily, gated_ffn,
+from triton_dist_tpu.models.llama import (PagedFamily, gated_ffn, live_rows,
                                           plain_chunk_walks, rope)
 
 # The recurrent state's dtype: a running sum over the whole context. Not a
@@ -283,10 +283,6 @@ def _my_layer(cfg: LinearAttnMoEConfig, kind: str, rank: int, layer):
         + rank
 
 
-def _live(kv_len, active):
-    return kv_len > 0 if active is None else jnp.logical_and(active,
-                                                             kv_len > 0)
-
 
 def _linear_mixer(rank: int, cfg: LinearAttnMoEConfig, p, h, layer, pool,
                   block_table, pos, kv_len, active, shared_table, lin,
@@ -300,7 +296,7 @@ def _linear_mixer(rank: int, cfg: LinearAttnMoEConfig, p, h, layer, pool,
     R = h.shape[0]
     H, Hk = cfg.lin_value_heads, cfg.lin_key_heads
     K, V, taps = cfg.lin_key_dim, cfg.lin_value_dim, cfg.lin_conv
-    live, slot = _live(kv_len, active), block_table[:, -1]
+    live, slot = live_rows(kv_len, active), block_table[:, -1]
     mine = _my_layer(cfg, "linear", rank, jnp.asarray(layer, jnp.int32))
     gdn, conv2d = pool["gdn"], pool["conv"]
     S = gdn.shape[1]
@@ -405,7 +401,7 @@ def _gated_attention(rank: int, cfg: LinearAttnMoEConfig, p, h, layer, pool,
         q = _rotate(cfg, zc_rmsnorm(q, p["q_norm"], cfg.norm_eps), positions)
         k = _rotate(cfg, zc_rmsnorm(k, p["k_norm"], cfg.norm_eps), positions)
         counts = {"attn_full_keys": jnp.sum(
-            jnp.where(_live(kv_len, active), kv_len, 0)).astype(jnp.int32)}
+            jnp.where(live_rows(kv_len, active), kv_len, 0)).astype(jnp.int32)}
         kp, vp = paged_kv_write(pool["k"], pool["v"], k, v, table, pos,
                                 active=active, layer=mine)
         if shared_table:

@@ -24,7 +24,7 @@ from __future__ import annotations
 import collections.abc
 import dataclasses
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -429,6 +429,19 @@ def init_page_pool(cfg: LlamaConfig, num_pages: int, page_size: int) -> dict:
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
+class Walks(NamedTuple):
+    """``PagedFamily.walks(cfg)``: ``n`` walks of the whole stack a
+    token-step. ``start(cfg, params, x) -> state`` (any pytree) before the
+    first; ``end(cfg, params, x, t, state, live) -> (x, state, counts)`` at
+    the end of walk ``t`` (a traced int32; ``live`` [R] bool the rows that
+    count; ``counts`` as an attention's); ``rows(state) -> [R, D]`` the rows
+    the head takes once the last walk has ended."""
+    n: int
+    start: Any
+    end: Any
+    rows: Any
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedFamily:
     """A model family, as the paged programs and ``serving.engine`` see it:
@@ -511,7 +524,18 @@ class PagedFamily:
       window (None: the whole context). The engine counts a chunk's walked
       and edge pages from it at the chunk's commit, on the host
       (``ops.flash_decode.chunk_walk_counts``: counters ``chunk_walk_pages``
-      / ``chunk_walk_edge_pages``); None: the chunk walks no K/V pages."""
+      / ``chunk_walk_edge_pages``); None: the chunk walks no K/V pages.
+    - ``walks(cfg) -> Walks``: for a family whose rows WALK THE WHOLE STACK
+      of layers several times over the same weights, each walk with cache
+      planes of its own: how often, and what happens at a walk's end (see
+      ``Walks``). Walk t of layer l is row l of the parameter stacks and
+      plane ``t * (the segments' layers) + l`` of the pool (``attention``
+      is handed the PLANE as its ``layer``, ``ffn`` the layer); the walks
+      are a ``fori_loop`` around the scan over layers, so the loop stays
+      one compiled body. The rows ``_paged_layers`` returns are then
+      ``Walks.rows`` of the walks' state, normed already: ``logits`` heads
+      them as they are. None: one walk and nothing at its end, layer l is
+      plane l."""
     name: str
     init_pool: Any
     segments: Any
@@ -528,6 +552,7 @@ class PagedFamily:
     slot_state: Any = None
     embed: Any = None
     chunk_walks: Any = None
+    walks: Any = None
 
     # ``benchmark/tools/fit_paged.py`` reads the two shared programs off the
     # record; they are the module's functions, whatever the family.
@@ -535,8 +560,10 @@ class PagedFamily:
     prefill_chunk = property(lambda self: prefill_chunk_paged)
 
     def logits(self, cfg, params, x, lin) -> jax.Array:
-        """The head on ``x`` [R, D]: final norm, then the family's head."""
-        x = (self.norm or rmsnorm)(x, params["final_norm"], cfg.norm_eps)
+        """The head on ``x`` [R, D]: final norm (a family with ``walks``
+        norms at every walk's end instead), then the family's head."""
+        if self.walks is None:
+            x = (self.norm or rmsnorm)(x, params["final_norm"], cfg.norm_eps)
         if self.head is not None:
             return self.head(cfg, params, x, lin)
         return lin(x, params["lm_head"], "lm_head").astype(jnp.float32)
@@ -555,6 +582,13 @@ def require_config(cfg, kind: type, who: str) -> None:
         raise NotImplementedError(
             f"{who} serves {kind.__name__} models only; got "
             f"{type(cfg).__name__}")
+
+
+def live_rows(kv_len: jax.Array, active: jax.Array | None) -> jax.Array:
+    """[R] bool: the rows of a dispatch that count (not parked, not padding,
+    not frozen mid-scan)."""
+    return kv_len > 0 if active is None else jnp.logical_and(active,
+                                                             kv_len > 0)
 
 
 def gated_ffn(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
@@ -640,6 +674,14 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     place (``paged_kv_write(layer=)``) and read in place
     (``gqa_decode_paged(layer=)``), it is never copied at all.
 
+    A family whose rows walk the stack SEVERAL TIMES (``PagedFamily.walks``)
+    gets one ``fori_loop`` over the walks around those scans: the scanned
+    body is still compiled once, walk t's attention is handed plane
+    ``t * layers + i`` of the pool where its parameters are row i, the
+    walk's end (the family's: a norm, a gate) runs once a trip, and ``x`` is
+    what the walks' state says the head takes. Without ``walks`` nothing of
+    this is traced: every other family's program is what it was.
+
     Any hook (``ffn`` / ``attn_io`` / ``linear``, see
     ``decode_step_paged``) unrolls the loop in Python over the same body.
     ``attn_io`` keeps its per-layer contract: it is handed ``K[i]``,
@@ -653,12 +695,14 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
     def add(counts, new):
         return {**counts, **{k: counts[k] + v for k, v in new.items()}}
 
-    def layer(carry, p, i, attention, seg_ffn):
+    def layer(carry, p, i, attention, seg_ffn, off=None):
+        # ``off``: the walk's first plane (``PagedFamily.walks``); layer i's
+        # cache is then plane off + i, its parameters row i all the same
         x, pool, counts = carry
         h = norm(x, p["attn_norm"], cfg.norm_eps)
-        attn, pool, *new = attention(cfg, p, h, i, pool, block_table, pos,
-                                     kv_len, active, shared_table, lin,
-                                     attn_io)
+        attn, pool, *new = attention(cfg, p, h, i if off is None else off + i,
+                                     pool, block_table, pos, kv_len, active,
+                                     shared_table, lin, attn_io)
         if new:
             counts = add(counts, new[0])
         if not fam.parallel:
@@ -675,60 +719,88 @@ def _paged_layers(params: dict, x: jax.Array, pos: jax.Array,
         x = x + ff.astype(x.dtype)
         return x, pool, counts
 
+    segments = fam.segments(cfg, params)
+
+    def walk(carry, off):
+        """Every run of layers once, in order."""
+        for blocks, first, n, seg_ffn, *own in segments:
+            period = own[0] if own else family_period
+            P = len(period)
+            assert n % P == 0, (
+                f"{n} layers are no whole number of periods of {P}")
+            # (attention, its stack, its rank among the period's layers of
+            # that stack) of every layer of the period, and each stack's
+            # layers a period: ONE stack (``None``) of P where the layers
+            # share a shape
+            plan, each = [], {}
+            for entry in period:
+                kind, attention = entry if isinstance(entry, tuple) \
+                    else (None, entry)
+                plan.append((attention, kind, each.get(kind, 0)))
+                each[kind] = each.get(kind, 0) + 1
+            stacks = {None: blocks} if None in each else blocks
+
+            def body(carry, xs, seg_ffn=seg_ffn, plan=plan, whole=P == 1):
+                p, i = xs   # a period's params {stack: [c, ...]}, its first
+                for j, (attention, kind, rank) in enumerate(plan):
+                    carry = layer(
+                        carry,
+                        p[kind] if whole else LayerParams(p[kind], rank),
+                        i + j if j else i, attention, seg_ffn, off)
+                return carry, None
+
+            def body_in_place(carry, i, seg_ffn=seg_ffn, plan=plan,
+                              stacks=stacks, each=each, first=first, P=P):
+                # SEVERAL periods of several layers: each layer's params are
+                # indexed where the stacks lie. As the scan's input a
+                # period's slice of every stack was copied whole every trip
+                # (100 MB of ``w_qkv`` [3, 2048, 8192] a period and
+                # token-step on the v5e); one layer's slice is fetched as a
+                # period-of-one's is.
+                for j, (attention, kind, rank) in enumerate(plan):
+                    at = (i - first) // P * each[kind] + rank
+                    carry = layer(carry, LayerParams(stacks[kind], at),
+                                  i + j if j else i, attention, seg_ffn, off)
+                return carry, None
+
+            if hooked:
+                for j in range(n):
+                    attention, kind, rank = plan[j % P]
+                    carry = layer(carry, LayerParams(
+                        stacks[kind], (j // P) * each[kind] + rank),
+                        first + j, attention, seg_ffn, off)
+            else:
+                firsts = jnp.arange(first, first + n, dtype=jnp.int32)
+                if P > 1 and n > P:
+                    carry, _ = lax.scan(body_in_place, carry, firsts[::P])
+                    continue
+                if P > 1:
+                    stacks = {kind: jax.tree.map(
+                        lambda a, c=c: a.reshape((n // P, c) + a.shape[1:]),
+                        stacks[kind]) for kind, c in each.items()}
+                    firsts = firsts[::P]
+                carry, _ = lax.scan(body, carry, (stacks, firsts))
+        return carry
+
     carry = (x, pages, {name: jnp.int32(0) for name in fam.counters})
-    for blocks, first, n, seg_ffn, *own in fam.segments(cfg, params):
-        period = own[0] if own else family_period
-        P = len(period)
-        assert n % P == 0, f"{n} layers are no whole number of periods of {P}"
-        # (attention, its stack, its rank among the period's layers of that
-        # stack) of every layer of the period, and each stack's layers a
-        # period: ONE stack (``None``) of P where the layers share a shape
-        plan, each = [], {}
-        for entry in period:
-            kind, attention = entry if isinstance(entry, tuple) \
-                else (None, entry)
-            plan.append((attention, kind, each.get(kind, 0)))
-            each[kind] = each.get(kind, 0) + 1
-        stacks = {None: blocks} if None in each else blocks
+    if fam.walks is None:
+        carry = walk(carry, None)
+    else:
+        # the stack walked ``w.n`` times: ONE loop around the same scans,
+        # the walk's planes ``stride`` apart
+        w = fam.walks(cfg)
+        stride = sum(seg[2] for seg in segments)
+        live = live_rows(kv_len, active)
 
-        def body(carry, xs, seg_ffn=seg_ffn, plan=plan, whole=P == 1):
-            p, i = xs       # a period's params {stack: [c, ...]}, its first
-            for j, (attention, kind, rank) in enumerate(plan):
-                carry = layer(carry,
-                              p[kind] if whole else LayerParams(p[kind], rank),
-                              i + j if j else i, attention, seg_ffn)
-            return carry, None
+        def whole_walk(t, c):
+            x, pool, counts = walk(c[:3], t * stride)
+            x, state, new = w.end(cfg, params, x, t, c[3], live)
+            return x, pool, add(counts, new), state
 
-        def body_in_place(carry, i, seg_ffn=seg_ffn, plan=plan,
-                          stacks=stacks, each=each, first=first, P=P):
-            # SEVERAL periods of several layers: each layer's params are
-            # indexed where the stacks lie. As the scan's input a period's
-            # slice of every stack was copied whole every trip (100 MB of
-            # ``w_qkv`` [3, 2048, 8192] a period and token-step on the v5e);
-            # one layer's slice is fetched as a period-of-one's is.
-            for j, (attention, kind, rank) in enumerate(plan):
-                at = (i - first) // P * each[kind] + rank
-                carry = layer(carry, LayerParams(stacks[kind], at),
-                              i + j if j else i, attention, seg_ffn)
-            return carry, None
-
-        if hooked:
-            for j in range(n):
-                attention, kind, rank = plan[j % P]
-                carry = layer(carry, LayerParams(
-                    stacks[kind], (j // P) * each[kind] + rank), first + j,
-                    attention, seg_ffn)
-        else:
-            firsts = jnp.arange(first, first + n, dtype=jnp.int32)
-            if P > 1 and n > P:
-                carry, _ = lax.scan(body_in_place, carry, firsts[::P])
-                continue
-            if P > 1:
-                stacks = {kind: jax.tree.map(
-                    lambda a, c=c: a.reshape((n // P, c) + a.shape[1:]),
-                    stacks[kind]) for kind, c in each.items()}
-                firsts = firsts[::P]
-            carry, _ = lax.scan(body, carry, (stacks, firsts))
+        with jax.named_scope("looped_layers"):
+            c = lax.fori_loop(0, w.n, whole_walk,
+                              (*carry, w.start(cfg, params, x)))
+        carry = (w.rows(c[3]), *c[1:3])
     x, pages, counts = carry
     return x, pages, tuple(counts[name] for name in fam.counters)
 
@@ -1210,9 +1282,9 @@ GQA_DENSE = PagedFamily(
     chunk_walks=lambda cfg: plain_chunk_walks(cfg.n_layers))
 
 
-__all__ = ["LlamaConfig", "LayerParams", "PagedFamily", "GQA_DENSE",
+__all__ = ["LlamaConfig", "LayerParams", "PagedFamily", "Walks", "GQA_DENSE",
            "plain_chunk_walks",
-           "swiglu_ffn", "gated_ffn", "require_config", "init_params",
+           "swiglu_ffn", "gated_ffn", "live_rows", "require_config", "init_params",
            "param_specs", "forward",
            "forward_tp_overlap", "mlp_tp_overlap", "rmsnorm", "rope",
            "block_apply", "init_kv_cache", "init_page_pool", "prefill",

@@ -60,8 +60,9 @@ from jax import lax
 
 from triton_dist_tpu.models.expert_share import (COUNTERS, held_experts,
                                                  held_ids, sigmoid_route)
-from triton_dist_tpu.models.llama import (PagedFamily, plain_chunk_walks,
-                                          rmsnorm, rope, swiglu_ffn)
+from triton_dist_tpu.models.llama import (PagedFamily, live_rows,
+                                          plain_chunk_walks, rmsnorm, rope,
+                                          swiglu_ffn)
 
 # the published normaliser of the chosen experts' weights: s_i / (sum + this)
 ROUTE_EPS = 1e-6
@@ -271,10 +272,6 @@ def _mine(cfg: ShortConvMoEConfig, kind: str, rank, layer):
             * cfg.layer_kinds.count(kind) + rank)
 
 
-def _live(kv_len, active):
-    return kv_len > 0 if active is None else jnp.logical_and(active,
-                                                             kv_len > 0)
-
 
 def _conv_mixer(rank, cfg: ShortConvMoEConfig, p, h, layer, pool,
                 block_table, pos, kv_len, active, shared_table, lin,
@@ -285,7 +282,7 @@ def _conv_mixer(rank, cfg: ShortConvMoEConfig, p, h, layer, pool,
     positions from ``pos[0]``, the live ones first."""
     assert attn_io is None, "the short-conv family has no attn_io hook"
     R, D, taps = h.shape[0], cfg.d_model, cfg.conv_taps
-    live, slot = _live(kv_len, active), block_table[:, -1]
+    live, slot = live_rows(kv_len, active), block_table[:, -1]
     mine = _mine(cfg, "conv", rank, jnp.asarray(layer, jnp.int32))
     conv2d = pool["conv"]
     S = cfg.state_slots + 1
@@ -351,7 +348,7 @@ def _full_attention(rank, cfg: ShortConvMoEConfig, p, h, layer, pool,
         q = rotated(cfg, normed_heads(q, p["q_norm"], cfg.norm_eps), positions)
         k = rotated(cfg, normed_heads(k, p["k_norm"], cfg.norm_eps), positions)
         counts = {"attn_full_keys": jnp.sum(
-            jnp.where(_live(kv_len, active), kv_len, 0)).astype(jnp.int32)}
+            jnp.where(live_rows(kv_len, active), kv_len, 0)).astype(jnp.int32)}
         kv, _ = paged_kv_write(pool["kv"], None, k, v, table, pos,
                                active=active, layer=mine)
         if shared_table:

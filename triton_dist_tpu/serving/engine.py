@@ -275,6 +275,14 @@ class ServingEngine:
             self.decode_horizon = self.spec_k
 
         self.pool = fam.init_pool(cfg, num_pages + 1, page_size)
+        if not self._slot_owned:
+            # a gauge: bytes a cached token holds over ALL planes, from the
+            # pool's leaves [plane, page, ...] (every one the ledger's pages
+            # here) and never from the config's layers: a family's planes may
+            # outnumber them (``PagedFamily.walks``)
+            self.metrics.counters["kv_bytes_per_token"] = sum(
+                a.nbytes // (a.shape[1] * page_size)
+                for a in jax.tree_util.tree_leaves(self.pool))
         # unified pool contract (ISSUE 12): subclasses that shard the pool
         # arrays over SP set _pool_sp_ranks BEFORE super().__init__ so the
         # ledger knows the padded device page range (padding pages are

@@ -263,6 +263,12 @@ class ServingMetrics:
     ``attn_window_keys`` (min(context, window) summed over live rows, inner
     steps and window layers: the keys its windowed walks had to attend) and
     ``attn_full_keys`` (the context, summed the same way over full layers).
+    A looped family (``PagedFamily.walks``) adds ``loop_plane_keys`` (keys
+    walked, summed over live rows, the walks x layers planes and inner
+    steps), ``loop_row_calls`` (live rows summed the same way) and
+    ``loop_early_exit_rows`` (live rows whose picked walk was not the last).
+    ``kv_bytes_per_token`` is a gauge set at build where every pool leaf is
+    the ledger's pages: bytes a cached token holds over all planes.
     For a family whose slots own rings of pages (``PagedFamily.slot_ring``)
     the engine samples two gauges every decode dispatch, pages held by kind:
     ``kv_pages_full`` (the seated sequences' ledger pages: what a full layer
