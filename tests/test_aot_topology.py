@@ -550,6 +550,8 @@ def test_ring_attention_flat_walk_lowers_1dev(ctx_single):
 
 POOL_L, POOL_P, POOL_PAGE, POOL_PPS = 2, 209, 128, 13
 POOL_HKV, POOL_D = 8, 128                   # Mistral-7B: 8 KV heads of 128
+# the pool's row-major 2-D view, as an HLO shape
+POOL_VIEW = f"[{POOL_L * POOL_P * POOL_HKV * POOL_PAGE},{POOL_D}]"
 
 
 def _kernel_grids(jaxpr, found):
@@ -579,6 +581,20 @@ def _moved_like(text, big):
                 r"copy|slice", kind) and not re.search(r"update.slice", kind):
             moved.append(line.strip()[:160])
     return moved
+
+
+def _yielding(text, shapes, kind):
+    """The instructions of an optimised program whose opcode (a fusion: its
+    name) matches ``kind`` and whose result is shaped like one of
+    ``shapes``."""
+    import re
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if m and re.search(kind, m[1] if m[3] == "fusion" else m[3]) \
+                and any(s in m[2] for s in shapes):
+            found.append(line.strip()[:160])
+    return found
 
 
 def _engine_programs(topo, cfg, init_params, pages, B, C, W, K=4):
@@ -673,6 +689,10 @@ def test_pool_is_not_copied_sliced_or_relaid(paged_programs, program):
                 r"copy|slice", kind):
             moved.append(line.strip()[:160])
     assert not moved, "\n".join(moved)
+    # nor is the row-major 2-D view both writes go through ever copied (a
+    # ``dynamic-update-slice`` of its shape is the chunk's write in place)
+    copied = _yielding(text, [POOL_VIEW], "copy")
+    assert not copied, "\n".join(copied)
     pool_bytes = 2 * POOL_L * POOL_P * POOL_HKV * POOL_PAGE * POOL_D * 2
     assert mem.temp_size_in_bytes < pool_bytes / 2, (
         mem.temp_size_in_bytes, pool_bytes)
@@ -983,14 +1003,46 @@ def test_sink_window_pools_stay_in_place(sink_window_programs, program):
     assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("family", ["dense", "sink_window"])
+def test_chunk_rows_land_a_page_at_a_time(request, family):
+    """The compiled CHUNK program scatters no row into a pool's 2-D view: its
+    rows are one sequence's run and go page by page
+    (``paged_kv_write(shared_table=True)``: a ``dynamic-update-slice`` of the
+    view inside the visits' loop, in place, and no ``copy`` of the view). The
+    DECODE program's rows belong to different sequences and keep their row
+    scatter, one a pool leaf a layer of the scanned body (ISSUE 47)."""
+    if family == "dense":
+        progs = request.getfixturevalue("paged_programs")
+        views, per_body = [POOL_VIEW], 2
+    else:
+        progs = request.getfixturevalue("sink_window_programs")
+        cfg = progs["chunk"][2]
+        ring = 1 + SINK_SLOTS * cfg.ring_pages(128)
+        views = [f"[{n * pages * heads * 128},{width}]"
+                 for n, pages, heads in ((2, SINK_P, 4), (5, ring, 8))
+                 for width in (256, 128)]
+        # the dense layer (full) and a period of five window layers and one
+        # full one, keys and values each
+        per_body = 2 * 7
+    text = progs["chunk"][0]
+    stray = _yielding(text, views, "scatter|copy")
+    assert not stray, "\n".join(stray)
+    updates = _yielding(text, views, "^dynamic-update-slice$")
+    assert len(updates) == per_body, "\n".join(updates)
+    assert len(_yielding(progs["decode"][0], views, "scatter")) == per_body
+
+
 def test_sink_window_segments_are_one_scanned_body_each(topo):
     """A leading dense layer and TWO periods of six layers whose kinds differ
     in shape lower to one ``while`` a segment (13 layers, not 13 bodies): the
-    chunk program has two, the decode program two inside its horizon's one."""
+    chunk program has two, the decode program two inside its horizon's one.
+    Each of a body's layers holds one more in the chunk program, the visits of
+    its K/V write (``paged_kv_write(shared_table=True)``): 1 + 6, whatever
+    the periods."""
     low = _sink_window_lowered(topo, _sink_window_cfg(periods=2))
     whiles = {name: lo.as_text().count("stablehlo.while")
               for name, lo in low.items()}
-    assert whiles == {"chunk": 2, "decode": 3}, whiles
+    assert whiles == {"chunk": 2 + 7, "decode": 3}, whiles
 
 
 # -- the linear-attention family at Qwen3-Next widths (ISSUE 39) --------------------
@@ -1118,8 +1170,9 @@ def test_looped_walks_are_one_body_over_planes_that_stay_in_place(topo):
     [16, 1, 128] query operand a row); the trace will find them by the dense
     kernels' names; each is ONE call in the traced program whatever the
     planes (the scan over layers inside ONE loop over the walks: 3 nested
-    loops with the horizon's, 2 in the chunk program); nothing shaped like
-    the 16-plane pool or a plane of it comes out of a ``copy`` or a slice."""
+    loops with the horizon's; 2 in the chunk program, and inside them the
+    visits of the layer's K/V write); nothing shaped like the 16-plane pool
+    or a plane of it comes out of a ``copy`` or a slice."""
     import re
     from triton_dist_tpu.models import looped as lp
     cfg = lp.LoopedConfig(n_layers=4, max_seq_len=5 * 128)
@@ -1135,7 +1188,7 @@ def test_looped_walks_are_one_body_over_planes_that_stay_in_place(topo):
         assert str(traced.jaxpr).count("pallas_call") == 1
         lowered = traced.lower()
         assert lowered.as_text().count("stablehlo.while") == {
-            "decode": 3, "chunk": 2}[program]
+            "decode": 3, "chunk": 2 + 1}[program]
         exe = lowered.compile()
         text, mem = exe.as_text(), exe.memory_analysis()
         assert re.search(rf"%{walk[program]}[.\d]* = [^\n]*custom-call", text)
@@ -1152,13 +1205,14 @@ def test_linear_attn_periods_are_one_scanned_body(topo):
     """Periods of four layers of two kinds lower to ONE ``while`` over the
     periods (8 or 12 layers, not 8 or 12 bodies): a third period adds no
     loop to either program (the body's own: a scan over blocks of 64 tokens
-    a linear layer of the chunk program, the compacted share's loop a layer,
-    the horizon's in the decode program)."""
+    a linear layer of the chunk program and the visits of the full layer's
+    K/V write, the compacted share's loop a layer, the horizon's in the decode
+    program)."""
     whiles = [{name: lo.as_text().count("stablehlo.while") for name, lo in
                _linear_attn_lowered(topo, _linear_attn_cfg(periods)).items()}
               for periods in (2, 3)]
     assert whiles[0] == whiles[1], whiles
-    assert whiles[0]["chunk"] == 1 + 3 + 4 and whiles[0]["decode"] == 2 + 4
+    assert whiles[0]["chunk"] == 1 + 3 + 1 + 4 and whiles[0]["decode"] == 2 + 4
 
 
 # -- the parameters are held in the layouts the decode program reads (ISSUE 38) -

@@ -313,7 +313,8 @@ def _mixer_and_attention(cfg: HybridSSMConfig, p, h, layer, pool,
         k = rope(k.reshape(R, 1, Hkv, Dh), positions, cfg.rope_theta)[:, 0]
         v = lin(a, p["wv"], "wv").reshape(R, Hkv, Dh)
         kp, vp = paged_kv_write(pool["k"], pool["v"], k, v, table, pos,
-                                active=active, layer=layer)
+                                active=active, layer=layer,
+                                shared_table=shared_table)
         if shared_table:
             attn = gqa_prefill_paged(q, kp, vp, table[0], kv_len,
                                      layer=layer)

@@ -403,7 +403,8 @@ def _gated_attention(rank: int, cfg: LinearAttnMoEConfig, p, h, layer, pool,
         counts = {"attn_full_keys": jnp.sum(
             jnp.where(live_rows(kv_len, active), kv_len, 0)).astype(jnp.int32)}
         kp, vp = paged_kv_write(pool["k"], pool["v"], k, v, table, pos,
-                                active=active, layer=mine)
+                                active=active, layer=mine,
+                                shared_table=shared_table)
         if shared_table:
             attn = gqa_prefill_paged(q, kp, vp, table[0], kv_len, layer=mine)
         else:

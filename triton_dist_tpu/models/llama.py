@@ -628,7 +628,8 @@ def _gqa_attention(cfg: LlamaConfig, p, h, layer, pool, block_table, pos,
     v = lin(h, p["wv"], "wv").reshape(R, 1, Hkv, Dh)[:, 0]
     if attn_io is None:
         kp, vp = paged_kv_write(kp, vp, k, v, block_table, pos,
-                                active=active, layer=layer)
+                                active=active, layer=layer,
+                                shared_table=shared_table)
         if shared_table:
             attn = gqa_prefill_paged(q, kp, vp, block_table[0], kv_len,
                                      layer=layer)
@@ -883,8 +884,14 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     share ONE block-table row:
 
     - KV lands straight in the pool via ``paged_kv_write`` (pos = the
-      absolute token position, ``active`` masks the padded tail onto the
-      scratch page) — no temporary contiguous cache.
+      absolute token position) — no temporary contiguous cache. The rows
+      are ONE sequence's run, so they land a PAGE at a time
+      (``shared_table=True``: at most ``C / page_size + 1`` pages a pool
+      and layer are read, merged and written back where a row scatter
+      paid for each of ``C x Hkv`` rows: PERF.md section 6, PR 47);
+      ``active`` masks the padded tail, whose rows write NOTHING (the
+      ``attn_io`` hook's rows of decode still park theirs on the scratch
+      page).
     - attention is the family's paged walk with per-row
       ``kv_len = position + 1``: each query attends ALL pages filled so
       far — the pages of every previous chunk plus this chunk's own
@@ -924,8 +931,8 @@ def prefill_chunk_paged(params: dict, tokens: jax.Array, start: jax.Array,
     C = tokens.shape[0]
     idx = start.astype(jnp.int32) + jnp.arange(C, dtype=jnp.int32)   # [C]
     valid = idx < prompt_len                                         # [C]
-    # padded rows park on the scratch page: position 0 keeps the block-
-    # table lookup in range, active=False reroutes the write to page 0
+    # padded rows reach no live page: position 0 keeps the block-table
+    # lookup in range, active=False masks the write
     pos = jnp.where(valid, idx, 0).astype(jnp.int32)
     kv_len = jnp.where(valid, idx + 1, 0).astype(jnp.int32)
     bt = jnp.broadcast_to(block_table[None, :], (C, block_table.shape[0]))

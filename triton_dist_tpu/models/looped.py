@@ -209,7 +209,8 @@ def _attention(cfg: LoopedConfig, p, h, plane, pool, block_table, pos,
                                          ).astype(jnp.int32),
               "loop_row_calls": jnp.sum(live).astype(jnp.int32)}
     kp, vp = paged_kv_write(pool["k"], pool["v"], k, v, block_table, pos,
-                            active=active, layer=write_plane(cfg, plane))
+                            active=active, layer=write_plane(cfg, plane),
+                            shared_table=shared_table)
     read = read_plane(cfg, plane, shared_table)
     if shared_table:
         attn = gqa_prefill_paged(q, kp, vp, block_table[0], kv_len,

@@ -350,7 +350,8 @@ def _full_attention(rank, cfg: ShortConvMoEConfig, p, h, layer, pool,
         counts = {"attn_full_keys": jnp.sum(
             jnp.where(live_rows(kv_len, active), kv_len, 0)).astype(jnp.int32)}
         kv, _ = paged_kv_write(pool["kv"], None, k, v, table, pos,
-                               active=active, layer=mine)
+                               active=active, layer=mine,
+                               shared_table=shared_table)
         if shared_table:
             attn = gqa_prefill_paged(q, kv, None, table[0], kv_len,
                                      layer=mine)

@@ -366,7 +366,8 @@ def _attention(kind: str, rank: int, cfg: WindowMoEConfig, p, h, layer, pool,
         counts = {counter: jnp.sum(jnp.where(live, attended, 0)
                                    ).astype(jnp.int32)}
         kp, vp = paged_kv_write(pool[names[0]], pool[names[1]], k, v, table,
-                                at, active=active, layer=mine)
+                                at, active=active, layer=mine,
+                                shared_table=shared_table)
         if shared_table:
             attn = gqa_prefill_paged(
                 q, kp, vp, table[0], kv_len, layer=mine, window=window,

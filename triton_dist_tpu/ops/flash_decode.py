@@ -968,9 +968,10 @@ def _prefill_walk(q, k_pages, v_pages, block_table, kv_len, layer, sinks, *,
 def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
                    k_new: jax.Array, v_new: jax.Array,
                    block_table: jax.Array, pos: jax.Array,
-                   active: jax.Array | None = None, layer=None
+                   active: jax.Array | None = None, layer=None,
+                   shared_table: bool = False
                    ) -> tuple[jax.Array, jax.Array]:
-    """Scatter one new (k, v) row per batch slot into the page pool:
+    """Put one new (k, v) row per batch slot into the page pool:
     page ``block_table[b, pos_b // page_size]``, row ``pos_b % page_size``.
 
     k/v_pages [P, Hkv, page_size, D], or with ``layer`` (a traced or Python
@@ -992,7 +993,7 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     cursor — overwritten by the next dispatch's writes before any read,
     the same argument that makes in-page padding tails safe.
 
-    The write is a scatter of ROWS of the pool's row-major 2-D view
+    Either form writes through the pool's row-major 2-D view
     ``[(L*)P*Hkv*page_size, D]`` (the reshape is a bitcast, and a 2-D array
     leaves the TPU layout pass nothing to choose), so the pool keeps the
     one layout ``gqa_decode_paged`` reads. The window scatter
@@ -1000,23 +1001,46 @@ def paged_kv_write(k_pages: jax.Array, v_pages: jax.Array,
     makes the compiler hold the pool slot-major of head and re-lay it out
     around every kernel call: half of the decode program's device time
     until PR 25 (``tests/test_aot_topology.py`` holds the compiled programs
-    to "no pool-shaped copy"). It keeps that form's rule for stray page
-    ids: one outside the pool drops the write, a negative one counts from
-    the end. ``v_pages`` None: ``k_pages`` holds ``[K | V]`` rows; the row
-    written is ``[k_new | v_new]`` and the result ``(pool, None)``.
+    to "no pool-shaped copy"). Which form follows what the rows ARE:
+
+    - rows of DIFFERENT sequences (``shared_table`` False: decode slots,
+      speculative verify rows, the sharded decode paths): a scatter of
+      ``B * Hkv`` rows of the view, 0.07 us a row on a v5e whatever the
+      row's width.
+    - ONE sequence's RUN (``shared_table``: a prefill chunk; every row of
+      ``block_table`` is the same row, every active row i sits at
+      ``pos[i] = p + i`` for one ``p``, wrapped by the caller where the
+      table is a ring): a
+      page of one layer is ``Hkv * page_size`` CONSECUTIVE rows of the
+      view, and C positions touch at most ``(C - 2) // page_size + 2``
+      pages, so the rows land a PAGE at a time (``_write_run``): a page is
+      read, the run's rows are selected into it, the page is written
+      back. Masked-off rows write NOTHING there (the scratch page keeps
+      its bytes, which are unspecified and never read): every page but
+      page 0 holds what the scatter would have left, bit for bit.
+
+    Both keep the window scatter's rule for stray page ids: one outside
+    the pool drops the write, a negative one counts from the end.
+    ``v_pages`` None: ``k_pages`` holds ``[K | V]`` rows; the row written
+    is ``[k_new | v_new]`` and the result ``(pool, None)``. Keys and values
+    may differ in width (k_new [B, Hkv, Dk], v_new [B, Hkv, Dv]): a row's
+    index in the two 2-D views is the same.
     """
     assert (layer is not None) == (k_pages.ndim == 5), (
         "layer= goes with a stacked [L, P, Hkv, page_size, D] pool")
-    idx = _page_row_index(k_pages.shape, block_table, pos, active, layer)
     if v_pages is None:
-        # ONE pool of ``[K | V]`` rows (``gqa_decode_paged``): one scatter
-        return _write_rows(k_pages, jnp.concatenate([k_new, v_new], -1),
-                           idx), None
-    # keys and values may differ in width (k_new [B, Hkv, Dk], v_new [B,
-    # Hkv, Dv]): a row's index in the two 2-D views is the same
-    assert k_pages.shape[:-1] == v_pages.shape[:-1], (k_pages.shape,
-                                                      v_pages.shape)
-    return _write_rows(k_pages, k_new, idx), _write_rows(v_pages, v_new, idx)
+        # ONE pool of ``[K | V]`` rows (``gqa_decode_paged``): one write
+        pools, news = (k_pages,), (jnp.concatenate([k_new, v_new], -1),)
+    else:
+        assert k_pages.shape[:-1] == v_pages.shape[:-1], (k_pages.shape,
+                                                          v_pages.shape)
+        pools, news = (k_pages, v_pages), (k_new, v_new)
+    if shared_table:
+        out = _write_run(pools, news, block_table, pos, active, layer)
+    else:
+        idx = _page_row_index(k_pages.shape, block_table, pos, active, layer)
+        out = tuple(_write_rows(p, n, idx) for p, n in zip(pools, news))
+    return out if v_pages is not None else (out[0], None)
 
 
 def _page_row_index(pool_shape, block_table, pos, active, layer):
@@ -1043,6 +1067,68 @@ def _write_rows(pool, new, idx):
     D = pool.shape[-1]
     return pool.reshape(-1, D).at[idx].set(
         new.reshape(-1, D), mode="drop").reshape(pool.shape)
+
+
+def _write_run(pools, news, block_table, pos, active, layer):
+    """``paged_kv_write`` for ONE sequence's run of positions, into every
+    pool of ``pools`` (same ``[(L,) P, Hkv, page_size]``, any width). Each
+    page the run touches (C positions that start on a page's last row reach
+    ``(C - 2) // page_size + 2``) is read, its live rows replaced head-major
+    as the page holds them, and written back as ``Hkv * page_size`` rows of
+    the 2-D view; the loop ends with the last page that has a live row. A
+    visit's page is that of its first live row; a visit with none or with a
+    stray id is aimed at page 0 and changes nothing. Two things the speed
+    depends on (PERF.md section 6, PR 47): the rows stay the 2-D ``[C, Hkv *
+    D]`` their projection yields until a visit slices its page's worth (a
+    head-major copy of all of them makes the compiler re-lay out the
+    projection's weights), and a page's first row is an UNSIGNED product
+    with ``Hkv * page_size`` made inside the loop, which the compiler knows
+    aligned and never wraps."""
+    C = pos.shape[0]
+    P_pool, Hkv, page_size = pools[0].shape[-4:-1]
+    V = (C + page_size - 2) // page_size + 1
+    live = jnp.ones((C,), jnp.bool_) if active is None else active
+    first = jnp.argmax(live).astype(jnp.int32)
+    off = (pos[first] - first) % page_size
+    # visit j holds positions [j * page_size - off, (j + 1) * page_size - off)
+    # of the run: its mask, its first live row, that row's page
+    mask = lax.dynamic_update_slice(
+        jnp.zeros((V * page_size,), jnp.bool_), live, (off,)
+    ).reshape(V, page_size)
+    visits = jnp.arange(V, dtype=jnp.int32)
+    row = jnp.clip(visits * page_size - off
+                   + jnp.argmax(mask, 1).astype(jnp.int32), 0, C - 1)
+    page = block_table[row, pos[row] // page_size]           # [V]
+    page = jnp.where(page < 0, page + P_pool, page)
+    visited = mask.any(1) & (page >= 0) & (page < P_pool)
+    page = jnp.where(visited, page, 0)
+    if layer is not None:
+        page = jnp.asarray(layer, jnp.int32) * P_pool + page
+    page = page.astype(jnp.uint32)
+    keep = (mask & visited[:, None])[:, None, :, None]      # [V, 1, page, 1]
+    # a page of padding before the rows and the last visit's worth behind
+    rows_of = [jnp.pad(new.reshape(C, -1),
+                       ((page_size, V * page_size - C), (0, 0)))
+               for new in news]
+
+    def visit(j, flat):
+        out = []
+        for view, rows in zip(flat, rows_of):
+            D = view.shape[-1]
+            at = (page[j] * (Hkv * page_size), jnp.uint32(0))
+            mine = lax.dynamic_slice_in_dim(
+                rows, (j + 1) * page_size - off, page_size
+            ).reshape(page_size, Hkv, D).swapaxes(0, 1)
+            old = lax.dynamic_slice(view, at, (Hkv * page_size, D))
+            out.append(lax.dynamic_update_slice(view, jnp.where(
+                keep[j], mine, old.reshape(Hkv, page_size, D)
+            ).reshape(Hkv * page_size, D), at))
+        return tuple(out)
+
+    flat = lax.fori_loop(
+        0, jnp.max(jnp.where(visited, visits + 1, 0)), visit,
+        tuple(p.reshape(-1, p.shape[-1]) for p in pools))
+    return tuple(f.reshape(p.shape) for f, p in zip(flat, pools))
 
 
 def paged_rows_write(pool: jax.Array, new: jax.Array,

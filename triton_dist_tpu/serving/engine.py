@@ -799,7 +799,7 @@ class ServingEngine:
         sp = len(req.prompt)
         start = req.prefill_cursor
         # the chunk this step actually advances: c_eff real tokens; the
-        # compiled program masks rows past n_eff onto the scratch page
+        # compiled program masks rows past n_eff (they write nothing)
         n_eff = min(start + c_eff, sp)
         toks = np.zeros(C, np.int32)
         part = req.prompt[start:n_eff]

@@ -7,10 +7,16 @@ Two cleanly separated halves:
   arrays the engine threads through its jitted step, donated. The pool has
   one layout (row-major) and one home: the programs carry it whole through
   their layer loop, ``paged_kv_write(layer=)`` writes the new rows into it
-  in place and ``gqa_decode_paged(layer=)`` streams pages out of it in
-  place, so a step moves the rows it writes and the pages it reads and no
-  other byte of the pool (``tests/test_aot_topology.py`` holds the compiled
-  programs to that). Nothing here ever looks at the arrays' values.
+  in place (decode rows, each of its own sequence, as a scatter of rows; a
+  prefill chunk's run of ONE sequence page by page, whole pages that the
+  request owns alone: the engine's copy-on-write guard covers every page
+  of a chunk before it is launched) and ``gqa_decode_paged(layer=)``
+  streams pages out of it in place, so a step moves the rows or pages it
+  writes and the pages it reads and no other byte of the pool
+  (``tests/test_aot_topology.py`` holds the compiled programs to that).
+  Page 0 is scratch: parked decode rows land there, a chunk's masked rows
+  land nowhere, and nothing live ever reads it. Nothing here ever looks at
+  the arrays' values.
 - **host accounting** (this module): ``KVPagePool`` — a free-list over
   page ids with per-sequence ownership, allocate-on-decode growth and
   free-on-finish. Pure Python, deterministic (LIFO free list), microsecond
